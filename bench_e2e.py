@@ -16,7 +16,9 @@ legitimately start from populated tiers. Raw configs (1, 2) ingest
 through ``tsdb.add_points``.
 
 Usage: python bench_e2e.py [--cpu] [--configs 1,2,3,4] [--repeats N]
-Prints one JSON line per config plus a summary line.
+Prints one JSON line per config plus a summary line. Every row names
+the platform, device kind and device count it ran on; without --cpu a
+default backend that is not a TPU is an error.
 """
 
 from __future__ import annotations
@@ -2567,8 +2569,21 @@ def main() -> None:
     if args.cpu:
         import os
         os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
+    import jax
+    if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "device_kind": devices[0].device_kind,
+              "device_count": len(devices)}
+    if not args.cpu and device["platform"] != "tpu":
+        sys.exit(f"bench_e2e.py: no TPU (default backend is "
+                 f"{device['platform']!r}); pass --cpu for a CPU "
+                 f"debug run, whose rows are not device numbers")
+    # a failed native build fails the benchmark here: no runner that
+    # asks for the native store can fall back to the Python twin
+    from opentsdb_tpu.native.store_backend import load_library
+    load_library()
 
     runners = {1: bench_config1, 2: bench_config2,
                3: lambda r: bench_config3(r, args.series3),
@@ -2589,6 +2604,7 @@ def main() -> None:
         t0 = time.perf_counter()
         res = runners[c](args.repeats)
         res["total_s"] = round(time.perf_counter() - t0, 1)
+        res.update(device)
         out.append(res)
         print(json.dumps(res), flush=True)
     ns = [r for r in out if r.get("config") == 3]

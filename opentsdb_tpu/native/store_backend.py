@@ -11,6 +11,7 @@ Python backend when no compiler is available.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,7 +23,10 @@ from opentsdb_tpu.core import const
 from opentsdb_tpu.core.store import MetricIndex, PaddedBatch, PointBatch
 
 _SRC = os.path.join(os.path.dirname(__file__), "tsdbstore.cc")
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "libtsdbstore.so")
+_LIB_DIR = os.path.dirname(__file__)
+_CXX = "g++"
+_CXXFLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
+             "-pthread")
 _lib = None
 _build_error: str | None = None  # negative cache for failed builds
 _lib_lock = threading.Lock()
@@ -32,26 +36,66 @@ class NativeBuildError(RuntimeError):
     pass
 
 
-def build_library(force: bool = False) -> str:
-    """Compile libtsdbstore.so if needed; returns its path.
-
-    Built on demand on the host that uses it (-march=native is safe
-    because the .so never ships to another machine); staleness checks
-    both the C++ source and THIS file (the build flags live here)."""
-    newest_src = max(os.path.getmtime(_SRC), os.path.getmtime(__file__))
-    if not force and os.path.isfile(_LIB_PATH) and \
-            os.path.getmtime(_LIB_PATH) >= newest_src:
-        return _LIB_PATH
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-           "-std=c++17", "-pthread", _SRC, "-o", _LIB_PATH]
+def _build_key() -> str:
+    """Content hash of what the library is made from: the C++ source,
+    the flags, and what ``-march=native`` resolves to on THIS machine
+    (the compiler's own account of its target). The key is part of the
+    library's file name, so a library carried over from another
+    machine, an older source or other flags is never loaded — mtimes
+    say nothing about any of those."""
     try:
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=180)
+        target = subprocess.run(
+            [_CXX, *_CXXFLAGS, "-Q", "--help=target"],
+            capture_output=True, text=True, timeout=60)
     except (OSError, subprocess.TimeoutExpired) as e:
-        raise NativeBuildError(f"g++ unavailable: {e}") from e
-    if proc.returncode != 0:
-        raise NativeBuildError(f"native build failed:\n{proc.stderr}")
-    return _LIB_PATH
+        raise NativeBuildError(f"{_CXX} unavailable: {e}") from e
+    if target.returncode != 0:
+        raise NativeBuildError(
+            f"{_CXX} cannot report its target:\n{target.stderr}")
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as fh:
+        h.update(fh.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(target.stdout.encode())
+    return h.hexdigest()[:16]
+
+
+def build_library() -> str:
+    """Compile the library for this machine if the one made from the
+    current source, flags and CPU is not on disk; returns its path
+    (``libtsdbstore.<key>.so``, see :func:`_build_key`)."""
+    lib_path = os.path.join(_LIB_DIR, f"libtsdbstore.{_build_key()}.so")
+    if os.path.isfile(lib_path):
+        return lib_path
+    # build beside the target and rename: a concurrent process (a
+    # router and its shards boot together) sees no library or a whole
+    # one, never a half-written file
+    tmp_path = f"{lib_path}.{os.getpid()}.tmp"
+    try:
+        try:
+            proc = subprocess.run(
+                [_CXX, *_CXXFLAGS, _SRC, "-o", tmp_path],
+                capture_output=True, text=True, timeout=180)
+        except (OSError, subprocess.TimeoutExpired) as e:
+            raise NativeBuildError(f"{_CXX} unavailable: {e}") from e
+        if proc.returncode != 0:
+            raise NativeBuildError(
+                f"native build failed:\n{proc.stderr}")
+        os.replace(tmp_path, lib_path)
+    finally:
+        if os.path.exists(tmp_path):
+            os.unlink(tmp_path)
+    # libraries of other keys can never be loaded again from here
+    for name in os.listdir(_LIB_DIR):
+        if name.startswith("libtsdbstore") and name.endswith(".so") \
+                and os.path.join(_LIB_DIR, name) != lib_path:
+            try:
+                os.unlink(os.path.join(_LIB_DIR, name))
+            except OSError:
+                # tsdlint: allow[swallow] best-effort tidy-up; another
+                # process may have removed it or still map it
+                pass
+    return lib_path
 
 
 def load_library():

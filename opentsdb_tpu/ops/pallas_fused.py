@@ -91,18 +91,69 @@ _SPAN_MAX = 8
 _SPAN_GROUP_MAX = 1024
 
 
-def supported(spec, dtype) -> bool:
-    """Can the kernel run this (ds_function, agg, rate) combination?"""
-    if spec.ds_function not in _DS_FNS or spec.agg_name not in _AGG_FNS:
-        return False
-    if spec.emit_raw or spec.num_groups > _MAX_GROUPS:
-        return False
+class KernelCounters:
+    """How the regular-cadence path was executed in this process,
+    as ``/api/health`` reports it (``device.pallas``): the kernel
+    compiled by Mosaic, the kernel in interpret mode (any backend but
+    TPU), or the XLA dense path taken in its place, by reason."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.compiled = 0
+        self.interpreted = 0
+        # tsdlint: allow[unbounded-growth] keyed by the closed set of
+        # reasons unsupported_reason() and its caller can return
+        self.dense_instead: dict[str, int] = {}
+
+    def ran(self, interpret: bool) -> None:
+        with self._lock:
+            if interpret:
+                self.interpreted += 1
+            else:
+                self.compiled += 1
+
+    def replaced(self, why: str) -> None:
+        with self._lock:
+            self.dense_instead[why] = self.dense_instead.get(why, 0) + 1
+
+    def as_dict(self) -> dict:
+        with self._lock:
+            return {"compiled": self.compiled,
+                    "interpreted": self.interpreted,
+                    "dense_instead": dict(self.dense_instead)}
+
+
+COUNTERS = KernelCounters()
+
+
+def _platform(device) -> str:
+    """Platform the operands will be committed to: the given device's,
+    or the default backend's when placement is left to jit."""
+    return device.platform if device is not None \
+        else jax.default_backend()
+
+
+def unsupported_reason(spec, dtype, device=None) -> str | None:
+    """Why the kernel cannot run this (ds_function, agg, rate)
+    combination on ``device``, or None when it can."""
+    if spec.ds_function not in _DS_FNS:
+        return f"ds_function:{spec.ds_function}"
+    if spec.agg_name not in _AGG_FNS:
+        return f"aggregator:{spec.agg_name}"
+    if spec.emit_raw:
+        return "emit_raw"
+    if spec.num_groups > _MAX_GROUPS:
+        return f"groups>{_MAX_GROUPS}"
     if spec.rate and spec.rate_drop_resets:
-        return False  # re-opens NaN holes mid-pipeline
-    if jnp.dtype(dtype) == jnp.float64 and \
-            jax.default_backend() == "tpu":
-        return False  # MXU has no f64
-    return True
+        return "rate_drop_resets"  # re-opens NaN holes mid-pipeline
+    if jnp.dtype(dtype) == jnp.float64 and _platform(device) == "tpu":
+        return "float64_on_tpu"  # MXU has no f64
+    return None
+
+
+def supported(spec, dtype, device=None) -> bool:
+    """Can the kernel run this (ds_function, agg, rate) combination?"""
+    return unsupported_reason(spec, dtype, device) is None
 
 
 def _span_fixed_bytes(g: int, b: int, itemsize: int) -> int:
@@ -487,7 +538,7 @@ def prepare(values2d: np.ndarray, bucket_ts: np.ndarray,
     tile_s = _tile_s(s, p, spec.num_groups, np_dtype.itemsize,
                      span=allow_span, b=spec.num_buckets)
     s_pad = -(-s // tile_s) * tile_s
-    interpret = jax.default_backend() != "tpu"
+    interpret = _platform(device) != "tpu"
     split = (force_split or not interpret) and np_dtype == np.float32
     a_mat = _build_membership(
         spec, k, np.float32 if split else np_dtype)
@@ -549,4 +600,6 @@ def fused_dense_pipeline(values2d: np.ndarray, bucket_ts: np.ndarray,
     rp = jnp.asarray([[cm, rv]], dtype)
     result, emit = _run(*args, spec=spec, tile_s=tile_s,
                         interpret=interpret, rate_params=rp)
-    return np.asarray(result), np.asarray(emit)
+    out = np.asarray(result), np.asarray(emit)
+    COUNTERS.ran(interpret)
+    return out

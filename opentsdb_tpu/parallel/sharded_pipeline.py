@@ -29,17 +29,8 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6 exposes it under experimental, where
-    # the replication check is spelled check_rep instead of check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_old(*args, **kwargs)
 
 from opentsdb_tpu.parallel.distributed import to_host as _to_host
 

@@ -4,8 +4,7 @@ The reference keeps hot HBase blocks in the region server's block
 cache so repeated scans don't touch disk; the TPU-native analogue
 keeps the query's pre-bucketized ``[S, B]`` grids resident in device
 HBM so repeated queries over the same window don't re-scan the host
-store or re-upload (host->device transfer is the dominant cost of a
-warm query — on shared/tunneled devices by an order of magnitude).
+store or re-upload.
 
 Entries are keyed by the exact reduction parameters and invalidated by
 the store's mutation version (every write or delete bumps it), so a
@@ -85,6 +84,22 @@ class DeviceGridCache:
         with self._lock:
             self._entries.clear()
             self._bytes = 0
+
+    def placement(self) -> dict[str, Any]:
+        """Where the resident arrays really sit: bytes per device over
+        every cached entry's shards. A mesh that claims four devices
+        while its grids sit on the first shows up here."""
+        by_device: dict[str, int] = {}
+        with self._lock:
+            entries = [e[1] for e in self._entries.values()]
+        for arrays in entries:
+            for a in arrays:
+                for x in getattr(a, "arrays", None) or (a,):
+                    for shard in getattr(x, "addressable_shards", ()):
+                        name = str(shard.device)
+                        by_device[name] = by_device.get(name, 0) \
+                            + shard.data.nbytes
+        return {"entries": len(entries), "bytes_by_device": by_device}
 
     def collect_stats(self, collector) -> None:
         collector.record(f"{self.stat_prefix}.bytes", self._bytes)

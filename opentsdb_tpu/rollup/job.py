@@ -36,8 +36,8 @@ from opentsdb_tpu.rollup.config import RollupConfig, RollupInterval
 ROLLUP_AGGS = ("sum", "count", "min", "max")
 
 # device cell budget per tile and bucket cap per window. Wider windows
-# amortize per-dispatch latency (the dominant cost on relayed devices);
-# the cap bounds the [S, B] output grids and the coarsen one-hot.
+# mean fewer dispatches; the cap bounds the [S, B] output grids and
+# the coarsen one-hot.
 _TILE_CELL_BUDGET = 64_000_000
 _MAX_WINDOW_BUCKETS = 360
 
@@ -267,10 +267,9 @@ def _rollup_window_native(tsdb, chunk, row_off: int, start_ms: int,
     """Storage-side tile: the C++ fused range-scan produces the base
     tier's sum/count/min/max grids directly (``tss_bucket_reduce``),
     and nested tiers coarsen by reshape reductions on the host — the
-    raw points never leave the storage arena. On hosts feeding a
-    remote/tunneled device this beats the device tiles by the full
-    transfer cost (the job is a pure reduction; there is no reuse to
-    amortize an upload against). Same output contract as
+    raw points never leave the storage arena (the job is a pure
+    reduction; there is no reuse to amortize an upload against). Same
+    output contract as
     :func:`_rollup_window`."""
     bucket_ts = ds_mod.fixed_bucket_edges(start_ms, end_ms,
                                           base.interval_ms)

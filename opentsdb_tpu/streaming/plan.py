@@ -1288,7 +1288,9 @@ class PlanView:
         cached = self._tail_cache
         if cached is not None and cached[0] == key:
             return cached[1]
-        from opentsdb_tpu.ops.pipeline import PipelineSpec, execute_grid
+        from opentsdb_tpu.ops.pipeline import (PipelineSpec,
+                                               execute_grid,
+                                               host_cpu_device)
         sub = self.sub
         spec = PipelineSpec(
             num_series=grid.shape[0], num_buckets=len(edges),
@@ -1301,10 +1303,9 @@ class PlanView:
             rate_counter=sub.rate_options.counter,
             rate_drop_resets=sub.rate_options.drop_resets,
             emit_raw=emit_raw, host=True)
-        import jax
-        cpu = jax.devices("cpu")[0]
         result, emit = execute_grid(grid, present, edges, group_ids,
-                                    spec, sub.rate_options, device=cpu)
+                                    spec, sub.rate_options,
+                                    device=host_cpu_device())
         out = (np.asarray(result), np.asarray(emit, dtype=bool))
         self._tail_cache = (key, out)
         return out

@@ -2134,6 +2134,10 @@ class HttpRpcRouter:
             # materializations, tenant shares, placement plan counters
             "control": control_info,
             "hook_errors": hook_errors,
+            # what this process runs on: platform, device kind and
+            # count, compile cache, storage backend, mesh, warm-up,
+            # kernel execution counts (TSDB.device_info)
+            "device": t.device_info(),
         }
         server = self.server
         if server is not None:

@@ -1,6 +1,6 @@
 """Host-tail fast path: small [S, B] grids run the fill/rate/aggregate
-tail on the host CPU backend instead of the (possibly remote/tunneled)
-accelerator — engine.host_tail_device. On the CPU test matrix the
+tail on the host CPU backend instead of the accelerator —
+engine.host_tail_device. On the CPU test matrix the
 default backend IS cpu, so these tests pin the decision logic and the
 committed-device plumbing (cache placement + execute), and the
 equivalence of results with the path forced off."""
