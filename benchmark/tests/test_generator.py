@@ -1,0 +1,103 @@
+"""The generator: the same seed gives the same bytes, and the seed
+changes values and order, never a count."""
+
+import collections
+
+import numpy as np
+import pytest
+from conftest import load, tiny_config
+
+import gen
+import traffic
+
+SEEDS = (0, 7, 2**31 + 11)
+
+
+@pytest.mark.parametrize("config", ["fleet-1m", "live-100k"])
+def test_same_seed_same_bytes(config):
+    data = gen.Data(tiny_config(config)["data"])
+    a = gen.chunk_lines(data, 2**31 + 11, 1)
+    b = gen.chunk_lines(data, 2**31 + 11, 1)
+    assert a[0] == b[0] and a[2] == b[2]
+    assert np.array_equal(a[1], b[1], equal_nan=True)
+    assert gen.chunk_lines(data, 5, 1)[0] != a[0]
+
+
+@pytest.mark.parametrize("config", ["fleet-1m", "live-100k"])
+def test_lines_say_what_the_reference_holds(config):
+    data = gen.Data(tiny_config(config)["data"])
+    text, values, points = gen.chunk_lines(data, 3, 3)
+    lines = text.decode().splitlines()
+    assert len(lines) == points == int((~np.isnan(values)).sum())
+    seen = collections.defaultdict(dict)
+    for ln in lines:
+        metric, ts, val, *tags = ln.split()
+        assert metric == data.metric
+        seen[tuple(tags)][int(ts)] = float(val)
+    lo = 3 * data.chunk_series
+    assert len(seen) == values.shape[0]
+    for tags, pts in seen.items():
+        t = dict(x.split("=") for x in tags)
+        i = data.tag_index("host", t["host"])
+        assert t["dc"] == data.tag_name("dc", i % data.dcs)
+        assert t["rack"] == data.tag_name("rack", i % data.racks)
+        assert t["fleet"] == data.tag_name(
+            "fleet", (i // 100) % data.fleets)
+        row = values[i - lo]
+        want = {data.t0 + data.cadence_s * j: row[j]
+                for j in range(data.points) if not np.isnan(row[j])}
+        assert pts == pytest.approx(want)
+
+
+@pytest.mark.parametrize("config", ["fleet-1m", "live-100k"])
+def test_counts_do_not_depend_on_the_seed(config):
+    data = gen.Data(tiny_config(config)["data"])
+    shapes = set()
+    for seed in SEEDS:
+        gappy = total = 0
+        sizes = collections.Counter()
+        for c in range(data.chunks):
+            idx, cents, drop = gen.chunk_values(data, seed, c)
+            assert cents.shape == drop.shape == (len(idx), data.points)
+            # points drop in the gappy tenth and nowhere else
+            assert not drop[~data.is_gappy(idx)].any()
+            gappy += int(data.is_gappy(idx).sum())
+            total += len(idx)
+            sizes.update(data.tag_ids("dc", idx).tolist())
+        shapes.add((gappy, total, tuple(sorted(sizes.items()))))
+    assert len(shapes) == 1
+    (gappy, total, sizes), = shapes
+    assert total == data.series and gappy == data.series // 10
+    assert {n for _dc, n in sizes} == {data.series // data.dcs}
+
+
+@pytest.mark.parametrize("cell", ["fleet-1m.wide-groupby",
+                                  "live-100k.groupby-quiet",
+                                  "fleet-1m.small-panels"])
+def test_requests_per_template_do_not_depend_on_the_seed(bench, cell):
+    w = next(x for x in bench["workloads"] if x["name"] == cell)
+    data = gen.Data(tiny_config(w["config"])["data"])
+    spec = load(f"benchmark/traffic/{w['traffic']}.json")
+    seen = set()
+    bodies = []
+    for seed in SEEDS:
+        t = traffic.Traffic(spec, data, seed, bench["run_seconds"])
+        count = collections.Counter(r.template for r in t.timed)
+        sizes = collections.Counter(len(r.body) for r in t.timed)
+        seen.add((tuple(sorted(count.items())),
+                  tuple(sorted(sizes.items())), len(t.warmup),
+                  len(t.writes), tuple(len(r.body) > 0
+                                       for r in t.writes)))
+        bodies.append([r.body for r in t.timed])
+        # one cost class: every request of the cell has the same shape
+        assert len(count) == 1 and len(sizes) == 1
+        # and no request repeats, so no cache can answer it
+        assert len(set(bodies[-1])) == len(bodies[-1])
+        if t.loop == "open":
+            due = [r.due_s for r in t.timed]
+            assert due == sorted(due) and 0 <= due[0] \
+                and due[-1] < bench["run_seconds"]
+    assert len(seen) == 1
+    assert bodies[0] != bodies[1]
+    again = traffic.Traffic(spec, data, SEEDS[0], bench["run_seconds"])
+    assert [r.body for r in again.timed] == bodies[0]
