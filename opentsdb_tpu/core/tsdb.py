@@ -268,7 +268,9 @@ class TSDB:
         self._wal_applied_seq = 0
         if self.data_dir:
             from opentsdb_tpu.core import persist
-            persist.load_store(self, self.data_dir)
+            from opentsdb_tpu.obs.trace import RUNTIME
+            with RUNTIME.phase("snapshot_load"):
+                persist.load_store(self, self.data_dir)
             if self.config.get_bool("tsd.storage.wal.enable", True):
                 from opentsdb_tpu.core.wal import WriteAheadLog
                 from opentsdb_tpu.utils.faults import RetryPolicy
@@ -302,7 +304,8 @@ class TSDB:
                             self.rollup_store._tiers.items():
                         wal.seed_known(f"tier:{iv}:{agg}",
                                        st.num_series())
-                recovered = wal.replay(self, self._wal_applied_seq)
+                with RUNTIME.phase("wal_replay"):
+                    recovered = wal.replay(self, self._wal_applied_seq)
                 if recovered:
                     logging.getLogger("tsdb").info(
                         "WAL replay recovered %d points", recovered)
@@ -686,6 +689,22 @@ class TSDB:
         """
         if self.mode == "ro":
             raise PermissionError("TSD is in read-only mode")
+        from opentsdb_tpu.obs import trace as trace_mod
+        if trace_mod.current() is not None:
+            return self._import_buffer(buf, on_error, durable)
+        # a loader outside any request (``tsdb import``, a plugin at
+        # start-up): root a sampled background trace, so that the
+        # stages below record and feed their histograms
+        ctx = self.tracer.start_background("ingest.import", sample=True,
+                                           bytes=len(buf))
+        try:
+            with trace_mod.use(ctx):
+                return self._import_buffer(buf, on_error, durable)
+        finally:
+            self.tracer.finish(ctx)
+
+    def _import_buffer(self, buf: bytes, on_error,
+                       durable: bool) -> tuple[int, list[str]]:
         from opentsdb_tpu.native.store_backend import (IMPORT_ERRORS,
                                                        parse_import_buffer)
         from opentsdb_tpu.obs.trace import trace_begin, trace_end
@@ -701,6 +720,13 @@ class TSDB:
         for i in np.nonzero(parsed.errors > 0)[0].tolist():
             fail(i + 1, IMPORT_ERRORS.get(int(parsed.errors[i]),
                                           "parse error"))
+        if _h_dec is not None:
+            _h_dec.tag(lines=int(parsed.num_lines)
+                       if hasattr(parsed, "num_lines")
+                       else len(parsed.ts))
+        trace_end(_h_dec)
+        _h_res = trace_begin("ingest.resolve",
+                             groups=int(parsed.num_groups))
         # resolve each distinct series once. The parser already
         # enforced the reference's charset/shape rules (code 5), so no
         # per-name re-validation here.
@@ -739,12 +765,7 @@ class TSDB:
         for g in failed:
             for i in np.nonzero(parsed.group_ids == g)[0].tolist():
                 fail(i + 1, str(ginfo[g]))
-        if _h_dec is not None:
-            _h_dec.tag(lines=int(parsed.num_lines)
-                       if hasattr(parsed, "num_lines")
-                       else len(parsed.ts),
-                       groups=int(parsed.num_groups))
-        trace_end(_h_dec)
+        trace_end(_h_res)
         written = 0
         if use_hooks:
             # per-point hooks are inherently per-datapoint: group runs

@@ -32,6 +32,17 @@ Span names form a CLOSED registry (:data:`KNOWN_SPANS`, the
 and tsdlint's ``trace-sites`` pass enforces it statically (plus
 reports registered-but-never-started names as stale).
 
+Spans NEST: a span begun without an explicit ``parent=`` hangs off the
+innermost span still open on the same thread in the same context, so
+``query.plan`` .. ``query.assemble`` are children of ``query.execute``
+and :meth:`Tracer.finish` can compute each parent's SELF time
+(duration minus the union of its children): what no child names.
+
+Beside the tracer sits the process's :data:`RUNTIME`: the
+device-occupancy clock (:class:`DeviceClock`, on the spans' own
+clock, so every idle millisecond lands on a host stage), JAX's
+compile events, the collector's pauses and the start-up phases.
+
 The query-shape log is the explicit precursor to workload-adaptive
 summaries (ROADMAP item 5 / Storyboard): each committed ``query.http``
 trace appends one JSONL line — metric, filters, downsample, pixel
@@ -42,10 +53,12 @@ file in ``data_dir`` for offline mining.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import logging
 import os
 import secrets
+import sys
 import threading
 import time
 from collections import deque
@@ -79,8 +92,10 @@ KNOWN_SPANS: frozenset[str] = frozenset({
     "cluster.read_repair",   # cluster/router.py staged-hint drain
     "telemetry.pump",        # obs/telemetry.py self-stats ingest
     "control.loop",          # control/plane.py one control tick
+    "ingest.import",         # core/tsdb.py import_buffer outside a request
     # ingest stages
     "ingest.decode",         # body parse + validate + series grouping
+    "ingest.resolve",        # import_buffer: UIDs + series per distinct key
     "store.scatter",         # columnar store appends (+ inline taps)
     "wal.commit_wait",       # WAL group-commit fsync wait
     "stream.tap",            # continuous-query ingest tap
@@ -91,6 +106,11 @@ KNOWN_SPANS: frozenset[str] = frozenset({
     "sketch.fold",           # lifecycle/manager.py demote-time
                              # quantile-sketch fold (fifth stat column)
     "query.execute",         # scan + device pipeline (parent stage)
+    "query.scan",            # storage read (the QueryStat scan timer)
+    "query.grid_build",      # host NumPy between scan and upload
+    "query.upload",          # operand casts + device_put (host side)
+    "query.program",         # jit call until its outputs are ready
+    "query.download",        # np.asarray of the results
     "query.assemble",        # result assembly incl. pixel reduce
     "query.serialize",       # response body serialization
     # cluster stages
@@ -128,6 +148,152 @@ def _next_id() -> str:
     return f"{_PROC_NONCE}{n:08x}"
 
 
+# one clock for spans, contexts and the occupancy clock (a test
+# scripts a sequence by replacing it)
+_now = time.monotonic
+
+
+# ---------------------------------------------------------------------------
+# the process's runtime: device occupancy, compiles, collector, start-up
+# ---------------------------------------------------------------------------
+
+class DeviceClock:
+    """When is a program in flight on the device? ``enter``/``exit``
+    bracket every device-placed ``query.program``; ``occupied_ms`` is
+    the cumulative time with at least one in flight, on the spans' own
+    clock, so a span that samples it at begin and finish knows how
+    much of its interval the device was occupied. Occupancy runs from
+    dispatch to ready: it contains the transfer and the launch, not
+    only the kernels."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._inflight = 0
+        self._since = 0.0
+        self._occupied_s = 0.0
+        self.dispatches = 0
+
+    def enter(self) -> None:
+        with self._lock:
+            if self._inflight == 0:
+                self._since = _now()
+            self._inflight += 1
+            self.dispatches += 1
+
+    def exit(self) -> None:
+        with self._lock:
+            self._inflight -= 1
+            if self._inflight == 0:
+                self._occupied_s += _now() - self._since
+
+    def occupied_ms(self) -> float:
+        with self._lock:
+            open_s = _now() - self._since if self._inflight else 0.0
+            return (self._occupied_s + open_s) * 1000.0
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_misses"}
+
+
+class ProcessRuntime:
+    """What only the process as a whole has: one device (so one
+    occupancy clock), JAX's compile events, the collector's pauses and
+    the start-up phases. Hooks are installed once, by the first
+    :class:`Tracer`; every tracer of the process exports the same
+    numbers (``tsd.device.*``, ``tsd.runtime.gc_*``,
+    ``tsd.startup.*``)."""
+
+    def __init__(self):
+        self.clock = DeviceClock()
+        self._lock = threading.Lock()
+        self._installed = False
+        # every compile JAX was asked for, fresh or from the
+        # persistent cache (the event wraps compile_or_get_cached)
+        self.compiles = 0
+        self.compile_ms = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        # collector pauses, by generation (written by the one thread
+        # that collects, under the GIL)
+        self.gc_pause_ms = [0.0, 0.0, 0.0]
+        self.gc_collections = [0, 0, 0]
+        self.gc_max_pause_ms = 0.0
+        self._gc_t0 = 0.0
+        # {phase: seconds}, in the order the phases ran
+        # tsdlint: allow[unbounded-growth] keyed by the start-up
+        # phases the code names (tools/cli.py, TSDB.__init__): seven
+        self.startup: dict[str, float] = {}
+
+    def install(self) -> None:
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+        gc.callbacks.append(self._on_gc)
+
+    def _on_duration(self, event: str, secs: float, **_kw) -> None:
+        if event == _COMPILE_EVENT:
+            with self._lock:
+                self.compiles += 1
+                self.compile_ms += secs * 1000.0
+
+    def _on_event(self, event: str, **_kw) -> None:
+        field = _CACHE_EVENTS.get(event)
+        if field is not None:
+            with self._lock:
+                setattr(self, field, getattr(self, field) + 1)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t0 = _now()
+            return
+        ms = (_now() - self._gc_t0) * 1000.0
+        gen = min(int(info.get("generation", 2)), 2)
+        self.gc_pause_ms[gen] += ms
+        self.gc_collections[gen] += 1
+        if ms > self.gc_max_pause_ms:
+            self.gc_max_pause_ms = ms
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """Time one start-up phase into :attr:`startup`."""
+        t0 = _now()
+        try:
+            yield
+        finally:
+            self.startup[name] = self.startup.get(name, 0.0) \
+                + _now() - t0
+
+    def collect_stats(self, collector) -> None:
+        clock = self.clock
+        collector.record("device.occupied_ms", clock.occupied_ms())
+        collector.record("device.dispatches", clock.dispatches)
+        collector.record("device.compiles", self.compiles)
+        collector.record("device.compile_ms", self.compile_ms)
+        collector.record("device.compile_cache_hits", self.cache_hits)
+        collector.record("device.compile_cache_misses",
+                         self.cache_misses)
+        for gen in range(3):
+            collector.record("runtime.gc_pause_ms",
+                             self.gc_pause_ms[gen], gen=str(gen))
+            collector.record("runtime.gc_collections",
+                             self.gc_collections[gen], gen=str(gen))
+        collector.record("runtime.gc_max_pause_ms",
+                         self.gc_max_pause_ms)
+        for name, secs in list(self.startup.items()):
+            collector.record("startup.phase_s", secs, phase=name)
+
+
+#: the one runtime of this process
+RUNTIME = ProcessRuntime()
+
+
 def parse_trace_header(value: str) -> tuple[str, str, bool] | None:
     """``trace_id:parent_span_id:sampled_flag`` -> parts, or None on
     anything malformed (a hostile header must never 500 a write)."""
@@ -150,6 +316,29 @@ def parse_trace_header(value: str) -> tuple[str, str, bool] | None:
 # ---------------------------------------------------------------------------
 
 _local = threading.local()
+
+
+def _open_spans() -> list:
+    """This thread's stack of open spans, innermost last."""
+    stack = getattr(_local, "open", None)
+    if stack is None:
+        stack = _local.open = []
+    return stack
+
+
+def _annotate(name: str, trace_id: str, tags: dict):
+    """Put a query stage on the device trace's timeline too: with a
+    ``jax.profiler`` capture live (and its host tracer on) the span
+    appears there under its own name, request id and sub index as
+    arguments; with none live this is a flag check. Only where JAX is
+    already loaded."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    args = {"sub": tags["sub"]} if "sub" in tags else {}
+    ann = jax.profiler.TraceAnnotation(name, trace_id=trace_id, **args)
+    ann.__enter__()
+    return ann
 
 
 def current() -> "TraceContext | None":
@@ -223,12 +412,13 @@ class SpanRecord:
     """One finished span. Immutable once appended to its context."""
 
     __slots__ = ("span_id", "parent_id", "name", "start_ms",
-                 "duration_ms", "status", "error", "tags")
+                 "duration_ms", "status", "error", "tags",
+                 "occupied_ms")
 
     def __init__(self, span_id: str, parent_id: str, name: str,
                  start_ms: float, duration_ms: float,
                  status: str = "ok", error: str = "",
-                 tags: dict | None = None):
+                 tags: dict | None = None, occupied_ms: float = 0.0):
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
@@ -237,6 +427,9 @@ class SpanRecord:
         self.status = status
         self.error = error
         self.tags = tags or {}
+        # how much of the interval a program was in flight on the
+        # device (RUNTIME.clock sampled at begin and finish)
+        self.occupied_ms = occupied_ms
 
     def to_json(self) -> dict[str, Any]:
         doc: dict[str, Any] = {
@@ -250,6 +443,8 @@ class SpanRecord:
             doc["error"] = self.error
         if self.tags:
             doc["tags"] = self.tags
+        if self.occupied_ms:
+            doc["deviceOccupiedMs"] = round(self.occupied_ms, 3)
         return doc
 
     @classmethod
@@ -261,14 +456,16 @@ class SpanRecord:
                    float(doc.get("durationMs", 0.0)),
                    str(doc.get("status", "ok")),
                    str(doc.get("error", "")),
-                   doc.get("tags") or {})
+                   doc.get("tags") or {},
+                   float(doc.get("deviceOccupiedMs", 0.0)))
 
 
 class SpanHandle:
     """An OPEN span: carry tags, then :meth:`finish` to record."""
 
     __slots__ = ("_ctx", "span_id", "parent_id", "name", "tags",
-                 "_t0", "status", "error", "_done")
+                 "_t0", "status", "error", "_done", "_occ0", "_stack",
+                 "_annotation")
 
     def __init__(self, ctx: "TraceContext", span_id: str,
                  parent_id: str, name: str, tags: dict):
@@ -277,10 +474,13 @@ class SpanHandle:
         self.parent_id = parent_id
         self.name = name
         self.tags = tags
-        self._t0 = time.monotonic()
         self.status = "ok"
         self.error = ""
         self._done = False
+        self._stack = None        # the thread's open-span stack
+        self._annotation = None   # jax.profiler.TraceAnnotation
+        self._occ0 = RUNTIME.clock.occupied_ms()
+        self._t0 = _now()
 
     def tag(self, **tags) -> None:
         self.tags.update(tags)
@@ -290,11 +490,23 @@ class SpanHandle:
         self.error = (f"{type(exc).__name__}: {exc}"
                       if isinstance(exc, BaseException) else str(exc))
 
-    def finish(self) -> None:
+    def finish(self) -> float:
+        """Record the span; returns its duration in ms (a caller that
+        also keeps a stat uses the span as its timer)."""
         if self._done:
-            return
+            return 0.0
         self._done = True
-        self._ctx._append(self, self._t0, time.monotonic())
+        t1 = _now()
+        occupied = RUNTIME.clock.occupied_ms() - self._occ0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        stack = self._stack
+        if stack is not None and self in stack:
+            # and whatever was opened inside and abandoned on an
+            # error path: a finished span has no open children
+            del stack[stack.index(self):]
+        self._ctx._append(self, self._t0, t1, occupied)
+        return (t1 - self._t0) * 1000.0
 
     def __enter__(self) -> "SpanHandle":
         return self
@@ -313,7 +525,7 @@ class TraceContext:
                  "sampled", "forced", "parent_id", "root_span_id",
                  "start_epoch_ms", "_t0", "_lock", "spans",
                  "_next_span", "_nonce", "finished", "committed",
-                 "slow", "error", "tags", "dropped_spans")
+                 "slow", "error", "tags", "dropped_spans", "_gc2_ms0")
 
     def __init__(self, tracer: "Tracer", trace_id: str,
                  root_name: str, sampled: bool, forced: bool,
@@ -330,7 +542,7 @@ class TraceContext:
         self._nonce = _next_id()
         self.root_span_id = f"{self._nonce}-0"
         self.start_epoch_ms = time.time() * 1000.0
-        self._t0 = time.monotonic()
+        self._t0 = _now()
         self._lock = threading.Lock()
         # tsdlint: allow[unbounded-growth] capped by the tracer's
         # tsd.trace.max_spans (overflow counted in spans_dropped),
@@ -343,11 +555,12 @@ class TraceContext:
         self.error = ""
         self.tags: dict[str, Any] = {}
         self.dropped_spans = 0
+        self._gc2_ms0 = RUNTIME.gc_pause_ms[2]
 
     # -- span surface --------------------------------------------------
 
     def begin(self, name: str, parent: str | None = None,
-              **tags) -> SpanHandle | None:
+              push: bool = True, **tags) -> SpanHandle | None:
         if name not in KNOWN_SPANS:
             raise ValueError(
                 f"unknown span name {name!r}; register it in "
@@ -359,24 +572,37 @@ class TraceContext:
                 return None
             self._next_span += 1
             sid = f"{self._nonce}-{self._next_span}"
-        return SpanHandle(self, sid,
-                          parent if parent is not None
-                          else self.root_span_id, name, tags)
+        # no explicit parent: the innermost span still open on THIS
+        # thread in THIS context, else the root
+        stack = _open_spans()
+        while stack and (stack[-1]._done or stack[-1]._ctx.finished):
+            stack.pop()
+        if parent is None:
+            parent = next((h.span_id for h in reversed(stack)
+                           if h._ctx is self), self.root_span_id)
+        h = SpanHandle(self, sid, parent, name, tags)
+        if push:
+            h._stack = stack
+            stack.append(h)
+            if name.startswith("query."):
+                h._annotation = _annotate(name, self.trace_id, tags)
+        return h
 
     def record(self, name: str, start_mono: float, end_mono: float,
                **tags) -> None:
         """Append an already-timed span (see :func:`record_span`)."""
-        h = self.begin(name, **tags)
+        h = self.begin(name, push=False, **tags)
         if h is None:
             return
-        h._t0 = start_mono
-        self._append(h, start_mono, end_mono)
+        self._append(h, start_mono, end_mono, 0.0)
 
-    def _append(self, h: SpanHandle, t0: float, t1: float) -> None:
+    def _append(self, h: SpanHandle, t0: float, t1: float,
+                occupied_ms: float) -> None:
         rec = SpanRecord(
             h.span_id, h.parent_id, h.name,
             self.start_epoch_ms + (t0 - self._t0) * 1000.0,
-            (t1 - t0) * 1000.0, h.status, h.error, h.tags)
+            (t1 - t0) * 1000.0, h.status, h.error, h.tags,
+            max(occupied_ms, 0.0))
         with self._lock:
             if self.finished:
                 self.dropped_spans += 1
@@ -393,7 +619,7 @@ class TraceContext:
                       if isinstance(exc, BaseException) else str(exc))
 
     def elapsed_ms(self) -> float:
-        return (time.monotonic() - self._t0) * 1000.0
+        return (_now() - self._t0) * 1000.0
 
 
 class TraceData:
@@ -468,7 +694,17 @@ class Tracer:
         self.slow_ms = config.get_float(
             "tsd.query.slowlog.threshold_ms", 0.0)
         self.stats = stats  # StatsCollectorRegistry (stage histograms)
+        RUNTIME.install()
         self._lock = threading.Lock()
+        # per stage: the idle part of its SELF time (thread-ms: two
+        # fan-out workers idle at once count twice)
+        # tsdlint: allow[unbounded-growth] keyed by span name: the
+        # closed KNOWN_SPANS registry
+        self.idle_stage_ms: dict[str, float] = {}
+        # programs dispatched, by (path, placement)
+        # tsdlint: allow[unbounded-growth] keyed by run_staged's
+        # tags: the eight paths its callers name x two placements
+        self.tails: dict[tuple[str, str], int] = {}
         self._ring: deque[TraceData] = deque(
             maxlen=max(config.get_int("tsd.trace.ring", 256), 1))
         self._slow_ring: deque[TraceData] = deque(
@@ -542,7 +778,7 @@ class Tracer:
         received = getattr(request, "received_at", 0.0)
         if received and name == "query.http":
             record_span(ctx, "query.admission", received,
-                        time.monotonic())
+                        _now())
         return ctx
 
     def start_background(self, name: str, sample: bool = False,
@@ -600,10 +836,17 @@ class Tracer:
             spans = list(ctx.spans)
             dropped = ctx.dropped_spans
         duration_ms = ctx.elapsed_ms()
+        gc_ms = RUNTIME.gc_pause_ms[2] - ctx._gc2_ms0
+        if gc_ms > 0:
+            # a full collection ran inside this root
+            ctx.tags["gc_ms"] = round(gc_ms, 1)
         root = SpanRecord(
             ctx.root_span_id, ctx.parent_id, ctx.root_name,
             ctx.start_epoch_ms, duration_ms,
-            "error" if ctx.error else "ok", ctx.error, dict(ctx.tags))
+            "error" if ctx.error else "ok", ctx.error, dict(ctx.tags),
+            # the root never sampled the clock: what its children saw
+            sum(s.occupied_ms for s in spans
+                if s.parent_id == ctx.root_span_id))
         # per-stage latency histograms see EVERY traced request —
         # sampling gates only ring retention, so /api/stats
         # percentiles are not biased toward the sampled subset
@@ -612,6 +855,7 @@ class Tracer:
             stats.observe_stage(root.name, duration_ms)
             for s in spans:
                 stats.observe_stage(s.name, s.duration_ms)
+        self._account_self_time(root, spans)
         slow = (self.slow_ms > 0 and duration_ms >= self.slow_ms
                 and ctx.root_name.startswith("query"))
         commit = ctx.sampled or ctx.forced or slow or bool(ctx.error)
@@ -667,6 +911,46 @@ class Tracer:
                 self.shape_path:
             self._write_shape(ctx, root, spans)
         return commit
+
+    def _account_self_time(self, root: SpanRecord,
+                           spans: list[SpanRecord]) -> None:
+        """Each span's SELF time (its duration minus the union of its
+        children's intervals) feeds ``tsd_stage_self_ms`` where the
+        span has children; the part of it with no program in flight
+        on the device adds to ``idle_stage_ms`` of its stage; every
+        ``query.program`` counts in ``tails``."""
+        kids: dict[str, list[SpanRecord]] = {}
+        for s in spans:
+            kids.setdefault(s.parent_id, []).append(s)
+        idle: dict[str, float] = {}
+        tails = []
+        for s in [root] + spans:
+            self_ms, occupied = s.duration_ms, s.occupied_ms
+            mine = kids.get(s.span_id)
+            if mine:
+                lo, hi = s.start_ms, s.start_ms + s.duration_ms
+                covered, edge = 0.0, lo
+                for c in sorted(mine, key=lambda c: c.start_ms):
+                    a = max(c.start_ms, edge)
+                    b = min(c.start_ms + c.duration_ms, hi)
+                    if b > a:
+                        covered += b - a
+                        edge = b
+                self_ms = max(self_ms - covered, 0.0)
+                occupied -= sum(c.occupied_ms for c in mine)
+                if self.stats is not None:
+                    self.stats.observe_stage_self(s.name, self_ms)
+            idle[s.name] = idle.get(s.name, 0.0) + self_ms \
+                - min(max(occupied, 0.0), self_ms)
+            if s.name == "query.program":
+                tails.append((str(s.tags.get("path", "?")),
+                              str(s.tags.get("placement", "?"))))
+        with self._lock:
+            for name, ms in idle.items():
+                self.idle_stage_ms[name] = \
+                    self.idle_stage_ms.get(name, 0.0) + ms
+            for key in tails:
+                self.tails[key] = self.tails.get(key, 0) + 1
 
     # -- retrieval -----------------------------------------------------
 
@@ -748,6 +1032,15 @@ class Tracer:
         collector.record("trace.spans_dropped", self.spans_dropped)
         collector.record("trace.shape_lines", self.shape_lines)
         collector.record("trace.shape_errors", self.shape_errors)
+        RUNTIME.collect_stats(collector)
+        with self._lock:
+            idle = sorted(self.idle_stage_ms.items())
+            tails = sorted(self.tails.items())
+        for stage, ms in idle:
+            collector.record("device.idle_stage_ms", ms, stage=stage)
+        for (path, placement), n in tails:
+            collector.record("query.tail", n, path=path,
+                             placement=placement)
 
     def health_info(self) -> dict[str, Any]:
         with self._lock:

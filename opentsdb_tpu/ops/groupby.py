@@ -225,7 +225,10 @@ def group_aggregate(grid, bucket_ts, group_ids, num_groups: int,
     reference's FillingDownsampler emits explicit NaN points there, so
     the merge loop sees a point (and skips its NaN value) instead of a
     gap — cross-series interpolation never triggers."""
-    filled = (fill_gaps(grid, bucket_ts, agg.interpolation.value)
-              if interpolate else grid)
-    return _group_reduce(filled, group_ids, num_groups, agg.name,
-                         prefer_segment=prefer_segment)
+    filled = grid
+    if interpolate:
+        with jax.named_scope("tail.interpolate"):
+            filled = fill_gaps(grid, bucket_ts, agg.interpolation.value)
+    with jax.named_scope("tail.group_reduce"):
+        return _group_reduce(filled, group_ids, num_groups, agg.name,
+                             prefer_segment=prefer_segment)

@@ -431,6 +431,7 @@ def _run(*arrays, spec, tile_s: int, interpret: bool,
         out_specs=pl.BlockSpec((g, b), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, b), dtype),
         interpret=interpret,
+        name="fused_dense_span" if span else "fused_dense_onehot",
     )(*operands)
     return _finalize(acc, group_sizes, spec, dtype)
 
@@ -592,14 +593,18 @@ def fused_dense_pipeline(values2d: np.ndarray, bucket_ts: np.ndarray,
     """Host entry mirroring :func:`pipeline.run_pipeline_dense` for
     complete data. values2d [S, P] (no NaN), bucket_ts [B] ms,
     group_ids [S] -> (result [G,B] np, emit [G,B] np)."""
-    args, tile_s, interpret = prepare(values2d, bucket_ts, group_ids,
-                                      spec, k, dtype, device)
+    from opentsdb_tpu.ops.pipeline import run_staged
     cm = float(rate_options.counter_max) if rate_options else \
         float(2**64 - 1)
     rv = float(rate_options.reset_value) if rate_options else 0.0
-    rp = jnp.asarray([[cm, rv]], dtype)
-    result, emit = _run(*args, spec=spec, tile_s=tile_s,
-                        interpret=interpret, rate_params=rp)
-    out = np.asarray(result), np.asarray(emit)
-    COUNTERS.ran(interpret)
+    out = run_staged(
+        "pallas",
+        lambda args, tile_s, interpret, rp: _run(
+            *args, spec=spec, tile_s=tile_s, interpret=interpret,
+            rate_params=rp),
+        lambda: (*prepare(values2d, bucket_ts, group_ids, spec, k,
+                          dtype, device),
+                 jnp.asarray([[cm, rv]], dtype)),
+        spec)
+    COUNTERS.ran(_platform(device) != "tpu")
     return out

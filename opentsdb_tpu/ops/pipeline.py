@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from opentsdb_tpu.obs.trace import RUNTIME, trace_span
 from opentsdb_tpu.ops import aggregators as aggs_mod
 from opentsdb_tpu.ops import downsample as ds_mod
 from opentsdb_tpu.ops import groupby as gb_mod
@@ -168,16 +169,22 @@ def _finish_pipeline(grid, has_data, bucket_ts, group_ids, rate_params,
                      fill_value, spec: PipelineSpec):
     g, b = spec.num_groups, spec.num_buckets
 
+    # the scopes name the device trace's operations by stage, so a
+    # reduction finds them after a refactor
+
     # 2. downsample fill policy
-    grid, has_data = apply_fill_policy(grid, has_data, fill_value, spec)
+    with jax.named_scope("tail.fill"):
+        grid, has_data = apply_fill_policy(grid, has_data, fill_value,
+                                           spec)
 
     # 3. rate conversion per series (ref: Downsampler -> RateSpan order)
     if spec.rate:
         counter_max, reset_value = rate_params
-        grid = _rate_kernel(grid, bucket_ts, spec.rate_counter,
-                            counter_max, reset_value,
-                            spec.rate_drop_resets)
-        has_data = has_data & ~jnp.isnan(grid)
+        with jax.named_scope("tail.rate"):
+            grid = _rate_kernel(grid, bucket_ts, spec.rate_counter,
+                                counter_max, reset_value,
+                                spec.rate_drop_resets)
+            has_data = has_data & ~jnp.isnan(grid)
 
     if spec.emit_raw:
         return grid, has_data
@@ -198,14 +205,15 @@ def _finish_pipeline(grid, has_data, bucket_ts, group_ids, rate_params,
     # every bucket (FillingDownsampler semantics). A verified-complete
     # grid emits everywhere by construction (every group has >= 1
     # member series and every cell is filled).
-    if spec.complete and not spec.rate:
-        emit = jnp.ones((g, b), dtype=bool)
-    elif spec.fill_policy == ds_mod.FillPolicy.NONE:
-        emit = gb_mod._group_sum(
-            has_data.astype(grid.dtype), group_ids, g,
-            prefer_segment=spec.host) > 0
-    else:
-        emit = jnp.ones((g, b), dtype=bool)
+    with jax.named_scope("tail.emit_mask"):
+        if spec.complete and not spec.rate:
+            emit = jnp.ones((g, b), dtype=bool)
+        elif spec.fill_policy == ds_mod.FillPolicy.NONE:
+            emit = gb_mod._group_sum(
+                has_data.astype(grid.dtype), group_ids, g,
+                prefer_segment=spec.host) > 0
+        else:
+            emit = jnp.ones((g, b), dtype=bool)
     return result, emit
 
 
@@ -251,13 +259,55 @@ def host_cpu_device():
     return jax.devices("cpu")[0]
 
 
+def run_staged(path: str, program, operands,
+               spec: PipelineSpec | None = None, download=np.asarray):
+    """Upload, run, download: the one way a host entry reaches a
+    compiled program, so that every path names the same three stages.
+
+    ``operands()`` makes the program's positional arguments (casts,
+    pads and ``device_put`` calls: ``query.upload``, host side only —
+    nothing waits for a transfer). ``query.program`` is the call up to
+    ``block_until_ready`` on its outputs, tagged with ``path``,
+    ``placement`` (``host`` for a tail pinned to the CPU backend,
+    ``spec.host``), the padded ``shape`` SxBxG and ``compiled`` when
+    JAX compiled (or loaded from its cache) inside it; a
+    device-placed program occupies :data:`RUNTIME`'s clock for that
+    stretch. ``query.download`` is ``download`` (``np.asarray``) of
+    each output. ``spec`` defaults to the :class:`PipelineSpec` among
+    the operands."""
+    with trace_span("query.upload"):
+        args = operands()
+    if spec is None:
+        spec = next(a for a in args if isinstance(a, PipelineSpec))
+    on_device = not spec.host
+    with trace_span(
+            "query.program", path=path,
+            placement="device" if on_device else "host",
+            shape=f"{spec.num_series}x{spec.num_buckets}"
+                  f"x{spec.num_groups}") as span:
+        compiles = RUNTIME.compiles
+        if on_device:
+            RUNTIME.clock.enter()
+        try:
+            out = jax.block_until_ready(program(*args))
+        finally:
+            if on_device:
+                RUNTIME.clock.exit()
+        if span is not None and RUNTIME.compiles != compiles:
+            span.tag(compiled=True)
+    with trace_span("query.download"):
+        return jax.tree_util.tree_map(download, out)
+
+
 def put_grid(grid, has_data, device=None):
     """Upload a [S, B] grid + presence mask once, in the compute dtype
     — callers cache the returned DEVICE arrays so repeated queries
     skip the host scan and the transfer entirely."""
     dtype = pipeline_dtype()
-    return (jax.device_put(as_operand(grid, dtype), device=device),
-            jax.device_put(as_operand(has_data, bool), device=device))
+    with trace_span("query.upload"):
+        return (jax.device_put(as_operand(grid, dtype), device=device),
+                jax.device_put(as_operand(has_data, bool),
+                               device=device))
 
 
 def _pad_2d(arr, s_pad: int, b_pad: int, fill):
@@ -317,24 +367,26 @@ def execute_grid(grid: np.ndarray, has_data: np.ndarray,
         dtype = pipeline_dtype()
     ro = rate_options or RateOptions()
     s, b, g = spec.num_series, spec.num_buckets, spec.num_groups
-    grid, has_data, bucket_ts, group_ids, pspec = bucket_grid_shapes(
-        grid if isinstance(grid, jax.Array) else np.asarray(grid),
-        has_data if isinstance(has_data, jax.Array)
-        else np.asarray(has_data), bucket_ts, group_ids, spec)
     put = partial(jax.device_put, device=device)
-    rate_params = (as_operand(ro.counter_max, dtype),
-                   as_operand(ro.reset_value, dtype))
-    # the grid is the committed operand deciding placement; everything
-    # else rides along as numpy (no eager default-device round trips)
-    result, emit = run_pipeline_grid(
-        put(as_operand(grid, dtype)),
-        put(as_operand(has_data, bool)),
-        as_operand(device_bucket_ts(bucket_ts)),
-        as_operand(group_ids, np.int32),
-        rate_params, as_operand(spec.fill_value, dtype), pspec)
+
+    def operands():
+        gp, hp, bts, gids, pspec = bucket_grid_shapes(
+            grid if isinstance(grid, jax.Array) else np.asarray(grid),
+            has_data if isinstance(has_data, jax.Array)
+            else np.asarray(has_data), bucket_ts, group_ids, spec)
+        # the grid is the committed operand deciding placement;
+        # everything else rides along as numpy (no eager
+        # default-device round trips)
+        return (put(as_operand(gp, dtype)), put(as_operand(hp, bool)),
+                as_operand(device_bucket_ts(bts)),
+                as_operand(gids, np.int32),
+                (as_operand(ro.counter_max, dtype),
+                 as_operand(ro.reset_value, dtype)),
+                as_operand(spec.fill_value, dtype), pspec)
+
+    result, emit = run_staged("grid", run_pipeline_grid, operands)
     rows = s if spec.emit_raw else g
-    return (np.asarray(result)[:rows, :b],
-            np.asarray(emit)[:rows, :b])
+    return result[:rows, :b], emit[:rows, :b]
 
 
 def avg_divide_grid(grid_sum, grid_cnt, xp=jnp):
@@ -374,23 +426,25 @@ def execute_avg_divide(grid_sum, grid_cnt, bucket_ts: np.ndarray,
         dtype = pipeline_dtype()
     ro = rate_options or RateOptions()
     s, b, g = spec.num_series, spec.num_buckets, spec.num_groups
-    s_pad, b_pad, bts_p, gids_p, pspec = _bucket_dims_and_aux(
-        bucket_ts, group_ids, spec, grid_sum.shape[0],
-        grid_sum.shape[1])
-    gsum = _pad_2d(grid_sum, s_pad, b_pad, np.nan)
-    gcnt = _pad_2d(grid_cnt, s_pad, b_pad, np.nan)
     put = partial(jax.device_put, device=device)
-    rate_params = (as_operand(ro.counter_max, dtype),
-                   as_operand(ro.reset_value, dtype))
-    result, emit = run_pipeline_avg_div(
-        put(as_operand(gsum, dtype)),
-        put(as_operand(gcnt, dtype)),
-        as_operand(device_bucket_ts(bts_p)),
-        as_operand(gids_p, np.int32),
-        rate_params, as_operand(spec.fill_value, dtype), pspec)
+
+    def operands():
+        s_pad, b_pad, bts_p, gids_p, pspec = _bucket_dims_and_aux(
+            bucket_ts, group_ids, spec, grid_sum.shape[0],
+            grid_sum.shape[1])
+        gsum = _pad_2d(grid_sum, s_pad, b_pad, np.nan)
+        gcnt = _pad_2d(grid_cnt, s_pad, b_pad, np.nan)
+        return (put(as_operand(gsum, dtype)),
+                put(as_operand(gcnt, dtype)),
+                as_operand(device_bucket_ts(bts_p)),
+                as_operand(gids_p, np.int32),
+                (as_operand(ro.counter_max, dtype),
+                 as_operand(ro.reset_value, dtype)),
+                as_operand(spec.fill_value, dtype), pspec)
+
+    result, emit = run_staged("avg_div", run_pipeline_avg_div, operands)
     rows = s if spec.emit_raw else g
-    return (np.asarray(result)[:rows, :b],
-            np.asarray(emit)[:rows, :b])
+    return result[:rows, :b], emit[:rows, :b]
 
 
 _DENSE_FNS = frozenset(("sum", "zimsum", "pfsum", "avg", "min", "mimmin",
@@ -504,13 +558,11 @@ def _run_dense_or_pallas(values2d, bucket_ts, group_ids, spec, k, ro,
                 np.asarray(group_ids), spec, k, dtype=dtype,
                 device=device, rate_options=ro)
         pallas_fused.COUNTERS.replaced(why)
-    put = partial(jax.device_put, device=device)
-    result, emit = run_pipeline_dense(
-        put(as_operand(values2d, dtype)),
+    return run_staged("dense", run_pipeline_dense, lambda: (
+        jax.device_put(as_operand(values2d, dtype), device=device),
         as_operand(device_bucket_ts(bucket_ts)),
         as_operand(group_ids, np.int32),
-        rate_params, fv, spec, k)
-    return np.asarray(result), np.asarray(emit)
+        rate_params, fv, spec, k))
 
 
 def execute_auto(padded, bucket_idx2d: np.ndarray,
@@ -541,13 +593,12 @@ def execute_auto(padded, bucket_idx2d: np.ndarray,
     cells = values2d.shape[0] * values2d.shape[1] * spec.num_buckets
     if ds_mod.padded_supported(spec.ds_function, spec.num_buckets) \
             and cells <= _PADDED_EINSUM_MAX_CELLS:
-        result, emit = run_pipeline_padded(
+        return run_staged("padded", run_pipeline_padded, lambda: (
             put(as_operand(values2d, dtype)),
             as_operand(bucket_idx2d, np.int32),
             as_operand(device_bucket_ts(bucket_ts)),
             as_operand(group_ids, np.int32),
-            rate_params, fv, spec)
-        return np.asarray(result), np.asarray(emit)
+            rate_params, fv, spec))
     values, series_idx, bucket_idx = flatten_padded(
         values2d, np.asarray(bucket_idx2d), counts)
     return execute(values, series_idx, bucket_idx, bucket_ts, group_ids,
@@ -605,21 +656,23 @@ def prepare_auto(padded, bucket_idx2d: np.ndarray, spec: PipelineSpec,
     s_pad = shapes.shape_bucket(s)
     k = detect_regular_padded(counts, bucket_idx2d, spec.num_buckets)
     if k is not None and spec.ds_function in _DENSE_FNS:
-        return PreparedBatch(
-            "dense",
-            (put(as_operand(_pad_rows(values2d, s_pad, np.nan),
-                            dtype)),),
-            k, pad=(s_pad, b))
+        with trace_span("query.upload"):
+            return PreparedBatch(
+                "dense",
+                (put(as_operand(_pad_rows(values2d, s_pad, np.nan),
+                                dtype)),),
+                k, pad=(s_pad, b))
     cells = s_pad * values2d.shape[1] * spec.num_buckets
     if ds_mod.padded_supported(spec.ds_function, spec.num_buckets) \
             and cells <= _PADDED_EINSUM_MAX_CELLS:
-        return PreparedBatch(
-            "padded",
-            (put(as_operand(_pad_rows(values2d, s_pad, np.nan),
-                            dtype)),
-             put(as_operand(_pad_rows(bucket_idx2d, s_pad, -1),
-                            np.int32))),
-            pad=(s_pad, b))
+        with trace_span("query.upload"):
+            return PreparedBatch(
+                "padded",
+                (put(as_operand(_pad_rows(values2d, s_pad, np.nan),
+                                dtype)),
+                 put(as_operand(_pad_rows(bucket_idx2d, s_pad, -1),
+                                np.int32))),
+                pad=(s_pad, b))
     values, series_idx, bucket_idx = flatten_padded(
         values2d, bucket_idx2d, counts)
     return prepare_flat(values, series_idx, bucket_idx, spec,
@@ -643,25 +696,27 @@ def prepare_flat(values: np.ndarray, series_idx: np.ndarray,
                      spec.ds_function)
     if k is not None:
         values2d = np.asarray(values).reshape(spec.num_series, -1)
+        with trace_span("query.upload"):
+            return PreparedBatch(
+                "dense",
+                (put(as_operand(_pad_rows(values2d, s_pad, np.nan),
+                                dtype)),),
+                k, pad=(s_pad, b))
+    with trace_span("query.upload"):
+        n = len(values)
+        s_pad = shapes.shape_bucket(s + 1)
+        b_pad = shapes.shape_bucket(b + 1)
+        n_pad = shapes.shape_bucket(n)
+        v = np.zeros(n_pad, dtype=np.asarray(values).dtype)
+        v[:n] = values
+        si = np.full(n_pad, s_pad - 1, dtype=np.int32)
+        si[:n] = series_idx
+        bi = np.full(n_pad, b_pad - 1, dtype=np.int32)
+        bi[:n] = bucket_idx
         return PreparedBatch(
-            "dense",
-            (put(as_operand(_pad_rows(values2d, s_pad, np.nan),
-                            dtype)),),
-            k, pad=(s_pad, b))
-    n = len(values)
-    s_pad = shapes.shape_bucket(s + 1)
-    b_pad = shapes.shape_bucket(b + 1)
-    n_pad = shapes.shape_bucket(n)
-    v = np.zeros(n_pad, dtype=np.asarray(values).dtype)
-    v[:n] = values
-    si = np.full(n_pad, s_pad - 1, dtype=np.int32)
-    si[:n] = series_idx
-    bi = np.full(n_pad, b_pad - 1, dtype=np.int32)
-    bi[:n] = bucket_idx
-    return PreparedBatch(
-        "flat", (put(as_operand(v, dtype)),
-                 put(si), put(bi)),
-        pad=(s_pad, b_pad))
+            "flat", (put(as_operand(v, dtype)),
+                     put(si), put(bi)),
+            pad=(s_pad, b_pad))
 
 
 def run_prepared(prep: PreparedBatch, bucket_ts: np.ndarray,
@@ -688,26 +743,20 @@ def run_prepared(prep: PreparedBatch, bucket_ts: np.ndarray,
                                          s_pad, g)
         spec = replace(spec, num_series=s_pad, num_buckets=b_pad,
                        num_groups=g_pad)
-    rate_params = (as_operand(ro.counter_max, dtype),
-                   as_operand(ro.reset_value, dtype))
-    fv = as_operand(spec.fill_value, dtype)
+    program, static = {
+        "dense": (run_pipeline_dense, (prep.k,)),
+        "padded": (run_pipeline_padded, ()),
+        "flat": (run_pipeline, ())}[prep.kind]
     # numpy operands ride with the committed prepared arrays — no
     # eager default-device materialization per query
-    bts = as_operand(device_bucket_ts(bucket_ts))
-    gids = as_operand(group_ids, np.int32)
-    if prep.kind == "dense":
-        result, emit = run_pipeline_dense(
-            prep.arrays[0], bts, gids, rate_params, fv, spec, prep.k)
-    elif prep.kind == "padded":
-        result, emit = run_pipeline_padded(
-            prep.arrays[0], prep.arrays[1], bts, gids, rate_params,
-            fv, spec)
-    else:
-        result, emit = run_pipeline(
-            prep.arrays[0], prep.arrays[1], prep.arrays[2], bts, gids,
-            rate_params, fv, spec)
+    result, emit = run_staged("prepared", program, lambda: (
+        *prep.arrays, as_operand(device_bucket_ts(bucket_ts)),
+        as_operand(group_ids, np.int32),
+        (as_operand(ro.counter_max, dtype),
+         as_operand(ro.reset_value, dtype)),
+        as_operand(spec.fill_value, dtype), spec, *static))
     rows = s if spec.emit_raw else g
-    return np.asarray(result)[:rows, :b], np.asarray(emit)[:rows, :b]
+    return result[:rows, :b], emit[:rows, :b]
 
 
 def execute(batch_values: np.ndarray, series_idx: np.ndarray,
@@ -737,12 +786,10 @@ def execute(batch_values: np.ndarray, series_idx: np.ndarray,
         return _run_dense_or_pallas(values2d, bucket_ts, group_ids,
                                     spec, k, ro, rate_params, fv,
                                     dtype, device, use_pallas)
-    values = put(as_operand(batch_values, dtype))
-    result, emit = run_pipeline(
-        values,
+    return run_staged("flat", run_pipeline, lambda: (
+        put(as_operand(batch_values, dtype)),
         as_operand(series_idx, np.int32),
         as_operand(bucket_idx, np.int32),
         as_operand(device_bucket_ts(bucket_ts)),
         as_operand(group_ids, np.int32),
-        rate_params, fv, spec)
-    return np.asarray(result), np.asarray(emit)
+        rate_params, fv, spec))

@@ -39,7 +39,8 @@ from opentsdb_tpu.ops.aggregators import Interpolation
 from opentsdb_tpu.ops import aggregators as aggs_mod
 from opentsdb_tpu.ops.interp import (_gather_minor, _next_valid_idx,
                                      _prev_valid_idx)
-from opentsdb_tpu.ops.pipeline import PipelineSpec
+from opentsdb_tpu.obs.trace import trace_span
+from opentsdb_tpu.ops.pipeline import PipelineSpec, run_staged
 
 # aggregators whose group reduction crosses the series axis with
 # psum/pmin/pmax partials and so keep per-device memory at
@@ -965,13 +966,14 @@ def sharded_device_args(mesh: Mesh, batch: ShardedBatch, dtype):
     from opentsdb_tpu.ops.pipeline import device_bucket_ts
     from opentsdb_tpu.parallel.distributed import put_global as put
     s3 = NamedSharding(mesh, P("series", "time", None))
-    return (put(np.asarray(batch.values, np.dtype(dtype)), s3),
-            put(batch.series_idx, s3),
-            put(batch.bucket_idx, s3),
-            put(device_bucket_ts(batch.bucket_ts),
-                NamedSharding(mesh, P("time"))),
-            put(batch.group_ids,
-                NamedSharding(mesh, P("series"))))
+    with trace_span("query.upload"):
+        return (put(np.asarray(batch.values, np.dtype(dtype)), s3),
+                put(batch.series_idx, s3),
+                put(batch.bucket_idx, s3),
+                put(device_bucket_ts(batch.bucket_ts),
+                    NamedSharding(mesh, P("time"))),
+                put(batch.group_ids,
+                    NamedSharding(mesh, P("series"))))
 
 
 def run_sharded_device(mesh: Mesh, spec: PipelineSpec, device_args,
@@ -983,13 +985,13 @@ def run_sharded_device(mesh: Mesh, spec: PipelineSpec, device_args,
         dtype = jnp.float64 if jax.config.read("jax_enable_x64") \
             else jnp.float32
     ro = rate_options or RateOptions()
-    step = _compiled_step(mesh, spec, s_loc, b_loc)
-    rate_params = (jnp.asarray(ro.counter_max, dtype),
-                   jnp.asarray(ro.reset_value, dtype))
-    result, emit = step(*device_args, rate_params,
-                        jnp.asarray(spec.fill_value, dtype))
-    result = _to_host(result)
-    emit = _to_host(emit)
+    result, emit = run_staged(
+        "mesh", _compiled_step(mesh, spec, s_loc, b_loc), lambda: (
+            *device_args,
+            (jnp.asarray(ro.counter_max, dtype),
+             jnp.asarray(ro.reset_value, dtype)),
+            jnp.asarray(spec.fill_value, dtype)),
+        spec, download=_to_host)
     b = spec.num_buckets
     return result[:num_groups, :b], emit[:num_groups, :b]
 
@@ -1116,9 +1118,10 @@ def prepare_sharded_grid(mesh: Mesh, grid: np.ndarray,
     bts = _pad_bts_tail(np.asarray(bucket_ts, dtype=np.int64), b_pad)
     from opentsdb_tpu.parallel.distributed import put_global as put
     s2 = NamedSharding(mesh, P("series", "time"))
-    args = (put(g, s2), put(h, s2),
-            put(device_bucket_ts(bts),
-                NamedSharding(mesh, P("time"))))
+    with trace_span("query.upload"):
+        args = (put(g, s2), put(h, s2),
+                put(device_bucket_ts(bts),
+                    NamedSharding(mesh, P("time"))))
     return args, s_loc, b_loc, s_pad
 
 
@@ -1127,9 +1130,10 @@ def sharded_grid_gids(mesh: Mesh, group_ids: np.ndarray, s_pad: int,
     """Per-query group-id upload (tiny [S_pad] vector)."""
     from jax.sharding import NamedSharding
     from opentsdb_tpu.parallel.distributed import put_global
-    gids = np.full(s_pad, num_groups, dtype=np.int32)
-    gids[:len(group_ids)] = group_ids
-    return put_global(gids, NamedSharding(mesh, P("series")))
+    with trace_span("query.upload"):
+        gids = np.full(s_pad, num_groups, dtype=np.int32)
+        gids[:len(group_ids)] = group_ids
+        return put_global(gids, NamedSharding(mesh, P("series")))
 
 
 def run_sharded_grid(mesh: Mesh, spec: PipelineSpec, device_args,
@@ -1141,13 +1145,13 @@ def run_sharded_grid(mesh: Mesh, spec: PipelineSpec, device_args,
         dtype = jnp.float64 if jax.config.read("jax_enable_x64") \
             else jnp.float32
     ro = rate_options or RateOptions()
-    step = _compiled_grid_step(mesh, spec, s_loc, b_loc)
-    rate_params = (jnp.asarray(ro.counter_max, dtype),
-                   jnp.asarray(ro.reset_value, dtype))
-    result, emit = step(*device_args, rate_params,
-                        jnp.asarray(spec.fill_value, dtype))
-    result = _to_host(result)
-    emit = _to_host(emit)
+    result, emit = run_staged(
+        "mesh", _compiled_grid_step(mesh, spec, s_loc, b_loc), lambda: (
+            *device_args,
+            (jnp.asarray(ro.counter_max, dtype),
+             jnp.asarray(ro.reset_value, dtype)),
+            jnp.asarray(spec.fill_value, dtype)),
+        spec, download=_to_host)
     b = spec.num_buckets
     rows = spec.num_series if spec.emit_raw else num_groups
     return result[:rows, :b], emit[:rows, :b]

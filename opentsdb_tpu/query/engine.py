@@ -870,7 +870,7 @@ class QueryEngine:
         # path never needs a scatter; see PaddedBatch). Skewed batches
         # (one dense series among many sparse ones would blow S * Pmax
         # up quadratically) stay on the flat layout.
-        t1 = time.monotonic()
+        scan = self._scan_begin()
         counts = store.count_range(sids, tsq.start_ms, tsq.end_ms)
         total = int(counts.sum())
         pmax = int(counts.max()) if len(counts) else 0
@@ -887,8 +887,7 @@ class QueryEngine:
             padded = None
             batch = store.materialize(sids, tsq.start_ms, tsq.end_ms)
             num_points = batch.num_points
-        self._record_scan(stats, (time.monotonic() - t1) * 1e3,
-                          num_points, len(sids))
+        self._record_scan(stats, scan, num_points, len(sids))
         # byte/dp guardrails (ref: SaltScanner budget enforcement via
         # QueryLimitOverride)
         self.tsdb.query_limits.check(metric_name, num_points)
@@ -901,6 +900,10 @@ class QueryEngine:
             return []
         bucket_idx2d = bucket_idx = None
         grid_complete = False
+        # points -> bucket indices on the host (the point path's twin
+        # of _grid_pipeline's fill and pad)
+        _h_build = trace_begin("query.grid_build", cells=cells,
+                               bytes=num_points * 16)
         if sub.ds_spec is not None:
             ds_function = ds_fn_override or sub.ds_spec.function
             fill_policy = sub.ds_spec.fill_policy
@@ -967,6 +970,7 @@ class QueryEngine:
                 bucket_ts, bucket_idx = np.unique(batch.ts_ms,
                                                   return_inverse=True)
                 bucket_idx = bucket_idx.astype(np.int32)
+        trace_end(_h_build)
 
         # --- device pipeline
         t2 = time.monotonic()
@@ -1010,8 +1014,9 @@ class QueryEngine:
                 batch = batch._replace(values=batch.values
                                        * rollup_scale)
         if padded is not None and (use_blocked or mesh is not None):
-            values, series_idx, bucket_idx = flatten_padded(
-                padded.values2d, bucket_idx2d, padded.counts)
+            with trace_span("query.grid_build", cells=cells):
+                values, series_idx, bucket_idx = flatten_padded(
+                    padded.values2d, bucket_idx2d, padded.counts)
         elif use_blocked or mesh is not None:
             values, series_idx = batch.values, batch.series_idx
         # the host-retry twin for the single-device paths below: on a
@@ -1315,11 +1320,24 @@ class QueryEngine:
             or tier_store
 
     @staticmethod
-    def _record_scan(stats, ms: float, num_points: int,
+    def _scan_begin():
+        """Open one sub-query's storage read: the ``query.scan`` span
+        where the request is traced (the span IS the timer), else a
+        clock reading. Closed by :meth:`_record_scan`."""
+        return trace_begin("query.scan") or time.monotonic()
+
+    @staticmethod
+    def _record_scan(stats, scan, num_points: int,
                      n_rows: int) -> None:
-        """Storage-scan stat points (ref: the per-scanner stats block,
+        """Close the storage read ``scan`` (:meth:`_scan_begin`) and
+        record its stat points (ref: the per-scanner stats block,
         QueryStats.java:137-151 — 'storage' here is the host column
         store, a column ≙ a stored point, a row ≙ a series)."""
+        if isinstance(scan, float):
+            ms = (time.monotonic() - scan) * 1e3
+        else:
+            scan.tag(points=num_points, series=n_rows)
+            ms = scan.finish()
         if not stats:
             return
         stats.add_stat(QueryStat.MATERIALIZE_TIME, ms)
@@ -1390,12 +1408,14 @@ class QueryEngine:
         mesh_args = mesh_meta = None
         if cache is not None:
             from opentsdb_tpu.query.device_cache import array_digest
-            ckey = ("grid", _store_id(store), array_digest(
-                np.ascontiguousarray(sids)), tsq.start_ms, tsq.end_ms,
-                int(bucket_ts[0]), ds_spec.interval_ms, b, fn, mesh)
-            cver = (store.points_written,
-                    getattr(store, "mutation_epoch", 0))
-            hit = cache.get(ckey, cver)
+            with trace_span("query.grid_build", stage="cache_lookup"):
+                ckey = ("grid", _store_id(store), array_digest(
+                    np.ascontiguousarray(sids)), tsq.start_ms,
+                    tsq.end_ms, int(bucket_ts[0]),
+                    ds_spec.interval_ms, b, fn, mesh)
+                cver = (store.points_written,
+                        getattr(store, "mutation_epoch", 0))
+                hit = cache.get(ckey, cver)
             if hit is not None:
                 if mesh is not None:
                     mesh_args, mesh_meta = hit
@@ -1404,20 +1424,31 @@ class QueryEngine:
                 else:
                     (grid, has_data), meta = hit
                     num_points = meta["num_points"]
-        t1 = time.monotonic()
+        scan = self._scan_begin()
         if grid is None:
             sums, cnts, mins, maxs = store.bucket_reduce(
                 sids, tsq.start_ms, tsq.end_ms, int(bucket_ts[0]),
                 ds_spec.interval_ms, b, want_minmax=want_minmax)
             num_points = int(cnts.sum())
-        self._record_scan(stats, (time.monotonic() - t1) * 1e3,
-                          num_points, len(sids))
+        self._record_scan(stats, scan, num_points, len(sids))
         self.tsdb.query_limits.check(metric_name, num_points)
         if tsq.delete and hasattr(store, "delete_range"):
             store.delete_range(sids, tsq.start_ms, tsq.end_ms)
         if num_points == 0:
             return (None, None, bucket_ts)
         if grid is None:
+            # pad to the geometric shape buckets NOW (host numpy,
+            # once): cached device grids are pre-padded, warm queries
+            # never pay a per-query device pad, and — on BOTH the
+            # single-device and mesh paths — compiled programs are
+            # keyed on bucketed shapes, so warmup's pre-compiles and
+            # repeat queries of the same class actually hit
+            from opentsdb_tpu.ops import shapes
+            sp = shapes.shape_bucket(len(sids))
+            bp = shapes.shape_bucket(b)
+            # an f64 grid and its presence mask
+            _h_build = trace_begin("query.grid_build", stage="fill_pad",
+                                   cells=sp * bp, bytes=sp * bp * 9)
             present = cnts > 0
             if fn in ("sum", "zimsum", "pfsum"):
                 grid = np.where(present, sums, np.nan)
@@ -1431,18 +1462,9 @@ class QueryEngine:
             else:  # max, mimmax
                 grid = np.where(present, maxs, np.nan)
             has_data = present
-            # pad to the geometric shape buckets NOW (host numpy,
-            # once): cached device grids are pre-padded, warm queries
-            # never pay a per-query device pad, and — on BOTH the
-            # single-device and mesh paths — compiled programs are
-            # keyed on bucketed shapes, so warmup's pre-compiles and
-            # repeat queries of the same class actually hit
-            from opentsdb_tpu.ops import shapes
-            s0, b0 = grid.shape
-            sp = shapes.shape_bucket(s0)
-            bp = shapes.shape_bucket(b0)
             grid = shapes.pad_2d_host(grid, sp, bp, np.nan)
             has_data = shapes.pad_2d_host(has_data, sp, bp, False)
+            trace_end(_h_build)
             if cache is not None and mesh is None:
                 from opentsdb_tpu.ops.pipeline import put_grid
                 grid, has_data = put_grid(grid, has_data)
@@ -1547,7 +1569,7 @@ class QueryEngine:
         average, not a mean of per-tier-point averages (ref: RollupSpan
         reading agg-prefixed sum+count qualifiers from one row).
         Returns (result, emit, bucket_ts) or None for no data."""
-        t1 = time.monotonic()
+        scan = self._scan_begin()
         # count series aligned to sum series by (metric, tags)
         # identity — computed lazily: a device-cache hit never needs it
         csids = present = None
@@ -1619,27 +1641,32 @@ class QueryEngine:
                         sum_c[present] = sc
                         cnt_c[present] = cc
                 num_points = int(cnt_s.sum() + cnt_c.sum())
-                # write NaN holes in place (np.where would copy 4x
-                # ~100MB at 1M series)
-                sum_s[cnt_s == 0] = np.nan
-                sum_c[cnt_c == 0] = np.nan
-                gs, gc = sum_s, sum_c
-                if self.tsdb.query_mesh is None:
-                    # pre-pad to the shape buckets (host, once; the
-                    # cache then holds padded device grids — no
-                    # per-query device pads on the warm path)
-                    from opentsdb_tpu.ops import shapes
-                    sp = shapes.shape_bucket(s)
-                    bp = shapes.shape_bucket(b)
-                    gs = shapes.pad_2d_host(gs, sp, bp, np.nan)
-                    gc = shapes.pad_2d_host(gc, sp, bp, np.nan)
+                self._record_scan(stats, scan, num_points, len(sids))
+                scan = None
+                with trace_span("query.grid_build", cells=s * b,
+                                bytes=sum_s.nbytes + sum_c.nbytes):
+                    # write NaN holes in place (np.where would copy
+                    # 4x ~100MB at 1M series)
+                    sum_s[cnt_s == 0] = np.nan
+                    sum_c[cnt_c == 0] = np.nan
+                    gs, gc = sum_s, sum_c
+                    if self.tsdb.query_mesh is None:
+                        # pre-pad to the shape buckets (host, once;
+                        # the cache then holds padded device grids —
+                        # no per-query device pads on the warm path)
+                        from opentsdb_tpu.ops import shapes
+                        sp = shapes.shape_bucket(s)
+                        bp = shapes.shape_bucket(b)
+                        gs = shapes.pad_2d_host(gs, sp, bp, np.nan)
+                        gc = shapes.pad_2d_host(gc, sp, bp, np.nan)
                 if cache is not None and num_points:
                     from opentsdb_tpu.ops.pipeline import pipeline_dtype
                     import jax
                     import jax.numpy as jnp
                     dt = pipeline_dtype()
-                    gs = jax.device_put(jnp.asarray(gs, dtype=dt))
-                    gc = jax.device_put(jnp.asarray(gc, dtype=dt))
+                    with trace_span("query.upload"):
+                        gs = jax.device_put(jnp.asarray(gs, dtype=dt))
+                        gc = jax.device_put(jnp.asarray(gc, dtype=dt))
                     cache.put(ckey, cver, (gs, gc),
                               {"num_points": num_points})
         else:
@@ -1649,8 +1676,8 @@ class QueryEngine:
             batch_c = cnt_store.materialize(csids[present],
                                             tsq.start_ms, tsq.end_ms)
             num_points = batch_s.num_points + batch_c.num_points
-        self._record_scan(stats, (time.monotonic() - t1) * 1e3,
-                          num_points, len(sids))
+        if scan is not None:
+            self._record_scan(stats, scan, num_points, len(sids))
         self.tsdb.query_limits.check(metric_name, num_points)
         if tsq.delete:
             csids, present = align()
@@ -1663,6 +1690,7 @@ class QueryEngine:
         if not fixed:
             if batch_s.num_points == 0:
                 return None
+            _h_build = trace_begin("query.grid_build")
             bidx_s, bucket_ts = ds_mod.assign_buckets(
                 batch_s.ts_ms, sub.ds_spec, tsq.start_ms, tsq.end_ms)
             bidx_c, _ = ds_mod.assign_buckets(
@@ -1675,6 +1703,7 @@ class QueryEngine:
             sidx_c = present[batch_c.series_idx].astype(np.int32)
             gc, _ = ds_mod.bucketize(batch_c.values, sidx_c, bidx_c, s,
                                      b, "sum")
+            trace_end(_h_build)
         spec = PipelineSpec(
             num_series=s, num_buckets=b, num_groups=num_groups,
             ds_function="avg", agg_name=sub.agg.name,
@@ -1689,10 +1718,11 @@ class QueryEngine:
             # the mesh with one point per present grid cell (bucketize
             # of a single-point cell reproduces the cell exactly)
             from opentsdb_tpu.ops.pipeline import avg_divide_grid
-            avg, valid = avg_divide_grid(np.asarray(gs), np.asarray(gc),
-                                         xp=np)
-            valid = np.asarray(valid)
-            sidx2, bidx2 = np.nonzero(valid)
+            with trace_span("query.grid_build", cells=s * b):
+                avg, valid = avg_divide_grid(np.asarray(gs),
+                                             np.asarray(gc), xp=np)
+                valid = np.asarray(valid)
+                sidx2, bidx2 = np.nonzero(valid)
             result, emit = self._run_device(
                 lambda: self._mesh_execute(
                     mesh, spec, avg[valid], sidx2.astype(np.int32),

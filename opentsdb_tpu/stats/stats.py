@@ -95,6 +95,9 @@ class StatsCollectorRegistry:
         # the CLOSED obs.trace.KNOWN_SPANS registry (runtime-raised
         # and tsdlint-gated), so the keyspace cannot grow unchecked
         self.stage_latency: dict[str, Histogram] = {}
+        # tsdlint: allow[unbounded-growth] the same closed keyspace:
+        # SELF time (duration minus children) of stages with children
+        self.stage_self: dict[str, Histogram] = {}
 
     def register(self, provider: Any) -> None:
         self._providers.append(provider)
@@ -107,6 +110,16 @@ class StatsCollectorRegistry:
         if h is None:
             with self._stage_lock:
                 h = self.stage_latency.setdefault(
+                    stage, Histogram(16000, 2, 1))
+        h.add(ms)
+
+    def observe_stage_self(self, stage: str, ms: float) -> None:
+        """Record the self time (ms) of one span that had children:
+        what none of them names (``tsd_stage_self_ms``)."""
+        h = self.stage_self.get(stage)
+        if h is None:
+            with self._stage_lock:
+                h = self.stage_self.setdefault(
                     stage, Histogram(16000, 2, 1))
         h.add(ms)
 
@@ -149,8 +162,11 @@ class StatsCollectorRegistry:
         # reachability evidence for the stage registry
         with self._stage_lock:
             stages = dict(self.stage_latency)
+            selfs = dict(self.stage_self)
         for stage, h in sorted(stages.items()):
             out.append(("tsd_stage_latency_ms", {"stage": stage}, h))
+        for stage, h in sorted(selfs.items()):
+            out.append(("tsd_stage_self_ms", {"stage": stage}, h))
         return out
 
     def collect(self, prefix: str = "tsd",
@@ -342,6 +358,8 @@ _GAUGE_NAMES: frozenset[str] = frozenset({
     "cluster.epoch",
     "cluster.rf",
     "datapoints.memory",
+    "runtime.gc_max_pause_ms",  # the longest pause so far
+    "startup.phase_s",       # how long a start-up phase took
     "uptime.seconds",
     "wal.sync_lag",          # records not yet fsynced: a level
     "wal.records_per_sync",  # a ratio, not a count

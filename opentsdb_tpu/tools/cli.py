@@ -73,8 +73,13 @@ def parse_common_args(argv: list[str]) -> tuple[Config, list[str]]:
 
 
 def make_tsdb(config: Config):
+    """Build the TSDB, timed from inside as the start-up phase
+    ``tsdb_init`` (the constructor; it contains ``snapshot_load`` and
+    ``wal_replay``), served as ``/api/health`` ``startup``."""
     from opentsdb_tpu.core.tsdb import TSDB
-    return TSDB(config)
+    from opentsdb_tpu.obs.trace import RUNTIME
+    with RUNTIME.phase("tsdb_init"):
+        return TSDB(config)
 
 
 # ---------------------------------------------------------------------------
@@ -91,14 +96,23 @@ def cmd_tsd(config: Config, args: list[str]) -> int:
 
     # StartupPlugin.initialize runs before the TSDB exists
     # (ref: TSDMain.java:251)
+    from opentsdb_tpu.obs.trace import RUNTIME
     startup = load_plugin_instances(config, "tsd.startup", single=True)
     tsdb = make_tsdb(config)
-    tsdb.initialize_plugins()
-    server = TSDServer(tsdb)
-    # protocol plugins sharing the process (ref: RpcPlugin.java:36,
-    # RpcManager tsd.rpc.plugins)
-    rpc_plugins = load_plugin_instances(config, "tsd.rpc",
-                                        init_arg=tsdb) or []
+    if config.get_string("tsd.cluster.role", "") != "router":
+        # JAX's client and the device, now and not inside the first
+        # request (a router runs no device program: the chip belongs
+        # to the shard process beside it)
+        with RUNTIME.phase("backend"):
+            import jax
+            jax.devices()
+    with RUNTIME.phase("plugins"):
+        tsdb.initialize_plugins()
+        server = TSDServer(tsdb)
+        # protocol plugins sharing the process (ref: RpcPlugin.java:36,
+        # RpcManager tsd.rpc.plugins)
+        rpc_plugins = load_plugin_instances(config, "tsd.rpc",
+                                            init_arg=tsdb) or []
 
     async def main():
         loop = asyncio.get_event_loop()
@@ -107,7 +121,8 @@ def cmd_tsd(config: Config, args: list[str]) -> int:
                 loop.add_signal_handler(sig, server.request_shutdown)
             except NotImplementedError:
                 pass
-        await server.start()
+        with RUNTIME.phase("bind"):
+            await server.start()
         if startup is not None:
             # server socket is bound (ref: StartupPlugin.setReady)
             startup.set_ready(tsdb)

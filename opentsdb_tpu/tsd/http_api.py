@@ -2138,6 +2138,11 @@ class HttpRpcRouter:
             # count, compile cache, storage backend, mesh, warm-up,
             # kernel execution counts (TSDB.device_info)
             "device": t.device_info(),
+            # start-up timed from inside, {phase: seconds} in the
+            # order the phases ran (tools/cli.py, TSDB.__init__;
+            # warm-up runs beside serving and lands when it ends)
+            "startup": {k: round(v, 3) for k, v
+                        in list(trace_mod.RUNTIME.startup.items())},
         }
         server = self.server
         if server is not None:

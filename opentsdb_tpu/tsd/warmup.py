@@ -171,6 +171,10 @@ def run_warmup(tsdb) -> int:
         report.seconds = time.monotonic() - t0
         if report.state == "running":
             report.state = "done"
+        # beside serving, not before it: the one start-up phase that
+        # overlaps the others
+        from opentsdb_tpu.obs.trace import RUNTIME
+        RUNTIME.startup["warmup"] = report.seconds
     log.info("warmup %s: %d programs compiled, %d failed in %.1fs",
              report.state, report.compiled, report.failed,
              report.seconds)

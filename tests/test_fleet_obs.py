@@ -372,9 +372,10 @@ class TestProfiler:
                 prof.sample_once(now_s=1000 + i)
             rep = prof.report(seconds=60, now_s=1004)
             assert "query" in rep, rep.keys()
-            assert sum(rep["query"].values()) == 5
-            stacks = list(rep["query"])
-            assert any("busy" in s for s in stacks), stacks
+            # only the stacks that hold `busy`: the role also counts
+            # any pool thread an earlier module left alive
+            assert sum(n for s, n in rep["query"].items()
+                       if "busy" in s) == 5
             text = prof.collapsed(seconds=60, now_s=1004)
             line = next(ln for ln in text.splitlines()
                         if ln.startswith("query;"))
@@ -407,7 +408,8 @@ class TestProfiler:
             # the ring kept only the trailing 5s: the always-running
             # worker contributed exactly one stack per retained second
             full = prof.report(seconds=999, now_s=2007)
-            assert sum(full["query"].values()) == 5
+            assert sum(n for s, n in full["query"].items()
+                       if "busy" in s) == 5
             resp = router.handle(req("GET", "/api/profile",
                                      seconds=60))
             assert resp.status == 200

@@ -20,11 +20,26 @@ from opentsdb_tpu.query.result_cache import QueryResultCache
 BASE = 1356998400
 
 
+_LIVE: list = []
+
+
 def _tsdb(**extra):
     # the memory backend so store methods are monkeypatchable
-    return TSDB(Config(**{"tsd.core.auto_create_metrics": "true",
-                          "tsd.storage.backend": "memory",
-                          **extra}))
+    t = TSDB(Config(**{"tsd.core.auto_create_metrics": "true",
+                       "tsd.storage.backend": "memory",
+                       **extra}))
+    _LIVE.append(t)
+    return t
+
+
+@pytest.fixture(autouse=True)
+def _shutdown_tsdbs():
+    """Every TSDB a test made is shut down after it: the fan-out
+    tests start ``tsd-subq`` pools, and one left alive shows in a
+    later module's thread census (the profiler's role counts)."""
+    yield
+    while _LIVE:
+        _LIVE.pop().shutdown()
 
 
 def _seed(t, metric="m", n=5, pts=50):
