@@ -176,6 +176,21 @@ def load_library():
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_int]
         lib.tss_bucket_reduce.restype = ctypes.c_int
+        try:
+            bucket_grid = lib.tss_bucket_grid
+        except AttributeError as e:
+            # the file name is a content hash of the source, so this is
+            # a library that was not made from tsdbstore.cc: the same
+            # failure as one that cannot be loaded, never a fallback
+            _build_error = f"{path} lacks tss_bucket_grid: {e}"
+            raise NativeBuildError(_build_error) from e
+        bucket_grid.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int]
+        bucket_grid.restype = ctypes.c_int64
         lib.tss_parse_import.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_void_p,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
@@ -202,6 +217,10 @@ def load_library():
 
 def _ptr(arr: np.ndarray):
     return arr.ctypes.data_as(ctypes.c_void_p)
+
+
+#: tss_bucket_grid's ``fn`` (tsdbstore.cc ``GridFn``)
+_GRID_FN_CODES = {"sum": 0, "count": 1, "avg": 2, "min": 3, "max": 4}
 
 
 class _NativeSeriesView:
@@ -566,6 +585,44 @@ class NativeTimeSeriesStore:
         if rc != 0:
             raise IndexError("invalid series id in bucket_reduce")
         return sums, cnts, mins, maxs
+
+    def bucket_grid(self, series_ids, start_ms: int, end_ms: int,
+                    t0: int, interval_ms: int, nbuckets: int, fn: str,
+                    grid: np.ndarray, has_data: np.ndarray) -> int:
+        """:meth:`bucket_reduce`'s pass, finished where the tail
+        program reads it: statistic ``fn`` (sum | count | avg | min |
+        max) of every bucket written ONCE into the caller's padded
+        ``grid`` ([s_pad, b_pad], float32 or float64) with the presence
+        mask ``has_data`` beside it; empty buckets and both pads hold
+        NaN / False. avg divides in f64 and rounds to the grid's type
+        once, so the cells are the bits ``fill_padded_grid`` (the
+        engine's statement of the contract) writes from
+        ``bucket_reduce``'s grids. Returns the points reduced."""
+        if self.fault_injector is not None:
+            self.fault_injector.check(self.fault_site)
+        sids = np.ascontiguousarray(series_ids, dtype=np.int64)
+        if grid.dtype not in (np.float32, np.float64) \
+                or has_data.dtype != np.bool_ \
+                or grid.ndim != 2 or grid.shape != has_data.shape \
+                or not grid.flags.c_contiguous \
+                or not has_data.flags.c_contiguous \
+                or not (grid.flags.writeable and has_data.flags.writeable):
+            raise ValueError(
+                "bucket_grid writes a C-contiguous float32/float64 grid "
+                "and a bool mask of one [s_pad, b_pad] shape")
+        s_pad, b_pad = grid.shape
+        if s_pad < len(sids) or b_pad < nbuckets:
+            raise ValueError(
+                f"a [{s_pad}, {b_pad}] grid cannot hold "
+                f"{len(sids)} series x {nbuckets} buckets")
+        num_points = self._lib.tss_bucket_grid(
+            self._h, _ptr(sids), len(sids), start_ms, end_ms, t0,
+            interval_ms, nbuckets, _GRID_FN_CODES[fn], s_pad, b_pad,
+            int(grid.dtype == np.float64), _ptr(grid), _ptr(has_data),
+            self.threads)
+        if num_points < 0:
+            raise IndexError("invalid series id in bucket_grid")
+        return int(num_points)
 
     def shards_of(self, series_ids: Iterable[int]) -> np.ndarray:
         return np.asarray([self._records[s].shard for s in series_ids],

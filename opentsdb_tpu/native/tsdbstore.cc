@@ -425,16 +425,186 @@ int tss_fill_range(void* h, const int64_t* sids, int64_t nsids,
   return 0;
 }
 
+}  // extern "C"
+
+namespace {
+
+// The per-series walk tss_bucket_reduce and tss_bucket_grid share:
+// every point of one series with start_ms <= ts <= end_ms lands in
+// bucket b = (ts - t0) / interval_ms (caller guarantees t0 <= start_ms
+// and the last bucket covers end_ms). ``emit(b, sum, cnt, mn, mx)`` is
+// called once for each bucket that holds a stored point, in rising
+// order of b; cnt counts the non-NaN ones (NaN stored values are
+// skipped, matching the device bucketize's NaN guard, ref:
+// Aggregators.runDouble skipping NaN), so cnt may be 0. mn/mx are
+// +inf/-inf unless MINMAX. Holds the buffer's lock for the walk.
+template <bool MINMAX, typename Emit>
+inline void reduce_series(SeriesBuffer* buf, int64_t start_ms,
+                          int64_t end_ms, int64_t t0,
+                          int64_t interval_ms, int64_t nbuckets,
+                          Emit&& emit) {
+  const double inf = std::numeric_limits<double>::infinity();
+  std::lock_guard<std::mutex> lock(buf->mu);
+  buf->ensure_sorted_locked();
+  int64_t lo =
+      std::lower_bound(buf->ts.begin(), buf->ts.end(), start_ms) -
+      buf->ts.begin();
+  int64_t hi =
+      std::upper_bound(buf->ts.begin(), buf->ts.end(), end_ms) -
+      buf->ts.begin();
+  // timestamps are sorted: resolve each bucket's point range with
+  // a binary search, then accumulate over a fixed-bound inner loop
+  // the compiler can vectorize (no per-point divide or
+  // data-dependent exit). The NaN guard is a branchless blend.
+  const int64_t* tsd = buf->ts.data();
+  const double* vd = buf->vals.data();
+  int64_t p = lo;
+  while (p < hi) {
+    // floor division (C++ '/' truncates toward zero): a point just
+    // below t0 must be DROPPED like the Python twin's '//' does,
+    // not folded into bucket 0
+    int64_t d = tsd[p] - t0;
+    int64_t b = d >= 0 ? d / interval_ms : -1;
+    if (b < 0) {  // cannot happen when t0 <= start_ms; be safe
+      ++p;
+      continue;
+    }
+    if (b >= nbuckets) break;
+    int64_t bucket_end = t0 + (b + 1) * interval_ms;
+    int64_t pe = std::lower_bound(tsd + p, tsd + hi, bucket_end) - tsd;
+    double sum = 0.0, cnt = 0.0, mn = inf, mx = -inf;
+    for (int64_t q = p; q < pe; ++q) {
+      double v = vd[q];
+      bool ok = v == v;
+      sum += ok ? v : 0.0;
+      cnt += ok ? 1.0 : 0.0;
+      if (MINMAX) {
+        mn = (ok && v < mn) ? v : mn;
+        mx = (ok && v > mx) ? v : mx;
+      }
+    }
+    emit(b, sum, cnt, mn, mx);
+    p = pe;
+  }
+}
+
+template <bool MINMAX>
+void bucket_reduce_rows(const std::vector<SeriesBuffer*>& bufs,
+                        int64_t start_ms, int64_t end_ms, int64_t t0,
+                        int64_t interval_ms, int64_t nbuckets,
+                        double* sum_out, double* cnt_out,
+                        double* min_out, double* max_out, int threads) {
+  const int64_t nsids = (int64_t)bufs.size();
+  std::atomic<int64_t> next{0};
+  const double inf = std::numeric_limits<double>::infinity();
+  auto worker = [&]() {
+    for (;;) {
+      int64_t i = next.fetch_add(1);
+      if (i >= nsids) break;
+      double* srow = sum_out + i * nbuckets;
+      double* crow = cnt_out + i * nbuckets;
+      double* mnrow = MINMAX ? min_out + i * nbuckets : nullptr;
+      double* mxrow = MINMAX ? max_out + i * nbuckets : nullptr;
+      for (int64_t b = 0; b < nbuckets; ++b) {
+        srow[b] = 0.0;
+        crow[b] = 0.0;
+        if (MINMAX) {
+          mnrow[b] = inf;
+          mxrow[b] = -inf;
+        }
+      }
+      reduce_series<MINMAX>(
+          bufs[i], start_ms, end_ms, t0, interval_ms, nbuckets,
+          [&](int64_t b, double sum, double cnt, double mn, double mx) {
+            srow[b] = sum;
+            crow[b] = cnt;
+            if (MINMAX) {
+              mnrow[b] = mn;
+              mxrow[b] = mx;
+            }
+          });
+    }
+  };
+  std::vector<std::thread> pool;
+  for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
+  worker();
+  for (auto& th : pool) th.join();
+}
+
+// tss_bucket_grid's statistic of one bucket (fn codes, the Python
+// wrapper's _GRID_FN_CODES): avg divides in f64; the caller rounds the
+// result to the output type once.
+enum GridFn { kSum = 0, kCount = 1, kAvg = 2, kMin = 3, kMax = 4 };
+
+// Rows [0, nsids) of the [s_pad, b_pad] grid are series, each written
+// once, left to right: NaN / 0 up to the next bucket with data, the
+// statistic / 1 there, NaN / 0 to the end of the padded row. Rows
+// [nsids, s_pad) are padding. Workers claim rows in chunks, so the
+// page faults of the caller's fresh buffers spread over the pool.
+template <typename T, bool MINMAX>
+int64_t bucket_grid_rows(const std::vector<SeriesBuffer*>& bufs,
+                         int64_t start_ms, int64_t end_ms, int64_t t0,
+                         int64_t interval_ms, int64_t nbuckets, int fn,
+                         int64_t s_pad, int64_t b_pad, T* grid,
+                         uint8_t* mask, int threads) {
+  const int64_t nsids = (int64_t)bufs.size();
+  const int64_t kChunk = 256;
+  const T nan = std::numeric_limits<T>::quiet_NaN();
+  std::atomic<int64_t> next{0};
+  std::atomic<int64_t> num_points{0};
+  auto worker = [&]() {
+    int64_t points = 0;
+    for (;;) {
+      int64_t r0 = next.fetch_add(kChunk);
+      if (r0 >= s_pad) break;
+      int64_t r1 = std::min(r0 + kChunk, s_pad);
+      for (int64_t i = r0; i < r1; ++i) {
+        T* grow = grid + i * b_pad;
+        uint8_t* mrow = mask + i * b_pad;
+        int64_t done = 0;  // columns of this row already written
+        if (i < nsids) {
+          reduce_series<MINMAX>(
+              bufs[i], start_ms, end_ms, t0, interval_ms, nbuckets,
+              [&](int64_t b, double sum, double cnt, double mn,
+                  double mx) {
+                if (cnt == 0.0) return;  // only NaNs stored: a hole
+                std::fill(grow + done, grow + b, nan);
+                std::memset(mrow + done, 0, b - done);
+                double v = fn == kSum     ? sum
+                           : fn == kCount ? cnt
+                           : fn == kAvg   ? sum / cnt
+                           : fn == kMin   ? mn
+                                          : mx;
+                grow[b] = static_cast<T>(v);
+                mrow[b] = 1;
+                done = b + 1;
+                points += (int64_t)cnt;
+              });
+        }
+        std::fill(grow + done, grow + b_pad, nan);
+        std::memset(mrow + done, 0, b_pad - done);
+      }
+    }
+    num_points.fetch_add(points);
+  };
+  std::vector<std::thread> pool;
+  for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
+  worker();
+  for (auto& th : pool) th.join();
+  return num_points.load();
+}
+
+}  // namespace
+
+extern "C" {
+
 // Fused range-scan + fixed-interval downsample pre-reduction: for
 // each series i, every point with start_ms <= ts <= end_ms lands in
-// bucket b = (ts - t0) / interval_ms (caller guarantees t0 <= start_ms
-// and the last bucket covers end_ms), accumulating sum / count / min /
-// max. Outputs are [nsids, nbuckets] row-major; cells with count 0
-// hold sum 0, min +inf, max -inf (the Python wrapper NaN-fills).
-// NaN stored values are skipped, matching the device bucketize's NaN
-// guard (ref: Aggregators.runDouble skipping NaN). min_out/max_out may
-// be null when the caller only needs sum/count. Threaded over series.
-// Returns -1 on a bad sid, else 0.
+// bucket b = (ts - t0) / interval_ms (see reduce_series),
+// accumulating sum / count / min / max. Outputs are [nsids, nbuckets]
+// row-major; cells with count 0 hold sum 0, min +inf, max -inf.
+// min_out/max_out may be null when the caller only needs sum/count.
+// Threaded over series. Returns -1 on a bad sid, else 0.
 //
 // This removes the [N]-point materialize + host->device upload for
 // simple-function downsamples: the device receives S*B cells instead
@@ -450,84 +620,45 @@ int tss_bucket_reduce(void* h, const int64_t* sids, int64_t nsids,
   if (!s->snapshot(sids, nsids, &bufs)) return -1;
   if (interval_ms <= 0 || nbuckets <= 0) return -1;
   if (threads < 1) threads = 1;
-  std::atomic<int64_t> next{0};
-  const double inf = std::numeric_limits<double>::infinity();
-  auto worker = [&]() {
-    for (;;) {
-      int64_t i = next.fetch_add(1);
-      if (i >= nsids) break;
-      double* srow = sum_out + i * nbuckets;
-      double* crow = cnt_out + i * nbuckets;
-      double* mnrow = min_out ? min_out + i * nbuckets : nullptr;
-      double* mxrow = max_out ? max_out + i * nbuckets : nullptr;
-      for (int64_t b = 0; b < nbuckets; ++b) {
-        srow[b] = 0.0;
-        crow[b] = 0.0;
-        if (mnrow) mnrow[b] = inf;
-        if (mxrow) mxrow[b] = -inf;
-      }
-      SeriesBuffer* buf = bufs[i];
-      std::lock_guard<std::mutex> lock(buf->mu);
-      buf->ensure_sorted_locked();
-      int64_t lo =
-          std::lower_bound(buf->ts.begin(), buf->ts.end(), start_ms) -
-          buf->ts.begin();
-      int64_t hi =
-          std::upper_bound(buf->ts.begin(), buf->ts.end(), end_ms) -
-          buf->ts.begin();
-      // timestamps are sorted: resolve each bucket's point range with
-      // a binary search, then accumulate over a fixed-bound inner loop
-      // the compiler can vectorize (no per-point divide or
-      // data-dependent exit). The NaN guard is a branchless blend.
-      const int64_t* tsd = buf->ts.data();
-      const double* vd = buf->vals.data();
-      int64_t p = lo;
-      while (p < hi) {
-        // floor division (C++ '/' truncates toward zero): a point just
-        // below t0 must be DROPPED like the Python twin's '//' does,
-        // not folded into bucket 0
-        int64_t d = tsd[p] - t0;
-        int64_t b = d >= 0 ? d / interval_ms : -1;
-        if (b < 0) {  // cannot happen when t0 <= start_ms; be safe
-          ++p;
-          continue;
-        }
-        if (b >= nbuckets) break;
-        int64_t bucket_end = t0 + (b + 1) * interval_ms;
-        int64_t pe =
-            std::lower_bound(tsd + p, tsd + hi, bucket_end) - tsd;
-        double sum = 0.0, cnt = 0.0;
-        if (mnrow) {
-          double mn = inf, mx = -inf;
-          for (int64_t q = p; q < pe; ++q) {
-            double v = vd[q];
-            bool ok = v == v;
-            sum += ok ? v : 0.0;
-            cnt += ok ? 1.0 : 0.0;
-            mn = (ok && v < mn) ? v : mn;
-            mx = (ok && v > mx) ? v : mx;
-          }
-          mnrow[b] = mn;
-          mxrow[b] = mx;
-        } else {
-          for (int64_t q = p; q < pe; ++q) {
-            double v = vd[q];
-            bool ok = v == v;
-            sum += ok ? v : 0.0;
-            cnt += ok ? 1.0 : 0.0;
-          }
-        }
-        srow[b] = sum;
-        crow[b] = cnt;
-        p = pe;
-      }
-    }
-  };
-  std::vector<std::thread> pool;
-  for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-  worker();
-  for (auto& th : pool) th.join();
+  if (min_out && max_out)
+    bucket_reduce_rows<true>(bufs, start_ms, end_ms, t0, interval_ms,
+                             nbuckets, sum_out, cnt_out, min_out,
+                             max_out, threads);
+  else
+    bucket_reduce_rows<false>(bufs, start_ms, end_ms, t0, interval_ms,
+                              nbuckets, sum_out, cnt_out, nullptr,
+                              nullptr, threads);
   return 0;
+}
+
+// tss_bucket_reduce's walk, finished where the tail program reads it:
+// ONE statistic (fn: GridFn) of each bucket, written once into the
+// caller's [s_pad, b_pad] grid of f32 (f64 when out_f64) with the
+// presence mask beside it; a bucket without a (non-NaN) point, the
+// columns past nbuckets and the rows past nsids hold NaN / 0. Returns
+// the number of points reduced, -1 on a bad sid or bad dimensions.
+int64_t tss_bucket_grid(void* h, const int64_t* sids, int64_t nsids,
+                        int64_t start_ms, int64_t end_ms, int64_t t0,
+                        int64_t interval_ms, int64_t nbuckets, int fn,
+                        int64_t s_pad, int64_t b_pad, int out_f64,
+                        void* grid_out, uint8_t* mask_out,
+                        int threads) {
+  Store* s = static_cast<Store*>(h);
+  std::vector<SeriesBuffer*> bufs;
+  if (!s->snapshot(sids, nsids, &bufs)) return -1;
+  if (interval_ms <= 0 || nbuckets <= 0 || s_pad < nsids ||
+      b_pad < nbuckets || fn < kSum || fn > kMax)
+    return -1;
+  if (threads < 1) threads = 1;
+  const bool minmax = fn == kMin || fn == kMax;
+#define TSS_GRID(T, MM)                                                \
+  bucket_grid_rows<T, MM>(bufs, start_ms, end_ms, t0, interval_ms,     \
+                          nbuckets, fn, s_pad, b_pad,                  \
+                          static_cast<T*>(grid_out), mask_out, threads)
+  if (out_f64)
+    return minmax ? TSS_GRID(double, true) : TSS_GRID(double, false);
+  return minmax ? TSS_GRID(float, true) : TSS_GRID(float, false);
+#undef TSS_GRID
 }
 
 }  // extern "C"

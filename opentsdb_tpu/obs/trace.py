@@ -705,6 +705,9 @@ class Tracer:
         # tsdlint: allow[unbounded-growth] keyed by run_staged's
         # tags: the eight paths its callers name x two placements
         self.tails: dict[tuple[str, str], int] = {}
+        # grids built, by who wrote the padded grid: "fused" (the
+        # store's own pass) or "host" (fill_padded_grid)
+        self.grid_builds = {"fused": 0, "host": 0}
         self._ring: deque[TraceData] = deque(
             maxlen=max(config.get_int("tsd.trace.ring", 256), 1))
         self._slow_ring: deque[TraceData] = deque(
@@ -918,12 +921,14 @@ class Tracer:
         children's intervals) feeds ``tsd_stage_self_ms`` where the
         span has children; the part of it with no program in flight
         on the device adds to ``idle_stage_ms`` of its stage; every
-        ``query.program`` counts in ``tails``."""
+        ``query.program`` counts in ``tails``, every ``query.grid_build``
+        that built a grid (tag ``fused``) in ``grid_builds``."""
         kids: dict[str, list[SpanRecord]] = {}
         for s in spans:
             kids.setdefault(s.parent_id, []).append(s)
         idle: dict[str, float] = {}
         tails = []
+        builds = []
         for s in [root] + spans:
             self_ms, occupied = s.duration_ms, s.occupied_ms
             mine = kids.get(s.span_id)
@@ -945,12 +950,16 @@ class Tracer:
             if s.name == "query.program":
                 tails.append((str(s.tags.get("path", "?")),
                               str(s.tags.get("placement", "?"))))
+            elif s.name == "query.grid_build" and "fused" in s.tags:
+                builds.append("fused" if s.tags["fused"] else "host")
         with self._lock:
             for name, ms in idle.items():
                 self.idle_stage_ms[name] = \
                     self.idle_stage_ms.get(name, 0.0) + ms
             for key in tails:
                 self.tails[key] = self.tails.get(key, 0) + 1
+            for mode in builds:
+                self.grid_builds[mode] += 1
 
     # -- retrieval -----------------------------------------------------
 
@@ -1036,11 +1045,14 @@ class Tracer:
         with self._lock:
             idle = sorted(self.idle_stage_ms.items())
             tails = sorted(self.tails.items())
+            builds = sorted(self.grid_builds.items())
         for stage, ms in idle:
             collector.record("device.idle_stage_ms", ms, stage=stage)
         for (path, placement), n in tails:
             collector.record("query.tail", n, path=path,
                              placement=placement)
+        for mode, n in builds:
+            collector.record("query.grid_build", n, mode=mode)
 
     def health_info(self) -> dict[str, Any]:
         with self._lock:

@@ -342,6 +342,42 @@ def compact_row_labels(mat: np.ndarray) -> tuple[np.ndarray, int]:
     return labels.astype(np.int32), count
 
 
+#: downsample functions the storage-side pre-reduction can serve, by
+#: the bucket statistic that answers each (avg is sum over count)
+GRID_STATS = {"sum": "sum", "zimsum": "sum", "pfsum": "sum",
+              "count": "count", "avg": "avg", "min": "min",
+              "mimmin": "min", "max": "max", "mimmax": "max"}
+
+
+def fill_padded_grid(stat: str, sums: np.ndarray, cnts: np.ndarray,
+                     mins: np.ndarray | None, maxs: np.ndarray | None,
+                     grid: np.ndarray, has_data: np.ndarray) -> None:
+    """What the tail program reads, from ``bucket_reduce``'s [S, B]
+    f64 grids: statistic ``stat`` (a value of :data:`GRID_STATS`) of
+    every bucket written in place into the padded ``grid`` ([s_pad,
+    b_pad], the compute dtype) with the presence mask ``has_data``
+    beside it; a bucket without a point and both pads hold NaN /
+    False. avg divides in f64 and rounds to the grid's dtype once.
+
+    This is the contract in plain NumPy: a store with a fused
+    ``bucket_grid`` (the native one) writes the same bits in its
+    storage pass, and every other store comes through here."""
+    s, b = cnts.shape
+    cells, mask = grid[:s, :b], has_data[:s, :b]
+    np.greater(cnts, 0.0, out=mask)
+    if stat == "avg":
+        np.divide(sums, cnts, out=cells, where=mask)
+    else:
+        src = {"sum": sums, "count": cnts, "min": mins,
+               "max": maxs}[stat]
+        np.copyto(cells, src, casting="same_kind")
+    np.copyto(cells, np.nan, where=~mask)
+    grid[s:] = np.nan
+    grid[:s, b:] = np.nan
+    has_data[s:] = False
+    has_data[:s, b:] = False
+
+
 class _UidNameCache:
     """Memoized UID->name lookups for result assembly (one cache per
     query; group loops hit the same few names over and over)."""
@@ -1350,19 +1386,63 @@ class QueryEngine:
         stats.add_stat(QueryStat.BYTES_FROM_STORAGE, num_points * 17)
         stats.add_stat(QueryStat.SUCCESSFUL_SCAN, 1)
 
-    # downsample functions the native pre-reduction can serve: linear
-    # bucket statistics (sum/count/min/max; avg is sum over count)
-    _GRID_FNS = frozenset(("sum", "zimsum", "pfsum", "count", "min",
-                           "mimmin", "max", "mimmax", "avg"))
-
     def _grid_eligible(self, sub: TSSubQuery) -> bool:
         spec = sub.ds_spec
         return (spec is not None and not spec.run_all
                 and not spec.use_calendar and spec.unit not in ("n", "y")
-                and spec.function in self._GRID_FNS
+                and spec.function in GRID_STATS
                 and spec.interval_ms > 0
                 and self.tsdb.config.get_bool("tsd.query.grid_reduce",
                                               True))
+
+    def _reduce_to_grid(self, store, sids: np.ndarray, tsq: TSQuery,
+                        bucket_ts: np.ndarray, interval_ms: int,
+                        stat: str, stats):
+        """One sub-query's storage pass -> ``(grid, has_data,
+        num_points)``: the tail program's operands made ONCE, padded
+        to the geometric shape buckets (cached device grids are
+        pre-padded, warm queries never pay a per-query device pad,
+        and on BOTH the single-device and mesh paths compiled
+        programs are keyed on bucketed shapes, so warmup's
+        pre-compiles and repeat queries of a class actually hit) and
+        in the compute dtype, so the upload's cast and pads find
+        nothing to do.
+
+        A store with a fused ``bucket_grid`` (the native one) writes
+        them in its storage pass; any other reduces to f64 grids that
+        :func:`fill_padded_grid` finishes. Chosen by what the store
+        offers: two paths that share the contract and no logic."""
+        from opentsdb_tpu.ops import shapes
+        from opentsdb_tpu.ops.pipeline import pipeline_dtype
+        b = len(bucket_ts)
+        padded = (shapes.shape_bucket(len(sids)), shapes.shape_bucket(b))
+        dtype = np.dtype(pipeline_dtype())
+        fused = getattr(store, "bucket_grid", None)
+        cells = padded[0] * padded[1]
+        tags = {"fused": fused is not None, "cells": cells,
+                "bytes": cells * (dtype.itemsize + 1)}
+
+        def alloc():
+            return np.empty(padded, dtype), np.empty(padded, np.bool_)
+
+        window = (sids, tsq.start_ms, tsq.end_ms, int(bucket_ts[0]),
+                  interval_ms, b)
+        if fused is not None:
+            with trace_span("query.grid_build", stage="alloc", **tags):
+                grid, has_data = alloc()
+            scan = self._scan_begin()
+            num_points = fused(*window, stat, grid, has_data)
+            self._record_scan(stats, scan, num_points, len(sids))
+            return grid, has_data, num_points
+        scan = self._scan_begin()
+        reduced = store.bucket_reduce(
+            *window, want_minmax=stat in ("min", "max"))
+        num_points = int(reduced[1].sum())
+        self._record_scan(stats, scan, num_points, len(sids))
+        with trace_span("query.grid_build", stage="fill_pad", **tags):
+            grid, has_data = alloc()
+            fill_padded_grid(stat, *reduced, grid, has_data)
+        return grid, has_data, num_points
 
     def _grid_pipeline(self, store, sids: np.ndarray, tsq: TSQuery,
                        sub: TSSubQuery, metric_name: str,
@@ -1386,7 +1466,6 @@ class QueryEngine:
         if len(sids) * b > budget:
             return None  # blocked streaming handles the oversized case
         fn = ds_fn_override or ds_spec.function
-        want_minmax = fn in ("min", "mimmin", "max", "mimmax")
         # small grids run the tail on the host CPU backend; decision is
         # per padded-shape class, matching warmup's pre-compiles
         host_dev = None
@@ -1424,52 +1503,24 @@ class QueryEngine:
                 else:
                     (grid, has_data), meta = hit
                     num_points = meta["num_points"]
-        scan = self._scan_begin()
-        if grid is None:
-            sums, cnts, mins, maxs = store.bucket_reduce(
-                sids, tsq.start_ms, tsq.end_ms, int(bucket_ts[0]),
-                ds_spec.interval_ms, b, want_minmax=want_minmax)
-            num_points = int(cnts.sum())
-        self._record_scan(stats, scan, num_points, len(sids))
+        built = grid is None
+        if built:
+            grid, has_data, num_points = self._reduce_to_grid(
+                store, sids, tsq, bucket_ts, ds_spec.interval_ms,
+                GRID_STATS[fn], stats)
+        else:
+            self._record_scan(stats, self._scan_begin(), num_points,
+                              len(sids))
         self.tsdb.query_limits.check(metric_name, num_points)
         if tsq.delete and hasattr(store, "delete_range"):
             store.delete_range(sids, tsq.start_ms, tsq.end_ms)
         if num_points == 0:
             return (None, None, bucket_ts)
-        if grid is None:
-            # pad to the geometric shape buckets NOW (host numpy,
-            # once): cached device grids are pre-padded, warm queries
-            # never pay a per-query device pad, and — on BOTH the
-            # single-device and mesh paths — compiled programs are
-            # keyed on bucketed shapes, so warmup's pre-compiles and
-            # repeat queries of the same class actually hit
-            from opentsdb_tpu.ops import shapes
-            sp = shapes.shape_bucket(len(sids))
-            bp = shapes.shape_bucket(b)
-            # an f64 grid and its presence mask
-            _h_build = trace_begin("query.grid_build", stage="fill_pad",
-                                   cells=sp * bp, bytes=sp * bp * 9)
-            present = cnts > 0
-            if fn in ("sum", "zimsum", "pfsum"):
-                grid = np.where(present, sums, np.nan)
-            elif fn == "count":
-                grid = np.where(present, cnts, np.nan)
-            elif fn == "avg":
-                grid = np.where(present, sums / np.maximum(cnts, 1.0),
-                                np.nan)
-            elif fn in ("min", "mimmin"):
-                grid = np.where(present, mins, np.nan)
-            else:  # max, mimmax
-                grid = np.where(present, maxs, np.nan)
-            has_data = present
-            grid = shapes.pad_2d_host(grid, sp, bp, np.nan)
-            has_data = shapes.pad_2d_host(has_data, sp, bp, False)
-            trace_end(_h_build)
-            if cache is not None and mesh is None:
-                from opentsdb_tpu.ops.pipeline import put_grid
-                grid, has_data = put_grid(grid, has_data)
-                cache.put(ckey, cver, (grid, has_data),
-                          {"num_points": num_points})
+        if built and cache is not None and mesh is None:
+            from opentsdb_tpu.ops.pipeline import put_grid
+            grid, has_data = put_grid(grid, has_data)
+            cache.put(ckey, cver, (grid, has_data),
+                      {"num_points": num_points})
         t2 = time.monotonic()
         spec = PipelineSpec(
             num_series=len(sids), num_buckets=b, num_groups=num_groups,

@@ -56,7 +56,7 @@ from opentsdb_tpu.utils import datetime_util
 
 # downsample functions whose bucket statistic decomposes into the
 # sum/count/min/max partials this plan maintains (avg = sum / count) —
-# mirrors the rollup tier decomposition AND the engine's _GRID_FNS, so
+# mirrors the rollup tier decomposition AND the engine's GRID_STATS, so
 # every continuous query is also batch-grid-eligible
 DECOMPOSABLE_DS = frozenset(("sum", "zimsum", "pfsum", "count", "min",
                              "mimmin", "max", "mimmax", "avg"))

@@ -324,7 +324,11 @@ class TestMaterialization:
             _seed(t, "ctl.hot")
             _seed(t, "ctl.cold")
             router = HttpRpcRouter(t)
-            _pump_shapes(router, n=8, metric="ctl.hot")
+            # score = count x p50 of the durations, and the p50 of two
+            # is the slower one: 32 against 2, so that one request
+            # stalled 4x (a collection, a busy worker) cannot turn the
+            # ranking as it could at 8 against 2
+            _pump_shapes(router, n=32, metric="ctl.hot")
             _pump_shapes(router, n=2, metric="ctl.cold")
             t.control.tick()
             mats = json.loads(_get(
