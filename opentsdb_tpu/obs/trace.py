@@ -708,6 +708,10 @@ class Tracer:
         # grids built, by who wrote the padded grid: "fused" (the
         # store's own pass) or "host" (fill_padded_grid)
         self.grid_builds = {"fused": 0, "host": 0}
+        # plan stages, by what the engine's plan index did for them:
+        # "hit" (planned from the cached index), "built" (built it
+        # first), "bypass" (a selection that is not a whole metric)
+        self.plans = {"hit": 0, "built": 0, "bypass": 0}
         self._ring: deque[TraceData] = deque(
             maxlen=max(config.get_int("tsd.trace.ring", 256), 1))
         self._slow_ring: deque[TraceData] = deque(
@@ -922,13 +926,16 @@ class Tracer:
         span has children; the part of it with no program in flight
         on the device adds to ``idle_stage_ms`` of its stage; every
         ``query.program`` counts in ``tails``, every ``query.grid_build``
-        that built a grid (tag ``fused``) in ``grid_builds``."""
+        that built a grid (tag ``fused``) in ``grid_builds``, every
+        ``query.plan`` that reached its filters (tag ``index``) in
+        ``plans``."""
         kids: dict[str, list[SpanRecord]] = {}
         for s in spans:
             kids.setdefault(s.parent_id, []).append(s)
         idle: dict[str, float] = {}
         tails = []
         builds = []
+        plans = []
         for s in [root] + spans:
             self_ms, occupied = s.duration_ms, s.occupied_ms
             mine = kids.get(s.span_id)
@@ -952,6 +959,9 @@ class Tracer:
                               str(s.tags.get("placement", "?"))))
             elif s.name == "query.grid_build" and "fused" in s.tags:
                 builds.append("fused" if s.tags["fused"] else "host")
+            elif s.name == "query.plan" and s.tags.get("index") \
+                    in self.plans:
+                plans.append(s.tags["index"])
         with self._lock:
             for name, ms in idle.items():
                 self.idle_stage_ms[name] = \
@@ -960,6 +970,8 @@ class Tracer:
                 self.tails[key] = self.tails.get(key, 0) + 1
             for mode in builds:
                 self.grid_builds[mode] += 1
+            for state in plans:
+                self.plans[state] += 1
 
     # -- retrieval -----------------------------------------------------
 
@@ -1046,6 +1058,7 @@ class Tracer:
             idle = sorted(self.idle_stage_ms.items())
             tails = sorted(self.tails.items())
             builds = sorted(self.grid_builds.items())
+            plans = sorted(self.plans.items())
         for stage, ms in idle:
             collector.record("device.idle_stage_ms", ms, stage=stage)
         for (path, placement), n in tails:
@@ -1053,6 +1066,8 @@ class Tracer:
                              placement=placement)
         for mode, n in builds:
             collector.record("query.grid_build", n, mode=mode)
+        for state, n in plans:
+            collector.record("query.plan", n, index=state)
 
     def health_info(self) -> dict[str, Any]:
         with self._lock:

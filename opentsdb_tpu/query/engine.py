@@ -21,7 +21,9 @@ Compiles one validated :class:`TSQuery` into the array pipeline:
 from __future__ import annotations
 
 import logging
+import threading
 import time
+from collections import OrderedDict
 from dataclasses import replace
 from typing import Any, Sequence
 
@@ -161,13 +163,20 @@ class TagMatrix:
     explicit_tags, tsuids) reads this matrix with array ops — the
     previous list-of-dicts walk cost ~0.4 s per 200k series and showed
     up directly in the north-star query budget.
+
+    ``origin`` is ``(index, rows)`` on a matrix selected out of a
+    cached :class:`PlanIndex`: its row i is row ``rows[i]`` of the
+    index, so what the index keeps per series of the metric (group
+    labels) is gathered instead of derived again.
     """
 
-    __slots__ = ("kids", "vids")
+    __slots__ = ("kids", "vids", "origin")
 
-    def __init__(self, kids: np.ndarray, vids: np.ndarray):
+    def __init__(self, kids: np.ndarray, vids: np.ndarray,
+                 origin: "tuple[PlanIndex, np.ndarray] | None" = None):
         self.kids = kids        # int64 [K] sorted distinct tagk ids
         self.vids = vids        # int64 [S, K]; -1 = key absent
+        self.origin = origin
 
     @classmethod
     def from_triples(cls, sids: np.ndarray, triples: np.ndarray,
@@ -216,8 +225,18 @@ class TagMatrix:
             return self.vids[:, j]
         return None
 
+    def distinct(self, kid: int) -> np.ndarray:
+        """Sorted distinct tagv ids present in one key's column."""
+        col = self.col(kid)
+        if col is None:
+            return np.empty(0, dtype=np.int64)
+        return np.unique(col[col >= 0])
+
     def select(self, mask_or_idx) -> "TagMatrix":
-        return TagMatrix(self.kids, self.vids[mask_or_idx])
+        origin = self.origin
+        if origin is not None:
+            origin = (origin[0], origin[1][mask_or_idx])
+        return TagMatrix(self.kids, self.vids[mask_or_idx], origin)
 
     def tags_of(self, i: int) -> list[tuple[int, int]]:
         """Series i's present (kid, vid) pairs, kid-ascending."""
@@ -340,6 +359,87 @@ def compact_row_labels(mat: np.ndarray) -> tuple[np.ndarray, int]:
             u2, labels = np.unique(labels, return_inverse=True)
             count = len(u2)
     return labels.astype(np.int32), count
+
+
+def group_labels(tags: TagMatrix, gb_kids: Sequence[int]
+                 ) -> tuple[np.ndarray, int]:
+    """Group label per row of ``tags`` + group count for the group-by
+    keys ``gb_kids``: rows with equal tagv-id tuples share a label, and
+    labels ascend with the tuple (-1 = key absent sorts first)."""
+    mat = np.empty((tags.num_series, len(gb_kids)), dtype=np.int64)
+    for j, k in enumerate(gb_kids):
+        col = tags.col(k)
+        mat[:, j] = col if col is not None else -1
+    return compact_row_labels(mat)
+
+
+class PlanIndex:
+    """What the plan stage needs of one metric's tag index and that
+    depends on nothing else: the metric's whole :class:`TagMatrix`,
+    and, built by the first request that asks, each tag key's distinct
+    tagv ids and each group-by key set's label for every series.
+
+    The tag index only appends, so its series count versions all of
+    it: the engine keeps one per (store, metric) in
+    ``tsdb._tagmat_cache`` and drops it whole when ``version`` no
+    longer equals the index's length. UID names are not kept here: a
+    filter's string predicate reads the live dictionary per request.
+
+    The lazy parts build under one lock (two sub-queries of a request
+    plan side by side: the second waits and reads what the first
+    built). At most :data:`LABEL_SETS` labellings are kept, least
+    recently used out (4 bytes a series each).
+    """
+
+    LABEL_SETS = 8
+
+    __slots__ = ("version", "tags", "_distinct", "_labels", "_lock")
+
+    def __init__(self, version: int, tags: TagMatrix):
+        self.version = version
+        self.tags = tags
+        # tsdlint: allow[unbounded-growth] keyed by tag key: at most
+        # one entry a column of ``tags``; gone with the index
+        self._distinct: dict[int, np.ndarray] = {}
+        self._labels: OrderedDict[tuple, tuple[np.ndarray, int]] = \
+            OrderedDict()
+        self._lock = threading.Lock()
+
+    @property
+    def num_series(self) -> int:
+        return self.tags.num_series
+
+    def col(self, kid: int) -> np.ndarray | None:
+        return self.tags.col(kid)
+
+    def distinct(self, kid: int) -> np.ndarray:
+        found = self._distinct.get(kid)
+        if found is None:
+            with self._lock:
+                found = self._distinct.get(kid)
+                if found is None:
+                    found = self._distinct[kid] = self.tags.distinct(kid)
+        return found
+
+    def labels(self, gb_kids: Sequence[int]) -> tuple[np.ndarray, int]:
+        """:func:`group_labels` of the whole metric (int32 [S], count);
+        the array is shared between requests: read it, never write."""
+        key = tuple(gb_kids)
+        with self._lock:
+            found = self._labels.get(key)
+            if found is None:
+                found = self._labels[key] = group_labels(self.tags, key)
+                while len(self._labels) > self.LABEL_SETS:
+                    self._labels.popitem(last=False)
+            else:
+                self._labels.move_to_end(key)
+        return found
+
+    def select(self, rows: np.ndarray) -> TagMatrix:
+        """The matrix of the index's rows ``rows`` (ascending
+        positions), remembering where it came from."""
+        return TagMatrix(self.tags.kids, self.tags.vids[rows],
+                         (self, rows))
 
 
 #: downsample functions the storage-side pre-reduction can serve, by
@@ -752,7 +852,10 @@ class QueryEngine:
             stats.add_stat(QueryStat.ROWS_PRE_FILTER, len(sids))
 
         # --- filters -> series mask (ref: findSpans post-scan filters)
-        sids, tag_mat = self._apply_filters(store, sub, sids)
+        sids, tag_mat, index_state = self._apply_filters(store, sub,
+                                                         sids)
+        if _h_plan is not None:
+            _h_plan.tag(index=index_state)
         if len(sids) == 0:
             trace_end(_h_plan)
             return []
@@ -1846,23 +1949,35 @@ class QueryEngine:
 
     def _apply_filters(self, store: TimeSeriesStore, sub: TSSubQuery,
                        sids: np.ndarray
-                       ) -> tuple[np.ndarray, TagMatrix]:
+                       ) -> tuple[np.ndarray, TagMatrix, str]:
+        """The sub-query's series and their tags, and what the plan
+        index did for it: ``hit`` (planned from the cached
+        :class:`PlanIndex`), ``built`` (built it first) or ``bypass``
+        (``sids`` is not the metric's whole index: tsuids, a write
+        between the selection and here)."""
         metric_id = store.series(int(sids[0])).metric_id
         idx = store.metric_index(metric_id)
+        index = None
+        state = "bypass"
         if idx is not None and not sub.tsuids:
             idx_sids, triples = idx.arrays()
-            # per-(store, metric) matrix cache: the index is
-            # append-only, so the series count versions it
-            tm_cache = self.tsdb._tagmat_cache
-            tm_key = (_store_id(store), metric_id)
-            hit = tm_cache.get(tm_key)
-            if hit is not None and hit[0] == len(idx_sids) \
-                    and sids is idx_sids:
-                tags = hit[1]
+            if sids is idx_sids:
+                # per-(store, metric) plan index: the tag index is
+                # append-only, so the series count versions it. Built
+                # aside and published by one assignment: two cold
+                # sub-queries may both build, either entry is right
+                tm_cache = self.tsdb._tagmat_cache
+                tm_key = (_store_id(store), metric_id)
+                index = tm_cache.get(tm_key)
+                state = "hit"
+                if index is None or index.version != len(idx_sids):
+                    index = tm_cache[tm_key] = PlanIndex(
+                        len(idx_sids),
+                        TagMatrix.from_triples(sids, triples))
+                    state = "built"
+                tags = index.tags
             else:
                 tags = TagMatrix.from_triples(sids, triples)
-                if sids is idx_sids:
-                    tm_cache[tm_key] = (len(idx_sids), tags)
         else:
             # tsuid queries name few series; a record walk is fine here
             rows = []
@@ -1874,9 +1989,11 @@ class QueryEngine:
                        if rows else np.empty((0, 3), dtype=np.int64))
             tags = TagMatrix.from_triples(sids, triples)
         if sub.filters:
-            mask = self._filter_eval.apply(sub.filters, sids, triples)
-            sids = sids[mask]
-            tags = tags.select(mask)
+            source = index if index is not None else tags
+            rows = np.flatnonzero(
+                self._filter_eval.apply(sub.filters, source))
+            sids = sids[rows]
+            tags = source.select(rows)
         if sub.explicit_tags and sub.filters:
             # keep series whose tag-KEY set equals the filters' key set
             # (ref: explicit_tags pruning in findSpans)
@@ -1897,7 +2014,7 @@ class QueryEngine:
                     .all(axis=1)
             sids = sids[keep]
             tags = tags.select(keep)
-        return sids, tags
+        return sids, tags, state
 
     @staticmethod
     def _group_ids(tags: TagMatrix, gb_kids: list[int]
@@ -1905,14 +2022,24 @@ class QueryEngine:
         """Group id per series + group count. Group key = tuple of
         group-by tagv ids; ids come out ordered by concatenated tagv id,
         matching the reference's ByteMap ordering of group keys
-        (ref: GroupByAndAggregateCB, TsdbQuery.java:995-1036)."""
+        (ref: GroupByAndAggregateCB, TsdbQuery.java:995-1036).
+
+        Rows selected out of a :class:`PlanIndex` gather the metric's
+        cached labels; dropping labels from an ordered labelling keeps
+        it ordered, so only the groups the selection emptied are
+        closed up. Any other matrix is labelled from its columns."""
         if not gb_kids:
             return np.zeros(tags.num_series, dtype=np.int32), 1
-        mat = np.empty((tags.num_series, len(gb_kids)), dtype=np.int64)
-        for j, k in enumerate(gb_kids):
-            col = tags.col(k)
-            mat[:, j] = col if col is not None else -1
-        return compact_row_labels(mat)
+        if tags.origin is None:
+            return group_labels(tags, gb_kids)
+        index, rows = tags.origin
+        labels, count = index.labels(gb_kids)
+        labels = labels[rows]
+        present = np.bincount(labels, minlength=count) > 0
+        if present.all():
+            return labels, count
+        renumber = np.cumsum(present, dtype=np.int32) - 1
+        return renumber[labels], int(renumber[-1]) + 1
 
     # ------------------------------------------------------------------
 

@@ -12,7 +12,18 @@ Evaluation is vectorized: instead of the reference's per-row
 resolves the set of matching tagv UIDs once (string predicates run over
 the distinct tag values of the metric, typically tiny compared to the
 series count) and then the series mask is a numpy ``isin`` over the
-metric's columnar tag index.
+metric's tag column.
+
+What is kept between requests, and by whom: the engine caches one
+``PlanIndex`` per (store, metric) — the series x tag-key matrix of tagv
+ids, each column's distinct ids, the group labels of recent group-by
+key sets — versioned by the metric's series count (the tag index only
+appends; a new series drops the whole entry). What is still walked per
+request: ``matching_tagv_ids`` reads the NAME of every distinct value of
+a filtered key from the live UID dictionary and runs the filter's
+predicate on it (2,000 rack names or 1,000,000 host names alike), so a
+renamed value shows in the next request. Filters that match every value
+(``*``, ``.*``) and ``not_key`` read the column alone.
 """
 
 from __future__ import annotations
@@ -31,6 +42,9 @@ class TagVFilter:
 
     filter_name = ""
     groupby_default = False
+    #: True when every present value matches (``*``, ``.*``): the
+    #: evaluator then reads the key's presence and walks no names
+    matches_all = False
 
     def __init__(self, tagk: str, filter_expr: str, group_by: bool = False):
         if not tagk:
@@ -258,7 +272,15 @@ def filter_types() -> dict[str, dict]:
 
 
 class FilterEvaluator:
-    """Vectorized filter application over a metric's columnar tag index."""
+    """Vectorized filter application over a metric's tag columns.
+
+    The columns come from a ``TagMatrix`` (``col(kid)``: the tagv id
+    of every series, -1 where the key is absent) or from the engine's
+    cached ``PlanIndex`` over one, which also keeps each column's
+    distinct tagv ids (``distinct(kid)``) for as long as the metric
+    gains no series; a plain matrix computes them on the spot. Names
+    are never cached: every request reads the live UID dictionary.
+    """
 
     def __init__(self, uids):
         self._uids = uids
@@ -271,21 +293,17 @@ class FilterEvaluator:
                 if filt.match_value(tagv.get_name(int(vid)))]
         return np.asarray(keep, dtype=np.int64)
 
-    def apply(self, filters: Sequence[TagVFilter], sids: np.ndarray,
-              tag_triples: np.ndarray) -> np.ndarray:
-        """Return the boolean keep-mask over ``sids``.
+    def apply(self, filters: Sequence[TagVFilter], tags) -> np.ndarray:
+        """Return the boolean keep-mask over the series of ``tags``.
 
-        ``tag_triples`` is the metric index's [T,3] (sid, tagk, tagv).
         Every filter must pass — same-key and cross-key filters all AND
         together (ref: TsdbQuery/SaltScanner filter chain semantics).
+        A filter that says it matches every value (``*``, ``.*``) is
+        the key's presence; ``not_key`` is its absence; any other runs
+        its string predicate over the column's distinct values.
         """
-        if len(sids) == 0:
-            return np.zeros(0, dtype=bool)
-        keep = np.ones(len(sids), dtype=bool)
-        # vectorized sid -> position mapping (a Python dict walk over
-        # the triples costs ~0.4 s at 200k series)
-        order = np.argsort(sids, kind="stable")
-        sorted_sids = sids[order]
+        n = tags.num_series
+        keep = np.ones(n, dtype=bool)
         by_key: dict[str, list[TagVFilter]] = {}
         for f in filters:
             by_key.setdefault(f.tagk, []).append(f)
@@ -295,27 +313,20 @@ class FilterEvaluator:
             except LookupError:
                 # unknown tag key: only not_key filters can match
                 if not all(f.match_absent for f in flist):
-                    return np.zeros(len(sids), dtype=bool)
+                    return np.zeros(n, dtype=bool)
                 continue
-            rows = tag_triples[tag_triples[:, 1] == kid]
-            has_key = np.zeros(len(sids), dtype=bool)
-            series_tagv = np.full(len(sids), -1, dtype=np.int64)
-            ins = np.searchsorted(sorted_sids, rows[:, 0])
-            ins_c = np.minimum(ins, len(sids) - 1)
-            valid = sorted_sids[ins_c] == rows[:, 0]
-            pos = order[ins_c[valid]]
-            has_key[pos] = True
-            series_tagv[pos] = rows[valid, 2]
-            key_mask = np.ones(len(sids), dtype=bool)
+            col = tags.col(kid)
+            has_key = col >= 0 if col is not None \
+                else np.zeros(n, dtype=bool)
             for f in flist:
-                if f.match_absent and not f.includes_present:
-                    fmask = ~has_key
-                else:
-                    cand = np.unique(series_tagv[has_key])
-                    matched = self.matching_tagv_ids(f, cand)
-                    fmask = has_key & np.isin(series_tagv, matched)
                 # same-key filters AND together like the reference's
                 # per-key chain (all must pass)
-                key_mask &= fmask
-            keep &= key_mask
+                if f.match_absent and not f.includes_present:
+                    keep &= ~has_key
+                elif f.matches_all or col is None:
+                    keep &= has_key
+                else:
+                    matched = self.matching_tagv_ids(
+                        f, tags.distinct(kid))
+                    keep &= np.isin(col, matched)
         return keep

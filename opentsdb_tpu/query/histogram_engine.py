@@ -83,7 +83,7 @@ def run_histogram_subquery(tsdb, tsq: TSQuery, sub: TSSubQuery) -> list:
     _, triples = idx.arrays()
     tag_mat = TagMatrix.from_triples(sids, triples)
     if sub.filters:
-        mask = FilterEvaluator(uids).apply(sub.filters, sids, triples)
+        mask = FilterEvaluator(uids).apply(sub.filters, tag_mat)
         sids = sids[mask]
         tag_mat = tag_mat.select(mask)
         if len(sids) == 0:

@@ -121,7 +121,7 @@ def _run_over_store(tsdb, tsq, sub, store, mid, alpha, max_buckets,
     _, triples = idx.arrays()
     tag_mat = TagMatrix.from_triples(sids, triples)
     if sub.filters:
-        mask = FilterEvaluator(uids).apply(sub.filters, sids, triples)
+        mask = FilterEvaluator(uids).apply(sub.filters, tag_mat)
         sids = sids[mask]
         tag_mat = tag_mat.select(mask)
         if len(sids) == 0:

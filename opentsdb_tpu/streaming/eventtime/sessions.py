@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from opentsdb_tpu.query.engine import TagMatrix
 from opentsdb_tpu.streaming.plan import SharedPartial
 
 
@@ -91,13 +92,8 @@ class SessionPartial(SharedPartial):
             self._slots[sid] = -1
             return -1
         if check_filters and self.filters:
-            triples = (np.asarray(
-                [(sid, k, v) for k, v in rec.tags],
-                dtype=np.int64).reshape(-1, 3)
-                if rec.tags else np.empty((0, 3), dtype=np.int64))
             mask = self._filter_eval.apply(
-                self.filters, np.asarray([sid], dtype=np.int64),
-                triples)
+                self.filters, TagMatrix.from_pairs([rec.tags]))
             if not bool(mask[0]):
                 self._slots[sid] = -1
                 return -1

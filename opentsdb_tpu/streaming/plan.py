@@ -51,6 +51,7 @@ import numpy as np
 from opentsdb_tpu.ops import downsample as ds_mod
 from opentsdb_tpu.ops import stream_fold
 from opentsdb_tpu.query import filters as filters_mod
+from opentsdb_tpu.query.engine import QueryEngine, TagMatrix
 from opentsdb_tpu.query.model import BadRequestError, TSSubQuery
 from opentsdb_tpu.utils import datetime_util
 
@@ -499,8 +500,8 @@ class SharedPartial:
             if len(sids) and self.filters:
                 idx = store.metric_index(self.metric_id)
                 _, triples = idx.arrays()
-                mask = self._filter_eval.apply(self.filters, sids,
-                                               triples)
+                mask = self._filter_eval.apply(
+                    self.filters, TagMatrix.from_triples(sids, triples))
                 sids = sids[mask]
             for sid in np.asarray(sids).tolist():
                 self._admit_locked(int(sid), check_filters=False)
@@ -698,13 +699,8 @@ class SharedPartial:
             self._slots[sid] = -1
             return -1
         if check_filters and self.filters:
-            triples = (np.asarray(
-                [(sid, k, v) for k, v in rec.tags],
-                dtype=np.int64).reshape(-1, 3)
-                if rec.tags else np.empty((0, 3), dtype=np.int64))
             mask = self._filter_eval.apply(
-                self.filters, np.asarray([sid], dtype=np.int64),
-                triples)
+                self.filters, TagMatrix.from_pairs([rec.tags]))
             if not bool(mask[0]):
                 self._slots[sid] = -1
                 return -1
@@ -1179,7 +1175,6 @@ class PlanView:
         cached = self._groups_cache
         if cached is not None and cached[0] == self.shared.member_seq:
             return cached[1]
-        from opentsdb_tpu.query.engine import QueryEngine, TagMatrix
         uids = self.shared.tsdb.uids
         tag_mat = TagMatrix.from_pairs(self.shared._tag_pairs)
         gb_tagks = sorted({f.tagk for f in self.sub.filters

@@ -9,6 +9,7 @@ TestTagVNotKeyFilter; ref: src/query/filter/TagVFilter.java:70).
 import numpy as np
 import pytest
 
+from opentsdb_tpu.query.engine import TagMatrix
 from opentsdb_tpu.query.filters import (FilterEvaluator, build_filter,
                                         filter_types, get_filter,
                                         tags_to_filters)
@@ -138,7 +139,7 @@ class TestFilterEvaluator:
         mid = tsdb.uids.metrics.get_id("m")
         sids = tsdb.store.series_ids_for_metric(mid)
         _, triples = tsdb.store.metric_index(mid).arrays()
-        return sids, triples
+        return sids, TagMatrix.from_triples(sids, triples)
 
     def hosts(self, tsdb, sids, mask):
         out = []
@@ -151,61 +152,54 @@ class TestFilterEvaluator:
         return sorted(out)
 
     def test_literal_filter(self, tsdb):
-        sids, triples = self.seed(tsdb)
+        sids, tags = self.seed(tsdb)
         ev = FilterEvaluator(tsdb.uids)
-        mask = ev.apply([get_filter("host", "literal_or(web01)")],
-                        sids, triples)
+        mask = ev.apply([get_filter("host", "literal_or(web01)")], tags)
         assert self.hosts(tsdb, sids, mask) == ["web01"]
 
     def test_wildcard_filter(self, tsdb):
-        sids, triples = self.seed(tsdb)
+        sids, tags = self.seed(tsdb)
         ev = FilterEvaluator(tsdb.uids)
-        mask = ev.apply([get_filter("host", "wildcard(web*)")],
-                        sids, triples)
+        mask = ev.apply([get_filter("host", "wildcard(web*)")], tags)
         assert self.hosts(tsdb, sids, mask) == ["web01", "web02"]
 
     def test_missing_tag_never_matches_value_filter(self, tsdb):
-        sids, triples = self.seed(tsdb)
+        sids, tags = self.seed(tsdb)
         ev = FilterEvaluator(tsdb.uids)
-        mask = ev.apply([get_filter("host", "regexp(.*)")], sids,
-                        triples)
+        mask = ev.apply([get_filter("host", "regexp(.*)")], tags)
         # the host-less series must not match
         assert "<none>" not in self.hosts(tsdb, sids, mask)
 
     def test_not_key_matches_only_absent(self, tsdb):
-        sids, triples = self.seed(tsdb)
+        sids, tags = self.seed(tsdb)
         ev = FilterEvaluator(tsdb.uids)
-        mask = ev.apply([get_filter("host", "not_key()")], sids,
-                        triples)
+        mask = ev.apply([get_filter("host", "not_key()")], tags)
         assert self.hosts(tsdb, sids, mask) == ["<none>"]
 
     def test_filters_on_same_key_and_together(self, tsdb):
         # every filter must pass, same-key included (reference chain)
-        sids, triples = self.seed(tsdb)
+        sids, tags = self.seed(tsdb)
         ev = FilterEvaluator(tsdb.uids)
         mask = ev.apply([get_filter("host", "wildcard(web*)"),
                          get_filter("host", "not_literal_or(web02)")],
-                        sids, triples)
+                        tags)
         assert self.hosts(tsdb, sids, mask) == ["web01"]
 
     def test_filters_across_keys_and_together(self, tsdb):
-        sids, triples = self.seed(tsdb)
+        sids, tags = self.seed(tsdb)
         ev = FilterEvaluator(tsdb.uids)
         mask = ev.apply([get_filter("host", "wildcard(*)"),
-                         get_filter("dc", "literal_or(lax)")],
-                        sids, triples)
+                         get_filter("dc", "literal_or(lax)")], tags)
         assert self.hosts(tsdb, sids, mask) == ["web01", "web02"]
 
     def test_unknown_tag_key_matches_nothing(self, tsdb):
-        sids, triples = self.seed(tsdb)
+        sids, tags = self.seed(tsdb)
         ev = FilterEvaluator(tsdb.uids)
-        mask = ev.apply([get_filter("nosuch", "literal_or(x)")],
-                        sids, triples)
+        mask = ev.apply([get_filter("nosuch", "literal_or(x)")], tags)
         assert not mask.any()
 
     def test_unknown_tag_key_not_key_matches_all(self, tsdb):
-        sids, triples = self.seed(tsdb)
+        sids, tags = self.seed(tsdb)
         ev = FilterEvaluator(tsdb.uids)
-        mask = ev.apply([get_filter("nosuch", "not_key()")], sids,
-                        triples)
+        mask = ev.apply([get_filter("nosuch", "not_key()")], tags)
         assert mask.all()

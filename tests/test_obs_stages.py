@@ -360,6 +360,50 @@ def test_a_served_grid_query_names_every_stage(flags, placement,
         tsdb.shutdown()
 
 
+@pytest.mark.parametrize("backend", ["native", "memory"])
+def test_the_plan_span_says_what_the_plan_index_did(backend):
+    # PR 28: the first grid query over a metric builds its plan index,
+    # the next one plans from it, a tsuid query cannot use it
+    tsdb = mk_tsdb(**{"tsd.storage.backend": backend})
+    router = HttpRpcRouter(tsdb)
+
+    def plan_tags(sub):
+        body = json.dumps({
+            "start": BASE * 1000, "end": (BASE + 600) * 1000,
+            "queries": [{"aggregator": "sum", "downsample": "1m-avg",
+                         **sub}]}).encode()
+        resp = router.handle(req("POST", "/api/query", body))
+        assert resp.status == 200, resp.body
+        (root,) = json.loads(router.handle(req(
+            "GET", "/api/trace/" + resp.headers["X-TSD-Trace-Id"])
+        ).body)["tree"]
+        execute = next(c for c in root["children"]
+                       if c["name"] == "query.execute")
+        (plan,) = [c for c in execute["children"]
+                   if c["name"] == "query.plan"]
+        return plan["tags"]
+
+    try:
+        tsdb.import_buffer(import_text(), durable=False)
+        grid = {"metric": "sys.stage", "filters": [{
+            "type": "wildcard", "tagk": "dc", "filter": "*",
+            "groupBy": True}]}
+        assert plan_tags(grid) == {
+            "sub": 0, "index": "built", "series": 16, "groups": 4}
+        assert plan_tags(grid)["index"] == "hit"
+        rec = tsdb.store.series(int(tsdb.store.series_ids_for_metric(
+            tsdb.uids.metrics.get_id("sys.stage"))[3]))
+        tsuid = tsdb.uids.tsuid(rec.metric_id, rec.tags).hex()
+        assert plan_tags({"tsuids": [tsuid]}) == {
+            "sub": 0, "index": "bypass", "series": 1, "groups": 1}
+        plans = {r["tags"]["index"]: r["value"] for r in json.loads(
+            router.handle(req("GET", "/api/stats")).body)
+            if r["metric"] == "tsd.query.plan"}
+        assert plans == {"built": 1, "hit": 1, "bypass": 1}
+    finally:
+        tsdb.shutdown()
+
+
 def test_a_loader_outside_any_request_records_its_stages():
     tsdb = mk_tsdb()
     try:

@@ -1,0 +1,385 @@
+"""The plan index (PR 28): what the plan stage derives from a metric's
+append-only tag index (the series x key matrix of tagv ids, each
+column's distinct ids, the group labels of a group-by key set) is kept
+per (store, metric) and versioned by the metric's series count. The
+same request through a cold index (built by it), a warm one (hit) and
+the path that cannot use it (bypass: the labels come from the selected
+columns, as every request's did before) must serialize to the same
+bytes, and select the series a walk over the records selects.
+"""
+
+import itertools
+import json
+import threading
+
+import numpy as np
+import pytest
+
+from opentsdb_tpu import TSDB, Config
+from opentsdb_tpu.query.engine import (PlanIndex, QueryEngine,
+                                       TagMatrix, group_labels)
+from opentsdb_tpu.query.filters import build_filter
+from opentsdb_tpu.tsd.http_api import HttpRequest, HttpRpcRouter
+
+BASE = 1356998400
+BACKENDS = ["native", "memory"]
+DCS = ["d3", "d0", "d2", "d1"]      # tagv ids ascend in THIS order
+SERIES = 48
+
+
+def lines():
+    """48 series of one metric. dc ids ascend against their names; a
+    sixth of the series has no rack, every eighth no dc."""
+    out = []
+    for h in range(SERIES):
+        tags = [f"host=h{h:02d}", f"fleet=f{h % 2}"]
+        if h % 8 != 7:
+            tags.append(f"dc={DCS[h % 4]}")
+        if h % 6 != 5:
+            tags.append(f"rack=r{h % 5}")
+        out += [f"sys.plan {BASE + i * 20} {h + i} {' '.join(tags)}\n"
+                for i in range(12)]
+    return "".join(out).encode()
+
+
+class Served:
+    """A TSDB behind its HTTP router, with the three ways through the
+    plan stage."""
+
+    def __init__(self, backend, monkeypatch):
+        self.tsdb = TSDB(Config(**{
+            "tsd.core.auto_create_metrics": "true",
+            "tsd.tpu.warmup": "false", "tsd.trace.sample": "1",
+            "tsd.query.cache.enable": "false",
+            "tsd.storage.backend": backend}))
+        self.router = HttpRpcRouter(self.tsdb)
+        self.monkeypatch = monkeypatch
+        for name in DCS + [f"r{i}" for i in range(5)]:
+            self.tsdb.uids.tag_values.get_or_create_id(name)
+        written, errors = self.tsdb.import_buffer(lines(),
+                                                  durable=False)
+        assert written == SERIES * 12 and not errors
+
+    def query(self, *subs, **top):
+        """(response body, the ``query.plan`` tags of each sub)."""
+        body = json.dumps({
+            "start": BASE * 1000, "end": (BASE + 240) * 1000,
+            "queries": [{"metric": "sys.plan", "aggregator": "sum",
+                         "downsample": "1m-avg", **sub}
+                        for sub in subs], **top}).encode()
+        resp = self.router.handle(HttpRequest(
+            method="POST", path="/api/query", params={}, headers={},
+            body=body))
+        assert resp.status == 200, resp.body
+        data = self.tsdb.tracer.get(resp.headers["X-TSD-Trace-Id"])
+        plans = sorted((s.tags for s in data.spans
+                        if s.name == "query.plan"),
+                       key=lambda t: t["sub"])
+        return resp.body, plans
+
+    def cold(self, *subs, **top):
+        self.tsdb._tagmat_cache.clear()
+        body, plans = self.query(*subs, **top)
+        assert "built" in [p["index"] for p in plans], plans
+        return body, plans
+
+    def warm(self, *subs, **top):
+        body, plans = self.query(*subs, **top)
+        assert [p["index"] for p in plans] == ["hit"] * len(plans)
+        return body, plans
+
+    def bypass(self, *subs, **top):
+        # the selection is a copy of the index's array: not the index
+        store = self.tsdb.store
+        whole = store.series_ids_for_metric
+        with self.monkeypatch.context() as m:
+            m.setattr(store, "series_ids_for_metric",
+                      lambda mid: whole(mid).copy())
+            body, plans = self.query(*subs, **top)
+        assert [p["index"] for p in plans] == ["bypass"] * len(plans)
+        return body, plans
+
+    def same_three_ways(self, *subs, **top):
+        cold, plans = self.cold(*subs, **top)
+        warm, warm_plans = self.warm(*subs, **top)
+        bypass, bypass_plans = self.bypass(*subs, **top)
+        assert cold == warm == bypass
+        for a, b, c in zip(plans, warm_plans, bypass_plans):
+            assert (a.get("series"), a.get("groups")) == \
+                (b.get("series"), b.get("groups")) == \
+                (c.get("series"), c.get("groups"))
+        return json.loads(cold), plans
+
+    def walked(self, filters):
+        """The series a walk over the records selects: every filter
+        must pass on every series, by its own string predicate."""
+        uids, store = self.tsdb.uids, self.tsdb.store
+        keep = []
+        for sid in store.series_ids_for_metric(
+                uids.metrics.get_id("sys.plan")):
+            tags = {uids.tag_names.get_name(k):
+                    uids.tag_values.get_name(v)
+                    for k, v in store.series(int(sid)).tags}
+            if all(f.match_value(tags[f.tagk]) if f.tagk in tags
+                   else f.match_absent for f in filters):
+                keep.append(tags)
+        return keep
+
+
+@pytest.fixture
+def served(request, monkeypatch):
+    s = Served(getattr(request, "param", "native"), monkeypatch)
+    yield s
+    s.tsdb.shutdown()
+
+
+def flt(type_, tagk, expr, group_by=False):
+    return {"type": type_, "tagk": tagk, "filter": expr,
+            "groupBy": group_by}
+
+
+FILTERS = [("literal_or", "r1|r3"), ("iliteral_or", "R1|r3"),
+           ("not_literal_or", "r2"), ("not_iliteral_or", "R2|r0"),
+           ("wildcard", "*"), ("iwildcard", "R*3"),
+           ("regexp", "r[12]"), ("not_key", "")]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+@pytest.mark.parametrize("group_by", [True, False],
+                         ids=["groupby", "nogroup"])
+@pytest.mark.parametrize("type_, expr", FILTERS,
+                         ids=[t for t, _ in FILTERS])
+def test_cold_warm_and_bypass_answer_the_same_bytes(served, type_,
+                                                    expr, group_by):
+    if type_ == "not_key":      # cannot group: dc groups beside it
+        filters = [flt(type_, "rack", expr)] + \
+            ([flt("wildcard", "dc", "*", True)] if group_by else [])
+    else:
+        filters = [flt(type_, "rack", expr, group_by)]
+    rows, (plan,) = served.same_three_ways({"filters": filters})
+    want = served.walked([build_filter(f) for f in filters])
+    assert plan["series"] == len(want) > 0
+    gb = [f["tagk"] for f in filters if f["groupBy"]]
+    keys = {tuple(t[k] for k in gb) for t in want}
+    assert plan["groups"] == len(rows) == len(keys)
+    assert {tuple(r["tags"][k] for k in gb) for r in rows} == keys
+
+
+def dcs_of(rows):
+    return [r["tags"].get("dc") for r in rows]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_emptied_groups_close_up_in_tagv_id_order(served):
+    # all four dcs, in the order their ids were assigned
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("wildcard", "dc", "*", True)]})
+    assert dcs_of(rows) == DCS and plan["groups"] == 4
+    # hosts of d0 and d1 alone (h % 4 in 1, 3; h % 8 == 7 has no dc):
+    # two groups left, still by id, not by name
+    hosts = "|".join(f"h{h:02d}" for h in (1, 3, 5, 9, 11))
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("wildcard", "dc", "*", True),
+                     flt("literal_or", "host", hosts)]})
+    assert dcs_of(rows) == ["d0", "d1"]
+    assert (plan["series"], plan["groups"]) == (5, 2)
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_two_group_by_keys(served):
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("wildcard", "dc", "*", True),
+                     flt("not_literal_or", "rack", "r4", True)]})
+    got = [(r["tags"]["dc"], r["tags"]["rack"]) for r in rows]
+    want = {(t["dc"], t["rack"]) for t in served.walked(
+        [build_filter(flt("wildcard", "dc", "*")),
+         build_filter(flt("not_literal_or", "rack", "r4"))])}
+    assert len(got) == len(set(got)) == plan["groups"] and \
+        set(got) == want
+    # dc-major, by id: the reference's ByteMap order of the pair
+    assert [d for d, _ in got] == sorted((d for d, _ in got),
+                                         key=DCS.index)
+    for dc in DCS:
+        racks = [r for d, r in got if d == dc]
+        assert racks == sorted(racks)
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_series_lacking_the_key(served):
+    # a value filter never takes them; grouping a key some series of
+    # the SELECTION lack puts those in the group that sorts first
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("wildcard", "rack", "*", True)]})
+    assert plan["series"] == SERIES - SERIES // 6
+    assert [r["tags"]["rack"] for r in rows] == \
+        [f"r{i}" for i in range(5)]
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("wildcard", "dc", "*", True),
+                     flt("not_key", "rack", "")]})
+    assert plan["series"] == sum(
+        1 for h in range(SERIES) if h % 6 == 5 and h % 8 != 7)
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("wildcard", "host", "h0*", True)],
+         "aggregator": "max"})
+    assert plan["groups"] == 10
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_an_unknown_tag_key(served):
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("literal_or", "nosuch", "x")]})
+    assert rows == [] and "series" not in plan
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("not_key", "nosuch", "")]})
+    assert plan["series"] == SERIES and len(rows) == 1
+    # a known key no series of this metric carries
+    served.tsdb.add_point("sys.other", BASE, 1, {"shelf": "s1"})
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("wildcard", "shelf", "*")]})
+    assert rows == []
+    rows, (plan,) = served.same_three_ways(
+        {"filters": [flt("not_key", "shelf", "")]})
+    assert plan["series"] == SERIES
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_two_filters_on_one_key(served):
+    filters = [flt("wildcard", "rack", "r*", True),
+               flt("not_literal_or", "rack", "r0|r3")]
+    rows, (plan,) = served.same_three_ways({"filters": filters})
+    assert [r["tags"]["rack"] for r in rows] == ["r1", "r2", "r4"]
+    assert plan["series"] == len(served.walked(
+        [build_filter(f) for f in filters]))
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_explicit_tags(served):
+    # host, fleet and dc filtered: only series with exactly those keys
+    filters = [flt("wildcard", "host", "*"),
+               flt("wildcard", "fleet", "*"),
+               flt("wildcard", "dc", "*", True)]
+    rows, (plan,) = served.same_three_ways(
+        {"filters": filters, "explicitTags": True})
+    assert plan["series"] == sum(
+        1 for h in range(SERIES) if h % 6 == 5 and h % 8 != 7)
+    assert plan["groups"] == len(rows) > 1
+    assert dcs_of(rows) == sorted(dcs_of(rows), key=DCS.index)
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_a_new_series_moves_the_version(served):
+    sub = {"filters": [flt("wildcard", "dc", "*", True)],
+           "aggregator": "count"}
+    _, (plan,) = served.cold(sub)
+    before = plan["series"]
+    served.warm(sub)
+    served.tsdb.add_point("sys.plan", BASE + 30, 7,
+                          {"host": "new", "dc": "d9", "fleet": "f0"})
+    body, (plan,) = served.query(sub)
+    assert (plan["index"], plan["series"], plan["groups"]) == \
+        ("built", before + 1, 5)
+    assert dcs_of(json.loads(body)) == DCS + ["d9"]
+    assert served.warm(sub)[0] == body == served.bypass(sub)[0]
+    counts = {r[2]["index"]: r[1] for r in stats_records(served.tsdb)
+              if r[0] == "tsd.query.plan"}
+    assert counts == {"built": 2, "hit": 2, "bypass": 1}
+
+
+def stats_records(tsdb):
+    from opentsdb_tpu.stats.stats import StatsCollector
+    c = StatsCollector("tsd")
+    tsdb.tracer.collect_stats(c)
+    return c.records
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_a_renamed_tagv_shows_in_the_next_request(served):
+    sub = {"filters": [flt("literal_or", "dc", "d2|east", True)]}
+    body, _ = served.cold(sub)
+    assert dcs_of(json.loads(body)) == ["d2"]
+    served.tsdb.uids.tag_values.rename("d0", "east")
+    body, _ = served.warm(sub)              # same index, live names
+    assert dcs_of(json.loads(body)) == ["east", "d2"]
+    assert body == served.bypass(sub)[0]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_two_sub_queries_on_a_cold_index_from_two_threads(served):
+    assert served.tsdb.query_fanout_pool is not None
+    subs = [{"filters": [flt("wildcard", "dc", "*", True),
+                         flt("not_literal_or", "rack", "r1")],
+             "aggregator": agg} for agg in ("sum", "max")]
+    want, _ = served.bypass(*subs)
+    for _ in range(5):
+        body, plans = served.cold(*subs)
+        assert body == want
+        assert [(p["series"], p["groups"]) for p in plans] == \
+            [(plans[0]["series"], 4)] * 2
+    assert served.warm(*subs)[0] == want
+
+
+def test_lazy_parts_are_built_once_under_threads():
+    rng = np.random.default_rng(28)
+    tags = TagMatrix(np.array([2, 5, 9]),
+                     rng.integers(-1, 40, size=(20000, 3)))
+    index = PlanIndex(20000, tags)
+    barrier = threading.Barrier(4)
+    got = []
+
+    def ask():
+        barrier.wait()
+        got.append((index.labels([5, 9]), index.distinct(5)))
+
+    threads = [threading.Thread(target=ask) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    (labels, count), distinct = got[0]
+    for (other, n), d in got[1:]:
+        assert other is labels and n == count and d is distinct
+    want, n = group_labels(tags, [5, 9])
+    assert n == count and np.array_equal(labels, want)
+    assert np.array_equal(distinct, tags.distinct(5))
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_the_label_cache_stays_within_its_bound(served):
+    keys = ["host", "dc", "rack", "fleet"]
+    sets = [c for n in range(1, 5)
+            for c in itertools.combinations(keys, n)]
+    assert len(sets) > PlanIndex.LABEL_SETS
+    served.tsdb._tagmat_cache.clear()
+    for gb in sets + sets[:3]:
+        sub = {"filters": [flt("wildcard", k, "*", True) for k in gb]}
+        body, _ = served.query(sub)
+        assert body == served.bypass(sub)[0]
+        (index,) = served.tsdb._tagmat_cache.values()
+        assert len(index._labels) <= PlanIndex.LABEL_SETS
+    assert len(index._labels) == PlanIndex.LABEL_SETS
+    uids = served.tsdb.uids.tag_names
+    newest = tuple(sorted(uids.get_id(k) for k in sets[2]))
+    assert next(reversed(index._labels)) == newest
+
+
+@pytest.mark.parametrize("rows", [
+    np.arange(0, 3000, 7), np.arange(3000), np.empty(0, np.int64),
+    np.array([5, 6, 2999])], ids=["some", "all", "none", "three"])
+def test_gathered_labels_equal_labels_of_the_selection(rows):
+    # what _group_ids does with the index against what it does
+    # without: the same labels, the same count, whatever emptied
+    rng = np.random.default_rng(len(rows))
+    tags = TagMatrix(np.array([1, 4, 6]),
+                     rng.integers(-1, 12, size=(3000, 3)))
+    index = PlanIndex(3000, tags)
+    for gb in ([4], [1, 6], [6, 4, 1], [3]):
+        got, n = QueryEngine._group_ids(index.select(rows), gb)
+        want, m = QueryEngine._group_ids(tags.select(rows), gb)
+        assert n == m and got.dtype == want.dtype == np.int32
+        assert np.array_equal(got, want)
+        # a second selection composes with the first
+        keep = np.arange(len(rows)) % 3 == 0
+        got, n = QueryEngine._group_ids(
+            index.select(rows).select(keep), gb)
+        want, m = QueryEngine._group_ids(tags.select(rows[keep]), gb)
+        assert n == m and np.array_equal(got, want)
