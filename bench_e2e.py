@@ -2,7 +2,7 @@
 
 Times the FULL query path the TSD server runs — store materialize ->
 filter/group construction -> device pipeline -> result assembly ->
-HTTP JSON serialization — not just the device kernels (bench.py).
+HTTP JSON serialization — not just the device kernels.
 This is the north-star measurement: p50 latency of config 3
 (1M series x 1h@1s, 5m avg downsample + rate) answered from the 1m
 rollup tier, target < 2 s (BASELINE.json "north_star";

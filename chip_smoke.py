@@ -25,10 +25,10 @@ Phases
         grouped rate at 1M series, the gappy series, a rank-class
         aggregator, histogram percentiles, the dashboard query
   B     a second server on the same data dir (snapshot load + WAL
-        replay) with the device cache and the storage-side grid
-        reduction off, so regular-cadence queries reach the fused
-        Pallas kernel: span layout, one-hot layout, max downsample,
-        counter rate
+        replay) with the storage-side grid reduction off and nothing
+        kept resident, so regular-cadence queries take the point path
+        and run its dense program on the device: 100 groups, 2000
+        groups, max downsample, counter rate
   C     (4+ devices) a third server with tsd.query.mesh=series:4
   R     a router and one shard as two processes: the router must not
         take the chip
@@ -75,8 +75,8 @@ K = 5                      # points per 5m bucket
 BUCKETS = POINTS // K
 INTERVAL_S = CADENCE_S * K
 END = T0 + POINTS * CADENCE_S - 1
-DCS = 100                  # group-by dc -> 100 groups (span layout)
-RACKS = 2000               # group-by rack -> 2000 groups (one-hot)
+DCS = 100                  # group-by dc -> 100 groups
+RACKS = 2000               # group-by rack -> 2000 groups
 CHUNK = 20_000             # series per generated chunk
 HIST_BUCKETS = 64
 HIST_BOUNDS = np.logspace(0, 4, HIST_BUCKETS + 1)
@@ -89,7 +89,7 @@ COUNTER_MAX = 10000.0
 # The server computes in float32 on the chip (x64 is off there); the
 # reference below is float64. A group sum over 10,000 series loses up
 # to ~1e-5 of the sum of magnitudes to f32 accumulation (measured on
-# the v5e: 1e-5 for the XLA segment sum, 2e-7 for the Pallas kernel),
+# the v5e: 1e-5 for the XLA segment sum),
 # while ONE dropped series moves it by 1e-4 and a wrong bucket or a
 # truncated timestamp by orders of magnitude more. So a cell passes
 # when |got - want| <= SUM_RTOL * (sum over the group's series of
@@ -585,16 +585,26 @@ class Server:
         raise Failed(f"{self.name}: warm-up not finished after "
                      f"{timeout:.0f}s")
 
+    def stats(self) -> list:
+        status, rows = http(self.port, "/api/stats")
+        if status != 200:
+            raise Failed(f"{self.name}: /api/stats -> {status}")
+        return rows
+
     def device_lookups(self) -> float:
         """Device-grid-cache lookups so far: only a device-placed tail
         consults that cache (host-placed ones skip it), so a query
         during which this grows was placed on the device."""
-        status, rows = http(self.port, "/api/stats")
-        if status != 200:
-            raise Failed(f"{self.name}: /api/stats -> {status}")
-        return sum(r["value"] for r in rows if r["metric"] in (
+        return sum(r["value"] for r in self.stats() if r["metric"] in (
             "tsd.query.devicecache.hits",
             "tsd.query.devicecache.misses"))
+
+    def tails(self) -> collections.Counter:
+        """Programs dispatched so far, by (path, placement): the
+        ``tsd.query.tail`` counter."""
+        return collections.Counter({
+            (r["tags"]["path"], r["tags"]["placement"]): r["value"]
+            for r in self.stats() if r["metric"] == "tsd.query.tail"})
 
     def query(self, sub: dict, start: int = T0, end: int = END):
         body = json.dumps({"start": start * 1000, "end": end * 1000,
@@ -877,18 +887,18 @@ class Phases:
         return facts
 
     def end_of_server(self, srv: Server) -> dict:
-        """Breaker and kernel counters at the end of a phase."""
+        """Breaker counters at the end of a phase."""
         doc = srv.health()
         br = doc["breakers"].get("device.pipeline", {})
         dev = doc["device"]
         rec = self.s.report["phases"][srv.name]
         rec.update(breaker_failures=br.get("total_failures"),
                    breaker_fallbacks=br.get("fallbacks"),
-                   pallas=dev["pallas"], resident=dev["resident"],
+                   resident=dev["resident"],
                    cache_entries_end=self.s.cache_entries())
         say(f"server {srv.name}: breaker failures="
             f"{br.get('total_failures')} fallbacks="
-            f"{br.get('fallbacks')}; kernel {dev['pallas']}")
+            f"{br.get('fallbacks')}")
         self.s.check(not br.get("total_failures")
                      and not br.get("fallbacks"),
                      f"{srv.name}: device.pipeline breaker counted "
@@ -1022,7 +1032,7 @@ class Phases:
             # a plumbing run: too few series for device placement
             flags.append("--tsd.query.host_tail_max_cells_linear=-1")
             say("B: small run, host-tail placement switched off so "
-                "that the kernel is reached")
+                "that the point path's program is placed on the device")
         srv, dev, _ = self.boot("B", s.data_dir, *flags)
         a = s.report["phases"].get("A")
         if a is not None:
@@ -1047,27 +1057,20 @@ class Phases:
                     "placement flag gives B other programs)")
         full = ~self.gappy
         names = dc_names()
-        pallas0 = dev["pallas"]
 
-        def kernel_runs():
-            return srv.health()["device"]["pallas"]
-
-        def kernel_query(label, sub, names, tagk, want, tol, emitted):
-            before = kernel_runs()
+        def point_query(label, sub, names, tagk, want, tol, emitted):
+            # regular cadence, no storage-side grid: the point path's
+            # dense program, placed on the device
+            before = srv.tails()
             got = self.run(srv, label, sub, names, tagk, want, tol,
                            emitted, placement_from="device")
-            after = kernel_runs()
-            ran = after["compiled"] - before["compiled"]
-            s.report["queries"][-1]["kernel"] = (
-                "compiled" if ran else "interpreted"
-                if after["interpreted"] > before["interpreted"]
-                else f"replaced: {after['dense_instead']}")
-            on_tpu = dev["platform"] == "tpu"
-            s.check(ran == 1 if on_tpu else
-                    after["interpreted"] - before["interpreted"] == 1,
-                    f"B/{label}: the fused kernel did not run "
-                    f"{'compiled' if on_tpu else 'interpreted'}: "
-                    f"{before} -> {after}")
+            ran = srv.tails() - before
+            s.report["queries"][-1]["tail"] = {
+                f"{path}/{placement}": n
+                for (path, placement), n in ran.items()}
+            s.check(ran == {("dense", "device"): 1},
+                    f"B/{label}: expected one dense program on the "
+                    f"device, tsd.query.tail counted {dict(ran)}")
             return got
 
         # the WAL replay: the point acknowledged over telnet in phase
@@ -1076,27 +1079,29 @@ class Phases:
               "downsample": "5m-avg", "rate": True,
               "filters": group_by("dc", fleet="a")}
         want, tol, emit = self.want_rate(full, self.dc, DCS)
-        kernel_query("kernel span: sum:5m-avg:rate{dc=*,fleet=a}", b1,
-                     names, "dc", want, tol, emit)
+        point_query("point path: sum:5m-avg:rate{dc=*,fleet=a}", b1,
+                    names, "dc", want, tol, emit)
         rack_names = [f"r{i:04d}" for i in range(RACKS)]
         b2 = dict(b1, filters=group_by("rack", fleet="a"))
         want, tol, emit = self.want_rate(full, self.rack, RACKS)
-        kernel_query("kernel one-hot: sum:5m-avg:rate{rack=*,fleet=a}",
-                     b2, rack_names, "rack", want, tol, emit)
+        point_query("point path, 2000 groups: "
+                    "sum:5m-avg:rate{rack=*,fleet=a}",
+                    b2, rack_names, "rack", want, tol, emit)
         b3 = {"metric": "smoke.cpu", "aggregator": "sum",
               "downsample": "5m-max",
               "filters": group_by("dc", fleet="a")}
         want, tol, emit = ref_group_sum(s.max[full], self.dc[full],
                                         DCS, VALUE_ATOL)
-        kernel_query("kernel max downsample: sum:5m-max{dc=*,fleet=a}",
-                     b3, names, "dc", want, tol, emit)
+        point_query("point path, max downsample: "
+                    "sum:5m-max{dc=*,fleet=a}",
+                    b3, names, "dc", want, tol, emit)
         b4 = dict(b1, rateOptions={"counter": True,
                                    "counterMax": COUNTER_MAX})
         want, tol, emit = self.want_rate(full, self.dc, DCS,
                                          counter_max=COUNTER_MAX)
-        kernel_query("kernel counter rate: sum:5m-avg:"
-                     "rate{counter,10000}{dc=*,fleet=a}", b4, names,
-                     "dc", want, tol, emit)
+        point_query("point path, counter rate: sum:5m-avg:"
+                    "rate{counter,10000}{dc=*,fleet=a}", b4, names,
+                    "dc", want, tol, emit)
         # what the WAL brought back besides: the dashboard metric
         if self.dash_want is not None:
             self.run(srv, "avg:1m-avg dashboard, back from the WAL",
@@ -1105,11 +1110,7 @@ class Phases:
                      self.dash_want, np.abs(self.dash_want) * 1e-5,
                      np.ones_like(self.dash_want, bool), n_buckets=60,
                      step=60, placement_from="not asked")
-        end = self.end_of_server(srv)
-        s.check(not end["pallas"]["dense_instead"],
-                f"B: the dense path replaced the kernel: "
-                f"{end['pallas']['dense_instead']}")
-        say(f"B: kernel executions {pallas0} -> {end['pallas']}")
+        self.end_of_server(srv)
         srv.stop("kill")
 
     # -- phase C -------------------------------------------------------

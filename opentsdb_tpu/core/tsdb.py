@@ -1029,11 +1029,9 @@ class TSDB:
         """What this process runs on (``/api/health`` ``device``, and
         the ``tsd.device.*`` stats): the JAX platform, device kind and
         count, x64, the compile-cache directory, the storage backend
-        actually loaded, the mesh actually built, what warm-up did,
-        and how the regular-cadence kernel was executed. Read-only;
-        lets a client tell a TPU server from a CPU one."""
-        import sys
-
+        actually loaded, the mesh actually built and what warm-up
+        did. Read-only; lets a client tell a TPU server from a CPU
+        one."""
         import jax
 
         from opentsdb_tpu.native.store_backend import \
@@ -1068,12 +1066,6 @@ class TSDB:
         from opentsdb_tpu.tsd.warmup import WarmupReport
         info["warmup"] = (getattr(self, "warmup_report", None)
                           or WarmupReport("off")).as_dict()
-        # the raw module: reporting must not import Pallas into a
-        # process that never ran the kernel
-        pallas = sys.modules.get("opentsdb_tpu.ops.pallas_fused")
-        info["pallas"] = pallas.COUNTERS.as_dict() \
-            if pallas is not None else {
-                "compiled": 0, "interpreted": 0, "dense_instead": {}}
         return info
 
     @property
@@ -1404,12 +1396,6 @@ class TSDB:
                          dev["warmup"]["compiled"])
         collector.record("device.warmup.failed",
                          dev["warmup"]["failed"])
-        collector.record("device.pallas.compiled",
-                         dev["pallas"]["compiled"])
-        collector.record("device.pallas.interpreted",
-                         dev["pallas"]["interpreted"])
-        collector.record("device.pallas.dense_instead",
-                         sum(dev["pallas"]["dense_instead"].values()))
         for hook, n in sorted(self.hook_errors.items()):
             collector.record("hooks.errors", n, hook=hook)
         collector.record("uptime.seconds",

@@ -703,7 +703,7 @@ class Tracer:
         self.idle_stage_ms: dict[str, float] = {}
         # programs dispatched, by (path, placement)
         # tsdlint: allow[unbounded-growth] keyed by run_staged's
-        # tags: the eight paths its callers name x two placements
+        # tags: the six paths its callers name x two placements
         self.tails: dict[tuple[str, str], int] = {}
         # grids built, by who wrote the padded grid: "fused" (the
         # store's own pass) or "host" (fill_padded_grid)

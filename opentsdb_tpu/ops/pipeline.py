@@ -537,75 +537,6 @@ def device_bucket_ts(bucket_ts: np.ndarray) -> np.ndarray:
     return rel.astype(np.float64)
 
 
-def _run_dense_or_pallas(values2d, bucket_ts, group_ids, spec, k, ro,
-                         rate_params, fv, dtype, device,
-                         use_pallas: bool) -> tuple[np.ndarray, np.ndarray]:
-    """Regular-cadence execution: the fused Pallas kernel when the data
-    and op combination allow it, the XLA dense reshape path otherwise.
-    Shared by :func:`execute` and :func:`execute_auto`."""
-    if use_pallas:
-        from opentsdb_tpu.ops import pallas_fused
-        why = "rate_drop_resets" if ro.drop_resets else \
-            pallas_fused.unsupported_reason(spec, dtype, device)
-        if why is None and np.isnan(values2d).any():
-            why = "nan_holes"
-        if why is None:
-            # a Mosaic compile or runtime failure propagates: the
-            # engine's device breaker counts it and answers by its
-            # designed degradation, never a quiet second path here
-            return pallas_fused.fused_dense_pipeline(
-                values2d, np.asarray(bucket_ts),
-                np.asarray(group_ids), spec, k, dtype=dtype,
-                device=device, rate_options=ro)
-        pallas_fused.COUNTERS.replaced(why)
-    return run_staged("dense", run_pipeline_dense, lambda: (
-        jax.device_put(as_operand(values2d, dtype), device=device),
-        as_operand(device_bucket_ts(bucket_ts)),
-        as_operand(group_ids, np.int32),
-        rate_params, fv, spec, k))
-
-
-def execute_auto(padded, bucket_idx2d: np.ndarray,
-                 bucket_ts: np.ndarray, group_ids: np.ndarray,
-                 spec: PipelineSpec,
-                 rate_options: RateOptions | None = None,
-                 dtype=None, device=None,
-                 use_pallas: bool = True) -> tuple[np.ndarray, np.ndarray]:
-    """Host entry over a :class:`~opentsdb_tpu.core.store.PaddedBatch`:
-    picks pallas/dense for regular data, the scatter-free padded kernel
-    for irregular data it supports, and the flat scatter path otherwise.
-    """
-    if dtype is None:
-        dtype = pipeline_dtype()
-    ro = rate_options or RateOptions()
-    values2d = np.asarray(padded.values2d)
-    counts = np.asarray(padded.counts)
-    k = detect_regular_padded(counts, np.asarray(bucket_idx2d),
-                              spec.num_buckets)
-    put = partial(jax.device_put, device=device)
-    rate_params = (as_operand(ro.counter_max, dtype),
-                   as_operand(ro.reset_value, dtype))
-    fv = as_operand(spec.fill_value, dtype)
-    if k is not None and spec.ds_function in _DENSE_FNS:
-        return _run_dense_or_pallas(values2d, bucket_ts, group_ids,
-                                    spec, k, ro, rate_params, fv,
-                                    dtype, device, use_pallas)
-    cells = values2d.shape[0] * values2d.shape[1] * spec.num_buckets
-    if ds_mod.padded_supported(spec.ds_function, spec.num_buckets) \
-            and cells <= _PADDED_EINSUM_MAX_CELLS:
-        return run_staged("padded", run_pipeline_padded, lambda: (
-            put(as_operand(values2d, dtype)),
-            as_operand(bucket_idx2d, np.int32),
-            as_operand(device_bucket_ts(bucket_ts)),
-            as_operand(group_ids, np.int32),
-            rate_params, fv, spec))
-    values, series_idx, bucket_idx = flatten_padded(
-        values2d, np.asarray(bucket_idx2d), counts)
-    return execute(values, series_idx, bucket_idx, bucket_ts, group_ids,
-                   spec, rate_options, dtype=dtype, device=device,
-                   use_pallas=use_pallas)
-
-
 @dataclass(frozen=True)
 class PreparedBatch:
     """Device-resident upload of one sub-query's point data, ready to
@@ -639,12 +570,25 @@ def _pad_rows(arr2d: np.ndarray, s_pad: int, fill) -> np.ndarray:
     return out
 
 
+def _prepared_dense(values2d: np.ndarray, k: int, s_pad: int, b: int,
+                    dtype, put) -> "PreparedBatch":
+    """Upload a regular-cadence [S, B * k] batch for the dense reshape
+    program (NaN rows up to the series bucket)."""
+    with trace_span("query.upload"):
+        return PreparedBatch(
+            "dense",
+            (put(as_operand(_pad_rows(values2d, s_pad, np.nan), dtype)),),
+            k, pad=(s_pad, b))
+
+
 def prepare_auto(padded, bucket_idx2d: np.ndarray, spec: PipelineSpec,
                  dtype=None, device=None) -> PreparedBatch:
-    """Layout-detect + upload a PaddedBatch (the same dispatch rules as
-    :func:`execute_auto`, minus the pallas micro-path). Shapes pad to
-    geometric buckets (ops.shapes): NaN rows for extra series, -1
-    bucket sentinels for extra point columns."""
+    """Layout-detect + upload a PaddedBatch: the dense reshape program
+    for regular-cadence data, the scatter-free padded kernel for
+    irregular data it supports, the flat scatter layout
+    (:func:`prepare_flat`) otherwise. Shapes pad to geometric buckets
+    (ops.shapes): NaN rows for extra series, -1 bucket sentinels for
+    extra point columns."""
     from opentsdb_tpu.ops import shapes
     if dtype is None:
         dtype = pipeline_dtype()
@@ -656,12 +600,7 @@ def prepare_auto(padded, bucket_idx2d: np.ndarray, spec: PipelineSpec,
     s_pad = shapes.shape_bucket(s)
     k = detect_regular_padded(counts, bucket_idx2d, spec.num_buckets)
     if k is not None and spec.ds_function in _DENSE_FNS:
-        with trace_span("query.upload"):
-            return PreparedBatch(
-                "dense",
-                (put(as_operand(_pad_rows(values2d, s_pad, np.nan),
-                                dtype)),),
-                k, pad=(s_pad, b))
+        return _prepared_dense(values2d, k, s_pad, b, dtype, put)
     cells = s_pad * values2d.shape[1] * spec.num_buckets
     if ds_mod.padded_supported(spec.ds_function, spec.num_buckets) \
             and cells <= _PADDED_EINSUM_MAX_CELLS:
@@ -696,12 +635,7 @@ def prepare_flat(values: np.ndarray, series_idx: np.ndarray,
                      spec.ds_function)
     if k is not None:
         values2d = np.asarray(values).reshape(spec.num_series, -1)
-        with trace_span("query.upload"):
-            return PreparedBatch(
-                "dense",
-                (put(as_operand(_pad_rows(values2d, s_pad, np.nan),
-                                dtype)),),
-                k, pad=(s_pad, b))
+        return _prepared_dense(values2d, k, s_pad, b, dtype, put)
     with trace_span("query.upload"):
         n = len(values)
         s_pad = shapes.shape_bucket(s + 1)
@@ -749,7 +683,7 @@ def run_prepared(prep: PreparedBatch, bucket_ts: np.ndarray,
         "flat": (run_pipeline, ())}[prep.kind]
     # numpy operands ride with the committed prepared arrays — no
     # eager default-device materialization per query
-    result, emit = run_staged("prepared", program, lambda: (
+    result, emit = run_staged(prep.kind, program, lambda: (
         *prep.arrays, as_operand(device_bucket_ts(bucket_ts)),
         as_operand(group_ids, np.int32),
         (as_operand(ro.counter_max, dtype),
@@ -763,33 +697,11 @@ def execute(batch_values: np.ndarray, series_idx: np.ndarray,
             bucket_idx: np.ndarray, bucket_ts: np.ndarray,
             group_ids: np.ndarray, spec: PipelineSpec,
             rate_options: RateOptions | None = None,
-            dtype=None, device=None,
-            use_pallas: bool = True) -> tuple[np.ndarray, np.ndarray]:
-    """Host entry: upload, run, download. Returns (result, emit_mask).
-
-    Automatically takes the dense reshape path when the batch is
-    regular-cadence (see :func:`detect_dense`), and within it the
-    fused Pallas kernel (:mod:`opentsdb_tpu.ops.pallas_fused`) when the
-    data is complete and the op combination is MXU-reducible."""
-    if dtype is None:
-        dtype = pipeline_dtype()
-    ro = rate_options or RateOptions()
-    put = partial(jax.device_put, device=device)
-    rate_params = (as_operand(ro.counter_max, dtype),
-                   as_operand(ro.reset_value, dtype))
-    fv = as_operand(spec.fill_value, dtype)
-    k = detect_dense(spec.num_series, spec.num_buckets,
-                     np.asarray(series_idx), np.asarray(bucket_idx),
-                     spec.ds_function)
-    if k is not None:
-        values2d = np.asarray(batch_values).reshape(spec.num_series, -1)
-        return _run_dense_or_pallas(values2d, bucket_ts, group_ids,
-                                    spec, k, ro, rate_params, fv,
-                                    dtype, device, use_pallas)
-    return run_staged("flat", run_pipeline, lambda: (
-        put(as_operand(batch_values, dtype)),
-        as_operand(series_idx, np.int32),
-        as_operand(bucket_idx, np.int32),
-        as_operand(device_bucket_ts(bucket_ts)),
-        as_operand(group_ids, np.int32),
-        rate_params, fv, spec))
+            dtype=None, device=None) -> tuple[np.ndarray, np.ndarray]:
+    """Upload, run, download a flat point batch in one call: the
+    tests' reference entry. The engine prepares and runs in two steps
+    so that the upload can stay resident."""
+    prep = prepare_flat(batch_values, series_idx, bucket_idx, spec,
+                        dtype=dtype, device=device)
+    return run_prepared(prep, bucket_ts, group_ids, spec, rate_options,
+                        dtype=dtype)

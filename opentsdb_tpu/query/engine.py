@@ -37,9 +37,9 @@ from opentsdb_tpu.ops import downsample as ds_mod
 from opentsdb_tpu.ops.blocked import (DEFAULT_CELL_BUDGET,
                                       execute_blocked,
                                       pick_block_buckets)
-from opentsdb_tpu.ops.pipeline import (PipelineSpec, execute,
-                                       execute_auto, execute_avg_divide,
-                                       flatten_padded)
+from opentsdb_tpu.ops.pipeline import (PipelineSpec, execute_avg_divide,
+                                       flatten_padded, prepare_auto,
+                                       prepare_flat, run_prepared)
 from opentsdb_tpu.query import filters as filters_mod
 from opentsdb_tpu.query.limits import QueryLimitExceeded
 from opentsdb_tpu.query.model import (BadRequestError, TSQuery,
@@ -765,7 +765,7 @@ class QueryEngine:
                           getattr(t.store, "mutation_epoch", 0)]
             return tuple(parts)
         try:
-            (store, _metric, _sids, _scale, avg_count_store,
+            (store, _metric, _sids, avg_count_store,
              _ds) = self._select_store(sub)
         except Exception:  # noqa: BLE001 - compute re-raises for real
             return ("all", t.serve_version(), ann)
@@ -826,8 +826,8 @@ class QueryEngine:
         # unfinished handle on an error path simply isn't recorded;
         # the enclosing query.execute span still carries the error)
         _h_plan = trace_begin("query.plan", sub=sub.index)
-        (store, metric_name, sids, rollup_scale,
-         avg_count_store, ds_fn_override) = self._select_store(sub)
+        (store, metric_name, sids, avg_count_store,
+         ds_fn_override) = self._select_store(sub)
         budget = self.tsdb.config.get_int(
             "tsd.query.max_device_cells", 0) or DEFAULT_CELL_BUDGET
         if avg_count_store is not None:
@@ -919,8 +919,7 @@ class QueryEngine:
         # bottleneck; here the "scan" IS the downsample)
         out = self._grid_pipeline(store, sids, tsq, sub, metric_name,
                                   group_ids, num_groups, emit_raw,
-                                  rollup_scale, budget, stats,
-                                  ds_fn_override)
+                                  budget, stats, ds_fn_override)
         if out is not None:
             result, emit, bucket_ts = out
             if result is None:
@@ -934,9 +933,8 @@ class QueryEngine:
         # the upload — the data lives in HBM already (the point-path
         # twin of _grid_pipeline's resident grids)
         mesh = self.tsdb.query_mesh
-        prep_cache = (self.tsdb.device_grid_cache
-                      if rollup_scale == 1.0 else None)
-        prep = pkey = pver = None
+        prep_cache = self.tsdb.device_grid_cache
+        pkey = pver = None
         if prep_cache is not None:
             from opentsdb_tpu.query.device_cache import array_digest
             from opentsdb_tpu.parallel.sharded_pipeline import \
@@ -1145,41 +1143,33 @@ class QueryEngine:
             emit_raw=emit_raw, host=host_dev is not None,
             complete=grid_complete
             and not (sub.rate and sub.rate_options.drop_resets))
-        if rollup_scale != 1.0:
-            if padded is not None:
-                padded = padded._replace(values2d=padded.values2d
-                                         * rollup_scale)
-            else:
-                batch = batch._replace(values=batch.values
-                                       * rollup_scale)
         if padded is not None and (use_blocked or mesh is not None):
             with trace_span("query.grid_build", cells=cells):
                 values, series_idx, bucket_idx = flatten_padded(
                     padded.values2d, bucket_idx2d, padded.counts)
         elif use_blocked or mesh is not None:
             values, series_idx = batch.values, batch.series_idx
-        # the host-retry twin for the single-device paths below: on a
+        # the one way a point batch reaches a single device: detect
+        # the layout (regular -> dense, else the padded einsum, else
+        # the flat scatter: prepare_auto -> prepare_flat) and upload
+        def prepare(device=None):
+            if padded is not None:
+                return prepare_auto(padded, bucket_idx2d, spec,
+                                    device=device)
+            return prepare_flat(batch.values, batch.series_idx,
+                                bucket_idx, spec, device=device)
+
+        # the host-retry twin for the single-device path below: on a
         # device-pipeline failure (or an armed device fault) the same
         # tail re-runs pinned to the host CPU backend — a degraded
         # answer instead of a 500. Mesh and blocked executions have no
         # in-process twin; their failures count toward the breaker and
         # propagate.
-        host_retry = None
-        if mesh is None and not use_blocked:
-            def host_retry():
-                from opentsdb_tpu.ops.pipeline import (prepare_auto,
-                                                       prepare_flat,
-                                                       run_prepared)
-                cpu = self._host_cpu()
-                hspec = replace(spec, host=True)
-                if padded is not None:
-                    prep = prepare_auto(padded, bucket_idx2d, hspec,
-                                        device=cpu)
-                else:
-                    prep = prepare_flat(batch.values, batch.series_idx,
-                                        bucket_idx, hspec, device=cpu)
-                return run_prepared(prep, bucket_ts, group_ids, hspec,
-                                    sub.rate_options)
+        def host_retry():
+            return run_prepared(prepare(self._host_cpu()), bucket_ts,
+                                group_ids, replace(spec, host=True),
+                                sub.rate_options)
+
         if use_blocked:
             # long-range streaming: bound memory at [S x block] cells
             # (SURVEY.md §5.7 time-axis blocking)
@@ -1237,66 +1227,32 @@ class QueryEngine:
                     num_groups, sub.rate_options)
 
             result, emit = self._run_device(mesh_compute)
-        elif host_dev is not None:
-            # host tail: place on the CPU backend; cached in the
+        else:
+            # single device: upload once, keep the batch where a cache
+            # can hold it, execute. A host-placed tail goes to the
             # host-RAM pool (NOT the device cache — host entries must
             # never evict HBM-resident grids) so warm repeats skip
-            # materialize + union-grid construction
-            from opentsdb_tpu.ops.pipeline import (prepare_auto,
-                                                   prepare_flat,
-                                                   run_prepared)
-            if padded is not None:
-                prep = prepare_auto(padded, bucket_idx2d, spec,
-                                    device=host_dev)
-            else:
-                prep = prepare_flat(batch.values, batch.series_idx,
-                                    bucket_idx, spec, device=host_dev)
-            hcache = self.tsdb.host_prep_cache \
-                if rollup_scale == 1.0 else None
-            if hcache is not None and pkey is not None:
-                hcache.put(pkey, pver, (prep,), {
-                    "num_points": num_points, "bucket_ts": bucket_ts,
-                    "ds_function": ds_function,
-                    "fill_policy": fill_policy,
-                    "fill_value": fill_value, "host": True,
-                    "complete": grid_complete})
-            result, emit = self._run_device(
-                lambda: run_prepared(prep, bucket_ts, group_ids,
-                                     spec, sub.rate_options),
-                on_device=False)
-        elif prep_cache is not None:
-            # upload once, cache the device-resident batch, execute
-            from opentsdb_tpu.ops.pipeline import (prepare_auto,
-                                                   prepare_flat,
-                                                   run_prepared)
+            # materialize + union-grid construction; with
+            # tsd.query.device_cache_mb=0 nothing stays resident and
+            # the same program runs
+            on_host = host_dev is not None
+            pool = self.tsdb.host_prep_cache if on_host else prep_cache
 
-            def cached_compute():
-                if padded is not None:
-                    prep = prepare_auto(padded, bucket_idx2d, spec)
-                else:
-                    prep = prepare_flat(batch.values,
-                                        batch.series_idx,
-                                        bucket_idx, spec)
-                prep_cache.put(pkey, pver, (prep,), {
-                    "num_points": num_points, "bucket_ts": bucket_ts,
-                    "ds_function": ds_function,
-                    "fill_policy": fill_policy,
-                    "fill_value": fill_value})
+            def compute():
+                prep = prepare(host_dev)
+                if pool is not None and pkey is not None:
+                    pool.put(pkey, pver, (prep,), {
+                        "num_points": num_points,
+                        "bucket_ts": bucket_ts,
+                        "ds_function": ds_function,
+                        "fill_policy": fill_policy,
+                        "fill_value": fill_value, "host": on_host,
+                        "complete": grid_complete})
                 return run_prepared(prep, bucket_ts, group_ids, spec,
                                     sub.rate_options)
 
-            result, emit = self._run_device(cached_compute, host_retry)
-        elif padded is not None:
-            result, emit = self._run_device(
-                lambda: execute_auto(
-                    padded, bucket_idx2d, bucket_ts, group_ids, spec,
-                    sub.rate_options), host_retry)
-        else:
-            result, emit = self._run_device(
-                lambda: execute(
-                    batch.values, batch.series_idx, bucket_idx,
-                    bucket_ts, group_ids, spec, sub.rate_options),
-                host_retry)
+            result, emit = self._run_device(compute, host_retry,
+                                            on_device=not on_host)
         if stats:
             stats.add_stat(QueryStat.COMPUTE_TIME,
                            (time.monotonic() - t2) * 1e3)
@@ -1344,7 +1300,6 @@ class QueryEngine:
                     sub.rate_options))
         else:
             (prep,) = cached_args
-            from opentsdb_tpu.ops.pipeline import run_prepared
             result, emit = self._run_device(
                 lambda: run_prepared(prep, bucket_ts, group_ids,
                                      spec, sub.rate_options),
@@ -1366,8 +1321,8 @@ class QueryEngine:
     def _select_store(self, sub: TSSubQuery):
         """Pick raw store or a rollup tier (ref: TsdbQuery rollup
         best-match :143-150 with ROLLUP_USAGE fallback :750).
-        Returns (store, metric_name, sids, rollup_scale,
-        avg_count_store, ds_fn_override).
+        Returns (store, metric_name, sids, avg_count_store,
+        ds_fn_override).
 
         ``avg_count_store`` is the COUNT-tier store when an ``avg``
         downsample is being answered from rollups: the reference
@@ -1384,14 +1339,13 @@ class QueryEngine:
         """
         uids = self.tsdb.uids
         if sub.tsuids:
-            return self._tsuid_store(sub)  # 6-tuple
+            return self._tsuid_store(sub)
         try:
             metric_id = uids.metrics.get_id(sub.metric)
         except LookupError:
             raise NoSuchMetricError(
                 f"No such name for 'metrics': '{sub.metric}'") from None
         store = self.tsdb.store
-        rollup_scale = 1.0
         avg_count_store = None
         ds_fn_override = None
         usage = (sub.rollup_usage or "ROLLUP_NOFALLBACK").upper()
@@ -1443,8 +1397,7 @@ class QueryEngine:
             sids = store.series_ids_for_metric(metric_id)
             avg_count_store = None
             ds_fn_override = None
-        return (store, sub.metric, sids, rollup_scale, avg_count_store,
-                ds_fn_override)
+        return store, sub.metric, sids, avg_count_store, ds_fn_override
 
     def _maybe_stitch(self, tier_store, metric_id: int, interval: str,
                       agg: str):
@@ -1550,8 +1503,8 @@ class QueryEngine:
     def _grid_pipeline(self, store, sids: np.ndarray, tsq: TSQuery,
                        sub: TSSubQuery, metric_name: str,
                        group_ids: np.ndarray, num_groups: int,
-                       emit_raw: bool, rollup_scale: float, budget: int,
-                       stats, ds_fn_override: str | None = None):
+                       emit_raw: bool, budget: int, stats,
+                       ds_fn_override: str | None = None):
         """Storage-side downsample: one fused native pass produces the
         [S, B] grid (ref analogue: the scan + Downsampler stages of
         TsdbQuery.java:795 + Downsampler.java:28 collapsed into the
@@ -1559,7 +1512,7 @@ class QueryEngine:
         fill/rate/interpolate/aggregate tail. Returns None when
         ineligible (caller falls through to the point paths), or
         (result, emit, bucket_ts) with result=None for no data."""
-        if not self._grid_eligible(sub) or rollup_scale != 1.0:
+        if not self._grid_eligible(sub):
             return None
         ds_spec = sub.ds_spec
         bucket_ts = ds_mod.fixed_bucket_edges(
@@ -1943,7 +1896,7 @@ class QueryEngine:
             if sid is not None:
                 sids.append(sid)
         return (store, metric_name or "", np.asarray(
-            sids, dtype=np.int64), 1.0, None, None)
+            sids, dtype=np.int64), None, None)
 
     # ------------------------------------------------------------------
 

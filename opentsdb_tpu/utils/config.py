@@ -361,12 +361,7 @@ _DEFAULTS: dict[str, str] = {
     "tsd.slo.put.latency_objective": "0.99",
     "tsd.slo.put.availability_objective": "0.999",
     # TPU-native keys (no reference equivalent)
-    "tsd.tpu.dtype": "float32",
     "tsd.tpu.platform": "",  # force jax platform (cpu|tpu); "" = auto
-    "tsd.tpu.mesh.series_axis": "8",
-    "tsd.tpu.mesh.time_axis": "1",
-    "tsd.tpu.time_block_points": "134217728",  # points per device block
-    "tsd.tpu.donate_buffers": "true",
 }
 
 _SEARCH_PATHS = (

@@ -6,7 +6,6 @@ built from, and ``chip_smoke.py`` refuses a run without a TPU."""
 from __future__ import annotations
 
 import json
-import logging
 import os
 import subprocess
 import sys
@@ -22,14 +21,13 @@ BASE = 1356998400
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _kernel_tsdb(**extra):
-    """A TSDB whose regular-cadence queries reach the fused kernel:
-    device cache, storage-side grid reduction and host-tail placement
-    off (the phase-B settings of chip_smoke.py)."""
+def _point_tsdb(**extra):
+    """A TSDB whose regular-cadence queries take the point path and
+    run its program on the device: storage-side grid reduction and
+    host-tail placement off (the phase-B settings of chip_smoke.py)."""
     t = TSDB(Config(**{
         "tsd.core.auto_create_metrics": "true",
         "tsd.tpu.warmup": "false",
-        "tsd.query.device_cache_mb": "0",
         "tsd.query.grid_reduce": "false",
         "tsd.query.host_tail_max_cells_linear": "-1",
         "tsd.query.cache.enable": "false", **extra}))
@@ -40,84 +38,77 @@ def _kernel_tsdb(**extra):
     return t
 
 
-def _kernel_query():
-    return TSQuery.from_json({
-        "start": BASE * 1000, "end": (BASE + 719) * 1000,
-        "queries": [{"metric": "k.cpu", "aggregator": "sum",
-                     "downsample": "3m-avg", "rate": True,
-                     "filters": [{"type": "wildcard", "tagk": "dc",
-                                  "filter": "*", "groupBy": True}]}]
-    }).validate()
+_POINT_QUERY = {
+    "start": BASE * 1000, "end": (BASE + 719) * 1000,
+    "queries": [{"metric": "k.cpu", "aggregator": "sum",
+                 "downsample": "3m-avg", "rate": True,
+                 "filters": [{"type": "wildcard", "tagk": "dc",
+                              "filter": "*", "groupBy": True}]}]}
+
+
+def _point_query():
+    return TSQuery.from_json(_POINT_QUERY).validate()
+
+
+def _device_program_fails(monkeypatch):
+    """The point path's program raises when it is placed on the
+    device; its host-placed twin (the designed degradation) runs."""
+    from opentsdb_tpu.query import engine as engine_mod
+    real = engine_mod.run_prepared
+
+    def boom(prep, bucket_ts, group_ids, spec, *a, **kw):
+        if not spec.host:
+            raise RuntimeError("the compiler said no")
+        return real(prep, bucket_ts, group_ids, spec, *a, **kw)
+
+    monkeypatch.setattr(engine_mod, "run_prepared", boom)
 
 
 class TestNoFallbackHidesTheDevice:
-    def test_kernel_runs_and_is_counted(self):
-        from opentsdb_tpu.ops import pallas_fused
-        before = pallas_fused.COUNTERS.as_dict()
-        t = _kernel_tsdb()
-        assert len(t.execute_query(_kernel_query())) == 2
-        after = t.device_info()["pallas"]
-        # interpret mode follows the device the operands are on: CPU
-        assert after["interpreted"] == before["interpreted"] + 1
-        assert after["compiled"] == before["compiled"]
-        assert after["dense_instead"] == before["dense_instead"]
-
-    def test_kernel_failure_reaches_the_breaker(self, monkeypatch,
-                                                caplog):
-        from opentsdb_tpu.ops import pallas_fused
-
-        def boom(*a, **kw):
-            raise RuntimeError("Mosaic said no")
-
-        monkeypatch.setattr(pallas_fused, "fused_dense_pipeline", boom)
-        t = _kernel_tsdb(**{
+    def test_kernel_failure_reaches_the_breaker(self, monkeypatch):
+        _device_program_fails(monkeypatch)
+        t = _point_tsdb(**{
             "tsd.query.degraded.host_fallback": "false"})
-        with caplog.at_level(logging.WARNING):
-            with pytest.raises(RuntimeError, match="Mosaic said no"):
-                t.execute_query(_kernel_query())
+        with pytest.raises(RuntimeError, match="the compiler said no"):
+            t.execute_query(_point_query())
         assert t.device_breaker.health_info()["total_failures"] == 1
         assert t.device_breaker.fallbacks == 0
-        assert "falling back to the XLA dense path" not in caplog.text
 
     def test_designed_degradation_still_answers(self, monkeypatch):
-        from opentsdb_tpu.ops import pallas_fused
-        want = _kernel_tsdb().execute_query(_kernel_query())
-
-        def boom(*a, **kw):
-            raise RuntimeError("Mosaic said no")
-
-        monkeypatch.setattr(pallas_fused, "fused_dense_pipeline", boom)
-        t = _kernel_tsdb()  # tsd.query.degraded.host_fallback = true
-        got = t.execute_query(_kernel_query())
+        want = _point_tsdb().execute_query(_point_query())
+        _device_program_fails(monkeypatch)
+        t = _point_tsdb()  # tsd.query.degraded.host_fallback = true
+        got = t.execute_query(_point_query())
         assert t.device_breaker.health_info()["total_failures"] == 1
         assert t.device_breaker.fallbacks == 1
+        assert len(got) == len(want) == 2
         for g, w in zip(got, want):
             np.testing.assert_allclose([v for _, v in g.dps],
                                        [v for _, v in w.dps])
 
-    def test_replaced_kernel_is_counted_by_reason(self):
-        from opentsdb_tpu.ops import pallas_fused
-        t = _kernel_tsdb()
-        tsq = _kernel_query()
-        tsq.queries[0].aggregator = "max"
-        tsq.validate()
-        before = pallas_fused.COUNTERS.as_dict()["dense_instead"]
-        t.execute_query(tsq)
-        after = pallas_fused.COUNTERS.as_dict()["dense_instead"]
-        assert after.get("aggregator:max", 0) == \
-            before.get("aggregator:max", 0) + 1
-
-    def test_interpret_follows_the_operands_device(self):
-        import jax
-        from opentsdb_tpu.ops import pallas_fused
-        from opentsdb_tpu.ops.pipeline import PipelineSpec
-        spec = PipelineSpec(num_series=4, num_buckets=2, num_groups=1,
-                            ds_function="avg", agg_name="sum")
-        _, _, interpret = pallas_fused.prepare(
-            np.ones((4, 4), np.float32), np.arange(2) * 60000,
-            np.zeros(4, np.int32), spec, 2,
-            device=jax.devices("cpu")[0])
-        assert interpret
+    def test_device_cache_size_selects_no_executor(self):
+        # PR 29: tsd.query.device_cache_mb=0 keeps nothing resident and
+        # runs the same program as the default
+        seen = []
+        for extra in ({}, {"tsd.query.device_cache_mb": "0"}):
+            t = _point_tsdb(**{"tsd.trace.sample": "1", **extra})
+            assert (t.device_grid_cache is None) == bool(extra)
+            router = HttpRpcRouter(t)
+            resp = router.handle(HttpRequest(
+                method="POST", path="/api/query", params={},
+                body=json.dumps(_POINT_QUERY).encode()))
+            assert resp.status == 200, resp.body
+            tails = [(r["tags"]["path"], r["tags"]["placement"],
+                      r["value"])
+                     for r in json.loads(router.handle(HttpRequest(
+                         method="GET", path="/api/stats", params={},
+                         body=b"")).body)
+                     if r["metric"] == "tsd.query.tail"]
+            seen.append((resp.body, tails))
+            t.shutdown()
+        assert seen[0] == seen[1]
+        assert len(json.loads(seen[0][0])) == 2
+        assert seen[0][1] == [("dense", "device", 1)]
 
 
 class TestServerSaysWhatItRunsOn:
@@ -134,8 +125,7 @@ class TestServerSaysWhatItRunsOn:
         assert dev["mesh"] == {"requested": "", "shape": None,
                                "devices": 1, "error": ""}
         assert dev["warmup"]["state"] == "off"
-        assert set(dev["pallas"]) == {"compiled", "interpreted",
-                                      "dense_instead"}
+        assert "pallas" not in dev      # PR 29: the kernel is gone
         assert dev["resident"]["entries"] == 0
 
     def test_stats_twin(self, tsdb):
@@ -146,7 +136,8 @@ class TestServerSaysWhatItRunsOn:
         by_name = {r["metric"]: r for r in rows}
         assert by_name["tsd.device.count"]["value"] == 8
         assert by_name["tsd.device.count"]["tags"]["platform"] == "cpu"
-        assert "tsd.device.pallas.compiled" in by_name
+        assert not [n for n in by_name
+                    if n.startswith("tsd.device.pallas.")]
         assert "tsd.device.warmup.failed" in by_name
 
     def test_mesh_really_built_is_reported(self):

@@ -11,7 +11,8 @@ import pytest
 
 from opentsdb_tpu.ops import downsample as ds_mod
 from opentsdb_tpu.ops.pipeline import (PipelineSpec, detect_regular_padded,
-                                       execute_auto, flatten_padded)
+                                       flatten_padded, prepare_auto,
+                                       run_prepared)
 from opentsdb_tpu.query.model import TSQuery
 
 
@@ -81,7 +82,7 @@ class TestDetectRegularPadded:
         assert detect_regular_padded(counts, bidx, 2) is None
 
 
-class TestExecuteAutoEquivalence:
+class TestPrepareAutoEquivalence:
     @pytest.mark.parametrize("agg,fn,rate", [
         ("sum", "avg", False), ("max", "sum", True),
         ("avg", "min", False), ("dev", "count", False),
@@ -98,8 +99,9 @@ class TestExecuteAutoEquivalence:
         padded = PaddedBatch(np.arange(s, dtype=np.int64), values2d,
                              np.zeros_like(values2d, dtype=np.int64),
                              counts)
-        got, got_emit = execute_auto(padded, bidx2d, bucket_ts, gids,
-                                     spec)
+        prep = prepare_auto(padded, bidx2d, spec)
+        assert prep.kind == "padded"
+        got, got_emit = run_prepared(prep, bucket_ts, gids, spec)
         vals, sidx, bidx = flatten_padded(values2d, bidx2d, counts)
         gold, gold_emit = execute(vals, sidx, bidx, bucket_ts, gids,
                                   spec)
@@ -125,8 +127,11 @@ class TestSkewGuard:
         tsdb = TSDB(Config(**{"tsd.core.auto_create_metrics": "true",
                               "tsd.query.grid_reduce": "false",
                               # materialize must run on every query for
-                              # the call-counting below
-                              "tsd.query.device_cache_mb": "0"}))
+                              # the call-counting below: keep no batch
+                              # resident and answer none from the
+                              # result cache
+                              "tsd.query.device_cache_mb": "0",
+                              "tsd.query.cache.enable": "false"}))
         base = 1356998400
         for i in range(2000):
             tsdb.add_point("m", base + i, float(i), {"host": "big"})

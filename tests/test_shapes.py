@@ -6,8 +6,8 @@ import pytest
 
 from opentsdb_tpu.ops import shapes
 from opentsdb_tpu.ops.pipeline import (PipelineSpec, execute_grid,
-                                       prepare_flat, run_prepared,
-                                       run_pipeline_grid)
+                                       prepare_flat, run_pipeline,
+                                       run_pipeline_grid, run_prepared)
 from opentsdb_tpu.ops.rate import RateOptions
 
 BASE_MS = 1356998400000
@@ -118,9 +118,13 @@ class TestPreparedBucketing:
         spec = PipelineSpec(num_series=s, num_buckets=b, num_groups=g,
                             ds_function="avg", agg_name="sum",
                             rate=True)
-        from opentsdb_tpu.ops.pipeline import execute
-        ref, ref_emit = execute(values, sidx, bidx, bts, gids, spec,
-                                RateOptions(), use_pallas=False)
+        # the reference is the scatter program at the true shapes: no
+        # layout detection, no shape buckets
+        ro = RateOptions()
+        ref, ref_emit = (np.asarray(x) for x in run_pipeline(
+            values, sidx, bidx, bts, gids,
+            (np.float64(ro.counter_max), np.float64(ro.reset_value)),
+            np.float64(spec.fill_value), spec))
         prep = prepare_flat(values, sidx, bidx, spec)
         assert prep.pad is not None
         got, got_emit = run_prepared(prep, bts, gids, spec,
