@@ -712,6 +712,10 @@ class Tracer:
         # "hit" (planned from the cached index), "built" (built it
         # first), "bypass" (a selection that is not a whole metric)
         self.plans = {"hit": 0, "built": 0, "bypass": 0}
+        # assemble stages, by where the groups' common and aggregated
+        # tags were read: "index" (the plan index's cached layout) or
+        # "matrix" (a sort of the request's own rows)
+        self.assembles = {"index": 0, "matrix": 0}
         self._ring: deque[TraceData] = deque(
             maxlen=max(config.get_int("tsd.trace.ring", 256), 1))
         self._slow_ring: deque[TraceData] = deque(
@@ -928,7 +932,8 @@ class Tracer:
         ``query.program`` counts in ``tails``, every ``query.grid_build``
         that built a grid (tag ``fused``) in ``grid_builds``, every
         ``query.plan`` that reached its filters (tag ``index``) in
-        ``plans``."""
+        ``plans``, every ``query.assemble`` by its tag ``tags`` in
+        ``assembles``."""
         kids: dict[str, list[SpanRecord]] = {}
         for s in spans:
             kids.setdefault(s.parent_id, []).append(s)
@@ -936,6 +941,7 @@ class Tracer:
         tails = []
         builds = []
         plans = []
+        assembles = []
         for s in [root] + spans:
             self_ms, occupied = s.duration_ms, s.occupied_ms
             mine = kids.get(s.span_id)
@@ -962,6 +968,9 @@ class Tracer:
             elif s.name == "query.plan" and s.tags.get("index") \
                     in self.plans:
                 plans.append(s.tags["index"])
+            elif s.name == "query.assemble" and s.tags.get("tags") \
+                    in self.assembles:
+                assembles.append(s.tags["tags"])
         with self._lock:
             for name, ms in idle.items():
                 self.idle_stage_ms[name] = \
@@ -972,6 +981,8 @@ class Tracer:
                 self.grid_builds[mode] += 1
             for state in plans:
                 self.plans[state] += 1
+            for way in assembles:
+                self.assembles[way] += 1
 
     # -- retrieval -----------------------------------------------------
 
@@ -1059,6 +1070,7 @@ class Tracer:
             tails = sorted(self.tails.items())
             builds = sorted(self.grid_builds.items())
             plans = sorted(self.plans.items())
+            assembles = sorted(self.assembles.items())
         for stage, ms in idle:
             collector.record("device.idle_stage_ms", ms, stage=stage)
         for (path, placement), n in tails:
@@ -1068,6 +1080,8 @@ class Tracer:
             collector.record("query.grid_build", n, mode=mode)
         for state, n in plans:
             collector.record("query.plan", n, index=state)
+        for way, n in assembles:
+            collector.record("query.assemble", n, tags=way)
 
     def health_info(self) -> dict[str, Any]:
         with self._lock:

@@ -166,17 +166,28 @@ class TagMatrix:
 
     ``origin`` is ``(index, rows)`` on a matrix selected out of a
     cached :class:`PlanIndex`: its row i is row ``rows[i]`` of the
-    index, so what the index keeps per series of the metric (group
-    labels) is gathered instead of derived again.
+    index (``rows`` None: every row), so what the index keeps per
+    series of the metric (group labels, the tag columns group by
+    group) is read there instead of derived again. Such a matrix
+    gathers its ``vids`` out of the index only when somebody reads
+    them.
     """
 
-    __slots__ = ("kids", "vids", "origin")
+    __slots__ = ("kids", "_vids", "origin")
 
-    def __init__(self, kids: np.ndarray, vids: np.ndarray,
-                 origin: "tuple[PlanIndex, np.ndarray] | None" = None):
+    def __init__(self, kids: np.ndarray, vids: np.ndarray | None,
+                 origin: "tuple[PlanIndex, np.ndarray | None] | None"
+                 = None):
         self.kids = kids        # int64 [K] sorted distinct tagk ids
-        self.vids = vids        # int64 [S, K]; -1 = key absent
+        self._vids = vids       # int64 [S, K]; -1 = key absent
         self.origin = origin
+
+    @property
+    def vids(self) -> np.ndarray:
+        if self._vids is None:
+            index, rows = self.origin
+            self._vids = index.tags.vids[rows]
+        return self._vids
 
     @classmethod
     def from_triples(cls, sids: np.ndarray, triples: np.ndarray,
@@ -215,7 +226,9 @@ class TagMatrix:
 
     @property
     def num_series(self) -> int:
-        return self.vids.shape[0]
+        if self._vids is None:
+            return len(self.origin[1])
+        return self._vids.shape[0]
 
     def col(self, kid: int) -> np.ndarray | None:
         """[S] tagv ids for one key (-1 absent), or None if no series
@@ -235,8 +248,20 @@ class TagMatrix:
     def select(self, mask_or_idx) -> "TagMatrix":
         origin = self.origin
         if origin is not None:
-            origin = (origin[0], origin[1][mask_or_idx])
-        return TagMatrix(self.kids, self.vids[mask_or_idx], origin)
+            index, rows = origin
+            if rows is None:
+                rows = np.arange(index.num_series)
+            origin = (index, rows[mask_or_idx])
+        vids = self._vids
+        return TagMatrix(self.kids,
+                         None if vids is None else vids[mask_or_idx],
+                         origin)
+
+    def num_pairs(self) -> int:
+        """Present (key, value) pairs over all rows."""
+        if self.origin is not None:
+            return self.origin[0].num_pairs(self.origin[1])
+        return int((self.vids >= 0).sum())
 
     def tags_of(self, i: int) -> list[tuple[int, int]]:
         """Series i's present (kid, vid) pairs, kid-ascending."""
@@ -373,11 +398,110 @@ def group_labels(tags: TagMatrix, gb_kids: Sequence[int]
     return compact_row_labels(mat)
 
 
+class GroupLayout:
+    """The rows of a tag matrix group by group, and what the SpanGroup
+    tag rule needs of each group: per tag key the minimum and maximum
+    tagv id over its members. A minimum below 0 says the key is absent
+    on a member (it vanishes), minimum == maximum that all members
+    agree (a common tag), anything else that they differ (an
+    aggregated tag).
+
+    ``order`` is the stable argsort of a compact labelling (every
+    label 0..G-1 has a member), ``starts`` [G + 1] its group
+    boundaries, ``cols`` [K, S] the tag columns read in that order, a
+    column a row. The
+    engine makes one per request from a matrix that came from nowhere,
+    and a :class:`PlanIndex` keeps one per cached labelling of the
+    whole metric, of which a request reads its selection
+    (:meth:`selected`).
+    """
+
+    #: members a block of whole groups holds before the next begins:
+    #: what :meth:`selected` copies at a time is 512 KB a column, not
+    #: the columns of the whole metric
+    BLOCK = 1 << 17
+
+    __slots__ = ("order", "starts", "cols", "minv", "maxv")
+
+    def __init__(self, order: np.ndarray, starts: np.ndarray,
+                 cols: np.ndarray):
+        self.order, self.starts, self.cols = order, starts, cols
+        # int64 [G, K] over whole groups
+        self.minv, self.maxv = (
+            reduce.reduceat(cols, starts[:-1], axis=1).T.astype(np.int64)
+            for reduce in (np.minimum, np.maximum))
+
+    def members(self, group: int) -> np.ndarray:
+        """The rows of one group, ascending."""
+        return self.order[self.starts[group]:self.starts[group + 1]]
+
+    def selected(self, mask: np.ndarray):
+        """``(minv, maxv, members)`` over the rows ``mask`` keeps, for
+        the groups that keep any, renumbered in label order:
+        ``members(g)`` gives the kept rows of the g-th of them.
+
+        A group that keeps every member reads the whole group's
+        minimum and maximum; the others are reduced over their kept
+        members, a block of whole groups at a time."""
+        starts = self.starts
+        chosen = mask[self.order]
+        kept = np.add.reduceat(chosen, starts[:-1], dtype=np.int64)
+        minv, maxv = self.minv.copy(), self.maxv.copy()
+        partial = (kept > 0) & (kept < np.diff(starts))
+        # the first group to start at or after each multiple of BLOCK
+        cuts = np.unique(np.append(
+            np.searchsorted(starts,
+                            np.arange(0, len(chosen), self.BLOCK)),
+            len(kept)))
+        for g0, g1 in zip(cuts[:-1], cuts[1:]):
+            if not partial[g0:g1].any():
+                continue
+            lo, hi = starts[g0], starts[g1]
+            picked = chosen[lo:hi]
+            live = np.flatnonzero(kept[g0:g1])
+            seg = (np.cumsum(kept[g0:g1]) - kept[g0:g1])[live]
+            live += g0
+            for j, col in enumerate(self.cols):
+                col = col[lo:hi][picked]
+                minv[live, j] = np.minimum.reduceat(col, seg)
+                maxv[live, j] = np.maximum.reduceat(col, seg)
+        present = np.flatnonzero(kept)
+
+        def members(group: int) -> np.ndarray:
+            at = slice(starts[present[group]],
+                       starts[present[group] + 1])
+            return self.order[at][chosen[at]]
+
+        return minv[present], maxv[present], members
+
+
+def _group_starts(labels: np.ndarray, count: int) -> np.ndarray:
+    """[count + 1] boundaries of the groups of a compact labelling in
+    its stable argsort."""
+    starts = np.zeros(count + 1, dtype=np.int64)
+    np.cumsum(np.bincount(labels, minlength=count), out=starts[1:])
+    return starts
+
+
+class _LabelSet:
+    """One cached labelling of a :class:`PlanIndex` and, built by the
+    first assemble stage that asks, its :class:`GroupLayout`."""
+
+    __slots__ = ("labels", "count", "layout")
+
+    def __init__(self, labels: np.ndarray, count: int):
+        self.labels, self.count = labels, count
+        self.layout: GroupLayout | None = None
+
+
 class PlanIndex:
-    """What the plan stage needs of one metric's tag index and that
-    depends on nothing else: the metric's whole :class:`TagMatrix`,
-    and, built by the first request that asks, each tag key's distinct
-    tagv ids and each group-by key set's label for every series.
+    """What the plan and assemble stages need of one metric's tag
+    index and that depends on nothing else: the metric's whole
+    :class:`TagMatrix`, and, built by the first request that asks,
+    each tag key's distinct tagv ids, each group-by key set's label
+    for every series, and that labelling's :class:`GroupLayout` (the
+    member order, the group boundaries and a group-major copy of the
+    tag columns, int32 where the ids fit: 4 + 4 K bytes a series).
 
     The tag index only appends, so its series count versions all of
     it: the engine keeps one per (store, metric) in
@@ -388,21 +512,26 @@ class PlanIndex:
     The lazy parts build under one lock (two sub-queries of a request
     plan side by side: the second waits and reads what the first
     built). At most :data:`LABEL_SETS` labellings are kept, least
-    recently used out (4 bytes a series each).
+    recently used out, each with its layout (4 bytes a series, and
+    4 + 4 K more once assembled from).
     """
 
     LABEL_SETS = 8
 
-    __slots__ = ("version", "tags", "_distinct", "_labels", "_lock")
+    __slots__ = ("version", "tags", "_keys_of", "_distinct", "_labels",
+                 "_lock")
 
     def __init__(self, version: int, tags: TagMatrix):
         self.version = version
         self.tags = tags
+        # present keys a row, or None where every row holds them all
+        present = tags.vids >= 0
+        self._keys_of = None if present.all() else \
+            present.sum(axis=1, dtype=np.int32)
         # tsdlint: allow[unbounded-growth] keyed by tag key: at most
         # one entry a column of ``tags``; gone with the index
         self._distinct: dict[int, np.ndarray] = {}
-        self._labels: OrderedDict[tuple, tuple[np.ndarray, int]] = \
-            OrderedDict()
+        self._labels: OrderedDict[tuple, _LabelSet] = OrderedDict()
         self._lock = threading.Lock()
 
     @property
@@ -421,25 +550,105 @@ class PlanIndex:
                     found = self._distinct[kid] = self.tags.distinct(kid)
         return found
 
+    def _label_set(self, key: tuple) -> _LabelSet:
+        """Called with the lock held."""
+        found = self._labels.get(key)
+        if found is None:
+            found = self._labels[key] = _LabelSet(
+                *group_labels(self.tags, key))
+            while len(self._labels) > self.LABEL_SETS:
+                self._labels.popitem(last=False)
+        else:
+            self._labels.move_to_end(key)
+        return found
+
     def labels(self, gb_kids: Sequence[int]) -> tuple[np.ndarray, int]:
         """:func:`group_labels` of the whole metric (int32 [S], count);
         the array is shared between requests: read it, never write."""
-        key = tuple(gb_kids)
         with self._lock:
-            found = self._labels.get(key)
-            if found is None:
-                found = self._labels[key] = group_labels(self.tags, key)
-                while len(self._labels) > self.LABEL_SETS:
-                    self._labels.popitem(last=False)
-            else:
-                self._labels.move_to_end(key)
-        return found
+            found = self._label_set(tuple(gb_kids))
+        return found.labels, found.count
 
-    def select(self, rows: np.ndarray) -> TagMatrix:
+    def layout(self, gb_kids: Sequence[int]) -> GroupLayout:
+        """The :class:`GroupLayout` of ``labels(gb_kids)`` over the
+        whole metric; shared between requests like the labels."""
+        with self._lock:
+            found = self._label_set(tuple(gb_kids))
+            if found.layout is None:
+                labels, count = found.labels, found.count
+                # 16-bit keys sort by radix: a sixth of the time
+                order = np.argsort(
+                    labels.astype(np.uint16) if count <= 1 << 16
+                    else labels, kind="stable").astype(np.int32)
+                vids = self.tags.vids
+                if not vids.size or \
+                        vids.max() <= np.iinfo(np.int32).max:
+                    vids = vids.astype(np.int32)
+                cols = np.empty(vids.shape[::-1], dtype=vids.dtype)
+                for j, col in enumerate(cols):
+                    np.take(vids[:, j], order, out=col)
+                found.layout = GroupLayout(
+                    order, _group_starts(labels, count), cols)
+        return found.layout
+
+    def num_pairs(self, rows: np.ndarray | None) -> int:
+        """Present (key, value) pairs over ``rows`` (None: all)."""
+        if self._keys_of is None:
+            n = self.num_series if rows is None else len(rows)
+            return n * len(self.tags.kids)
+        return int((self._keys_of if rows is None
+                    else self._keys_of[rows]).sum())
+
+    def select(self, rows: np.ndarray | None) -> TagMatrix:
         """The matrix of the index's rows ``rows`` (ascending
-        positions), remembering where it came from."""
-        return TagMatrix(self.tags.kids, self.tags.vids[rows],
-                         (self, rows))
+        positions; None: all of them), remembering where it came
+        from. Its ``vids`` are gathered when first read."""
+        if rows is None:
+            return TagMatrix(self.tags.kids, self.tags.vids,
+                             (self, None))
+        return TagMatrix(self.tags.kids, None, (self, rows))
+
+
+#: a selection of fewer than one row in SMALL_SELECTION of its metric
+#: is summarized from its own rows (a sort of n group ids and two
+#: gathers, ~0.1 us a selected row) and not from the index's layout (a
+#: mask over all S rows read in member order, ~0.005 us a row of the
+#: METRIC, and up to as much again for the groups a filter cut): the
+#: costs cross near one row in ten. A panel of 8 hosts of a million
+#: never reads, or builds, the layout.
+SMALL_SELECTION = 8
+
+
+def group_tag_summary(tags: TagMatrix, group_ids: np.ndarray,
+                      num_groups: int, gb_kids: Sequence[int] | None):
+    """``(way, minv, maxv, members, source)`` for the groups
+    ``group_ids`` makes of the rows of ``tags``: int64 [G, K] minimum
+    and maximum tagv id a group and key (:class:`GroupLayout` has the
+    rule they decide), ``members(g)`` the rows of ``source`` (a
+    :class:`TagMatrix`) in group g, ascending.
+
+    ``way`` says where they were read: ``index`` when ``group_ids``
+    are the labels of ``gb_kids`` gathered from the :class:`PlanIndex`
+    the matrix was selected from (``gb_kids`` None says they are not),
+    and the selection is no :data:`SMALL_SELECTION`: the index's
+    cached layout, of which an unfiltered request reads the whole
+    groups as they stand. ``matrix`` otherwise: a layout of the
+    matrix's own rows, made here."""
+    if tags.origin is not None and gb_kids is not None:
+        index, rows = tags.origin
+        if rows is None or len(rows) == index.num_series:
+            layout = index.layout(gb_kids)
+            return ("index", layout.minv, layout.maxv, layout.members,
+                    index.tags)
+        if len(rows) * SMALL_SELECTION >= index.num_series:
+            mask = np.zeros(index.num_series, dtype=bool)
+            mask[rows] = True
+            return ("index", *index.layout(gb_kids).selected(mask),
+                    index.tags)
+    order = np.argsort(group_ids, kind="stable")
+    layout = GroupLayout(order, _group_starts(group_ids, num_groups),
+                         tags.vids[order].T)
+    return "matrix", layout.minv, layout.maxv, layout.members, tags
 
 
 #: downsample functions the storage-side pre-reduction can serve, by
@@ -881,7 +1090,7 @@ class QueryEngine:
                            (time.monotonic() - t0) * 1e3)
             stats.add_stat(QueryStat.ROWS_POST_FILTER, len(sids))
             stats.add_stat(QueryStat.UID_PAIRS_RESOLVED,
-                           int((tag_mat.vids >= 0).sum()))
+                           tag_mat.num_pairs())
 
         # --- group construction (ref: GroupByAndAggregateCB :916)
         gb_tagks = sorted({f.tagk for f in sub.filters if f.group_by})
@@ -1928,7 +2137,7 @@ class QueryEngine:
                         len(idx_sids),
                         TagMatrix.from_triples(sids, triples))
                     state = "built"
-                tags = index.tags
+                tags = index.select(None)
             else:
                 tags = TagMatrix.from_triples(sids, triples)
         else:
@@ -1987,6 +2196,8 @@ class QueryEngine:
             return group_labels(tags, gb_kids)
         index, rows = tags.origin
         labels, count = index.labels(gb_kids)
+        if rows is None:
+            return labels, count
         labels = labels[rows]
         present = np.bincount(labels, minlength=count) > 0
         if present.all():
@@ -2002,14 +2213,14 @@ class QueryEngine:
         from opentsdb_tpu.query.model import effective_pixels as _epx
         with trace_span("query.assemble", sub=sub.index,
                         groups=num_groups,
-                        pixels=_epx(tsq, sub)[0]):
+                        pixels=_epx(tsq, sub)[0]) as span:
             return self._build_results_inner(
                 tsq, sub, metric_name, sids, tags, group_ids,
-                num_groups, gb_kids, bucket_ts, result, emit)
+                num_groups, gb_kids, bucket_ts, result, emit, span)
 
     def _build_results_inner(self, tsq, sub, metric_name, sids, tags,
                              group_ids, num_groups, gb_kids,
-                             bucket_ts, result, emit
+                             bucket_ts, result, emit, span
                              ) -> list[QueryResult]:
         uids = self.tsdb.uids
         out: list[QueryResult] = []
@@ -2039,28 +2250,19 @@ class QueryEngine:
         bucket_ts = np.asarray(bucket_ts, dtype=np.int64)
         ts_out = (bucket_ts if tsq.ms_resolution
                   else (bucket_ts // 1000) * 1000)
-        # group membership via one sort (the per-gid nonzero scan was
-        # O(G*S) — quadratic under wildcard group-by)
-        order = np.argsort(group_ids, kind="stable")
-        sorted_gids = group_ids[order]
-        gid_range = np.arange(num_groups, dtype=group_ids.dtype)
-        starts = np.searchsorted(sorted_gids, gid_range, side="left")
-        ends = np.searchsorted(sorted_gids, gid_range, side="right")
-        # SpanGroup tag semantics for ALL groups in two segment
+        # SpanGroup tag semantics for ALL groups from two segment
         # reductions: a key with min vid >= 0 is present on every
-        # member; min == max means one distinct value
+        # member; min == max means one distinct value. With agg=none
+        # every series is its own group, whatever the index labels
+        way, minv, maxv, members_of, source = group_tag_summary(
+            tags, group_ids, num_groups,
+            None if sub.agg.is_none else gb_kids)
+        if span is not None:
+            span.tag(tags=way)
+        gid_range = np.arange(num_groups, dtype=group_ids.dtype)
         kname = _UidNameCache(uids.tag_names)
         vname = _UidNameCache(uids.tag_values)
-        k_cnt = tags.vids.shape[1]
-        if k_cnt and len(order):
-            v_sorted = tags.vids[order]
-            # clip so reduceat never indexes past the end; an empty
-            # group's row is garbage but its gid is skipped below
-            seg = np.minimum(starts, len(order) - 1)
-            minv = np.minimum.reduceat(v_sorted, seg, axis=0)
-            maxv = np.maximum.reduceat(v_sorted, seg, axis=0)
-        else:
-            minv = maxv = np.empty((num_groups, 0), dtype=np.int64)
+        k_cnt = len(tags.kids)
         metric_id = None
         if tsq.show_tsuids or sub.tsuids or fetch_annotations:
             try:
@@ -2077,9 +2279,6 @@ class QueryEngine:
         e_ts = ts_out[e_bidx]
         e_vals = np.asarray(result[e_gidx, e_bidx], dtype=np.float64)
         for gid in range(num_groups):
-            members = order[starts[gid]:ends[gid]]
-            if len(members) == 0:
-                continue
             lo_e, hi_e = e_starts[gid], e_ends[gid]
             if lo_e == hi_e:
                 continue
@@ -2094,18 +2293,16 @@ class QueryEngine:
                     g_tags[kname(int(tags.kids[j]))] = vname(int(lo))
                 else:
                     agg_tags.append(kname(int(tags.kids[j])))
-            tsuids = []
-            if (tsq.show_tsuids or sub.tsuids) and metric_id is not None:
-                for m in members:
-                    tsuids.append(uids.tsuid(
-                        metric_id, tags.tags_of(m)).hex().upper())
+            member_tsuids = [
+                uids.tsuid(metric_id, source.tags_of(m)).hex().upper()
+                for m in members_of(gid)] if metric_id is not None else []
+            tsuids = member_tsuids \
+                if tsq.show_tsuids or sub.tsuids else []
             annotations = []
-            if fetch_annotations and metric_id is not None:
+            if fetch_annotations:
                 start_s = tsq.start_ms // 1000
                 end_s = tsq.end_ms // 1000
-                for m in members:
-                    tsuid_hex = uids.tsuid(
-                        metric_id, tags.tags_of(m)).hex().upper()
+                for tsuid_hex in member_tsuids:
                     annotations.extend(
                         self.tsdb.annotations.range(tsuid_hex,
                                                     start_s, end_s))

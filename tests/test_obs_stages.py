@@ -404,6 +404,50 @@ def test_the_plan_span_says_what_the_plan_index_did(backend):
         tsdb.shutdown()
 
 
+@pytest.mark.parametrize("backend", ["native", "memory"])
+def test_the_assemble_span_says_where_the_group_tags_were_read(backend):
+    # PR 30: a planned request reads its groups' common and aggregated
+    # tags from the plan index's layout; a tsuid query has no index
+    # behind its matrix and sorts its own rows
+    tsdb = mk_tsdb(**{"tsd.storage.backend": backend})
+    router = HttpRpcRouter(tsdb)
+
+    def assemble_tags(sub):
+        body = json.dumps({
+            "start": BASE * 1000, "end": (BASE + 600) * 1000,
+            "queries": [{"aggregator": "sum", "downsample": "1m-avg",
+                         **sub}]}).encode()
+        resp = router.handle(req("POST", "/api/query", body))
+        assert resp.status == 200, resp.body
+        (root,) = json.loads(router.handle(req(
+            "GET", "/api/trace/" + resp.headers["X-TSD-Trace-Id"])
+        ).body)["tree"]
+        execute = next(c for c in root["children"]
+                       if c["name"] == "query.execute")
+        (assemble,) = [c for c in execute["children"]
+                       if c["name"] == "query.assemble"]
+        return assemble["tags"]
+
+    try:
+        tsdb.import_buffer(import_text(), durable=False)
+        grid = {"metric": "sys.stage", "filters": [{
+            "type": "wildcard", "tagk": "dc", "filter": "*",
+            "groupBy": True}]}
+        for _ in range(2):      # the index built, then hit
+            assert assemble_tags(grid) == {
+                "sub": 0, "groups": 4, "pixels": 0, "tags": "index"}
+        rec = tsdb.store.series(int(tsdb.store.series_ids_for_metric(
+            tsdb.uids.metrics.get_id("sys.stage"))[3]))
+        tsuid = tsdb.uids.tsuid(rec.metric_id, rec.tags).hex()
+        assert assemble_tags({"tsuids": [tsuid]})["tags"] == "matrix"
+        ways = {r["tags"]["tags"]: r["value"] for r in json.loads(
+            router.handle(req("GET", "/api/stats")).body)
+            if r["metric"] == "tsd.query.assemble"}
+        assert ways == {"index": 2, "matrix": 1}
+    finally:
+        tsdb.shutdown()
+
+
 def test_a_loader_outside_any_request_records_its_stages():
     tsdb = mk_tsdb()
     try:

@@ -6,6 +6,13 @@ same request through a cold index (built by it), a warm one (hit) and
 the path that cannot use it (bypass: the labels come from the selected
 columns, as every request's did before) must serialize to the same
 bytes, and select the series a walk over the records selects.
+
+PR 30: the assemble stage reads each group's common and aggregated
+tags from the index too (a group-major copy of the tag columns beside
+each cached labelling), where the request's matrix came out of the
+index and selects no small part of it. The index way, the matrix way
+(bypass: a sort of the request's own rows) and a walk over the records
+must agree on ``tags``, ``aggregateTags``, ``tsuids`` and every byte.
 """
 
 import itertools
@@ -16,8 +23,10 @@ import numpy as np
 import pytest
 
 from opentsdb_tpu import TSDB, Config
-from opentsdb_tpu.query.engine import (PlanIndex, QueryEngine,
-                                       TagMatrix, group_labels)
+from opentsdb_tpu.query import engine as engine_mod
+from opentsdb_tpu.query.engine import (GroupLayout, PlanIndex,
+                                       QueryEngine, TagMatrix,
+                                       group_labels, group_tag_summary)
 from opentsdb_tpu.query.filters import build_filter
 from opentsdb_tpu.tsd.http_api import HttpRequest, HttpRpcRouter
 
@@ -75,6 +84,9 @@ class Served:
         plans = sorted((s.tags for s in data.spans
                         if s.name == "query.plan"),
                        key=lambda t: t["sub"])
+        self.ways = [t["tags"] for t in sorted(
+            (s.tags for s in data.spans if s.name == "query.assemble"),
+            key=lambda t: t["sub"])]
         return resp.body, plans
 
     def cold(self, *subs, **top):
@@ -110,20 +122,61 @@ class Served:
                 (c.get("series"), c.get("groups"))
         return json.loads(cold), plans
 
-    def walked(self, filters):
+    def walked(self, filters, tsuids=False):
         """The series a walk over the records selects: every filter
         must pass on every series, by its own string predicate."""
         uids, store = self.tsdb.uids, self.tsdb.store
         keep = []
         for sid in store.series_ids_for_metric(
                 uids.metrics.get_id("sys.plan")):
+            rec = store.series(int(sid))
             tags = {uids.tag_names.get_name(k):
-                    uids.tag_values.get_name(v)
-                    for k, v in store.series(int(sid)).tags}
+                    uids.tag_values.get_name(v) for k, v in rec.tags}
             if all(f.match_value(tags[f.tagk]) if f.tagk in tags
                    else f.match_absent for f in filters):
-                keep.append(tags)
+                keep.append((tags, uids.tsuid(
+                    rec.metric_id, rec.tags).hex().upper())
+                    if tsuids else tags)
         return keep
+
+    def walked_groups(self, filters, gb):
+        """{group-by values: (tags, aggregateTags, tsuids)} by the
+        SpanGroup rule, member by member."""
+        groups = {}
+        for tags, tsuid in self.walked(
+                [build_filter(f) for f in filters], tsuids=True):
+            groups.setdefault(tuple(tags[k] for k in gb),
+                              []).append((tags, tsuid))
+        out = {}
+        for key, members in groups.items():
+            everywhere = set.intersection(
+                *(set(tags) for tags, _ in members))
+            values = {k: {tags[k] for tags, _ in members}
+                      for k in everywhere}
+            out[key] = (
+                {k: v.pop() for k, v in values.items() if len(v) == 1},
+                sorted(k for k, v in values.items() if len(v) > 1),
+                [tsuid for _, tsuid in members])
+        return out
+
+    def index_matrix_and_walk(self, sub, **top):
+        """The rows of one sub-query answered the index way, after the
+        matrix way gave the same bytes and the walk the same tags."""
+        sub = {"aggregator": "sum", **sub}
+        self.query(sub, **top)             # the index and its layout
+        body, _ = self.warm(sub, showTSUIDs=True, **top)
+        assert self.ways == ["index"]
+        assert body == self.bypass(sub, showTSUIDs=True, **top)[0]
+        assert self.ways == ["matrix"]
+        rows = json.loads(body)
+        filters = sub.get("filters", [])
+        gb = sorted({f["tagk"] for f in filters if f["groupBy"]})
+        want = self.walked_groups(filters, gb)
+        got = {tuple(r["tags"][k] for k in gb):
+               (r["tags"], sorted(r["aggregateTags"]), r["tsuids"])
+               for r in rows}
+        assert got == want and len(rows) == len(want)
+        return rows
 
 
 @pytest.fixture
@@ -383,3 +436,370 @@ def test_gathered_labels_equal_labels_of_the_selection(rows):
             index.select(rows).select(keep), gb)
         want, m = QueryEngine._group_ids(tags.select(rows[keep]), gb)
         assert n == m and np.array_equal(got, want)
+
+
+# --- PR 30: the assemble stage reads the index -------------------------
+
+GROUP_BYS = {"one": ["dc"], "two": ["dc", "fleet"], "none": []}
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+@pytest.mark.parametrize("keys", GROUP_BYS)
+@pytest.mark.parametrize("type_, expr", FILTERS,
+                         ids=[t for t, _ in FILTERS])
+def test_index_matrix_and_walk_agree_on_the_groups_tags(served, type_,
+                                                        expr, keys):
+    served.index_matrix_and_walk({"filters": [
+        flt(type_, "rack", expr),
+        *(flt("wildcard", k, "*", True) for k in GROUP_BYS[keys])]})
+
+
+def tag_rule(rows, key, by=None):
+    """Where each group's answer put ``key``: ``tags``' value, "agg"
+    or None (vanished); a list, or a dict by the groups' tag ``by``."""
+    rule = [r["tags"].get(key) or
+            ("agg" if key in r["aggregateTags"] else None)
+            for r in rows]
+    if by is None:
+        return rule
+    return dict(zip((r["tags"][by] for r in rows), rule, strict=True))
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_a_key_absent_on_members_the_filter_removes_or_keeps(served):
+    # a sixth of the series has no rack, all of them in d0 and d1:
+    # over those whole groups rack vanishes, and the cached
+    # whole-group summary says so
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "dc", "*", True)]})
+    assert dcs_of(rows) == DCS
+    assert tag_rule(rows, "rack") == ["agg", None, "agg", None]
+    # the filter removes them: rack is back, aggregated
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "dc", "*", True),
+                     flt("wildcard", "rack", "*")]})
+    assert tag_rule(rows, "rack") == ["agg"] * 4
+    # ... or common, where one value is left
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "dc", "*", True),
+                     flt("literal_or", "rack", "r2")]})
+    assert tag_rule(rows, "rack") == ["r2"] * 4
+    # a filter that keeps some of them: still vanished
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "dc", "*", True),
+                     flt("literal_or", "fleet", "f1")]})
+    assert dcs_of(rows) == ["d0", "d1"]
+    assert tag_rule(rows, "rack") == [None, None]
+    assert tag_rule(rows, "fleet") == ["f1", "f1"]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_one_value_left_of_a_key_the_whole_group_has_many_of(served):
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "fleet", "*", True)]})
+    assert tag_rule(rows, "host") == ["agg"] * 2
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "fleet", "*", True),
+                     flt("regexp", "host", "h0[0-7]")]})
+    assert tag_rule(rows, "host") == ["agg"] * 2
+    # h07, of the odd fleet, has no dc
+    assert tag_rule(rows, "dc", "fleet") == {"f0": "agg", "f1": None}
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "dc", "*", True),
+                     flt("regexp", "host", "h0[0-7]")]})
+    # d3: h00, h04; d0: h01, h05; d2: h02, h06; d1: h03 alone
+    assert tag_rule(rows, "host") == ["agg", "agg", "agg", "h03"]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_groups_the_selection_empties_leave_the_summary(served):
+    hosts = "|".join(f"h{h:02d}" for h in (1, 3, 5, 9, 11, 13, 17))
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "dc", "*", True),
+                     flt("literal_or", "host", hosts)]})
+    assert dcs_of(rows) == ["d0", "d1"]
+    assert tag_rule(rows, "fleet") == ["f1", "f1"]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+@pytest.mark.parametrize("sub", [
+    {}, {"filters": [flt("wildcard", "host", "*", True)]},
+    {"filters": [flt("wildcard", "fleet", "*", True),
+                 flt("not_key", "nosuch", "")]}],
+    ids=["no-filter", "group-by-host", "two-groups"])
+def test_an_unfiltered_request_reads_the_whole_groups(served, sub,
+                                                      monkeypatch):
+    served.query(sub)
+    # every row selected: no mask, no pass over the columns
+    monkeypatch.setattr(GroupLayout, "selected", None)
+    rows = served.index_matrix_and_walk(sub)
+    by = next((f["tagk"] for f in sub.get("filters", ())
+               if f["groupBy"]), None)
+    if by == "host":    # a group each: its own rack, or none at all
+        assert tag_rule(rows, "rack", by) == {
+            f"h{h:02d}": f"r{h % 5}" if h % 6 != 5 else None
+            for h in range(SERIES)}
+    elif by == "fleet":     # every series without a rack is odd
+        assert tag_rule(rows, "rack", by) == {"f0": "agg", "f1": None}
+    else:
+        assert tag_rule(rows, "rack") == [None]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_agg_none_gives_every_series_its_own_tags(served):
+    sub = {"aggregator": "none",
+           "filters": [flt("wildcard", "dc", "*", True)]}
+    served.query(sub)
+    body, (plan,) = served.warm(sub, showTSUIDs=True)
+    assert served.ways == ["matrix"]
+    assert body == served.bypass(sub, showTSUIDs=True)[0]
+    rows = json.loads(body)
+    want = served.walked([build_filter(sub["filters"][0])],
+                         tsuids=True)
+    assert plan["groups"] == len(rows) == len(want)
+    assert [(r["tags"], r["aggregateTags"], r["tsuids"])
+            for r in rows] == [(tags, [], [tsuid])
+                               for tags, tsuid in want]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_tsuids_and_annotations_keep_the_member_order(served):
+    from opentsdb_tpu.meta.annotation import Annotation
+    sub = {"filters": [flt("wildcard", "dc", "*", True),
+                       flt("not_literal_or", "rack", "r0")]}
+    members = served.walked([build_filter(f) for f in sub["filters"]],
+                            tsuids=True)
+    for i, (_, tsuid) in enumerate(reversed(members)):
+        served.tsdb.annotations.store(Annotation(
+            start_time=BASE + 60, tsuid=tsuid, description=f"n{i}"))
+    rows = served.index_matrix_and_walk(sub)
+    for row in rows:
+        noted = [a["tsuid"] for a in row["annotations"]]
+        assert noted == row["tsuids"] and len(noted) > 1
+    # annotations alone ask for the members too
+    body, _ = served.warm(sub)
+    assert served.ways == ["index"] and body == served.bypass(sub)[0]
+    assert [[a["tsuid"] for a in r["annotations"]]
+            for r in json.loads(body)] == [r["tsuids"] for r in rows]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_explicit_tags_compose_a_second_selection(served):
+    # host, fleet and dc filtered: the rack-less series alone, picked
+    # out of the first selection; their matrix still knows its rows
+    rows = served.index_matrix_and_walk(
+        {"filters": [flt("wildcard", "host", "*"),
+                     flt("wildcard", "fleet", "*"),
+                     flt("wildcard", "dc", "*", True)]})
+    assert tag_rule(rows, "rack", "dc") == {
+        "d3": "agg", "d0": None, "d2": "agg", "d1": None}
+    sub = {"filters": [flt("wildcard", "host", "*"),
+                       flt("wildcard", "fleet", "*"),
+                       flt("wildcard", "dc", "*", True)],
+           "explicitTags": True}
+    body, (plan,) = served.warm(sub, showTSUIDs=True)
+    assert served.ways == ["index"] and plan["series"] == 6
+    assert body == served.bypass(sub, showTSUIDs=True)[0]
+    rows = json.loads(body)
+    assert sum(len(r["tsuids"]) for r in rows) == 6
+    assert all("rack" not in r["tags"] and
+               "rack" not in r["aggregateTags"] for r in rows)
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_a_small_selection_never_reads_or_builds_the_layout(served):
+    served.tsdb._tagmat_cache.clear()
+    hosts = "|".join(f"h{h:02d}" for h in (1, 3, 5, 9, 11))
+    sub = {"filters": [flt("wildcard", "dc", "*", True),
+                       flt("literal_or", "host", hosts)]}
+    for _ in range(2):
+        body, (plan,) = served.query(sub, showTSUIDs=True)
+        assert served.ways == ["matrix"] and plan["series"] == 5
+    (index,) = served.tsdb._tagmat_cache.values()
+    assert [s.layout for s in index._labels.values()] == [None]
+    assert body == served.bypass(sub, showTSUIDs=True)[0]
+    # one more host and it is no small part of 48 any more
+    sub["filters"][1]["filter"] += "|h13"
+    served.query(sub)
+    assert served.ways == ["index"]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_the_hit_path_never_gathers_the_selected_tag_rows(
+        served, monkeypatch):
+    seen = []
+    whole = QueryEngine._build_results
+
+    def watched(self, tsq, sub, metric_name, sids, tags, *rest):
+        seen.append(tags)
+        return whole(self, tsq, sub, metric_name, sids, tags, *rest)
+
+    monkeypatch.setattr(QueryEngine, "_build_results", watched)
+    filtered = {"filters": [flt("wildcard", "dc", "*", True),
+                            flt("not_literal_or", "rack", "r1")]}
+    served.query(filtered, {}, showTSUIDs=True)
+    body, plans = served.warm(filtered, {}, showTSUIDs=True)
+    assert served.ways == ["index", "index"] and len(seen) == 4
+    some, every = sorted(seen[2:], key=lambda t: t.origin[1] is None)
+    (index,) = served.tsdb._tagmat_cache.values()
+    assert some.origin[0] is index and some._vids is None
+    assert some.num_series == plans[0]["series"] < SERIES
+    # the unfiltered matrix is the index's own, not a copy
+    assert every.origin == (index, None)
+    assert every._vids is index.tags.vids
+    # what the request counted came from the index as well
+    done = json.loads(served.router.handle(HttpRequest(
+        method="GET", path="/api/stats/query", params={}, headers={},
+        body=b"")).body)["completed"][-1]["stats"]
+    pairs = sum(len(t) for t in served.walked(
+        [build_filter(f) for f in filtered["filters"]]))
+    whole_pairs = sum(len(t) for t in served.walked([]))
+    assert done["uidPairsResolved"] == pairs + whole_pairs
+    assert some.num_pairs() == pairs == int((some.vids >= 0).sum())
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_a_new_series_builds_the_layout_again(served):
+    sub = {"filters": [flt("wildcard", "fleet", "*", True),
+                       flt("not_literal_or", "rack", "r4")]}
+    rows = served.index_matrix_and_walk(sub)
+    assert tag_rule(rows, "dc", "fleet") == {"f0": "agg", "f1": None}
+    (old,) = served.tsdb._tagmat_cache.values()
+    served.tsdb.add_point("sys.plan", BASE + 30, 7, {
+        "host": "new", "dc": "d9", "fleet": "f2", "rack": "r9"})
+    rows = served.index_matrix_and_walk(sub)
+    assert {"host": "new", "dc": "d9", "fleet": "f2",
+            "rack": "r9"} in [r["tags"] for r in rows]
+    (new,) = served.tsdb._tagmat_cache.values()
+    assert new is not old and new.version == old.version + 1
+    (label_set,) = new._labels.values()
+    assert len(label_set.layout.order) == SERIES + 1
+
+
+@pytest.fixture
+def built(monkeypatch):
+    """The length of every GroupLayout made, in order."""
+    made = []
+    init = GroupLayout.__init__
+
+    def counted(self, order, *args):
+        made.append(len(order))
+        init(self, order, *args)
+
+    monkeypatch.setattr(GroupLayout, "__init__", counted)
+    return made
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_two_cold_sub_queries_build_one_layout(served, built):
+    assert served.tsdb.query_fanout_pool is not None
+    subs = [{"filters": [flt("wildcard", "dc", "*", True),
+                         flt("not_literal_or", "rack", "r1")],
+             "aggregator": agg} for agg in ("sum", "max")]
+    want, _ = served.bypass(*subs)
+    for _ in range(5):
+        del built[:]
+        body, _ = served.cold(*subs)
+        assert body == want and served.ways == ["index", "index"]
+        # the metric's layout once, by whichever index won the race
+        assert built.count(SERIES) in (1, 2)
+        indexes = list(served.tsdb._tagmat_cache.values())
+        assert len(indexes) == 1
+
+
+def test_a_layout_is_built_once_under_threads(built):
+    rng = np.random.default_rng(30)
+    index = PlanIndex(20000, TagMatrix(
+        np.array([2, 5, 9]), rng.integers(-1, 40, size=(20000, 3))))
+    barrier = threading.Barrier(4)
+    got = []
+
+    def ask():
+        barrier.wait()
+        got.append(index.layout([5, 9]))
+
+    threads = [threading.Thread(target=ask) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive()
+    assert len(got) == 4 and built == [20000]
+    assert all(layout is got[0] for layout in got)
+    assert got[0].cols[0].dtype == np.int32 == got[0].order.dtype
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_an_evicted_label_set_takes_its_layout_with_it(served):
+    keys = ["host", "dc", "rack", "fleet"]
+    sets = [c for n in range(1, 5)
+            for c in itertools.combinations(keys, n)]
+    served.tsdb._tagmat_cache.clear()
+    uids = served.tsdb.uids.tag_names
+    first = None
+    for gb in sets:
+        sub = {"filters": [flt("wildcard", k, "*", True) for k in gb]}
+        body, _ = served.query(sub)
+        assert served.ways == ["index"]
+        (index,) = served.tsdb._tagmat_cache.values()
+        key = tuple(sorted(uids.get_id(k) for k in gb))
+        first = first or (key, index._labels[key].layout)
+        assert 0 < len(index._labels) <= PlanIndex.LABEL_SETS
+        assert all(s.layout is not None
+                   for s in index._labels.values())
+    key, layout = first
+    assert key not in index._labels and layout is not None
+    # asked for again, it is made again: nothing else kept it
+    assert index.layout(key) is not layout
+    assert np.array_equal(index.layout(key).order, layout.order)
+
+
+def summary_of(tags, group_ids, num_groups, gb, k=0):
+    way, minv, maxv, members, source = group_tag_summary(
+        tags, group_ids, num_groups, gb)
+    return way, minv.tolist(), maxv.tolist(), [
+        [source.tags_of(m) for m in members(g)[:k]]
+        for g in range(num_groups)], minv.dtype
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["int32", "int64"])
+@pytest.mark.parametrize("rows", [
+    np.arange(0, 3000, 7), np.arange(3000), np.arange(1500, 3000),
+    np.array([5, 6, 2999]), np.flatnonzero(np.arange(3000) % 11 > 2)],
+    ids=["a-seventh", "all", "half", "three", "most"])
+def test_a_summary_from_the_layout_equals_one_from_the_rows(
+        rows, wide, monkeypatch):
+    # blocks of a few groups, so that a selection cuts some blocks,
+    # empties some and leaves some whole
+    monkeypatch.setattr(GroupLayout, "BLOCK", 256)
+    rng = np.random.default_rng(len(rows))
+    vids = rng.integers(-1, 12, size=(3000, 3))
+    vids[:, 2] = np.where(vids[:, 1] > 4, 7, vids[:, 2])
+    if wide:
+        vids[vids >= 0] += 1 << 40
+    tags = TagMatrix(np.array([1, 4, 6]), vids)
+    index = PlanIndex(3000, tags)
+    for gb in ([4], [1, 6], [6, 4, 1], [3], []):
+        selected = index.select(rows)
+        gids, n = QueryEngine._group_ids(selected, gb)
+        got = summary_of(selected, gids, n, gb, k=3)
+        want = summary_of(tags.select(rows), gids, n, gb, k=3)
+        small = len(rows) * engine_mod.SMALL_SELECTION < 3000
+        assert got[0] == ("matrix" if small else "index")
+        assert want[0] == "matrix"
+        assert small or selected._vids is None
+        assert got[1:] == want[1:] and got[4] == np.int64
+        layout = index.layout(gb)
+        assert layout.cols[0].dtype == (np.int64 if wide
+                                        else np.int32)
+        # a second selection composes with the first
+        keep = np.arange(len(rows)) % 3 != 1
+        twice = selected.select(keep)
+        gids, n = QueryEngine._group_ids(twice, gb)
+        got = summary_of(twice, gids, n, gb, k=3)
+        want = summary_of(tags.select(rows[keep]), gids, n, gb, k=3)
+        assert got[1:] == want[1:]
+        # agg=none: the labels are not the request's groups
+        own = np.arange(len(rows), dtype=np.int32)
+        assert summary_of(selected, own, len(rows), None)[0] == \
+            "matrix"
