@@ -933,7 +933,7 @@ class Phases:
             while_warming=self.ingest_http)
         everyone = np.ones(s.n, dtype=bool)
         names = dc_names()
-        # 1,048,576 x 12 padded cells are past the 1 << 23 host budget
+        # 1,048,576 x 12 padded cells are past the 1 << 16 host budget
         on_device = "device" if s.n >= FULL_WIDTH else None
 
         # 1. the north-star shape: sum:5m-avg:rate by dc, all series

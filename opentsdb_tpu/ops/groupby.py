@@ -41,8 +41,8 @@ def _group_sum(data, group_ids, num_groups: int,
     Lowered as a one-hot MXU contraction when S*G permits; TPU scatter
     (segment_sum) otherwise. ``prefer_segment`` (host-CPU placement)
     forces the scatter lowering: XLA:CPU grinds the one-hot dot at
-    cells*groups flops (~1 s at [114688, 32] x 1024) while its
-    segment_sum is a linear pass (~3 ms at the same shape).
+    cells*groups flops while its segment_sum is a linear pass (the
+    whole host tail: 7-15 ns a padded cell, PERF.md section 6, PR 32).
     """
     if prefer_segment:
         return _seg(jax.ops.segment_sum, data, group_ids, num_groups)

@@ -46,11 +46,10 @@ class PipelineSpec:
     rate_drop_resets: bool = False
     emit_raw: bool = False    # agg 'none': emit per-series, skip group stage
     # True when this program is placed on the host CPU backend (the
-    # host-tail path): the group stage then lowers to segment ops
-    # instead of the one-hot MXU contraction — measured 3 ms vs 1.0 s
-    # at [114688, 32] x 1024 groups on one CPU core, while on TPU the
-    # MXU contraction wins by ~300x. Static, so host and device
-    # programs compile separately.
+    # host-tail path): the group stage then lowers to segment ops, one
+    # linear pass, instead of the one-hot contraction that costs the
+    # CPU cells x groups flops and that the MXU wins by ~300x on TPU.
+    # Static, so host and device programs compile separately.
     host: bool = False
     # True when the CALLER verified every (series, bucket) cell holds
     # a real value (no pads, no NaNs — the regular-cadence dashboard

@@ -285,13 +285,20 @@ HOST_TAIL_DEFAULT_CELLS = 1 << 20
 # G-factor that a single-core host grinds through slowly.
 HOST_TAIL_DEFAULT_CELLGROUPS = 1 << 25
 # LINEAR aggregators (sum/min/max/avg/dev/count/... — everything the
-# pipeline reduces with segment ops) get a larger cells-only budget:
-# with PipelineSpec.host=True the group stage lowers to segment
-# scatter, an O(cells) pass (measured 3 ms at [114688, 32] x 1024
-# groups on one CPU core, vs 1.0 s for the one-hot contraction the
-# old cells*groups cap modeled). A 1000-group dashboard over 100k
-# series is host-served.
-HOST_TAIL_DEFAULT_CELLS_LINEAR = 1 << 23
+# pipeline reduces with segment ops) have a cells-only budget: with
+# PipelineSpec.host=True the group stage lowers to segment scatter, an
+# O(cells) pass with no group factor. The budget is the crossover of
+# the placement sweep on a TPU v5e and its host, rounded down to a
+# power of two (PERF.md section 6, PR 32: upload + program + download
+# of execute_grid, sum and max, 64 x 112 padded): at 1,024 x 64 =
+# 65,536 cells the host takes 2.3-2.5 ms against the chip's 2.6-2.7
+# (a dispatch, a transfer and a download cost that at any size), at
+# 2,048 x 64 2.8-2.9 against 2.6-2.7, at 114,688 x 64 (BASELINE
+# config 2's dashboard) 112-113 against 13-15, and 160.5 a sub-query
+# inside a server that runs two such tails side by side (ledger PR
+# 31). The panels' class (8 series x 60 buckets) takes 0.7 ms on the
+# host and 2.7 on the chip.
+HOST_TAIL_DEFAULT_CELLS_LINEAR = 1 << 16
 
 
 def _rank_class_agg(agg_name: str) -> bool:
@@ -313,12 +320,12 @@ def host_tail_device(config, padded_cells: int,
 
     For rank-class aggregators: below ``tsd.query.host_tail_max_cells``
     AND ``cells * groups`` below ``tsd.query.host_tail_max_cellgroups``.
-    For linear (segment-reducible) aggregators: below
+    For linear (segment-reducible) aggregators: at or below
     ``tsd.query.host_tail_max_cells_linear`` — no group factor, the
     host group stage is O(cells) segment scatter (see
-    HOST_TAIL_DEFAULT_CELLS_LINEAR). All dims are shape-bucket-PADDED,
-    so the decision is deterministic per compiled-shape class and
-    warmup can pre-compile the same programs.
+    HOST_TAIL_DEFAULT_CELLS_LINEAR, the measured crossover). All dims
+    are shape-bucket-PADDED, so the decision is deterministic per
+    compiled-shape class and warmup can pre-compile the same programs.
 
     The reference serves this class straight from the local JVM heap
     (ref: QueryRpc.java:128 -> TsdbQuery compute in-process). Set a key

@@ -268,9 +268,9 @@ def _run_warmup(tsdb, report: WarmupReport, t0: float) -> None:
             # device_put once (mirroring pipeline.as_operand: eager
             # jnp allocation would round-trip the default device)
             from opentsdb_tpu.query.engine import host_tail_for_dims
-            # placement is aggregator-class dependent (linear aggs get
-            # the larger segment-reduction budget) — warm each class on
-            # the device the engine would pick for it
+            # placement is aggregator-class dependent (linear aggs
+            # have a cells-only budget of their own) — warm each class
+            # on the device the engine would pick for it
             dev_lin = host_tail_for_dims(tsdb.config, s, b, g_raw,
                                          agg_name="sum")
             dev_pct = host_tail_for_dims(tsdb.config, s, b, g_raw,

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from opentsdb_tpu.query.engine import (HOST_TAIL_DEFAULT_CELLS,
+                                       HOST_TAIL_DEFAULT_CELLS_LINEAR,
                                        host_tail_device)
 from opentsdb_tpu.query.model import TSQuery
 
@@ -18,19 +19,44 @@ def _cfg(**over):
     return Config(**{k: str(v) for k, v in over.items()})
 
 
-def test_host_tail_decision_thresholds():
-    # under default threshold -> a committed cpu device
-    dev = host_tail_device(_cfg(), 64 * 1024)
-    assert dev is not None and dev.platform == "cpu"
-    # above the default threshold -> accelerator (None)
-    assert host_tail_device(_cfg(), HOST_TAIL_DEFAULT_CELLS + 1) is None
-    # custom threshold
-    cfg = _cfg(**{"tsd.query.host_tail_max_cells": 1000})
-    assert host_tail_device(cfg, 999) is not None
-    assert host_tail_device(cfg, 1001) is None
-    # -1 disables the path entirely
-    off = _cfg(**{"tsd.query.host_tail_max_cells": -1})
-    assert host_tail_device(off, 1) is None
+LINEAR, RANK = True, False
+
+
+@pytest.mark.parametrize("over, cells, linear, placed", [
+    # the rank class: under its default budget a committed cpu device,
+    # above it the accelerator (None)
+    ({}, 64 * 1024, RANK, "host"),
+    ({}, HOST_TAIL_DEFAULT_CELLS + 1, RANK, "device"),
+    # the linear class: the crossover measured on the chip (PR 32).
+    # The panels' 8 x 64 and the sweep's 1,024 x 64 on the host, the
+    # sweep's 8,192 x 64, config 2's 114,688 x 64 and the north star's
+    # 1,048,576 x 64 on the accelerator
+    ({}, 8 * 64, LINEAR, "host"),
+    ({}, 1024 * 64, LINEAR, "host"),
+    ({}, HOST_TAIL_DEFAULT_CELLS_LINEAR + 1, LINEAR, "device"),
+    ({}, 8192 * 64, LINEAR, "device"),
+    ({}, 114688 * 64, LINEAR, "device"),
+    ({}, 1048576 * 64, LINEAR, "device"),
+    # custom thresholds
+    ({"tsd.query.host_tail_max_cells": 1000}, 999, RANK, "host"),
+    ({"tsd.query.host_tail_max_cells": 1000}, 1001, RANK, "device"),
+    ({"tsd.query.host_tail_max_cells_linear": 1000}, 999, LINEAR,
+     "host"),
+    ({"tsd.query.host_tail_max_cells_linear": 1000}, 1001, LINEAR,
+     "device"),
+    # -1 disables the path entirely, a class at a time
+    ({"tsd.query.host_tail_max_cells": -1}, 1, RANK, "device"),
+    ({"tsd.query.host_tail_max_cells": -1}, 1, LINEAR, "host"),
+    ({"tsd.query.host_tail_max_cells_linear": -1}, 1, LINEAR,
+     "device"),
+    ({"tsd.query.host_tail_max_cells_linear": -1}, 1, RANK, "host"),
+])
+def test_host_tail_decision_thresholds(over, cells, linear, placed):
+    dev = host_tail_device(_cfg(**over), cells, linear_agg=linear)
+    if placed == "host":
+        assert dev is not None and dev.platform == "cpu"
+    else:
+        assert dev is None
 
 
 def _query(tsdb, m):

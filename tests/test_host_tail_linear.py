@@ -1,6 +1,7 @@
 """Host-tail placement for the wildcard group-by dashboard class.
 
-Covers: the linear-vs-rank budget split (engine.host_tail_device),
+Covers: the linear-vs-rank budget split (engine.host_tail_device;
+the linear budget is the crossover measured on the chip, PR 32),
 the segment-lowered group stage (PipelineSpec.host), the verified-
 complete-grid interpolation skip (PipelineSpec.complete), and the
 host-RAM prepared-batch cache (tsdb.host_prep_cache).
@@ -23,22 +24,78 @@ def _cfg(**kw):
     return Config(**{str(k): str(v) for k, v in kw.items()})
 
 
-class TestDecision:
-    def test_linear_gets_larger_budget(self):
-        # config-2 shape: 114688 x 32 padded cells, 1024 padded groups
-        cfg = _cfg()
-        assert host_tail_for_dims(cfg, 100_000, 30, 1000,
-                                  agg_name="sum") is not None
-        # rank class at the same shape: cells*groups blows the budget
-        assert host_tail_for_dims(cfg, 100_000, 30, 1000,
-                                  agg_name="p99") is None
+# The placement sweep's shape classes (PERF.md section 6, PR 32: host
+# and chip timed at 8 ... 114,688 padded series x 64 x 112) and where
+# the default rule places each: raw series, buckets, groups, aggregator.
+SHAPE_CLASSES = [
+    # the panels' class: 8 hosts x 60 buckets, 0.7 ms on the host
+    pytest.param(8, 60, 8, "max", "host", id="panels-max"),
+    pytest.param(8, 60, 8, "sum", "host", id="panels-sum"),
+    pytest.param(128, 60, 100, "sum", "host", id="sweep-128"),
+    # 1,024 x 64 = 65,536 padded cells: the last class the host wins
+    pytest.param(1024, 60, 100, "sum", "host", id="sweep-1024-sum"),
+    pytest.param(1024, 60, 100, "max", "host", id="sweep-1024-max"),
+    # one shape bucket on (1,280 x 64) and every class above it
+    pytest.param(1025, 60, 100, "sum", "device", id="sweep-1280"),
+    pytest.param(8192, 60, 100, "sum", "device", id="sweep-8192-sum"),
+    pytest.param(8192, 60, 100, "max", "device", id="sweep-8192-max"),
+    pytest.param(32768, 60, 100, "avg", "device", id="sweep-32768"),
+    # BASELINE config 2: 100,000 series, sum + max by dc over an hour
+    pytest.param(100_000, 60, 100, "sum", "device", id="config2-sum"),
+    pytest.param(100_000, 60, 100, "max", "device", id="config2-max"),
+    pytest.param(100_000, 30, 1000, "sum", "device",
+                 id="config2-1000-groups"),
+    # the north star: 1M series
+    pytest.param(1_000_000, 60, 100, "sum", "device",
+                 id="north-star-60"),
+    pytest.param(1_000_000, 12, 100, "sum", "device",
+                 id="north-star-12"),
+    # the rank class keeps its own budgets (cells and cells x groups)
+    pytest.param(1024, 60, 100, "p99", "host", id="rank-small"),
+    pytest.param(16384, 60, 4, "median", "host", id="rank-16384x8"),
+    pytest.param(100_000, 30, 1000, "p99", "device",
+                 id="rank-cells-x-groups"),
+    pytest.param(100_000, 60, 1, "p95", "device", id="rank-cells"),
+]
 
-    def test_linear_budget_cells_cap(self):
+
+class TestDecision:
+    @pytest.mark.parametrize("s, b, g, agg, placement", SHAPE_CLASSES)
+    def test_default_placement_by_shape_class(self, s, b, g, agg,
+                                              placement):
+        dev = host_tail_for_dims(_cfg(), s, b, g, agg_name=agg)
+        if placement == "host":
+            assert dev is not None and dev.platform == "cpu"
+        else:
+            assert dev is None
+
+    def test_linear_budget_is_the_measured_crossover(self):
+        """65,536 padded cells: a power of two, at which the host
+        still wins (<=) and past which the chip does; smaller than
+        the rank class's cells budget, which nothing has measured."""
+        from opentsdb_tpu.query.engine import (
+            HOST_TAIL_DEFAULT_CELLS, HOST_TAIL_DEFAULT_CELLS_LINEAR)
+        limit = HOST_TAIL_DEFAULT_CELLS_LINEAR
+        assert limit == 1 << 16 < HOST_TAIL_DEFAULT_CELLS
         cfg = _cfg()
-        # 1M series x 60 buckets exceeds even the linear budget: the
-        # north-star class stays on the accelerator
-        assert host_tail_for_dims(cfg, 1_000_000, 60, 100,
-                                  agg_name="sum") is None
+        assert host_tail_device(cfg, limit, 1024,
+                                linear_agg=True) is not None
+        assert host_tail_device(cfg, limit + 1, 1024,
+                                linear_agg=True) is None
+
+    def test_linear_budget_has_no_group_factor(self):
+        cfg = _cfg()
+        # 1,024 groups over 64 x 512 cells: cells x groups is at the
+        # rank class's cap and far past it one bucket on; the linear
+        # rule does not look
+        assert host_tail_for_dims(cfg, 64, 512, 1000,
+                                  agg_name="sum") is not None
+        assert host_tail_for_dims(cfg, 64, 512, 1000,
+                                  agg_name="p99") is not None
+        assert host_tail_for_dims(cfg, 64, 512, 2000,
+                                  agg_name="sum") is not None
+        assert host_tail_for_dims(cfg, 64, 512, 2000,
+                                  agg_name="p99") is None
 
     def test_disable_keys(self):
         assert host_tail_for_dims(
@@ -47,6 +104,15 @@ class TestDecision:
         assert host_tail_for_dims(
             _cfg(**{"tsd.query.host_tail_max_cells": -1}),
             100, 10, 2, agg_name="p99") is None
+
+    def test_linear_key_overrides_the_default(self):
+        # an operator's own crossover, either way from the default
+        wide = _cfg(**{"tsd.query.host_tail_max_cells_linear": 1 << 23})
+        assert host_tail_for_dims(wide, 100_000, 60, 100,
+                                  agg_name="sum") is not None
+        narrow = _cfg(**{"tsd.query.host_tail_max_cells_linear": 256})
+        assert host_tail_for_dims(narrow, 8, 60, 8,
+                                  agg_name="max") is None
 
     def test_rank_class_detection(self):
         from opentsdb_tpu.query.engine import _rank_class_agg
@@ -63,11 +129,12 @@ class TestDecision:
 
     def test_host_tail_device_linear_flag(self):
         cfg = _cfg()
-        big = 4 << 20  # over rank cells cap, under linear cap
-        assert host_tail_device(cfg, big, 1024,
-                                linear_agg=True) is not None
-        assert host_tail_device(cfg, big, 1024,
-                                linear_agg=False) is None
+        # between the two budgets: past the linear one, under the rank
+        # class's cells cap (and, at 8 groups, its cells x groups cap)
+        mid = 1 << 19
+        assert host_tail_device(cfg, mid, 8, linear_agg=True) is None
+        assert host_tail_device(cfg, mid, 8,
+                                linear_agg=False) is not None
 
 
 def _seed_groupby(n_series=3000, pts=20, groups=50, **extra):
