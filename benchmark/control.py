@@ -28,7 +28,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-import gen  # noqa: E402
+import deploy  # noqa: E402
 import reference  # noqa: E402
 import run  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
@@ -46,18 +46,19 @@ def to_bfloat16(x: np.ndarray) -> np.ndarray:
     return np.where(np.isnan(x), np.nan, out)
 
 
-def control_numbers(data: gen.Data, values: np.ndarray, limits: dict,
-                    requests) -> dict:
+def control_numbers(data, values: np.ndarray, limits: dict,
+                    requests, judge=reference) -> dict:
     """The numbers a run would compare, had the program answered as
-    the control does."""
-    true = reference.Reference(data, values, limits)
-    low = reference.Reference(data, to_bfloat16(values), limits)
+    the control does: the configuration's judge (``deploy.judge_of``)
+    over bfloat16 values against itself over the values as made."""
+    true = judge.Reference(data, values, limits)
+    low = judge.Reference(data, to_bfloat16(values), limits)
     worst = {"shape_errors": 0, "sum_rel_err": 0.0, "rank_abs_err": 0.0}
     for req in requests:
         for sub in req.doc["queries"]:
             _tagk, _names, _secs, want = true.answer(sub)
             _tagk, _names, _secs, got = low.answer(sub)
-            v = reference.compare(
+            v = judge.compare(
                 np.where(got.emitted, got.want, np.nan), 0, want)
             worst["shape_errors"] += v.shape_errors
             worst["sum_rel_err"] = max(worst["sum_rel_err"],
@@ -83,12 +84,14 @@ def main(argv: list[str]) -> int:
     config = run.load_json(os.path.join(ROOT, conf["file"]))
     spec = run.load_json(os.path.join(HERE, "traffic",
                                       cell["traffic"] + ".json"))
-    data = gen.Data(config["data"])
-    values, _points = gen.generate(data, args.seed)
+    generator = deploy.generator_of(config)
+    data = generator.Data(config["data"])
+    values, _points = generator.generate(data, args.seed, None)
     traffic = traffic_mod.Traffic(spec, data, args.seed,
                                   bench["run_seconds"])
     out = control_numbers(data, values, config["limits"],
-                          traffic.timed[:args.requests])
+                          traffic.timed[:args.requests],
+                          deploy.judge_of(config))
     out.update(workload=args.workload, seed=args.seed,
                requests=min(args.requests, len(traffic.timed)))
     print(json.dumps(out))

@@ -3,7 +3,9 @@ needs of it. Copied from ``chip_smoke.py`` (PR 21) and made to read its
 sizes from the configuration file, so that later PRs may change the
 program and its smoke test but not the yardstick.
 
-Nothing here imports JAX or ``opentsdb_tpu``.
+Nothing here imports JAX or ``opentsdb_tpu``. A configuration file may
+name another generator (``deploy.py`` has the contract); this one is
+what a file that names none gets.
 
 A deployment is ``series`` series of one metric, ``points`` points each
 at ``cadence_s`` from ``t0``, values in cents drawn from the seed. Tags
@@ -30,6 +32,8 @@ _POW10 = 10 ** np.arange(9, -1, -1, dtype=np.int64)
 
 class Data:
     """The ``data`` section of a configuration file."""
+
+    tags = ("host", "dc", "rack", "fleet")     # of every series, in order
 
     def __init__(self, spec: dict):
         self.metric = spec["metric"]
@@ -168,15 +172,18 @@ def chunk_only_values(data: Data, seed: int, chunk: int):
     return b"", np.where(drop, np.nan, cents / 100.0), int((~drop).sum())
 
 
-def generate(data: Data, seed: int, on_text=None):
+def generate(data: Data, seed: int, on_text=None, lines=None):
     """Every chunk, made by worker processes and handed over in order:
     ``on_text(bytes)`` gets the import text where one is given. Returns
     the values the reference wants ([series, points] float64, NaN where
-    a point was dropped) and the number of points."""
+    a point was dropped) and the number of points. ``lines`` is for a
+    generator that builds on this one: its own :func:`chunk_lines`, a
+    function of its module, which the workers import by name."""
     values = np.empty((data.series, data.points))
     points = 0
     workers = max(1, min(8, (os.cpu_count() or 2) - 2))
-    make = chunk_lines if on_text is not None else chunk_only_values
+    make = (lines or chunk_lines) if on_text is not None \
+        else chunk_only_values
     pending: collections.deque = collections.deque()
     nxt = 0
     with ProcessPoolExecutor(

@@ -1,7 +1,11 @@
 """The grid-tail program's share of its roofline: the least time the
 chip could take for one request's bytes (``kernels.grid_tail_bytes``
 over the HBM bandwidth of ``peaks.json``; the program is memory-bound)
-over the mean device time of one program execution in the trace."""
+over the mean device time of one program execution in the trace. The
+shape is the first sub-query's and the mean is over every program of
+the traced stretch: a live request runs two programs over the same
+grid, ``sum`` and ``max`` (2.8 and 4.6 ms, PERF.md), and the share is
+that of their mean."""
 import kernels
 import readers
 

@@ -11,7 +11,12 @@ downsample with ``fn`` in avg, max, min, sum, an optional (counter)
 rate, and filters of the types ``wildcard`` (``*``), ``literal_or`` and
 ``not_literal_or`` with at most one group-by tag. Anything else raises
 :class:`Unsupported`: traffic the reference cannot answer cannot be
-judged and must not be sent.
+judged and must not be sent. :meth:`Reference.supports` says so from
+the sub-query and the deployment's parameters alone, and ``run.py``
+asks it of every template before the server starts.
+
+A configuration file may name another judge (``deploy.py``); one that
+builds on this file subclasses :class:`Reference`.
 """
 
 from __future__ import annotations
@@ -135,7 +140,15 @@ class Cells:
         return out
 
 
+def _must_tile(d, secs: int) -> None:
+    if secs % d.cadence_s or (d.points * d.cadence_s) % secs \
+            or d.t0 % secs:
+        raise Unsupported(f"{secs}s buckets do not tile the data")
+
+
 class Reference:
+    aggregators = _AGGS     # a subclass that answers more lists them
+
     def __init__(self, data, values: np.ndarray, limits: dict):
         """``values``: [series, points] float64, NaN where dropped."""
         self.data = data
@@ -158,9 +171,7 @@ class Reference:
         if hit is not None:
             return hit
         d = self.data
-        if secs % d.cadence_s or (d.points * d.cadence_s) % secs \
-                or d.t0 % secs:
-            raise Unsupported(f"{secs}s buckets do not tile the data")
+        _must_tile(d, secs)
         k = secs // d.cadence_s
         if k == 1:
             grid = self.values
@@ -222,15 +233,19 @@ class Reference:
 
     # -- one sub-query ---------------------------------------------------
 
-    def answer(self, sub: dict):
-        """(group-by tag or '', group names, bucket seconds, Cells)."""
-        d = self.data
+    @classmethod
+    def supports(cls, sub: dict, d):
+        """The parsing half of :meth:`answer`: raises
+        :class:`Unsupported` for a sub-query this judge does not
+        answer over the deployment ``d``, from the two alone (no
+        values), and returns what it parsed."""
         if sub.get("metric") != d.metric:
             raise Unsupported(f"metric {sub.get('metric')!r}")
         agg = sub.get("aggregator")
-        if agg not in _AGGS:
+        if agg not in cls.aggregators:
             raise Unsupported(f"aggregator {agg!r}")
         secs, fn = parse_downsample(sub.get("downsample") or "")
+        _must_tile(d, secs)
         rate = bool(sub.get("rate"))
         if rate and agg != "sum":
             raise Unsupported("a rate under a rank aggregator")
@@ -248,10 +263,23 @@ class Reference:
                 exclude.append((tagk, f["filter"].split("|")))
             else:
                 raise Unsupported(f"filter {f!r}")
+            try:
+                d.tag_count(tagk)
+            except KeyError:
+                raise Unsupported(f"filter {f!r}: the deployment has "
+                                  f"no tag {tagk!r}") from None
             if f.get("groupBy"):
                 if group_tag and group_tag != tagk:
                     raise Unsupported("two group-by tags")
                 group_tag = tagk
+        return (agg, secs, fn, rate, counter_max, include, exclude,
+                group_tag)
+
+    def answer(self, sub: dict):
+        """(group-by tag or '', group names, bucket seconds, Cells)."""
+        d = self.data
+        agg, secs, fn, rate, counter_max, include, exclude, group_tag \
+            = self.supports(sub, d)
         base_key = json.dumps([agg, secs, fn, rate, counter_max,
                                include, group_tag], sort_keys=True)
         base = self._bases.get(base_key)

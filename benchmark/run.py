@@ -5,13 +5,16 @@
         --seed 7 --seconds 51 --trace 0
 
 reads ``BENCHMARK.json`` at the root of the checkout for the cell, the
-cell's configuration under ``benchmark/configs/`` and its traffic mix
-under ``benchmark/traffic/``, makes the deployment's data from the seed,
+cell's configuration under ``benchmark/configs/`` (with the generator
+and the reference it names, ``deploy.py``) and its traffic mix under
+``benchmark/traffic/``, makes the deployment's data from the seed,
 loads it into a TSD started as a child process, warms the cell's own
 requests by count, drives the window over real sockets, and only then
 compares every answer of the window with the float64 reference. The
 last line of standard output is the result object; every line before it
-is commentary. With ``--trace 1`` the run also has ``jax.profiler``
+is commentary. Each number compared stands beside its limit under the
+object's last key, ``compared``, and in the last lines of standard
+error. With ``--trace 1`` the run also has ``jax.profiler``
 record the end of the window inside the server and reports the cell's
 per-layer metrics in place of the end-to-end ones.
 
@@ -40,7 +43,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-import gen  # noqa: E402
+import deploy  # noqa: E402
 import loadgen  # noqa: E402
 import readers  # noqa: E402
 import reference  # noqa: E402
@@ -147,9 +150,10 @@ def metrics_of(bench: dict, kind: str, cell: dict) -> list[dict]:
 # the comparison that decides ``correct``
 # ---------------------------------------------------------------------
 
-def check_answers(ref: reference.Reference, data: gen.Data, results,
-                  limits: dict) -> dict:
-    """Every answer of the window against the reference. Returns the
+def check_answers(ref, data, results, limits: dict,
+                  judge=reference) -> dict:
+    """Every answer of the window against the reference ``ref``, a
+    ``Reference`` of ``judge`` (``deploy.judge_of``). Returns the
     numbers compared, each with its limit, and the count that failed."""
     n_buckets_of = {}
     worst = {"http_failures": 0, "shape_errors": 0,
@@ -176,10 +180,10 @@ def check_answers(ref: reference.Reference, data: gen.Data, results,
                         secs, data.points * data.cadence_s // secs)
                     mine = rows[at:at + len(names)]
                     at += len(names)
-                    got, stray = reference.rows_to_grid(
+                    got, stray = judge.rows_to_grid(
                         mine, tagk, names, data.t0, nb, secs,
                         data.metric)
-                    v = reference.compare(got, stray, cells)
+                    v = judge.compare(got, stray, cells)
                     worst["shape_errors"] += v.shape_errors
                     worst["sum_rel_err"] = max(worst["sum_rel_err"],
                                                v.sum_rel_err)
@@ -204,13 +208,14 @@ def check_answers(ref: reference.Reference, data: gen.Data, results,
     ]}
 
 
-def written_data(config: dict, traffic) -> gen.Data:
+def written_data(config: dict, traffic):
     """The span the window's writes fill, as a deployment of its own:
     the same series, one point a step from the end of the history."""
+    make = deploy.generator_of(config).Data
     spec = dict(config["data"], block_points=1,
                 points=traffic.written.shape[1])
-    spec["t0"] = gen.Data(config["data"]).end + 1
-    return gen.Data(spec)
+    spec["t0"] = make(config["data"]).end + 1
+    return make(spec)
 
 
 def readback_request(config: dict, traffic):
@@ -223,6 +228,15 @@ def readback_request(config: dict, traffic):
                  "groupBy": True}]}]})
 
 
+def judged_requests(config: dict, traffic) -> list:
+    """One request of every template a run will have judged: the timed
+    templates, the probe and, with writers, the read-back."""
+    one = {r.template: r for r in traffic.timed + traffic.probes}
+    if traffic.writes:
+        one["readback"] = readback_request(config, traffic)
+    return list(one.values())
+
+
 def check_writes(ctx: Context, limits: dict) -> dict:
     """Every body acknowledged, and every acknowledged point read
     back: the written span asked again after the window, against the
@@ -230,8 +244,9 @@ def check_writes(ctx: Context, limits: dict) -> dict:
     lost = sum(1 for r in ctx.write_results
                if r.error or r.status != 204)
     d = written_data(ctx.config, ctx.traffic)
-    ref = reference.Reference(d, ctx.traffic.written, limits)
-    back = check_answers(ref, d, [ctx.readback], limits)
+    judge = deploy.judge_of(ctx.config)
+    ref = judge.Reference(d, ctx.traffic.written, limits)
+    back = check_answers(ref, d, [ctx.readback], limits, judge)
     return {"failed": lost + back["failed"], "notes": back["notes"],
             "numbers": [("writes_not_acked", lost, 0)]
             + [("readback_" + n, v, lim)
@@ -257,7 +272,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         config["data"].update(shrink)
     spec = load_json(os.path.join(HERE, "traffic",
                                   cell["traffic"] + ".json"))
-    data = gen.Data(config["data"])
+    generator = deploy.generator_of(config)
+    judge = deploy.judge_of(config)
+    data = generator.Data(config["data"])
     peaks_table = load_json(os.path.join(HERE, "peaks.json"))
     tag = f"{workload}.seed{seed}.trace{int(trace)}"
     work = os.path.join(ROOT, ".bench", "work", workload)
@@ -270,6 +287,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     ctx = Context()
     ctx.workload, ctx.config, ctx.traffic = cell, config, traffic
     ctx.seconds = seconds
+    # traffic the judge cannot answer is refused here, not after the
+    # window
+    deploy.refuse_unjudged(judge, data, judged_requests(config, traffic),
+                           f"benchmark/traffic/{cell['traffic']}.json")
     say(f"{workload} seed {seed}: {data.series} series x {data.points} "
         f"points, {traffic.loop} loop, {seconds:g} s, trace "
         f"{int(trace)}")
@@ -303,8 +324,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         ctx.trace = _reduce_trace(os.path.join(out, "trace"))
     # the reference, after the window and after the server has gone
     t_ref = time.monotonic()
-    ref = reference.Reference(data, values, config["limits"])
-    verdict = check_answers(ref, data, ctx.results, config["limits"])
+    ref = judge.Reference(data, values, config["limits"])
+    verdict = check_answers(ref, data, ctx.results, config["limits"],
+                            judge)
     first = ctx.results[0].request.doc["queries"][0]
     _tagk, names, secs, _cells = ref.answer(first)
     ctx.first_shape = (ref.selected(first),
@@ -348,6 +370,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         dev["busy_s"] = ctx.trace["busy_s"]
         dev["window_s"] = ctx.trace_window_s
         doc["breakdown"] = _breakdown(ctx)
+    # each number compared beside its limit, last in the line
+    doc["compared"] = {name: {"value": value, "limit": limit}
+                       for name, value, limit in verdict["numbers"]}
     if device["platform"] != "tpu" and require_tpu:
         # the work was done, but nothing ran on an accelerator: no
         # result line
@@ -440,9 +465,11 @@ async def _window(tsd, ctx: Context, traffic, seconds: float,
         ctx.trace_queries = sum(
             1 for r in results if r.done >= marks["start"])
         if traffic.probes:
-            # host-placed traffic runs no device program: one request
-            # that does, after the last timed one and inside the
-            # trace, so that the trace holds the device path
+            # traffic whose tail the program places on the host (the
+            # panels; the live cells' tails run on the chip since
+            # PR 32) runs no device program: one request that does,
+            # after the last timed one and inside the trace, so that
+            # the trace holds the device path
             probe = await loadgen.send_all(port, traffic.probes[2:],
                                            traffic.timeout_s)
             if probe[0].error or probe[0].status != 200:
@@ -574,7 +601,10 @@ def main(argv: list[str]) -> int:
         print(f"benchmark/run.py: {e}", file=sys.stderr)
         return 1
     if code == 0:
-        print(json.dumps(doc))
+        print(json.dumps(doc), flush=True)
+        for name, c in doc["compared"].items():
+            print(f"compared {name} = {c['value']:.6g} (limit "
+                  f"{c['limit']:g})", file=sys.stderr)
     return code
 
 

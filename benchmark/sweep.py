@@ -28,7 +28,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-import gen  # noqa: E402
+import deploy  # noqa: E402
 import loadgen  # noqa: E402
 import readers  # noqa: E402
 import run  # noqa: E402
@@ -76,7 +76,7 @@ def main(argv: list[str]) -> int:
         print("sweep.py: the cell's loop is closed: it has no rate",
               file=sys.stderr)
         return 2
-    data = gen.Data(config["data"])
+    data = deploy.generator_of(config).Data(config["data"])
     work = os.path.join(ROOT, ".bench", "work", args.workload)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)

@@ -101,3 +101,53 @@ def test_requests_per_template_do_not_depend_on_the_seed(bench, cell):
     assert bodies[0] != bodies[1]
     again = traffic.Traffic(spec, data, SEEDS[0], bench["run_seconds"])
     assert [r.body for r in again.timed] == bodies[0]
+
+
+def _mix(name="wide-groupby", **top):
+    return dict(load(f"benchmark/traffic/{name}.json"), **top)
+
+
+def test_the_closed_list_is_a_key_of_the_traffic_file():
+    data = gen.Data(tiny_config("fleet-1m")["data"])
+    # the default stays 2,000, warm-up included
+    t = traffic.Traffic(_mix(), data, 1, 51)
+    assert len(t.warmup) == 3 and len(t.timed) == 2000 - 3
+    t = traffic.Traffic(_mix(closed_list=300), data, 1, 51)
+    assert len(t.warmup) == 3 and len(t.timed) == 300 - 3
+    # the panels ship 16,000: 3.2 ms a request over 51 s
+    spec = _mix("small-panels")
+    assert spec["closed_list"] == 16000
+    t = traffic.Traffic(spec, data, 1, 51)
+    assert len(t.warmup) + len(t.timed) == 16000
+    assert 51 / len(t.timed) < 0.0032
+    # an open loop's count is its rate's, whatever the key says
+    t = traffic.Traffic(_mix(loop="open", clients=32, rate_per_s=10,
+                             closed_list=300), data, 1, 51)
+    assert len(t.timed) == 510
+
+
+def test_a_draw_repeats_only_where_its_file_says_so():
+    data = gen.Data(tiny_config("fleet-1m")["data"])
+    # 2,000 racks and a list of 5,000: refused, a repeated request
+    # would be answered from the result cache
+    with pytest.raises(ValueError, match="a repeated request"):
+        traffic.Traffic(_mix(closed_list=5000), data, 1, 51)
+    spec = _mix(closed_list=5000)
+    spec["requests"][0]["draw"]["rack"].update(range=[0, 20],
+                                               repeat=True)
+    seen = set()
+    for seed in (1, 2):
+        t = traffic.Traffic(spec, data, seed, 51)
+        assert len(t.timed) == 5000 - 3
+        bodies = [r.body for r in t.timed]
+        # 20 panels asked again and again, each about as often
+        count = collections.Counter(bodies)
+        assert len(count) == 20 and min(count.values()) > 150
+        seen.add(tuple(bodies[:50]))
+    assert len(seen) == 2
+    # said of a range that is wide enough, it still draws with
+    # replacement: some rack comes twice in 1,500 of 2,000
+    spec = _mix(closed_list=1500)
+    spec["requests"][0]["draw"]["rack"]["repeat"] = True
+    t = traffic.Traffic(spec, data, 1, 51)
+    assert len(set(r.body for r in t.timed)) < len(t.timed)

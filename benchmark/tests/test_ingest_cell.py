@@ -16,8 +16,21 @@ import traffic
 
 CELL = "live-100k.groupby-ingest"
 CONFIG = "live-100k-ingest"
-# what only a device trace gives is left out on the CPU
-DEVICE_ONLY = {"device.idle_share"}
+# what only a device trace or a device-placed tail gives is left out
+# on the CPU
+DEVICE_ONLY = {"device.idle_share", "grid_tail_roofline",
+               "devicecache.hit_share", "device.resident_mb",
+               "program.busy_ms_per_query"}
+# the query stages and the device programs the cell shares with its
+# quiet pair: PR 31 could not touch their lists, PR 33 did
+SHARED = {"plan.ms", "scan.ms", "execute.ms", "execute.self_ms",
+          "grid_build.ms", "upload.ms", "program.wait_ms", "download.ms",
+          "assemble.ms", "serialize.ms", "tail.query_p90_ms",
+          "placement.on_device_share", "device.unoccupied_share",
+          "idle.unnamed_share", "idle.no_request_share",
+          "program.compiles", "gc.pause_share",
+          "program.busy_ms_per_query", "grid_tail_roofline",
+          "devicecache.hit_share", "device.resident_mb"}
 NEW = {"put.ack_p50_ms", "put.ack_p95_ms", "put.late_ms",
        "ingest.decode_ms", "ingest.scatter_ms", "wal.commit_wait_ms",
        "ingest.points_per_s", "wal.bodies_per_fsync",
@@ -45,7 +58,7 @@ def test_cell_runs_to_its_last_line_and_then_wants_a_tpu(
     want = {m["name"] for m in run.metrics_of(bench, kind, cell)}
     got = set(doc["metrics"])
     if trace:
-        assert NEW <= want
+        assert NEW | SHARED <= want
         assert want - DEVICE_ONLY <= got <= want
         v = {k: m["value"] for k, m in doc["metrics"].items()}
         assert v["window.compiles"] == 0
@@ -122,9 +135,14 @@ def test_new_metrics_list_the_cell_alone(bench):
     assert {m["moves"] for m in mine.values()} == {"query_p50_ms"}
     assert {m["layer"] for m in mine.values()} == {
         "ingest front end", "store write", "WAL", "load generator"}
-    # no other metric's list was touched
-    assert not any(CELL in m.get("workloads", ())
-                   for m in bench["per_layer"] if m["name"] not in NEW)
+    # beside them the cell is listed where its quiet pair is (but for
+    # two start-up readers ISSUE 33 did not name), and nowhere else
+    quiet = {m["name"] for m in bench["per_layer"]
+             if "live-100k.groupby-quiet" in m.get("workloads", ())}
+    assert quiet - {"startup.backend_s", "startup.import_resolve_s"} \
+        == SHARED
+    assert {m["name"] for m in bench["per_layer"]
+            if CELL in m.get("workloads", ())} == NEW | SHARED
 
 
 @pytest.mark.parametrize("seconds", [3, 51])

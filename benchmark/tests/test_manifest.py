@@ -7,6 +7,8 @@ import re
 import pytest
 from conftest import BENCH, ROOT, load
 
+import deploy
+
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter",
@@ -49,6 +51,12 @@ def test_configs(bench):
         # the environment and the flags of the child, listed in full
         assert doc["server"]["env"]["PYTHONHASHSEED"] == "0"
         assert doc["guarantees"] and doc["assumed"]
+        # the generator and the reference a deployment brings
+        # (deploy.py): plain files under benchmark/
+        assert all(os.path.isfile(os.path.join(ROOT, doc[key]))
+                   for key in ("generator", "reference") if key in doc)
+        deploy.generator_of(doc)
+        deploy.judge_of(doc)
 
 
 def test_workloads(bench):
@@ -122,9 +130,13 @@ def test_files_under_paths_have_plain_names():
 
 
 @pytest.mark.parametrize("traffic", ["wide-groupby", "groupby-quiet",
-                                     "small-panels"])
+                                     "small-panels", "groupby-ingest"])
 def test_traffic_files(traffic):
     spec = load(f"benchmark/traffic/{traffic}.json")
+    # a closed list that a window of 51 s cannot use up above 3.2 ms a
+    # request where the cell aims under 25.5 ms (the panels, S1)
+    assert spec.get("closed_list", 2000) \
+        == (16000 if traffic == "small-panels" else 2000)
     assert spec["loop"] in ("closed", "open")
     assert spec["warmup_per_template"] >= 3
     assert spec["timeout_s"] == 30

@@ -14,9 +14,17 @@ the deployment's span and metric, and ``$<name>`` for a draw. A draw
 names a ``tag``, a ``range`` of that tag's values (``[lo, hi)`` or
 ``"all"``) and how many to ``pick``: one value at a time from a
 seeded permutation of the range, or several distinct ones joined with
-``|``. ``trace_probe`` is one more template, of which a traced run
-sends three drawn requests: two to warm it and one at the end of the
-traced stretch (see ``run.py``).
+``|``. A draw of one value that says ``"repeat": true`` is drawn with
+replacement instead: a few panels asked again and again, which the
+result cache may answer; one that does not say so is refused where the
+list needs more values than the range has. ``trace_probe`` is one more
+template, of which a traced run sends three drawn requests: two to
+warm it and one at the end of the traced stretch (see ``run.py``).
+
+A closed loop sends as many requests as the server answers, so its
+list has to outlast any window: ``closed_list`` is its length, warm-up
+included, 2,000 where a file does not say (25.5 ms a request over
+51 s). ``loadgen.py`` raises when a window uses the list up.
 
 ``writes``, where a file has it, is a schedule of ``/api/put`` bodies
 beside the queries, open loop whatever the queries' loop is:
@@ -63,7 +71,9 @@ def _draws(spec: dict, data, n: int, rng) -> list[str]:
     lo, hi = (0, data.tag_count(tag)) if spec["range"] == "all" \
         else spec["range"]
     pick = int(spec["pick"])
-    if pick == 1:
+    if pick == 1 and spec.get("repeat"):
+        ids = rng.integers(lo, hi, size=(n, 1))
+    elif pick == 1:
         if n > hi - lo:
             raise ValueError(
                 f"traffic needs {n} distinct {tag} values and the file "
@@ -115,7 +125,8 @@ class Traffic:
         else:
             # a closed loop sends as many as the server answers; the
             # list (warm-up included) is longer than any window can use
-            total = closed_max - n_warm * len(templates)
+            total = int(spec.get("closed_list", closed_max)) \
+                - n_warm * len(templates)
         counts = np.floor(shares * total).astype(int)
         counts[0] += total - counts.sum()
         self.warmup: list[Request] = []
@@ -173,13 +184,10 @@ class Traffic:
     @staticmethod
     def _put(w, data, metric, ids, step, cents, due_s) -> Request:
         ts = data.end + 1 + step * data.cadence_s
+        names = [[data.tag_name(k, int(v)) for v in data.tag_ids(k, ids)]
+                 for k in data.tags]
         doc = [{"metric": metric, "timestamp": ts,
                 "value": int(c) / 100.0,
-                "tags": {"host": data.tag_name("host", int(i)),
-                         "dc": data.tag_name("dc", int(i) % data.dcs),
-                         "rack": data.tag_name("rack",
-                                               int(i) % data.racks),
-                         "fleet": data.tag_name(
-                             "fleet", (int(i) // 100) % data.fleets)}}
-               for i, c in zip(ids, cents)]
+                "tags": dict(zip(data.tags, row))}
+               for c, row in zip(cents, zip(*names))]
         return Request(w["name"], "POST", w["path"], doc, due_s)

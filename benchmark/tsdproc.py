@@ -19,11 +19,8 @@ import urllib.request
 
 import numpy as np
 
-import gen
-
-
-class Failed(Exception):
-    """The run cannot give a result; the message says why."""
+import deploy
+from deploy import Failed
 
 
 def free_port() -> int:
@@ -75,15 +72,16 @@ class Tsd:
         with open(self.log, "r", errors="replace") as fh:
             return fh.read()[-n:]
 
-    def load(self, data: gen.Data, seed: int) -> tuple[np.ndarray, int]:
+    def load(self, data, seed: int) -> tuple[np.ndarray, int]:
         """Seeded series -> ``tsdb import`` text -> the server's
-        standard input. Generator workers run beside the server; the
-        text never touches the disk. Returns the values the reference
-        wants ([series, points], NaN where dropped) and the number of
-        points written."""
+        standard input, by the configuration's generator. Generator
+        workers run beside the server; the text never touches the
+        disk. Returns the values the reference wants ([series, points],
+        NaN where dropped) and the number of points written."""
+        generator = deploy.generator_of(self.config)
         try:
-            values, points = gen.generate(data, seed,
-                                          self.proc.stdin.write)
+            values, points = generator.generate(data, seed,
+                                                self.proc.stdin.write)
             self.proc.stdin.close()
         except BrokenPipeError:
             raise Failed("the server closed its standard input "
