@@ -701,10 +701,11 @@ class Tracer:
         # tsdlint: allow[unbounded-growth] keyed by span name: the
         # closed KNOWN_SPANS registry
         self.idle_stage_ms: dict[str, float] = {}
-        # programs dispatched, by (path, placement)
+        # programs dispatched, by (path, placement, class)
         # tsdlint: allow[unbounded-growth] keyed by run_staged's
-        # tags: the six paths its callers name x two placements
-        self.tails: dict[tuple[str, str], int] = {}
+        # tags: the six paths its callers name x two placements x
+        # two classes of group stage (rank | linear)
+        self.tails: dict[tuple[str, str, str], int] = {}
         # grids built, by who wrote the padded grid: "fused" (the
         # store's own pass) or "host" (fill_padded_grid)
         self.grid_builds = {"fused": 0, "host": 0}
@@ -962,7 +963,8 @@ class Tracer:
                 - min(max(occupied, 0.0), self_ms)
             if s.name == "query.program":
                 tails.append((str(s.tags.get("path", "?")),
-                              str(s.tags.get("placement", "?"))))
+                              str(s.tags.get("placement", "?")),
+                              str(s.tags.get("class", "?"))))
             elif s.name == "query.grid_build" and "fused" in s.tags:
                 builds.append("fused" if s.tags["fused"] else "host")
             elif s.name == "query.plan" and s.tags.get("index") \
@@ -1073,9 +1075,9 @@ class Tracer:
             assembles = sorted(self.assembles.items())
         for stage, ms in idle:
             collector.record("device.idle_stage_ms", ms, stage=stage)
-        for (path, placement), n in tails:
+        for (path, placement, cls), n in tails:
             collector.record("query.tail", n, path=path,
-                             placement=placement)
+                             placement=placement, **{"class": cls})
         for mode, n in builds:
             collector.record("query.grid_build", n, mode=mode)
         for state, n in plans:

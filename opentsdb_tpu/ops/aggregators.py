@@ -202,6 +202,14 @@ class Aggregator:
     def is_none(self) -> bool:
         return self.name == "none"
 
+    @property
+    def rank_class(self) -> bool:
+        """median / exact & estimated percentiles: the group stage is
+        one sort of the grid along the series axis, not a segment
+        reduction. Placement budgets, the prep cache's key and the
+        tracing's ``class`` (``rank`` | ``linear``) all read this."""
+        return self.name == "median" or self.is_percentile
+
 
 def _make_percentile(name: str, q: float, estimation: str) -> Aggregator:
     def reduce(x, axis=0, _q=q, _e=estimation):

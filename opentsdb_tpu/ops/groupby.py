@@ -171,7 +171,9 @@ def _group_reduce(filled, group_ids, num_groups: int, agg_name: str,
             q, est = agg.percentile, agg.estimation
         else:
             raise ValueError(f"unsupported group aggregator {agg_name}")
-        out = _group_rank(filled, valid, cnt, group_ids, num_groups, q, est)
+        with jax.named_scope("tail.group_rank"):
+            out = _group_rank(filled, valid, cnt, group_ids, num_groups,
+                              q, est)
     return jnp.where(any_valid, out, jnp.nan)
 
 

@@ -58,6 +58,15 @@ class PipelineSpec:
     # is ~190 ms of a [114688, 30] host-tail query on one core).
     complete: bool = False
 
+    @property
+    def tail_class(self) -> str:
+        """Which class of group stage the program runs: ``rank`` (one
+        sort of the grid: median, percentiles) or ``linear`` (a
+        contraction or a segment reduction; ``none`` has no group
+        stage and counts here)."""
+        return "rank" if aggs_mod.get(self.agg_name).rank_class \
+            else "linear"
+
     def __post_init__(self):
         # CPython >= 3.10 hashes each NaN object by identity, so a spec
         # built with a fresh float("nan") never compares/hashes equal to
@@ -268,7 +277,8 @@ def run_staged(path: str, program, operands,
     nothing waits for a transfer). ``query.program`` is the call up to
     ``block_until_ready`` on its outputs, tagged with ``path``,
     ``placement`` (``host`` for a tail pinned to the CPU backend,
-    ``spec.host``), the padded ``shape`` SxBxG and ``compiled`` when
+    ``spec.host``), ``class`` (``spec.tail_class``: ``rank`` |
+    ``linear``), the padded ``shape`` SxBxG and ``compiled`` when
     JAX compiled (or loaded from its cache) inside it; a
     device-placed program occupies :data:`RUNTIME`'s clock for that
     stretch. ``query.download`` is ``download`` (``np.asarray``) of
@@ -283,7 +293,8 @@ def run_staged(path: str, program, operands,
             "query.program", path=path,
             placement="device" if on_device else "host",
             shape=f"{spec.num_series}x{spec.num_buckets}"
-                  f"x{spec.num_groups}") as span:
+                  f"x{spec.num_groups}",
+            **{"class": spec.tail_class}) as span:
         compiles = RUNTIME.compiles
         if on_device:
             RUNTIME.clock.enter()

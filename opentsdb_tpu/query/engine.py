@@ -302,14 +302,13 @@ HOST_TAIL_DEFAULT_CELLS_LINEAR = 1 << 16
 
 
 def _rank_class_agg(agg_name: str) -> bool:
-    """median / exact & estimated percentiles: the group stage is a
-    sort with a G-broadcast, not a segment reduction."""
-    if agg_name == "median":
-        return True
+    """:attr:`Aggregator.rank_class` by name, for callers that hold no
+    sub-query (the warm-up); an unknown name gets the conservative
+    rank budgets."""
+    from opentsdb_tpu.ops import aggregators as _aggs
     try:
-        from opentsdb_tpu.ops import aggregators as _aggs
-        return bool(_aggs.get(agg_name).is_percentile)
-    except Exception:  # noqa: BLE001 - unknown agg: be conservative
+        return _aggs.get(agg_name).rank_class
+    except KeyError:  # unknown agg: be conservative
         return True
 
 
@@ -353,19 +352,24 @@ def host_tail_device(config, padded_cells: int,
 
 def host_tail_for_dims(config, s: int, b: int, num_groups: int,
                        emit_raw: bool = False,
-                       agg_name: str = "p99"):
+                       agg_name: str = "p99",
+                       rank_class: bool | None = None):
     """:func:`host_tail_device` from RAW query dims — the ONE place the
     decision inputs are shape-bucketed, shared by the engine paths and
     tsd.warmup so a warmed placement cannot drift from the engine's
     (ADVICE r04). emit_raw has no group contraction: group factor 1.
-    ``agg_name`` picks the linear vs rank-class budget; the default is
-    a rank-class name so legacy callers keep the conservative rule."""
+    ``rank_class`` (the sub-query's ``agg.rank_class``) picks the
+    linear vs rank-class budget; without it ``agg_name`` does, and the
+    default is a rank-class name so legacy callers keep the
+    conservative rule."""
     from opentsdb_tpu.ops import shapes as _shapes
+    if rank_class is None:
+        rank_class = _rank_class_agg(agg_name)
     return host_tail_device(
         config,
         _shapes.shape_bucket(s) * _shapes.shape_bucket(b),
         1 if emit_raw else _shapes.shape_bucket(num_groups + 1),
-        linear_agg=not _rank_class_agg(agg_name))
+        linear_agg=not rank_class)
 
 
 def compact_row_labels(mat: np.ndarray) -> tuple[np.ndarray, int]:
@@ -747,7 +751,7 @@ class QueryEngine:
         return host_cpu_device()
 
     def _tail_device(self, s: int, b: int, num_groups: int,
-                     emit_raw: bool, agg_name: str):
+                     emit_raw: bool, rank_class: bool):
         """:func:`host_tail_for_dims` + the degraded override: an OPEN
         breaker pins the tail to the host CPU backend (the
         always-available in-process compute path — the analogue of the
@@ -761,7 +765,7 @@ class QueryEngine:
                     "(tsd.query.degraded.host_fallback)")
             return self._host_cpu()
         return host_tail_for_dims(self.tsdb.config, s, b, num_groups,
-                                  emit_raw, agg_name)
+                                  emit_raw, rank_class=rank_class)
 
     def _run_device(self, compute, host_retry=None,
                     on_device: bool = True):
@@ -1177,7 +1181,7 @@ class QueryEngine:
                 # of the key — two group-by cardinalities of the same
                 # series set must not share a placement (mirrors the
                 # mesh ('pct', num_groups) key above)
-                if not _rank_class_agg(sub.agg.name):
+                if not sub.agg.rank_class:
                     acls = "lin"
                 else:
                     from opentsdb_tpu.ops import shapes as _shapes
@@ -1348,7 +1352,7 @@ class QueryEngine:
         if mesh is None and not use_blocked:
             host_dev = self._tail_device(
                 len(sids), len(bucket_ts), num_groups, emit_raw,
-                sub.agg.name)
+                sub.agg.rank_class)
         spec = PipelineSpec(
             num_series=len(sids), num_buckets=len(bucket_ts),
             num_groups=num_groups, ds_function=ds_function,
@@ -1743,7 +1747,7 @@ class QueryEngine:
         host_dev = None
         if mesh is None:
             host_dev = self._tail_device(len(sids), b, num_groups,
-                                         emit_raw, sub.agg.name)
+                                         emit_raw, sub.agg.rank_class)
         # device-resident cache: a warm repeat of this reduction skips
         # the host scan AND the upload (HBM ≙ HBase block cache).
         # Under a mesh the cached value is the pre-SHARDED device args
@@ -1921,7 +1925,8 @@ class QueryEngine:
             mesh = self.tsdb.query_mesh
             if mesh is None:
                 host_dev = self._tail_device(s, b, num_groups,
-                                             emit_raw, sub.agg.name)
+                                             emit_raw,
+                                             sub.agg.rank_class)
             # host-tail queries skip the device cache (see
             # _grid_pipeline: cheap native re-scan; host RAM must not
             # evict HBM-resident grids)

@@ -9,7 +9,8 @@ the device kernels, so a differential test against the engine can catch
 bugs in the shared XLA pipeline that path-vs-path comparisons cannot.
 
 Scope: fixed-interval downsampling, NONE/ZERO/NAN/SCALAR fills, rate
-(plain + counter), the non-percentile aggregators, group-by merge.
+(plain + counter), the scalar aggregators, the median and the six
+legacy percentiles (p50 ... p999), group-by merge.
 """
 
 from __future__ import annotations
@@ -27,7 +28,30 @@ INTERP = {
     "mimmin": "max", "mimmax": "min",
     "pfsum": "prev",
     "diff": "lerp", "first": "zim", "last": "zim",
+    "median": "lerp",
 }
+
+# the percentile family (ref: Aggregators.java PercentileAgg over
+# commons-math's Percentile, EstimationType.LEGACY; interpolation
+# LERP): registry name -> p
+PERCENTILES = {"p50": 0.5, "p75": 0.75, "p90": 0.9, "p95": 0.95,
+               "p99": 0.99, "p999": 0.999}
+INTERP.update(dict.fromkeys(PERCENTILES, "lerp"))
+
+
+def legacy_percentile(xs, p):
+    """The p-th percentile of the sorted ``xs`` at the one-based
+    position h = p (n + 1): the minimum below 1, the maximum at or
+    above n, else the straight line between the two neighbours (ref:
+    commons-math Percentile.evaluate, LEGACY)."""
+    n = len(xs)
+    h = p * (n + 1)
+    k = math.floor(h)
+    if h < 1:
+        return xs[0]
+    if h >= n:
+        return xs[-1]
+    return xs[k - 1] + (h - k) * (xs[k] - xs[k - 1])
 
 
 def downsample_series(ts_ms, vals, interval_ms, function, start_ms,
@@ -161,6 +185,11 @@ def aggregate_group(series_points, agg, interpolate=True):
             out[t] = xs[-1]
         elif agg == "diff":
             out[t] = 0.0 if len(xs) == 1 else xs[-1] - xs[0]
+        elif agg == "median":
+            # the upper median (ref: Aggregators.Median.runDouble)
+            out[t] = sorted(xs)[len(xs) // 2]
+        elif agg in PERCENTILES:
+            out[t] = legacy_percentile(sorted(xs), PERCENTILES[agg])
         else:
             raise ValueError(agg)
         if out.get(t) is None:

@@ -220,7 +220,7 @@ class TestSelfTimeAndOccupancy:
         assert tracer.idle_stage_ms == pytest.approx({
             "query.http": 0.0, "query.execute": 40.0,
             "query.plan": 20.0, "query.program": 0.0})
-        assert tracer.tails == {("grid", "device"): 1}
+        assert tracer.tails == {("grid", "device", "?"): 1}
         spans = by_name(tracer.get(ctx.trace_id))
         assert spans["query.program"].occupied_ms == pytest.approx(40.0)
         assert spans["query.execute"].occupied_ms == pytest.approx(40.0)
@@ -257,7 +257,9 @@ class TestSelfTimeAndOccupancy:
         assert prog.tags["path"] == "grid"
         assert prog.tags["placement"] == ("host" if host else "device")
         assert (prog.occupied_ms > 0) == (not host)
-        assert tracer.tails == {("grid", prog.tags["placement"]): 1}
+        assert prog.tags["class"] == "linear"
+        assert tracer.tails == {
+            ("grid", prog.tags["placement"], "linear"): 1}
 
 
 def _records(tracer):
