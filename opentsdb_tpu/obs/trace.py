@@ -713,6 +713,11 @@ class Tracer:
         # "hit" (planned from the cached index), "built" (built it
         # first), "bypass" (a selection that is not a whole metric)
         self.plans = {"hit": 0, "built": 0, "bypass": 0}
+        # filters evaluated, by how each became a series mask: "ids"
+        # (the UIDs of the exact names it holds), "walk" (its
+        # predicate over the name of every distinct value of its
+        # key), "presence" (the key's column alone: *, .*, not_key)
+        self.filters = {"ids": 0, "walk": 0, "presence": 0}
         # assemble stages, by where the groups' common and aggregated
         # tags were read: "index" (the plan index's cached layout) or
         # "matrix" (a sort of the request's own rows)
@@ -933,7 +938,8 @@ class Tracer:
         ``query.program`` counts in ``tails``, every ``query.grid_build``
         that built a grid (tag ``fused``) in ``grid_builds``, every
         ``query.plan`` that reached its filters (tag ``index``) in
-        ``plans``, every ``query.assemble`` by its tag ``tags`` in
+        ``plans`` and its filters (tags ``resolve_<way>``) in
+        ``filters``, every ``query.assemble`` by its tag ``tags`` in
         ``assembles``."""
         kids: dict[str, list[SpanRecord]] = {}
         for s in spans:
@@ -942,6 +948,7 @@ class Tracer:
         tails = []
         builds = []
         plans = []
+        filters = []
         assembles = []
         for s in [root] + spans:
             self_ms, occupied = s.duration_ms, s.occupied_ms
@@ -970,6 +977,9 @@ class Tracer:
             elif s.name == "query.plan" and s.tags.get("index") \
                     in self.plans:
                 plans.append(s.tags["index"])
+                filters += [(way, s.tags["resolve_" + way])
+                            for way in self.filters
+                            if "resolve_" + way in s.tags]
             elif s.name == "query.assemble" and s.tags.get("tags") \
                     in self.assembles:
                 assembles.append(s.tags["tags"])
@@ -983,6 +993,8 @@ class Tracer:
                 self.grid_builds[mode] += 1
             for state in plans:
                 self.plans[state] += 1
+            for way, n in filters:
+                self.filters[way] += n
             for way in assembles:
                 self.assembles[way] += 1
 
@@ -1072,6 +1084,7 @@ class Tracer:
             tails = sorted(self.tails.items())
             builds = sorted(self.grid_builds.items())
             plans = sorted(self.plans.items())
+            filters = sorted(self.filters.items())
             assembles = sorted(self.assembles.items())
         for stage, ms in idle:
             collector.record("device.idle_stage_ms", ms, stage=stage)
@@ -1082,6 +1095,8 @@ class Tracer:
             collector.record("query.grid_build", n, mode=mode)
         for state, n in plans:
             collector.record("query.plan", n, index=state)
+        for way, n in filters:
+            collector.record("query.filter", n, resolve=way)
         for way, n in assembles:
             collector.record("query.assemble", n, tags=way)
 

@@ -23,7 +23,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from dataclasses import replace
 from typing import Any, Sequence
 
@@ -518,7 +518,8 @@ class PlanIndex:
     it: the engine keeps one per (store, metric) in
     ``tsdb._tagmat_cache`` and drops it whole when ``version`` no
     longer equals the index's length. UID names are not kept here: a
-    filter's string predicate reads the live dictionary per request.
+    filter reads the live dictionary per request (its own names' ids,
+    or, a pattern, the name of every id of ``distinct``).
 
     The lazy parts build under one lock (two sub-queries of a request
     plan side by side: the second waits and reads what the first
@@ -1072,10 +1073,9 @@ class QueryEngine:
             stats.add_stat(QueryStat.ROWS_PRE_FILTER, len(sids))
 
         # --- filters -> series mask (ref: findSpans post-scan filters)
-        sids, tag_mat, index_state = self._apply_filters(store, sub,
-                                                         sids)
+        sids, tag_mat, plan_tags = self._apply_filters(store, sub, sids)
         if _h_plan is not None:
-            _h_plan.tag(index=index_state)
+            _h_plan.tag(**plan_tags)
         if len(sids) == 0:
             trace_end(_h_plan)
             return []
@@ -2123,12 +2123,15 @@ class QueryEngine:
 
     def _apply_filters(self, store: TimeSeriesStore, sub: TSSubQuery,
                        sids: np.ndarray
-                       ) -> tuple[np.ndarray, TagMatrix, str]:
-        """The sub-query's series and their tags, and what the plan
-        index did for it: ``hit`` (planned from the cached
-        :class:`PlanIndex`), ``built`` (built it first) or ``bypass``
-        (``sids`` is not the metric's whole index: tsuids, a write
-        between the selection and here)."""
+                       ) -> tuple[np.ndarray, TagMatrix, dict]:
+        """The sub-query's series and their tags, and the ``query.plan``
+        span's tags for it. ``index``: what the plan index did,
+        ``hit`` (planned from the cached :class:`PlanIndex`), ``built``
+        (built it first) or ``bypass`` (``sids`` is not the metric's
+        whole index: tsuids, a write between the selection and here).
+        ``names_read`` and ``resolve_<way>``: the names of stored tag
+        values its filters read and how many of them went each way
+        (:meth:`FilterEvaluator.apply`'s tally)."""
         metric_id = store.series(int(sids[0])).metric_id
         idx = store.metric_index(metric_id)
         index = None
@@ -2162,10 +2165,11 @@ class QueryEngine:
             triples = (np.asarray(rows, dtype=np.int64).reshape(-1, 3)
                        if rows else np.empty((0, 3), dtype=np.int64))
             tags = TagMatrix.from_triples(sids, triples)
+        plan_tags = Counter(names_read=0)
         if sub.filters:
             source = index if index is not None else tags
             rows = np.flatnonzero(
-                self._filter_eval.apply(sub.filters, source))
+                self._filter_eval.apply(sub.filters, source, plan_tags))
             sids = sids[rows]
             tags = source.select(rows)
         if sub.explicit_tags and sub.filters:
@@ -2188,7 +2192,7 @@ class QueryEngine:
                     .all(axis=1)
             sids = sids[keep]
             tags = tags.select(keep)
-        return sids, tags, state
+        return sids, tags, {"index": state, **plan_tags}
 
     @staticmethod
     def _group_ids(tags: TagMatrix, gb_kids: list[int]

@@ -9,28 +9,39 @@ not_iliteral_or, wildcard, iwildcard, regexp, not_key — with the same
 
 Evaluation is vectorized: instead of the reference's per-row
 ``match(tags)`` callbacks post-scan (SaltScanner.java:660-692), a filter
-resolves the set of matching tagv UIDs once (string predicates run over
-the distinct tag values of the metric, typically tiny compared to the
-series count) and then the series mask is a numpy ``isin`` over the
-metric's tag column.
+resolves the set of matching tagv UIDs once and then the series mask is
+one pass over the metric's tag column.
 
 What is kept between requests, and by whom: the engine caches one
 ``PlanIndex`` per (store, metric) — the series x tag-key matrix of tagv
 ids, each column's distinct ids, the group labels of recent group-by
 key sets — versioned by the metric's series count (the tag index only
-appends; a new series drops the whole entry). What is still walked per
-request: ``matching_tagv_ids`` reads the NAME of every distinct value of
-a filtered key from the live UID dictionary and runs the filter's
-predicate on it (2,000 rack names or 1,000,000 host names alike), so a
-renamed value shows in the next request. Filters that match every value
-(``*``, ``.*``) and ``not_key`` read the column alone.
+appends; a new series drops the whole entry). Names are never kept: each
+request reads the live UID dictionary, one of three ways (counted by
+``tsd.query.filter{resolve=}``), so a renamed value shows in the next
+request:
+
+- ``ids``: a filter whose predicate is membership in a set of exact
+  names (``exact_names``: ``literal_or``, ``not_literal_or`` and the
+  old-style ``tagk=value`` / ``tagk=a|b``) looks its own names up in the
+  dictionary's forward map, a look-up a name it holds, and reads the
+  name of no stored value (ref: TagVLiteralOrFilter resolves its
+  literals to tagv UIDs when the query is built, TagVFilter.resolveTags);
+- ``walk``: a filter that cannot say so (``iliteral_or``,
+  ``not_iliteral_or``, ``wildcard``, ``iwildcard``, ``regexp``) has
+  ``matching_tagv_ids`` read the NAME of every distinct value of its
+  key and run its predicate on it: 1,000,000 look-ups for a pattern
+  over a key of 1,000,000 hosts;
+- ``presence``: filters that match every value (``*``, ``.*``) and
+  ``not_key`` read the column alone.
 """
 
 from __future__ import annotations
 
 import fnmatch
 import re
-from typing import Callable, Sequence
+from collections import Counter
+from typing import Sequence
 
 import numpy as np
 
@@ -71,6 +82,13 @@ class TagVFilter:
         """True when series having the key may match."""
         return True
 
+    def exact_names(self) -> tuple[frozenset[str], bool] | None:
+        """``(names, negated)`` when the predicate is "the value is
+        (``negated``: is not) one of these exact names", which the
+        evaluator answers from the names' own UIDs; None when only the
+        predicate run over a stored value's name can tell."""
+        return None
+
     def to_json(self) -> dict:
         return {"tagk": self.tagk, "filter": self.filter_expr,
                 "type": self.filter_name, "groupBy": self.group_by}
@@ -93,17 +111,26 @@ class TagVLiteralOrFilter(TagVFilter):
     """``literal_or(v1|v2)`` (ref: TagVLiteralOrFilter.java:35)"""
     filter_name = "literal_or"
     case_insensitive = False
+    negated = False
 
     def post_init(self) -> None:
         if not self.filter_expr:
             raise ValueError("empty literal_or filter")
         values = self.filter_expr.split("|")
-        self._literals = {v.lower() if self.case_insensitive else v
-                          for v in values if v}
+        self._literals = frozenset(
+            v.lower() if self.case_insensitive else v
+            for v in values if v)
 
     def match_value(self, value: str) -> bool:
         v = value.lower() if self.case_insensitive else value
-        return v in self._literals
+        return (v in self._literals) != self.negated
+
+    def exact_names(self) -> tuple[frozenset[str], bool] | None:
+        # a name's other spellings have UIDs of their own, which only
+        # the stored names can tell
+        if self.case_insensitive:
+            return None
+        return self._literals, self.negated
 
     @property
     def literals(self) -> set[str]:
@@ -117,16 +144,12 @@ class TagVILiteralOrFilter(TagVLiteralOrFilter):
 
 class TagVNotLiteralOrFilter(TagVLiteralOrFilter):
     filter_name = "not_literal_or"
-
-    def match_value(self, value: str) -> bool:
-        return not super().match_value(value)
+    negated = True
 
 
 class TagVNotILiteralOrFilter(TagVILiteralOrFilter):
     filter_name = "not_iliteral_or"
-
-    def match_value(self, value: str) -> bool:
-        return not super().match_value(value)
+    negated = True
 
 
 class TagVWildcardFilter(TagVFilter):
@@ -271,15 +294,32 @@ def filter_types() -> dict[str, dict]:
             for name, (d, e) in docs.items()}
 
 
+def _member_mask(col: np.ndarray, ids: list[int],
+                 negated: bool) -> np.ndarray:
+    """Rows of ``col`` whose tagv id is one of ``ids`` or, ``negated``,
+    that hold the key with another value. One gather through a table
+    of a byte a tagv id up to the largest asked for (ids are assigned
+    in sequence, so at most a byte a name of the dictionary): ``clip``
+    sends -1 (key absent) to entry 0, the id no name ever gets, and
+    every id past the table to its last entry."""
+    table = np.full(max(ids, default=0) + 2, negated, dtype=bool)
+    table[0] = False
+    table[ids] = not negated
+    return np.take(table, col, mode="clip")
+
+
 class FilterEvaluator:
     """Vectorized filter application over a metric's tag columns.
 
     The columns come from a ``TagMatrix`` (``col(kid)``: the tagv id
     of every series, -1 where the key is absent) or from the engine's
     cached ``PlanIndex`` over one, which also keeps each column's
-    distinct tagv ids (``distinct(kid)``) for as long as the metric
-    gains no series; a plain matrix computes them on the spot. Names
-    are never cached: every request reads the live UID dictionary.
+    distinct tagv ids (``distinct(kid)``, built by the first filter
+    that has to walk the key) for as long as the metric gains no
+    series; a plain matrix computes them on the spot. Names are never
+    cached: every request reads the live UID dictionary, the forward
+    map for a filter that holds exact names, the name of every
+    distinct value of the key for one that holds a pattern.
     """
 
     def __init__(self, uids):
@@ -293,15 +333,35 @@ class FilterEvaluator:
                 if filt.match_value(tagv.get_name(int(vid)))]
         return np.asarray(keep, dtype=np.int64)
 
-    def apply(self, filters: Sequence[TagVFilter], tags) -> np.ndarray:
+    def exact_tagv_ids(self, names) -> list[int]:
+        """The UIDs of the names that have one. tagv ids are shared
+        between keys: whether a key holds one, its column says."""
+        tagv = self._uids.tag_values
+        ids = []
+        for name in names:
+            try:
+                ids.append(tagv.get_id(name))
+            except LookupError:
+                pass
+        return ids
+
+    def apply(self, filters: Sequence[TagVFilter], tags,
+              tally: Counter | None = None) -> np.ndarray:
         """Return the boolean keep-mask over the series of ``tags``.
 
         Every filter must pass — same-key and cross-key filters all AND
         together (ref: TsdbQuery/SaltScanner filter chain semantics).
         A filter that says it matches every value (``*``, ``.*``) is
-        the key's presence; ``not_key`` is its absence; any other runs
-        its string predicate over the column's distinct values.
+        the key's presence; ``not_key`` is its absence; one that names
+        exact values (``exact_names``) is the column against their
+        UIDs; any other runs its string predicate over the names of
+        the column's distinct values. ``tally`` counts the filters
+        evaluated each way (``resolve_ids``, ``resolve_walk``,
+        ``resolve_presence``) and the names of stored values read
+        (``names_read``): the ``query.plan`` span's tags.
         """
+        if tally is None:
+            tally = Counter()
         n = tags.num_series
         keep = np.ones(n, dtype=bool)
         by_key: dict[str, list[TagVFilter]] = {}
@@ -311,22 +371,35 @@ class FilterEvaluator:
             try:
                 kid = self._uids.tag_names.get_id(tagk)
             except LookupError:
-                # unknown tag key: only not_key filters can match
+                kid = None
+            col = None if kid is None else tags.col(kid)
+            if col is None:
+                # a key nobody named, or that no series here holds:
+                # only not_key filters can match
+                tally["resolve_presence"] += len(flist)
                 if not all(f.match_absent for f in flist):
                     return np.zeros(n, dtype=bool)
                 continue
-            col = tags.col(kid)
-            has_key = col >= 0 if col is not None \
-                else np.zeros(n, dtype=bool)
             for f in flist:
                 # same-key filters AND together like the reference's
                 # per-key chain (all must pass)
                 if f.match_absent and not f.includes_present:
-                    keep &= ~has_key
-                elif f.matches_all or col is None:
-                    keep &= has_key
+                    tally["resolve_presence"] += 1
+                    keep &= col < 0
+                elif f.matches_all:
+                    tally["resolve_presence"] += 1
+                    keep &= col >= 0
+                elif (exact := f.exact_names()) is not None:
+                    tally["resolve_ids"] += 1
+                    names, negated = exact
+                    ids = self.exact_tagv_ids(names)
+                    if not ids and not negated:
+                        return np.zeros(n, dtype=bool)
+                    keep &= _member_mask(col, ids, negated)
                 else:
-                    matched = self.matching_tagv_ids(
-                        f, tags.distinct(kid))
-                    keep &= np.isin(col, matched)
+                    tally["resolve_walk"] += 1
+                    candidates = tags.distinct(kid)
+                    tally["names_read"] += len(candidates)
+                    keep &= np.isin(
+                        col, self.matching_tagv_ids(f, candidates))
         return keep

@@ -6,13 +6,19 @@ TestTagVWildcardFilter, TestTagVNotLiteralOrFilter,
 TestTagVNotKeyFilter; ref: src/query/filter/TagVFilter.java:70).
 """
 
+import json
+from collections import Counter
+
 import numpy as np
 import pytest
 
-from opentsdb_tpu.query.engine import TagMatrix
+from opentsdb_tpu import TSDB, Config
+from opentsdb_tpu.core.uid import NoSuchUniqueId
+from opentsdb_tpu.query.engine import PlanIndex, TagMatrix
 from opentsdb_tpu.query.filters import (FilterEvaluator, build_filter,
                                         filter_types, get_filter,
                                         tags_to_filters)
+from opentsdb_tpu.tsd.http_api import HttpRequest, HttpRpcRouter
 
 
 # ---------------------------------------------------------------------------
@@ -203,3 +209,249 @@ class TestFilterEvaluator:
         ev = FilterEvaluator(tsdb.uids)
         mask = ev.apply([get_filter("nosuch", "not_key()")], tags)
         assert mask.all()
+
+
+# ---------------------------------------------------------------------------
+# the three ways a filter becomes a mask (PR 35) against the reference's
+# per-row predicate (ref: TagVFilter.match(tags) post-scan): a filter
+# that holds exact names resolves them through the dictionary's forward
+# map and reads no stored name; the others walk the key's distinct
+# values; ``*``, ``.*`` and not_key read the column alone
+# ---------------------------------------------------------------------------
+
+BASE = 1356998400
+#: "web01" is a host and a dc; "lax" a dc only; "ghost" has a UID and
+#: no series; "nosuch" / "nothere" have none; the fourth series has no
+#: host; the sixth differs from the second in case alone
+FLEET = [{"host": "web01", "dc": "lax"},
+         {"host": "web02", "dc": "lax"},
+         {"host": "db01", "dc": "sjc"},
+         {"dc": "sjc"},
+         {"host": "z\u00fcrich-01", "dc": "m\u00fcnchen"},
+         {"host": "WEB02", "dc": "LAX"},
+         {"host": "db02", "dc": "web01"}]
+LITERALS = ["web01", "web01|nosuch", "lax", "nosuch|nothere",
+            "web01||db01", "web01|", "z\u00fcrich-01", "ghost",
+            "WEB02|db02", "lax|web01"]
+EXPRS = (
+    [(t, e) for t in ("literal_or", "iliteral_or", "not_literal_or",
+                      "not_iliteral_or") for e in LITERALS]
+    + [(t, e) for t in ("wildcard", "iwildcard")
+       for e in ("web*", "*", "*01", "*\u00fc*", "W*", "*nosuch*")]
+    + [("regexp", e) for e in ("web\\d+", ".*", "^z.*", "nosuch")]
+    + [("not_key", "")])
+#: two filters on one key, filters on two keys: all must pass
+CHAINS = [
+    [("host", "literal_or(web01|web02|db01)"),
+     ("host", "not_literal_or(web02)")],
+    [("host", "not_literal_or(web01)"), ("host", "wildcard(*0*)")],
+    [("host", "literal_or(web01|db02)"), ("dc", "literal_or(web01)")],
+    [("host", "not_literal_or(db01)"), ("dc", "not_literal_or(lax)")],
+    [("host", "not_key()"), ("dc", "literal_or(sjc)")],
+    [("host", "literal_or(web01)"), ("rack", "not_key()")],
+    [("host", "iliteral_or(web02)"), ("dc", "not_literal_or(LAX)")],
+    [("host", "web01|db01"), ("dc", "*")],      # old style
+    [("host", "web01")],                        # old style, exact
+]
+CASES = ([[("host", f"{t}({e})")] for t, e in EXPRS]
+         # a key some series lack, a key of every series, an unknown key
+         + [[("dc", f"{t}({e})")] for t, e in EXPRS]
+         + [[("rack", f"{t}({e})")] for t, e in EXPRS[::7]]
+         + CHAINS)
+
+
+def fleet(tsdb):
+    """(uids, the metric's whole TagMatrix, each series' tags by name)"""
+    for i, tags in enumerate(FLEET):
+        tsdb.add_point("m", BASE, i, tags)
+    tsdb.add_point("other", BASE, 1, {"host": "ghost"})
+    mid = tsdb.uids.metrics.get_id("m")
+    sids = tsdb.store.series_ids_for_metric(mid)
+    _, triples = tsdb.store.metric_index(mid).arrays()
+    return tsdb.uids, TagMatrix.from_triples(sids, triples), sids
+
+
+def tags_by_name(tsdb, sids):
+    uids = tsdb.uids
+    return [{uids.tag_names.get_name(k): uids.tag_values.get_name(v)
+             for k, v in tsdb.store.series(int(s)).tags} for s in sids]
+
+
+def predicate_walk(filters, rows):
+    """The reference's per-row chain: every filter must pass; a series
+    without the key passes a not_key alone."""
+    return np.array([all(
+        f.match_value(tags[f.tagk]) if f.tagk in tags else f.match_absent
+        for f in filters) for tags in rows], dtype=bool)
+
+
+SOURCES = {"matrix": lambda tags: tags,
+           "index": lambda tags: PlanIndex(tags.num_series, tags)}
+
+
+@pytest.mark.parametrize("source", sorted(SOURCES))
+@pytest.mark.parametrize(
+    "case", CASES, ids=[" ".join(f"{k}={e}" for k, e in c) for c in CASES])
+def test_every_way_selects_what_the_predicate_selects(tsdb, source, case):
+    uids, tags, sids = fleet(tsdb)
+    filters = [get_filter(k, e) for k, e in case]
+    tally = Counter()
+    mask = FilterEvaluator(uids).apply(filters, SOURCES[source](tags),
+                                       tally)
+    assert mask.dtype == bool and mask.shape == (len(FLEET),)
+    assert mask.tolist() == predicate_walk(
+        filters, tags_by_name(tsdb, sids)).tolist()
+    # each filter evaluated went exactly one way, and only a walk
+    # reads names of stored values
+    ways = sum(tally[f"resolve_{w}"] for w in ("ids", "walk", "presence"))
+    assert 1 <= ways <= len(filters)
+    assert (tally["names_read"] > 0) == (tally["resolve_walk"] > 0)
+
+
+@pytest.mark.parametrize("source", sorted(SOURCES))
+@pytest.mark.parametrize("ftype", ["literal_or", "not_literal_or",
+                                   "iliteral_or", "not_iliteral_or",
+                                   "wildcard", "iwildcard", "regexp"])
+def test_a_renamed_value_shows_in_the_next_apply(tsdb, source, ftype):
+    """Nothing of the dictionary is kept between two requests: the
+    same source answers by the new name once a value is renamed."""
+    uids, tags, sids = fleet(tsdb)
+    src = SOURCES[source](tags)
+    ev = FilterEvaluator(uids)
+    exprs = {"regexp": ("web01", "web99"),
+             "wildcard": ("web01*", "web99*"),
+             "iwildcard": ("WEB01*", "WEB99*")}.get(
+        ftype, ("web01|db01", "web99|db01"))
+    old, new = (get_filter("host", f"{ftype}({e})") for e in exprs)
+    before = [ev.apply([f], src).tolist() for f in (old, new)]
+    uids.tag_values.rename("web01", "web99")
+    rows = tags_by_name(tsdb, sids)
+    assert rows[0]["host"] == "web99" and rows[6]["dc"] == "web99"
+    after = [ev.apply([f], src).tolist() for f in (old, new)]
+    assert after == [predicate_walk([f], rows).tolist()
+                     for f in (old, new)]
+    assert before != after and before[0][0] != after[0][0]
+
+
+class TestHowAFilterIsResolved:
+    """The exact way reads no stored name, whatever the key holds."""
+
+    N = 300
+
+    @pytest.fixture
+    def served(self):
+        tsdb = TSDB(Config(**{
+            "tsd.core.auto_create_metrics": "true",
+            "tsd.tpu.warmup": "false", "tsd.trace.sample": "1",
+            "tsd.query.cache.enable": "false"}))
+        text = "".join(
+            f"sys.f {BASE + 10 * j} {i + j} host=h{i:03d}x dc=d{i % 3}\n"
+            for i in range(self.N) for j in range(3))
+        written, errors = tsdb.import_buffer(text.encode(), durable=False)
+        assert written == 3 * self.N and not errors
+        yield tsdb, HttpRpcRouter(tsdb)
+        tsdb.shutdown()
+
+    @staticmethod
+    def spy(tsdb, monkeypatch):
+        tagv, reads = tsdb.uids.tag_values, []
+        real = tagv.get_name
+        monkeypatch.setattr(
+            tagv, "get_name", lambda uid: reads.append(uid) or real(uid))
+        return reads
+
+    @staticmethod
+    def ask(router, *filters):
+        """(hosts answered, the ``query.plan`` span's tags)"""
+        body = json.dumps({
+            "start": BASE * 1000, "end": (BASE + 60) * 1000,
+            "queries": [{"metric": "sys.f", "aggregator": "none",
+                         "filters": [
+                             {"type": t, "tagk": k, "filter": e,
+                              "groupBy": False}
+                             for k, t, e in filters]}]}).encode()
+        resp = router.handle(HttpRequest(
+            method="POST", path="/api/query", params={}, headers={},
+            body=body))
+        assert resp.status == 200, resp.body
+        data = router.tsdb.tracer.get(resp.headers["X-TSD-Trace-Id"])
+        (plan,) = [s.tags for s in data.spans if s.name == "query.plan"]
+        return sorted(r["tags"]["host"] for r in json.loads(resp.body)), \
+            plan
+
+    @staticmethod
+    def resolved(router):
+        resp = router.handle(HttpRequest(
+            method="GET", path="/api/stats", params={}, headers={},
+            body=b""))
+        return {r["tags"]["resolve"]: r["value"]
+                for r in json.loads(resp.body)
+                if r["metric"] == "tsd.query.filter"}
+
+    def test_exact_names_read_no_stored_name(self, served, monkeypatch):
+        tsdb, router = served
+        assert self.resolved(router) == {"ids": 0, "walk": 0,
+                                         "presence": 0}
+        reads = self.spy(tsdb, monkeypatch)
+        hosts, plan = self.ask(
+            router, ("host", "literal_or", "h007x|h123x|nosuch"))
+        assert hosts == ["h007x", "h123x"]
+        assert (plan["names_read"], plan["resolve_ids"]) == (0, 1)
+        hosts, plan = self.ask(
+            router, ("host", "not_literal_or", "h007x|h123x"),
+            ("dc", "wildcard", "*"))
+        assert len(hosts) == self.N - 2 and "h007x" not in hosts
+        assert (plan["names_read"], plan["resolve_ids"],
+                plan["resolve_presence"]) == (0, 1, 1)
+        assert "resolve_walk" not in plan
+        assert self.resolved(router) == {"ids": 2, "walk": 0,
+                                         "presence": 1}
+        # (the serializer names the answered series' tags: not the plan)
+        del reads[:]
+        mask = FilterEvaluator(tsdb.uids).apply(
+            [get_filter("host", "literal_or(h007x|h123x)"),
+             get_filter("host", "not_literal_or(h007x)")],
+            TagMatrix.from_triples(*self.index_of(tsdb)))
+        assert mask.sum() == 1 and reads == []
+
+    @staticmethod
+    def index_of(tsdb):
+        mid = tsdb.uids.metrics.get_id("sys.f")
+        return (tsdb.store.series_ids_for_metric(mid),
+                tsdb.store.metric_index(mid).arrays()[1])
+
+    def test_a_pattern_walks_every_distinct_value(self, served,
+                                                  monkeypatch):
+        tsdb, router = served
+        reads = self.spy(tsdb, monkeypatch)
+        mask = FilterEvaluator(tsdb.uids).apply(
+            [get_filter("host", "wildcard(*7x*)")],
+            TagMatrix.from_triples(*self.index_of(tsdb)))
+        assert mask.sum() == 30 and len(reads) == self.N
+        hosts, plan = self.ask(router, ("host", "wildcard", "*7x*"))
+        assert len(hosts) == 30
+        assert (plan["names_read"], plan["resolve_walk"]) == (self.N, 1)
+        assert "resolve_ids" not in plan
+        hosts, plan = self.ask(router, ("host", "iliteral_or", "H007X"),
+                               ("dc", "regexp", "d[01]"))
+        assert hosts == ["h007x"]
+        assert (plan["names_read"], plan["resolve_walk"]) == \
+            (self.N + 3, 2)
+        assert self.resolved(router) == {"ids": 0, "walk": 3,
+                                         "presence": 0}
+
+    def test_a_deleted_uid_stops_the_walk_alone(self, served):
+        """A stored value whose UID left the dictionary: the exact way
+        reads no stored name and answers, as the reference's
+        literal_or does; a walk of that key raises as it always did."""
+        tsdb, _ = served
+        tags = TagMatrix.from_triples(*self.index_of(tsdb))
+        ev = FilterEvaluator(tsdb.uids)
+        tsdb.uids.tag_values.delete("h200x")
+        for expr, count in (("literal_or(h007x|h200x)", 1),
+                            ("not_literal_or(h007x|h200x)", self.N - 1)):
+            assert ev.apply([get_filter("host", expr)],
+                            tags).sum() == count
+        for expr in ("wildcard(h0*)", "iliteral_or(h007x)", "regexp(h.*)"):
+            with pytest.raises(NoSuchUniqueId):
+                ev.apply([get_filter("host", expr)], tags)
