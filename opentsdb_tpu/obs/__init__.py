@@ -26,6 +26,23 @@ Surfaces: ``GET /api/trace`` (recent roots), ``GET /api/trace/<id>``
 (full span tree, cluster-stitched on a router), per-stage latency
 percentiles at ``/api/stats`` + ``/api/health``, ``GET /metrics``,
 ``GET /api/profile``.
+
+A served query is four intervals that touch, from its first byte
+read to its last byte written: ``query.receive`` and
+``query.admission`` (from the socket server's stamps), the root
+``query.http`` (the worker, up to the handler's return) and
+``query.respond`` (from the tracer's finish to the response's last
+drain: the loop's wake-up, the latency feeds, gzip, the write; a
+retained tree gains that span after the response has left). At
+``/api/stats``: ``tsd.runtime.thread_cpu_ms{thread}`` (CPU time the
+kernel has charged to the live Python threads, by pool:
+``tsd-query``, ``tsd-subq``, ``asyncio`` for the puts, ``MainThread``
+for the loop; read from ``/proc/self/task`` when stats are collected,
+nothing on a request's path: beside a pool's stage sums, how much of
+its requests its threads ran), ``tsd.runtime.minor_faults`` /
+``major_faults`` (the process's page faults),
+``tsd.trace.finish_ms`` / ``tsd.trace.observations`` (what
+the tracer's own bookkeeping cost).
 """
 
 from opentsdb_tpu.obs.trace import (KNOWN_SPANS, Tracer, current,

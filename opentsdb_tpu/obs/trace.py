@@ -6,7 +6,8 @@ Design constraints, in priority order:
    outside a traced request) every instrumentation site costs one
    thread-local read returning ``None``. With tracing on, a span is
    two ``time.monotonic()`` calls, one small object and one
-   lock-guarded list append — spans wrap request-scoped *stages*
+   lock-guarded list append, and :meth:`Tracer.finish` times itself
+   (``tsd.trace.finish_ms``) — spans wrap request-scoped *stages*
    (decode, WAL commit wait, plan, execute, serialize), never
    per-point work. Sampling (``tsd.trace.sample`` = keep 1 in N
    request roots) gates only *retention*: every request still records
@@ -38,10 +39,17 @@ innermost span still open on the same thread in the same context, so
 and :meth:`Tracer.finish` can compute each parent's SELF time
 (duration minus the union of its children): what no child names.
 
+A served query is four intervals that touch: ``query.receive`` and
+``query.admission`` recorded from the socket server's stamps, the root
+(begun in the worker, ended where :meth:`Tracer.finish` begins) and
+``query.respond`` (:meth:`Tracer.record_respond`: from the end of
+``finish`` to the response's last drain).
+
 Beside the tracer sits the process's :data:`RUNTIME`: the
 device-occupancy clock (:class:`DeviceClock`, on the spans' own
 clock, so every idle millisecond lands on a host stage), JAX's
-compile events, the collector's pauses and the start-up phases.
+compile events, the collector's pauses, the process's page faults,
+its threads' CPU time and the start-up phases.
 
 The query-shape log is the explicit precursor to workload-adaptive
 summaries (ROADMAP item 5 / Storyboard): each committed ``query.http``
@@ -57,6 +65,8 @@ import gc
 import json
 import logging
 import os
+import re
+import resource
 import secrets
 import sys
 import threading
@@ -100,6 +110,7 @@ KNOWN_SPANS: frozenset[str] = frozenset({
     "wal.commit_wait",       # WAL group-commit fsync wait
     "stream.tap",            # continuous-query ingest tap
     # query stages
+    "query.receive",         # first byte in the buffer -> request parsed
     "query.admission",       # admission + worker-queue wait
     "query.streaming_lookup",  # CQ registry try_serve
     "query.plan",            # store/tier selection, filters, groups
@@ -113,6 +124,7 @@ KNOWN_SPANS: frozenset[str] = frozenset({
     "query.download",        # np.asarray of the results
     "query.assemble",        # result assembly incl. pixel reduce
     "query.serialize",       # response body serialization
+    "query.respond",         # handler's return -> last byte written
     # cluster stages
     "cluster.scatter",       # router read fan-out (parent stage)
     "cluster.peer",          # one shard's scatter leg (error = degraded)
@@ -197,12 +209,49 @@ _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
                  "/jax/compilation_cache/cache_misses": "cache_misses"}
 
 
+_TASKS = "/proc/self/task"
+_TICK_MS = 1000.0 / os.sysconf("SC_CLK_TCK")
+# a pool's or the library's numbering of a thread's name:
+# "tsd-query_3", "asyncio_0", "Thread-7 (attempt)"
+_THREAD_NUMBER = re.compile(r"[-_ ]?\d+( \(.*\))?$")
+
+
+def thread_group(name: str) -> str:
+    """A thread's name without its number: the threads of one pool
+    are one group (``tsd-query_3`` -> ``tsd-query``)."""
+    return _THREAD_NUMBER.sub("", name) or name
+
+
+def thread_cpu_ms(tasks: str = _TASKS) -> dict[str, float]:
+    """CPU time (user + system, ms) the kernel has charged to each
+    live Python thread since it started, summed by
+    :func:`thread_group`: ``/proc/self/task/<tid>/stat``, whose ticks
+    the kernel counts whether or not anybody asks, so a request's path
+    reads no clock for it. Beside the stage histograms' sums it says
+    how much of a pool's requests its threads ran. Empty where there
+    is no procfs; a thread that ended takes its time with it."""
+    out: dict[str, float] = {}
+    for t in threading.enumerate():
+        try:
+            with open(f"{tasks}/{t.native_id}/stat", "rb") as f:
+                # the name (field 2) may hold spaces and brackets:
+                # count the fields from its last bracket on
+                fields = f.read().rpartition(b")")[2].split()
+            ticks = int(fields[11]) + int(fields[12])  # utime, stime
+        except (OSError, ValueError, IndexError):
+            continue        # not started yet, or ended since
+        group = thread_group(t.name)
+        out[group] = out.get(group, 0.0) + ticks * _TICK_MS
+    return out
+
+
 class ProcessRuntime:
     """What only the process as a whole has: one device (so one
-    occupancy clock), JAX's compile events, the collector's pauses and
-    the start-up phases. Hooks are installed once, by the first
-    :class:`Tracer`; every tracer of the process exports the same
-    numbers (``tsd.device.*``, ``tsd.runtime.gc_*``,
+    occupancy clock), JAX's compile events, the collector's pauses,
+    the kernel's account of the process (page faults, CPU time by
+    thread) and the start-up phases. Hooks are installed once, by the
+    first :class:`Tracer`; every tracer of the process exports the
+    same numbers (``tsd.device.*``, ``tsd.runtime.*``,
     ``tsd.startup.*``)."""
 
     def __init__(self):
@@ -286,6 +335,16 @@ class ProcessRuntime:
                              self.gc_collections[gen], gen=str(gen))
         collector.record("runtime.gc_max_pause_ms",
                          self.gc_max_pause_ms)
+        # pages the kernel had to map (minor) or read (major) for this
+        # process since it started: the allocator giving a request's
+        # large temporaries back and mapping them fresh shows here and
+        # in no stage. Read when stats are collected, never on a
+        # request's path.
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        collector.record("runtime.minor_faults", ru.ru_minflt)
+        collector.record("runtime.major_faults", ru.ru_majflt)
+        for group, ms in sorted(thread_cpu_ms().items()):
+            collector.record("runtime.thread_cpu_ms", ms, thread=group)
         for name, secs in list(self.startup.items()):
             collector.record("startup.phase_s", secs, phase=name)
 
@@ -525,7 +584,8 @@ class TraceContext:
                  "sampled", "forced", "parent_id", "root_span_id",
                  "start_epoch_ms", "_t0", "_lock", "spans",
                  "_next_span", "_nonce", "finished", "committed",
-                 "slow", "error", "tags", "dropped_spans", "_gc2_ms0")
+                 "slow", "error", "tags", "dropped_spans", "_gc2_ms0",
+                 "finished_at")
 
     def __init__(self, tracer: "Tracer", trace_id: str,
                  root_name: str, sampled: bool, forced: bool,
@@ -556,6 +616,9 @@ class TraceContext:
         self.tags: dict[str, Any] = {}
         self.dropped_spans = 0
         self._gc2_ms0 = RUNTIME.gc_pause_ms[2]
+        # the instant Tracer.finish was done with it (0.0 until
+        # then): where the server's query.respond begins
+        self.finished_at = 0.0
 
     # -- span surface --------------------------------------------------
 
@@ -617,9 +680,6 @@ class TraceContext:
     def set_error(self, exc: BaseException | str) -> None:
         self.error = (f"{type(exc).__name__}: {exc}"
                       if isinstance(exc, BaseException) else str(exc))
-
-    def elapsed_ms(self) -> float:
-        return (_now() - self._t0) * 1000.0
 
 
 class TraceData:
@@ -701,6 +761,10 @@ class Tracer:
         # tsdlint: allow[unbounded-growth] keyed by span name: the
         # closed KNOWN_SPANS registry
         self.idle_stage_ms: dict[str, float] = {}
+        # what finish() itself cost, and the histogram observations
+        # it made
+        self.finish_ms = 0.0
+        self.observations = 0
         # programs dispatched, by (path, placement, class)
         # tsdlint: allow[unbounded-growth] keyed by run_staged's
         # tags: the six paths its callers name x two placements x
@@ -719,9 +783,11 @@ class Tracer:
         # key), "presence" (the key's column alone: *, .*, not_key)
         self.filters = {"ids": 0, "walk": 0, "presence": 0}
         # assemble stages, by where the groups' common and aggregated
-        # tags were read: "index" (the plan index's cached layout) or
-        # "matrix" (a sort of the request's own rows)
-        self.assembles = {"index": 0, "matrix": 0}
+        # tags were read: "index" (the plan index's cached layout),
+        # "small" (a sort of the request's own rows, few enough of an
+        # index's that this is the cheaper way) or "matrix" (the same
+        # sort because there was no index to read)
+        self.assembles = {"index": 0, "small": 0, "matrix": 0}
         self._ring: deque[TraceData] = deque(
             maxlen=max(config.get_int("tsd.trace.ring", 256), 1))
         self._slow_ring: deque[TraceData] = deque(
@@ -789,13 +855,16 @@ class Tracer:
             remote=remote or getattr(request, "remote", ""))
         with self._lock:
             self.traces_started += 1
-        # the admission/queue wait predates this context: synthesize
-        # it from the server's receipt stamp so the trace shows where
-        # a loaded TSD's queries actually wait
+        # the read of the request and the admission/queue wait
+        # predate this context: synthesize them from the server's
+        # stamps so the trace shows where a loaded TSD's queries
+        # actually wait (a direct handle() call has neither stamp)
         received = getattr(request, "received_at", 0.0)
         if received and name == "query.http":
-            record_span(ctx, "query.admission", received,
-                        _now())
+            first = getattr(request, "first_byte_at", 0.0)
+            if first:
+                record_span(ctx, "query.receive", first, received)
+            record_span(ctx, "query.admission", received, ctx._t0)
         return ctx
 
     def start_background(self, name: str, sample: bool = False,
@@ -852,7 +921,10 @@ class Tracer:
             ctx.finished = True
             spans = list(ctx.spans)
             dropped = ctx.dropped_spans
-        duration_ms = ctx.elapsed_ms()
+        # the root ends here, and what follows is the tracer's own
+        # bookkeeping, timed into tsd.trace.finish_ms
+        t_end = _now()
+        duration_ms = (t_end - ctx._t0) * 1000.0
         gc_ms = RUNTIME.gc_pause_ms[2] - ctx._gc2_ms0
         if gc_ms > 0:
             # a full collection ran inside this root
@@ -868,11 +940,13 @@ class Tracer:
         # sampling gates only ring retention, so /api/stats
         # percentiles are not biased toward the sampled subset
         stats = self.stats
+        observed = 0
         if stats is not None:
             stats.observe_stage(root.name, duration_ms)
             for s in spans:
                 stats.observe_stage(s.name, s.duration_ms)
-        self._account_self_time(root, spans)
+            observed = 1 + len(spans)
+        observed += self._account_self_time(root, spans)
         slow = (self.slow_ms > 0 and duration_ms >= self.slow_ms
                 and ctx.root_name.startswith("query"))
         commit = ctx.sampled or ctx.forced or slow or bool(ctx.error)
@@ -927,10 +1001,45 @@ class Tracer:
         if commit and ctx.root_name == "query.http" and \
                 self.shape_path:
             self._write_shape(ctx, root, spans)
+        done = ctx.finished_at = _now()
+        with self._lock:
+            self.finish_ms += (done - t_end) * 1000.0
+            self.observations += observed
         return commit
 
+    def record_respond(self, ctx: TraceContext | None,
+                       end_mono: float) -> None:
+        """The last stage of a served query, which no handler sees:
+        from the instant :meth:`finish` was done with the root (in
+        the worker) to ``end_mono``, the server's stamp after the
+        response's last ``drain`` -- the wake-up of the event loop,
+        the latency / SLO / tenant feeds, CORS, gzip, the write. Fed
+        to the stage histograms for every request; a retained trace
+        gets the span under its root LATE: the trace was committed
+        (ring, shape log, a router's stitch) before the response was
+        written, so a reader that is quick enough sees the tree
+        without it, and a window's last request may feed the
+        histogram after the window's last snapshot. The server calls
+        this only where the worker's response is the one it wrote
+        (not for a shed query or one that timed out)."""
+        if ctx is None or not ctx.finished_at:
+            return
+        ms = (end_mono - ctx.finished_at) * 1000.0
+        if self.stats is not None:
+            self.stats.observe_stage("query.respond", ms)
+        if not ctx.committed:
+            return
+        rec = SpanRecord(
+            f"{ctx._nonce}-respond", ctx.root_span_id, "query.respond",
+            ctx.start_epoch_ms + (ctx.finished_at - ctx._t0) * 1000.0,
+            ms)
+        with self._lock:
+            data = self._index.get(ctx.trace_id)
+            if data is not None:
+                data.spans += (rec,)
+
     def _account_self_time(self, root: SpanRecord,
-                           spans: list[SpanRecord]) -> None:
+                           spans: list[SpanRecord]) -> int:
         """Each span's SELF time (its duration minus the union of its
         children's intervals) feeds ``tsd_stage_self_ms`` where the
         span has children; the part of it with no program in flight
@@ -940,11 +1049,12 @@ class Tracer:
         ``query.plan`` that reached its filters (tag ``index``) in
         ``plans`` and its filters (tags ``resolve_<way>``) in
         ``filters``, every ``query.assemble`` by its tag ``tags`` in
-        ``assembles``."""
+        ``assembles``. Returns the histogram observations made."""
         kids: dict[str, list[SpanRecord]] = {}
         for s in spans:
             kids.setdefault(s.parent_id, []).append(s)
         idle: dict[str, float] = {}
+        observed = 0
         tails = []
         builds = []
         plans = []
@@ -966,6 +1076,7 @@ class Tracer:
                 occupied -= sum(c.occupied_ms for c in mine)
                 if self.stats is not None:
                     self.stats.observe_stage_self(s.name, self_ms)
+                    observed += 1
             idle[s.name] = idle.get(s.name, 0.0) + self_ms \
                 - min(max(occupied, 0.0), self_ms)
             if s.name == "query.program":
@@ -997,6 +1108,7 @@ class Tracer:
                 self.filters[way] += n
             for way in assembles:
                 self.assembles[way] += 1
+        return observed
 
     # -- retrieval -----------------------------------------------------
 
@@ -1080,6 +1192,8 @@ class Tracer:
         collector.record("trace.shape_errors", self.shape_errors)
         RUNTIME.collect_stats(collector)
         with self._lock:
+            collector.record("trace.finish_ms", self.finish_ms)
+            collector.record("trace.observations", self.observations)
             idle = sorted(self.idle_stage_ms.items())
             tails = sorted(self.tails.items())
             builds = sorted(self.grid_builds.items())

@@ -644,8 +644,11 @@ def group_tag_summary(tags: TagMatrix, group_ids: np.ndarray,
     the matrix was selected from (``gb_kids`` None says they are not),
     and the selection is no :data:`SMALL_SELECTION`: the index's
     cached layout, of which an unfiltered request reads the whole
-    groups as they stand. ``matrix`` otherwise: a layout of the
-    matrix's own rows, made here."""
+    groups as they stand. Otherwise a layout of the matrix's own
+    rows, made here: ``small`` where that was the cheaper of two ways
+    (a :data:`SMALL_SELECTION` of an index), ``matrix`` where there
+    was no index to read (``path.fallbacks`` counts it)."""
+    way = "matrix"
     if tags.origin is not None and gb_kids is not None:
         index, rows = tags.origin
         if rows is None or len(rows) == index.num_series:
@@ -657,10 +660,11 @@ def group_tag_summary(tags: TagMatrix, group_ids: np.ndarray,
             mask[rows] = True
             return ("index", *index.layout(gb_kids).selected(mask),
                     index.tags)
+        way = "small"
     order = np.argsort(group_ids, kind="stable")
     layout = GroupLayout(order, _group_starts(group_ids, num_groups),
                          tags.vids[order].T)
-    return "matrix", layout.minv, layout.maxv, layout.members, tags
+    return way, layout.minv, layout.maxv, layout.members, tags
 
 
 #: downsample functions the storage-side pre-reduction can serve, by

@@ -198,6 +198,11 @@ class StatsCollectorRegistry:
         return collector
 
 
+#: observations a Histogram holds back before it folds them into its
+#: quantile sketch in one pass
+_SKETCH_FOLD_AT = 256
+
+
 class Histogram:
     """Exponentially-bucketed histogram (ref: src/stats/Histogram.java:38).
 
@@ -226,6 +231,14 @@ class Histogram:
         # cluster/fleet.py). Rides the snapshot as a base64 field.
         from opentsdb_tpu.sketch.ddsketch import DDSketch
         self._sketch = DDSketch()
+        # values observed and not yet folded into the sketch: one
+        # value costs the sketch what 256 cost it (a mask, a unique,
+        # a merge of arrays), and an observation sits on a request's
+        # path, so add() appends here and whoever reads the sketch
+        # folds first. A DDSketch's state is canonical, so values
+        # folded at once leave what one by one would (a test holds
+        # every exported number and the base64 sketch to that).
+        self._pending: list[float] = []
 
     def add(self, value: float) -> None:
         # bisect_left: first bound >= value, i.e. the first bucket
@@ -236,7 +249,15 @@ class Histogram:
             self.buckets[min(idx, len(self.buckets) - 1)] += 1
             self.count += 1
             self.sum += value
-            self._sketch.add(value)
+            self._pending.append(value)
+            if len(self._pending) >= _SKETCH_FOLD_AT:
+                self._fold_pending()
+
+    def _fold_pending(self) -> None:
+        """Under the lock: the pending values into the sketch."""
+        if self._pending:
+            self._sketch.add_values(self._pending)
+            self._pending.clear()
 
     def snapshot(self) -> dict[str, Any]:
         """Consistent copy of the raw state — the wire form the
@@ -244,6 +265,7 @@ class Histogram:
         (bounds are construction-time constants; counts/sum are read
         under the lock so a snapshot is never torn mid-``add``)."""
         with self._lock:
+            self._fold_pending()
             return {"bounds": list(self.bounds),
                     "buckets": list(self.buckets),
                     "count": self.count, "sum": self.sum,

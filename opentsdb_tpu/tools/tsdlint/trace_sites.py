@@ -2,8 +2,10 @@
 
 Every span literal started anywhere — ``trace_begin``/``trace_span``/
 ``record_span`` helpers, ``tracer.start_request``/
-``tracer.start_background`` roots, and the HTTP router's
-``_trace_request`` wrapper — must resolve to
+``tracer.start_background`` roots, the HTTP router's
+``_trace_request`` wrapper, and a stage histogram fed under a literal
+name (``observe_stage``: ``query.respond``, which ends after its
+root has finished) — must resolve to
 :data:`opentsdb_tpu.obs.trace.KNOWN_SPANS` (the ``faults.KNOWN_SITES``
 idiom): a typo'd stage would otherwise record an orphan stage nothing
 dashboards or the shape-log miner ever look for. The reverse is
@@ -24,7 +26,7 @@ PASS_ID = "trace-sites"
 # unique helper names: the first str constant among the leading args
 # is the span name (record_span takes (ctx, name, ...))
 _FUNCS = {"trace_begin", "trace_span", "record_span",
-          "_trace_request"}
+          "_trace_request", "observe_stage"}
 # root starters: only on tracer-ish receivers (other classes may
 # legitimately own a start_background)
 _METHODS = {"start_request", "start_background"}

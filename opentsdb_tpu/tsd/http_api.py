@@ -61,6 +61,13 @@ class HttpRequest:
     # wait from here to handler start (0.0 = unknown, e.g. direct
     # router.handle calls in tests)
     received_at: float = 0.0
+    # time.monotonic() when the first bytes of this request were in
+    # the server's buffer: query.receive runs from here to
+    # received_at (0.0 = unknown, as above)
+    first_byte_at: float = 0.0
+    # the finished query.http context of a request the socket server
+    # serves: its query.respond span starts where finish() ended
+    traced: Any = None
 
     def param(self, key: str, default: str | None = None) -> str | None:
         vals = self.params.get(key)
@@ -457,6 +464,8 @@ class HttpRpcRouter:
             tracer.finish(ctx)
             if ctx.committed:
                 request.trace_id_hint = ctx.trace_id
+            if request.received_at and name == "query.http":
+                request.traced = ctx
         return resp
 
     # -- write path ----------------------------------------------------

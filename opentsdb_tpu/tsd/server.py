@@ -587,11 +587,18 @@ class TSDServer:
         buffer = first
         keep_alive = True
         while keep_alive:
+            # the instant this request's first bytes are in the buffer
+            # (query.receive runs from here to received_at): now when
+            # a pipelined or sniffed request already sits there, else
+            # the return of the first read that brings any
+            first_byte_at = time.monotonic() if buffer else 0.0
             # read until end of headers
             while b"\r\n\r\n" not in buffer:
                 chunk = await self._on_client(reader.read(65536))
                 if not chunk:
                     return
+                if not first_byte_at:
+                    first_byte_at = time.monotonic()
                 buffer += chunk
             head, _, buffer = buffer.partition(b"\r\n\r\n")
             lines = head.decode("latin-1").split("\r\n")
@@ -682,8 +689,10 @@ class TSDServer:
                 method=method.upper(), path=parsed.path, params=params,
                 headers=headers, body=body,
                 remote=f"{peer[0]}:{peer[1]}" if peer else "",
-                received_at=t0)
-            is_query = False
+                received_at=t0, first_byte_at=first_byte_at)
+            # is_query: a query path; answered: its worker's response
+            # is the one written (not a shed's or a timeout's)
+            is_query = answered = False
             if method.upper() == "OPTIONS":
                 # preflight bypasses auth — browsers never attach
                 # Authorization to OPTIONS
@@ -760,6 +769,7 @@ class TSDServer:
                     else:
                         fut = asyncio.get_event_loop().run_in_executor(
                             None, self.http_router.handle, request)
+                    answered = is_query
                     if is_query and self.query_timeout_ms > 0:
                         try:
                             response = await asyncio.wait_for(
@@ -771,6 +781,10 @@ class TSDServer:
                             response = _structured_error(
                                 504, "Query timeout exceeded "
                                 f"({self.query_timeout_ms}ms)")
+                            # the worker may yet finish its root
+                            # before this answer is written: what is
+                            # written is not its response
+                            answered = False
                     else:
                         response = await fut
                 # request-level latency histograms (exported with
@@ -823,6 +837,13 @@ class TSDServer:
                         and response.body_iter is not None else None)
             await self._write_response(writer, response, version,
                                        keep_alive, deadline=deadline)
+            if answered:
+                # the served query's last stage, from the worker's
+                # return to the last byte drained; a shed query had
+                # no worker, and a timed-out one's answer is not its
+                # worker's
+                self.tsdb.tracer.record_respond(
+                    request.traced, time.monotonic())
 
     def _overload_response(self, cause: str) -> HttpResponse:
         """Structured load-shed answer (503 + Retry-After), one

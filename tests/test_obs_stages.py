@@ -447,7 +447,7 @@ def test_the_assemble_span_says_where_the_group_tags_were_read(backend):
         ways = {r["tags"]["tags"]: r["value"] for r in json.loads(
             router.handle(req("GET", "/api/stats")).body)
             if r["metric"] == "tsd.query.assemble"}
-        assert ways == {"index": 2, "matrix": 1}
+        assert ways == {"index": 2, "small": 0, "matrix": 1}
     finally:
         tsdb.shutdown()
 

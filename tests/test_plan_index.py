@@ -614,7 +614,7 @@ def test_a_small_selection_never_reads_or_builds_the_layout(served):
                        flt("literal_or", "host", hosts)]}
     for _ in range(2):
         body, (plan,) = served.query(sub, showTSUIDs=True)
-        assert served.ways == ["matrix"] and plan["series"] == 5
+        assert served.ways == ["small"] and plan["series"] == 5
     (index,) = served.tsdb._tagmat_cache.values()
     assert [s.layout for s in index._labels.values()] == [None]
     assert body == served.bypass(sub, showTSUIDs=True)[0]
@@ -622,6 +622,29 @@ def test_a_small_selection_never_reads_or_builds_the_layout(served):
     sub["filters"][1]["filter"] += "|h13"
     served.query(sub)
     assert served.ways == ["index"]
+
+
+@pytest.mark.parametrize("served", BACKENDS, indirect=True)
+def test_a_request_with_no_index_still_counts_matrix(served):
+    """``small`` is a choice between two ways; ``matrix`` is the fall
+    to the one way left (``path.fallbacks`` counts it): the same small
+    selection, once of the index and once of a matrix without one."""
+    hosts = "|".join(f"h{h:02d}" for h in (1, 3, 5, 9, 11))
+    sub = {"filters": [flt("wildcard", "dc", "*", True),
+                       flt("literal_or", "host", hosts)]}
+    counted = served.tsdb.tracer.assembles
+    before = dict(counted)
+    served.query(sub)
+    assert served.ways == ["small"]
+    served.bypass(sub)
+    assert served.ways == ["matrix"]
+    assert {k: counted[k] - before[k] for k in counted} \
+        == {"index": 0, "small": 1, "matrix": 1}
+    exported = {r["tags"]["tags"]: r["value"] for r in json.loads(
+        served.router.handle(HttpRequest(
+            method="GET", path="/api/stats", params={}, headers={})
+        ).body) if r["metric"] == "tsd.query.assemble"}
+    assert exported == counted
 
 
 @pytest.mark.parametrize("served", BACKENDS, indirect=True)
@@ -785,7 +808,7 @@ def test_a_summary_from_the_layout_equals_one_from_the_rows(
         got = summary_of(selected, gids, n, gb, k=3)
         want = summary_of(tags.select(rows), gids, n, gb, k=3)
         small = len(rows) * engine_mod.SMALL_SELECTION < 3000
-        assert got[0] == ("matrix" if small else "index")
+        assert got[0] == ("small" if small else "index")
         assert want[0] == "matrix"
         assert small or selected._vids is None
         assert got[1:] == want[1:] and got[4] == np.int64
