@@ -211,12 +211,26 @@ def load_library():
         lib.tss_format_dps.restype = ctypes.c_int64
         lib.tss_fmt_fast.argtypes = []
         lib.tss_fmt_fast.restype = ctypes.c_int64
+        lib.tss_pool_stats.argtypes = [ctypes.c_void_p]
+        lib.tss_pool_stats.restype = None
         _lib = lib
         return lib
 
 
 def _ptr(arr: np.ndarray):
     return arr.ctypes.data_as(ctypes.c_void_p)
+
+
+def pool_stats() -> dict:
+    """The process's worker pool (tsdbstore.cc ``WorkerPool``): parallel
+    passes that ran on their caller alone (``inline``), passes that woke
+    helpers (``pooled``), and the threads the pool has created since the
+    process began (``threads``): constant once the server is warm, since
+    no pass creates a thread of its own."""
+    out = np.zeros(3, dtype=np.int64)
+    load_library().tss_pool_stats(_ptr(out))
+    return {"inline": int(out[0]), "pooled": int(out[1]),
+            "threads": int(out[2])}
 
 
 #: tss_bucket_grid's ``fn`` (tsdbstore.cc ``GridFn``)
@@ -671,6 +685,13 @@ class NativeTimeSeriesStore:
                          mi["resident_bytes"])
         collector.record("storage.live_bytes", mi["live_bytes"])
         collector.record("storage.dead_bytes", mi["dead_bytes"])
+        # the process's pool, not this store's (the stores share it)
+        ps = pool_stats()
+        collector.record("storage.native.passes", ps["inline"],
+                         mode="inline")
+        collector.record("storage.native.passes", ps["pooled"],
+                         mode="pooled")
+        collector.record("storage.native.pool_threads", ps["threads"])
 
 
 IMPORT_ERRORS = {

@@ -17,6 +17,7 @@
 #include <atomic>
 #include <charconv>
 #include <cmath>
+#include <condition_variable>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
@@ -25,6 +26,7 @@
 #include <mutex>
 #include <shared_mutex>
 #include <string>
+#include <system_error>
 #include <thread>
 #include <unordered_map>
 #include <vector>
@@ -134,13 +136,178 @@ struct Store {
   }
 };
 
+// ---- the process's worker pool --------------------------------------
+//
+// Every parallel pass of this file is one parallel_for: the caller and
+// up to cap - 1 helpers claim chunks of `grain` items until none is
+// left. The helpers are parked threads of ONE pool for the process
+// (several stores live in it: raw, rollup tiers, pre-aggregates),
+// min(16, cores) - 1 of them, created by the first pass that wants
+// one, and joined when the last store is destroyed. No pass creates a
+// thread of its own: a panel's grid of 8 rows spent 3 ms creating and
+// joining 12 threads that found nothing to do.
+//
+// Under concurrent callers (a live request's two sub-queries, writers
+// beside them) the caller always works itself, so a pass never waits
+// for a helper that has not started; helpers take the oldest pass with
+// a seat left; the pass returns only after every helper that entered
+// its body has left it (the body lives on the caller's stack).
+class WorkerPool {
+ public:
+  std::atomic<int64_t> inline_passes{0}, pooled_passes{0};
+  std::atomic<int64_t> threads_created{0};
+  std::atomic<int64_t> stores{0};
+
+  // fn(ctx) on the caller and on up to `helpers` pool threads.
+  void run(int helpers, void (*fn)(void*), void* ctx) {
+    Pass pass;
+    pass.fn = fn;
+    pass.ctx = ctx;
+    if (helpers > 0) {
+      std::lock_guard<std::mutex> lock(mu_);
+      try {  // the first pass that wants a helper makes them all
+        while (!stop_ && (int)threads_.size() < max_threads_) {
+          threads_.emplace_back([this] { park(); });
+          threads_created.fetch_add(1, std::memory_order_relaxed);
+        }
+      } catch (const std::system_error&) {
+        // no thread to be had: the pass runs on what there is
+      }
+      helpers = pass.seats = std::min(helpers, (int)threads_.size());
+      if (helpers > 0) open_.push_back(&pass);
+    }
+    if (helpers <= 0) {
+      inline_passes.fetch_add(1, std::memory_order_relaxed);
+      fn(ctx);
+      return;
+    }
+    pooled_passes.fetch_add(1, std::memory_order_relaxed);
+    for (int i = 0; i < helpers; ++i) wake_.notify_one();
+    fn(ctx);
+    std::unique_lock<std::mutex> lock(mu_);
+    if (pass.seats > 0)  // nothing is left to claim: admit no one else
+      open_.erase(std::find(open_.begin(), open_.end(), &pass));
+    pass.left.wait(lock, [&] { return pass.inside == 0; });
+  }
+
+  // Join every parked thread (tss_destroy of the last store). A pass in
+  // flight keeps its caller; the next pass that wants helpers makes them.
+  void shutdown() {
+    std::lock_guard<std::mutex> one(shutdown_mu_);
+    std::vector<std::thread> gone;
+    {
+      std::lock_guard<std::mutex> lock(mu_);
+      stop_ = true;
+      gone.swap(threads_);
+    }
+    wake_.notify_all();
+    for (auto& th : gone) th.join();
+    std::lock_guard<std::mutex> lock(mu_);
+    stop_ = false;
+  }
+
+ private:
+  struct Pass {
+    void (*fn)(void*);
+    void* ctx;
+    int seats = 0;   // helpers still admitted
+    int inside = 0;  // helpers that entered fn and have not left
+    std::condition_variable left;
+  };
+
+  void park() {
+    std::unique_lock<std::mutex> lock(mu_);
+    for (;;) {
+      wake_.wait(lock, [&] { return stop_ || !open_.empty(); });
+      if (stop_) return;
+      Pass* pass = open_.front();
+      if (--pass->seats == 0) open_.erase(open_.begin());
+      ++pass->inside;
+      lock.unlock();
+      pass->fn(pass->ctx);
+      lock.lock();
+      if (--pass->inside == 0) pass->left.notify_one();
+    }
+  }
+
+  std::mutex mu_, shutdown_mu_;
+  std::condition_variable wake_;
+  std::vector<Pass*> open_;  // passes with a seat left, oldest first
+  std::vector<std::thread> threads_;
+  bool stop_ = false;
+  const int max_threads_ =
+      (int)std::min(16u, std::max(1u, std::thread::hardware_concurrency())) - 1;
+};
+
+// Never destroyed: a thread of the interpreter may still be in a pass
+// while the process exits.
+WorkerPool& pool() {
+  static WorkerPool* p = new WorkerPool();
+  return *p;
+}
+
+// body(begin, end) over [0, items) in chunks of `grain`, on
+// min(cap, ceil(items / grain)) participants, the caller included: a
+// pass of one chunk touches no other thread.
+template <typename Body>
+void parallel_for(int cap, int64_t items, int64_t grain, Body&& body) {
+  std::atomic<int64_t> next{0};
+  auto work = [&]() {
+    for (;;) {
+      int64_t begin = next.fetch_add(grain, std::memory_order_relaxed);
+      if (begin >= items) return;
+      body(begin, std::min(begin + grain, items));
+    }
+  };
+  const int64_t chunks = (items + grain - 1) / grain;
+  pool().run((int)std::min<int64_t>(cap, chunks) - 1,
+             [](void* w) { (*static_cast<decltype(work)*>(w))(); }, &work);
+}
+
+// Grains: what one participant must find to be worth its wake. On the
+// benchmark's host (gVisor, 13 cores; PR 39's probe, PERF.md section 6)
+// a caller pays 36 us to wake one helper and 13 us for each further one,
+// and a helper starts claiming ~0.3 ms after its wake: two participants
+// lose to one up to ~0.4 ms of work. So a grain is >= 0.25 ms at the
+// item's cost there: a grid row of 360 points 1.2 us, a bucket_reduce
+// row 0.85-1.05 us, a series of count_range 0.04 us, a copied point
+// ~1 ns; an appended cell 40 ns and a MB of import text 6.5 ms, both
+// with more room because their participants fight over fresh memory
+// (vectors that grow, a group table a chunk that the merge walks alone).
+constexpr int64_t kGridRows = 256;         // bucket_grid: rows of the grid
+constexpr int64_t kReduceRows = 256;       // bucket_reduce: series
+constexpr int64_t kCountSeries = 8192;     // count_range: series
+constexpr int64_t kFillPoints = 1 << 18;   // fill_range: points copied
+constexpr int64_t kAppendCells = 1 << 15;  // append_grid: cells of the grid
+constexpr int64_t kImportBytes = 1 << 18;  // parse_import: text
+
+// The grain in items where an item's cost is known in smaller units.
+inline int64_t items_per(int64_t units, int64_t units_per_item) {
+  return std::max<int64_t>(1, units / std::max<int64_t>(1, units_per_item));
+}
+
 }  // namespace
 
 extern "C" {
 
-void* tss_create() { return new Store(); }
+void* tss_create() {
+  pool().stores.fetch_add(1);
+  return new Store();
+}
 
-void tss_destroy(void* h) { delete static_cast<Store*>(h); }
+void tss_destroy(void* h) {
+  delete static_cast<Store*>(h);
+  if (h && pool().stores.fetch_sub(1) == 1) pool().shutdown();
+}
+
+// out[0..2]: passes that ran on their caller alone, passes that woke
+// helpers, threads the pool has created since the process began (it
+// stops growing after start-up: no request creates a thread).
+void tss_pool_stats(int64_t* out) {
+  out[0] = pool().inline_passes.load(std::memory_order_relaxed);
+  out[1] = pool().pooled_passes.load(std::memory_order_relaxed);
+  out[2] = pool().threads_created.load(std::memory_order_relaxed);
+}
 
 // Returns the new series id. Series identity (metric+tags -> sid) is
 // managed by the Python wrapper; this just allocates the buffer.
@@ -253,14 +420,11 @@ int64_t tss_append_grid(void* h, const int64_t* sids, int64_t nsids,
   Store* s = static_cast<Store*>(h);
   std::vector<SeriesBuffer*> bufs;
   if (!s->snapshot(sids, nsids, &bufs)) return -1;
-  if (threads < 1) threads = 1;
-  std::atomic<int64_t> next{0};
   std::atomic<int64_t> total{0};
-  auto worker = [&]() {
+  parallel_for(threads, nsids, items_per(kAppendCells, nbuckets),
+               [&](int64_t r0, int64_t r1) {
     int64_t local = 0;
-    for (;;) {
-      int64_t i = next.fetch_add(1);
-      if (i >= nsids) break;
+    for (int64_t i = r0; i < r1; ++i) {
       SeriesBuffer* buf = bufs[i];
       const double* row = grid + i * nbuckets;
       const uint8_t* m = mask + i * nbuckets;
@@ -277,11 +441,7 @@ int64_t tss_append_grid(void* h, const int64_t* sids, int64_t nsids,
       }
     }
     total.fetch_add(local);
-  };
-  std::vector<std::thread> pool;
-  for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-  worker();
-  for (auto& t : pool) t.join();
+  });
   s->points_written.fetch_add(total.load());
   return total.load();
 }
@@ -351,21 +511,13 @@ int tss_count_range(void* h, const int64_t* sids, int64_t nsids,
   Store* s = static_cast<Store*>(h);
   std::vector<SeriesBuffer*> bufs;
   if (!s->snapshot(sids, nsids, &bufs)) return -1;
-  if (threads < 1) threads = 1;
-  std::atomic<int64_t> next{0};
-  auto worker = [&]() {
-    for (;;) {
-      int64_t i = next.fetch_add(1);
-      if (i >= nsids) break;
+  parallel_for(threads, nsids, kCountSeries, [&](int64_t r0, int64_t r1) {
+    for (int64_t i = r0; i < r1; ++i) {
       int64_t lo, hi;
       bufs[i]->range_bounds(start_ms, end_ms, &lo, &hi);
       counts_out[i] = hi - lo;
     }
-  };
-  std::vector<std::thread> pool;
-  for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-  worker();
-  for (auto& th : pool) th.join();
+  });
   return 0;
 }
 
@@ -383,12 +535,12 @@ int tss_fill_range(void* h, const int64_t* sids, int64_t nsids,
   Store* s = static_cast<Store*>(h);
   std::vector<SeriesBuffer*> bufs;
   if (!s->snapshot(sids, nsids, &bufs)) return -1;
-  if (threads < 1) threads = 1;
-  std::atomic<int64_t> next{0};
-  auto worker = [&]() {
-    for (;;) {
-      int64_t i = next.fetch_add(1);
-      if (i >= nsids) break;
+  int64_t points = 0;
+  for (int64_t i = 0; i < nsids; ++i) points += counts[i];
+  const int64_t grain =  // by the mean series: one long one is one item
+      items_per(kFillPoints, points / std::max<int64_t>(nsids, 1));
+  parallel_for(threads, nsids, grain, [&](int64_t r0, int64_t r1) {
+    for (int64_t i = r0; i < r1; ++i) {
       SeriesBuffer* buf = bufs[i];
       std::lock_guard<std::mutex> lock(buf->mu);
       buf->ensure_sorted_locked();
@@ -417,11 +569,7 @@ int tss_fill_range(void* h, const int64_t* sids, int64_t nsids,
         series_idx_out[off + j] = (int32_t)i;
       }
     }
-  };
-  std::vector<std::thread> pool;
-  for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-  worker();
-  for (auto& th : pool) th.join();
+  });
   return 0;
 }
 
@@ -495,12 +643,9 @@ void bucket_reduce_rows(const std::vector<SeriesBuffer*>& bufs,
                         double* sum_out, double* cnt_out,
                         double* min_out, double* max_out, int threads) {
   const int64_t nsids = (int64_t)bufs.size();
-  std::atomic<int64_t> next{0};
   const double inf = std::numeric_limits<double>::infinity();
-  auto worker = [&]() {
-    for (;;) {
-      int64_t i = next.fetch_add(1);
-      if (i >= nsids) break;
+  parallel_for(threads, nsids, kReduceRows, [&](int64_t r0, int64_t r1) {
+    for (int64_t i = r0; i < r1; ++i) {
       double* srow = sum_out + i * nbuckets;
       double* crow = cnt_out + i * nbuckets;
       double* mnrow = MINMAX ? min_out + i * nbuckets : nullptr;
@@ -524,11 +669,7 @@ void bucket_reduce_rows(const std::vector<SeriesBuffer*>& bufs,
             }
           });
     }
-  };
-  std::vector<std::thread> pool;
-  for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-  worker();
-  for (auto& th : pool) th.join();
+  });
 }
 
 // tss_bucket_grid's statistic of one bucket (fn codes, the Python
@@ -539,8 +680,8 @@ enum GridFn { kSum = 0, kCount = 1, kAvg = 2, kMin = 3, kMax = 4 };
 // Rows [0, nsids) of the [s_pad, b_pad] grid are series, each written
 // once, left to right: NaN / 0 up to the next bucket with data, the
 // statistic / 1 there, NaN / 0 to the end of the padded row. Rows
-// [nsids, s_pad) are padding. Workers claim rows in chunks, so the
-// page faults of the caller's fresh buffers spread over the pool.
+// [nsids, s_pad) are padding. Participants claim rows in chunks, so
+// the page faults of the caller's fresh buffers spread over them.
 template <typename T, bool MINMAX>
 int64_t bucket_grid_rows(const std::vector<SeriesBuffer*>& bufs,
                          int64_t start_ms, int64_t end_ms, int64_t t0,
@@ -548,49 +689,37 @@ int64_t bucket_grid_rows(const std::vector<SeriesBuffer*>& bufs,
                          int64_t s_pad, int64_t b_pad, T* grid,
                          uint8_t* mask, int threads) {
   const int64_t nsids = (int64_t)bufs.size();
-  const int64_t kChunk = 256;
   const T nan = std::numeric_limits<T>::quiet_NaN();
-  std::atomic<int64_t> next{0};
   std::atomic<int64_t> num_points{0};
-  auto worker = [&]() {
+  parallel_for(threads, s_pad, kGridRows, [&](int64_t r0, int64_t r1) {
     int64_t points = 0;
-    for (;;) {
-      int64_t r0 = next.fetch_add(kChunk);
-      if (r0 >= s_pad) break;
-      int64_t r1 = std::min(r0 + kChunk, s_pad);
-      for (int64_t i = r0; i < r1; ++i) {
-        T* grow = grid + i * b_pad;
-        uint8_t* mrow = mask + i * b_pad;
-        int64_t done = 0;  // columns of this row already written
-        if (i < nsids) {
-          reduce_series<MINMAX>(
-              bufs[i], start_ms, end_ms, t0, interval_ms, nbuckets,
-              [&](int64_t b, double sum, double cnt, double mn,
-                  double mx) {
-                if (cnt == 0.0) return;  // only NaNs stored: a hole
-                std::fill(grow + done, grow + b, nan);
-                std::memset(mrow + done, 0, b - done);
-                double v = fn == kSum     ? sum
-                           : fn == kCount ? cnt
-                           : fn == kAvg   ? sum / cnt
-                           : fn == kMin   ? mn
-                                          : mx;
-                grow[b] = static_cast<T>(v);
-                mrow[b] = 1;
-                done = b + 1;
-                points += (int64_t)cnt;
-              });
-        }
-        std::fill(grow + done, grow + b_pad, nan);
-        std::memset(mrow + done, 0, b_pad - done);
+    for (int64_t i = r0; i < r1; ++i) {
+      T* grow = grid + i * b_pad;
+      uint8_t* mrow = mask + i * b_pad;
+      int64_t done = 0;  // columns of this row already written
+      if (i < nsids) {
+        reduce_series<MINMAX>(
+            bufs[i], start_ms, end_ms, t0, interval_ms, nbuckets,
+            [&](int64_t b, double sum, double cnt, double mn, double mx) {
+              if (cnt == 0.0) return;  // only NaNs stored: a hole
+              std::fill(grow + done, grow + b, nan);
+              std::memset(mrow + done, 0, b - done);
+              double v = fn == kSum     ? sum
+                         : fn == kCount ? cnt
+                         : fn == kAvg   ? sum / cnt
+                         : fn == kMin   ? mn
+                                        : mx;
+              grow[b] = static_cast<T>(v);
+              mrow[b] = 1;
+              done = b + 1;
+              points += (int64_t)cnt;
+            });
       }
+      std::fill(grow + done, grow + b_pad, nan);
+      std::memset(mrow + done, 0, b_pad - done);
     }
-    num_points.fetch_add(points);
-  };
-  std::vector<std::thread> pool;
-  for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-  worker();
-  for (auto& th : pool) th.join();
+    num_points.fetch_add(points, std::memory_order_relaxed);
+  });
   return num_points.load();
 }
 
@@ -619,7 +748,6 @@ int tss_bucket_reduce(void* h, const int64_t* sids, int64_t nsids,
   std::vector<SeriesBuffer*> bufs;
   if (!s->snapshot(sids, nsids, &bufs)) return -1;
   if (interval_ms <= 0 || nbuckets <= 0) return -1;
-  if (threads < 1) threads = 1;
   if (min_out && max_out)
     bucket_reduce_rows<true>(bufs, start_ms, end_ms, t0, interval_ms,
                              nbuckets, sum_out, cnt_out, min_out,
@@ -649,7 +777,6 @@ int64_t tss_bucket_grid(void* h, const int64_t* sids, int64_t nsids,
   if (interval_ms <= 0 || nbuckets <= 0 || s_pad < nsids ||
       b_pad < nbuckets || fn < kSum || fn > kMax)
     return -1;
-  if (threads < 1) threads = 1;
   const bool minmax = fn == kMin || fn == kMax;
 #define TSS_GRID(T, MM)                                                \
   bucket_grid_rows<T, MM>(bufs, start_ms, end_ms, t0, interval_ms,     \
@@ -1071,12 +1198,14 @@ int64_t tss_parse_import(const char* buf, int64_t len, int64_t* ts_out,
                          int64_t* rep_off, int64_t* rep_len,
                          int64_t max_groups, int64_t* nlines_out,
                          int threads) {
-  if (threads < 1) threads = 1;
-  // chunk boundaries aligned to line starts
+  // chunk boundaries aligned to line starts: a chunk a participant, and
+  // none under kImportBytes (a telnet batch of a few lines is one chunk)
+  const int64_t parts =
+      std::clamp<int64_t>(len / kImportBytes, 1, std::max(threads, 1));
   std::vector<int64_t> starts;
   starts.push_back(0);
-  for (int t = 1; t < threads; ++t) {
-    int64_t pos = len * t / threads;
+  for (int64_t t = 1; t < parts; ++t) {
+    int64_t pos = len * t / parts;
     const char* nl =
         (const char*)memchr(buf + pos, '\n', (size_t)(len - pos));
     int64_t aligned = nl ? (nl - buf) + 1 : len;
@@ -1089,30 +1218,19 @@ int64_t tss_parse_import(const char* buf, int64_t len, int64_t* ts_out,
   int nchunks = (int)starts.size() - 1;
   // per-chunk line counts -> global line bases
   std::vector<int64_t> nlines(nchunks), base(nchunks);
-  {
-    std::atomic<int> next{0};
-    auto worker = [&]() {
-      for (;;) {
-        int c = next.fetch_add(1);
-        if (c >= nchunks) break;
-        int64_t cnt = 0;
-        const char* p = buf + starts[c];
-        const char* e = buf + starts[c + 1];
-        // each line ends with '\n' except possibly the buffer's last
-        while ((p = (const char*)memchr(p, '\n', e - p)) != nullptr) {
-          ++cnt;
-          ++p;
-        }
-        if (c == nchunks - 1 && len > 0 && buf[len - 1] != '\n')
-          ++cnt;  // trailing line without newline
-        nlines[c] = cnt;
-      }
-    };
-    std::vector<std::thread> pool;
-    for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-    worker();
-    for (auto& th : pool) th.join();
-  }
+  parallel_for(nchunks, nchunks, 1, [&](int64_t c, int64_t) {
+    int64_t cnt = 0;
+    const char* p = buf + starts[c];
+    const char* e = buf + starts[c + 1];
+    // each line ends with '\n' except possibly the buffer's last
+    while ((p = (const char*)memchr(p, '\n', e - p)) != nullptr) {
+      ++cnt;
+      ++p;
+    }
+    if (c == nchunks - 1 && len > 0 && buf[len - 1] != '\n')
+      ++cnt;  // trailing line without newline
+    nlines[c] = cnt;
+  });
   int64_t total_lines = 0;
   for (int c = 0; c < nchunks; ++c) {
     base[c] = total_lines;
@@ -1121,22 +1239,10 @@ int64_t tss_parse_import(const char* buf, int64_t len, int64_t* ts_out,
   *nlines_out = total_lines;
   // parse each chunk with a local group table
   std::vector<LocalGroups> locals(nchunks);
-  {
-    std::atomic<int> next{0};
-    auto worker = [&]() {
-      for (;;) {
-        int c = next.fetch_add(1);
-        if (c >= nchunks) break;
-        parse_import_range(buf, starts[c], starts[c + 1], base[c],
-                           ts_out, val_out, int_out, group_out,
-                           err_out, &locals[c]);
-      }
-    };
-    std::vector<std::thread> pool;
-    for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-    worker();
-    for (auto& th : pool) th.join();
-  }
+  parallel_for(nchunks, nchunks, 1, [&](int64_t c, int64_t) {
+    parse_import_range(buf, starts[c], starts[c + 1], base[c], ts_out,
+                       val_out, int_out, group_out, err_out, &locals[c]);
+  });
   // merge local tables into the global numbering and remap gids
   std::unordered_map<std::string, int64_t> global;
   std::vector<std::vector<int64_t>> remap(nchunks);
@@ -1157,24 +1263,12 @@ int64_t tss_parse_import(const char* buf, int64_t len, int64_t* ts_out,
       remap[c][kv.second] = gid;
     }
   }
-  {
-    // local gid -> global gid, every chunk (the merge renumbers in
-    // hash-iteration order even for a single chunk)
-    std::atomic<int> next{0};
-    auto worker = [&]() {
-      for (;;) {
-        int c = next.fetch_add(1);
-        if (c >= nchunks) break;
-        for (int64_t i = base[c]; i < base[c] + nlines[c]; ++i)
-          if (group_out[i] >= 0)
-            group_out[i] = remap[c][group_out[i]];
-      }
-    };
-    std::vector<std::thread> pool;
-    for (int t = 1; t < threads; ++t) pool.emplace_back(worker);
-    worker();
-    for (auto& th : pool) th.join();
-  }
+  // local gid -> global gid, every chunk (the merge renumbers in
+  // hash-iteration order even for a single chunk)
+  parallel_for(nchunks, nchunks, 1, [&](int64_t c, int64_t) {
+    for (int64_t i = base[c]; i < base[c] + nlines[c]; ++i)
+      if (group_out[i] >= 0) group_out[i] = remap[c][group_out[i]];
+  });
   return (int64_t)global.size();
 }
 

@@ -483,6 +483,46 @@ def test_a_loader_outside_any_request_records_its_stages():
 # the process's own counters
 # ---------------------------------------------------------------------
 
+@pytest.mark.parametrize("backend", ["native", "memory"])
+def test_the_native_stores_pool_is_counted_at_api_stats(backend):
+    # PR 39: the native store's parallel passes share one pool of parked
+    # threads; a served panel is a pass on its caller alone, and the
+    # threads the pool has made do not grow with requests
+    tsdb = mk_tsdb(**{"tsd.storage.backend": backend})
+    router = HttpRpcRouter(tsdb)
+
+    def pool():
+        rows = json.loads(router.handle(req("GET", "/api/stats")).body)
+        passes = {r["tags"]["mode"]: r["value"] for r in rows
+                  if r["metric"] == "tsd.storage.native.passes"}
+        threads = [r["value"] for r in rows
+                   if r["metric"] == "tsd.storage.native.pool_threads"]
+        return passes, threads
+
+    try:
+        written, errors = tsdb.import_buffer(import_text(),
+                                             durable=False)
+        assert written == 16 * 60 and not errors
+        if backend == "memory":     # the Python twin has no threads
+            assert pool() == ({}, [])
+            return
+        (passes, (threads,)) = pool()
+        assert set(passes) == {"inline", "pooled"}
+        body = json.dumps({
+            "start": BASE * 1000, "end": (BASE + 600) * 1000,
+            "queries": [{"metric": "sys.stage", "aggregator": "max",
+                         "downsample": "1m-max"}]}).encode()
+        for _ in range(3):
+            assert router.handle(
+                req("POST", "/api/query", body)).status == 200
+        after, (threads_after,) = pool()
+        assert after == {"inline": passes["inline"] + 3,
+                         "pooled": passes["pooled"]}
+        assert threads_after == threads
+    finally:
+        tsdb.shutdown()
+
+
 def test_the_compile_counter_moves_on_a_new_shape_only():
     import jax
     jax.clear_caches()
