@@ -114,6 +114,7 @@ KNOWN_SPANS: frozenset[str] = frozenset({
     "query.admission",       # admission + worker-queue wait
     "query.streaming_lookup",  # CQ registry try_serve
     "query.plan",            # store/tier selection, filters, groups
+    "query.filter_resolve",  # one value filter -> its tagv ids (in plan)
     "sketch.fold",           # lifecycle/manager.py demote-time
                              # quantile-sketch fold (fifth stat column)
     "query.execute",         # scan + device pipeline (parent stage)
@@ -782,6 +783,9 @@ class Tracer:
         # predicate over the name of every distinct value of its
         # key), "presence" (the key's column alone: *, .*, not_key)
         self.filters = {"ids": 0, "walk": 0, "presence": 0}
+        # names of stored tag values those filters read (the
+        # ``query.filter_resolve`` spans' ``names_read``, summed)
+        self.filter_names_read = 0
         # assemble stages, by where the groups' common and aggregated
         # tags were read: "index" (the plan index's cached layout),
         # "small" (a sort of the request's own rows, few enough of an
@@ -1048,8 +1052,10 @@ class Tracer:
         that built a grid (tag ``fused``) in ``grid_builds``, every
         ``query.plan`` that reached its filters (tag ``index``) in
         ``plans`` and its filters (tags ``resolve_<way>``) in
-        ``filters``, every ``query.assemble`` by its tag ``tags`` in
-        ``assembles``. Returns the histogram observations made."""
+        ``filters``, every ``query.filter_resolve``'s ``names_read``
+        in ``filter_names_read``, every ``query.assemble`` by its tag
+        ``tags`` in ``assembles``. Returns the histogram observations
+        made."""
         kids: dict[str, list[SpanRecord]] = {}
         for s in spans:
             kids.setdefault(s.parent_id, []).append(s)
@@ -1059,6 +1065,7 @@ class Tracer:
         builds = []
         plans = []
         filters = []
+        names_read = 0
         assembles = []
         for s in [root] + spans:
             self_ms, occupied = s.duration_ms, s.occupied_ms
@@ -1091,6 +1098,8 @@ class Tracer:
                 filters += [(way, s.tags["resolve_" + way])
                             for way in self.filters
                             if "resolve_" + way in s.tags]
+            elif s.name == "query.filter_resolve":
+                names_read += s.tags.get("names_read", 0)
             elif s.name == "query.assemble" and s.tags.get("tags") \
                     in self.assembles:
                 assembles.append(s.tags["tags"])
@@ -1106,6 +1115,7 @@ class Tracer:
                 self.plans[state] += 1
             for way, n in filters:
                 self.filters[way] += n
+            self.filter_names_read += names_read
             for way in assembles:
                 self.assembles[way] += 1
         return observed
@@ -1199,6 +1209,7 @@ class Tracer:
             builds = sorted(self.grid_builds.items())
             plans = sorted(self.plans.items())
             filters = sorted(self.filters.items())
+            names_read = self.filter_names_read
             assembles = sorted(self.assembles.items())
         for stage, ms in idle:
             collector.record("device.idle_stage_ms", ms, stage=stage)
@@ -1211,6 +1222,7 @@ class Tracer:
             collector.record("query.plan", n, index=state)
         for way, n in filters:
             collector.record("query.filter", n, resolve=way)
+        collector.record("query.filter.names_read", names_read)
         for way, n in assembles:
             collector.record("query.assemble", n, tags=way)
 

@@ -34,16 +34,22 @@ request:
   over a key of 1,000,000 hosts;
 - ``presence``: filters that match every value (``*``, ``.*``) and
   ``not_key`` read the column alone.
+
+Turning one value filter into its tagv ids, the ``ids`` way or the
+``walk`` way, is the stage ``query.filter_resolve`` (a child of
+``query.plan``; tags ``way``, ``names_read``, ``matched``), and the
+names of stored values read add up in ``tsd.query.filter.names_read``.
 """
 
 from __future__ import annotations
 
-import fnmatch
 import re
 from collections import Counter
 from typing import Sequence
 
 import numpy as np
+
+from opentsdb_tpu.obs.trace import trace_span
 
 _FILTER_RE = re.compile(r"^(\w+)\((.*)\)$", re.DOTALL)
 
@@ -154,7 +160,8 @@ class TagVNotILiteralOrFilter(TagVILiteralOrFilter):
 
 class TagVWildcardFilter(TagVFilter):
     """``wildcard(*web*)`` — ``*`` globs, case sensitive
-    (ref: TagVWildcardFilter.java:34)"""
+    (ref: TagVWildcardFilter.java:34). ``*`` alone is special: ``?``,
+    ``[`` and every other character stand for themselves."""
     filter_name = "wildcard"
     case_insensitive = False
 
@@ -165,7 +172,11 @@ class TagVWildcardFilter(TagVFilter):
                 f"wildcard filter must contain '*': {expr!r}")
         if self.case_insensitive:
             expr = expr.lower()
-        self._regex = re.compile(fnmatch.translate(expr))
+        # anchored by ``match`` and ``\Z``: ``fullmatch`` costs a
+        # name 10 ns more, 1% of a walk over a million names
+        self._regex = re.compile(
+            "(?s:" + ".*".join(re.escape(part)
+                               for part in expr.split("*")) + r")\Z")
         self.matches_all = expr.strip("*") == ""
 
     def match_value(self, value: str) -> bool:
@@ -358,7 +369,9 @@ class FilterEvaluator:
         the column's distinct values. ``tally`` counts the filters
         evaluated each way (``resolve_ids``, ``resolve_walk``,
         ``resolve_presence``) and the names of stored values read
-        (``names_read``): the ``query.plan`` span's tags.
+        (``names_read``): the ``query.plan`` span's tags. A filter
+        that becomes tagv ids (``ids``, ``walk``) does so inside a
+        ``query.filter_resolve`` span of its own.
         """
         if tally is None:
             tally = Counter()
@@ -392,14 +405,23 @@ class FilterEvaluator:
                 elif (exact := f.exact_names()) is not None:
                     tally["resolve_ids"] += 1
                     names, negated = exact
-                    ids = self.exact_tagv_ids(names)
+                    with trace_span("query.filter_resolve", way="ids",
+                                    names_read=0) as span:
+                        ids = self.exact_tagv_ids(names)
+                        if span is not None:
+                            span.tag(matched=len(ids))
                     if not ids and not negated:
                         return np.zeros(n, dtype=bool)
                     keep &= _member_mask(col, ids, negated)
                 else:
                     tally["resolve_walk"] += 1
-                    candidates = tags.distinct(kid)
+                    with trace_span("query.filter_resolve",
+                                    way="walk") as span:
+                        candidates = tags.distinct(kid)
+                        ids = self.matching_tagv_ids(f, candidates)
+                        if span is not None:
+                            span.tag(names_read=len(candidates),
+                                     matched=len(ids))
                     tally["names_read"] += len(candidates)
-                    keep &= np.isin(
-                        col, self.matching_tagv_ids(f, candidates))
+                    keep &= np.isin(col, ids)
         return keep

@@ -60,6 +60,29 @@ class TestPredicates:
     def test_iwildcard(self):
         assert get_filter("h", "iwildcard(WEB*)").match_value("web01")
 
+    @pytest.mark.parametrize("expr, value, hit", [
+        # ``*`` alone is special (ref: TagVWildcardFilter splits on it
+        # and compares the parts as they are): a glob's ``?`` and
+        # ``[...]`` stand for themselves
+        ("web?1*", "webx12", False), ("web?1*", "web?12", True),
+        ("h[01]*", "h[01]x", True), ("h[01]*", "h0x", False),
+        ("*[!a]", "b", False), ("*[!a]", "x[!a]", True),
+        # and so does what a regular expression would read
+        ("a.b*", "axb1", False), ("a.b*", "a.b1", True),
+        ("*+)", "x+)", True), ("w(eb*", "w(eb1", True),
+        ("*\\d", "x\\d", True), ("*\\d", "x7", False),
+        # a part with no ``*`` beside it is anchored at its end
+        ("web*01", "web5501", True), ("web*01", "xweb01", False),
+        ("web*01", "web011", False), ("**web**", "aweba", True),
+        ("a*b*c", "abc", True), ("a*b*c", "acb", False),
+        ("web*", "web\nx", True)])
+    @pytest.mark.parametrize("ftype", ["wildcard", "iwildcard"])
+    def test_only_the_star_is_special(self, ftype, expr, value, hit):
+        if ftype == "iwildcard":
+            expr = expr.upper()
+        f = get_filter("h", f"{ftype}({expr})")
+        assert f.match_value(value) is hit
+
     def test_regexp(self):
         f = get_filter("h", "regexp(web\\d+)")
         assert f.match_value("web01")

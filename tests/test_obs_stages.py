@@ -234,6 +234,50 @@ class TestSelfTimeAndOccupancy:
             == pytest.approx(40.0)
         assert rows[("tsd.device.dispatches", None)] == 1
 
+    @pytest.mark.parametrize("walks, read", [
+        ((), 0), ((1_000_000,), 1_000_000), ((100, 2000), 2100)])
+    def test_resolving_a_filter_is_the_plans_child(self, monkeypatch,
+                                                   walks, read):
+        # PR 40: what the plan spent turning a filter into tagv ids
+        # is a stage, so the plan's self time is the rest of it, and
+        # the names the walks read add up in a counter of their own
+        clock = Clock(monkeypatch)
+        tracer, stats = mk_tracer()
+        ctx = tracer.start_request("query.http")
+        with trace_mod.use(ctx):
+            ex = ctx.begin("query.execute")
+            plan = ctx.begin("query.plan", index="hit")
+            ids = ctx.begin("query.filter_resolve", way="ids",
+                            names_read=0)
+            clock.ms = 1.0
+            ids.tag(matched=8)
+            ids.finish()
+            for n in walks:
+                walk = ctx.begin("query.filter_resolve", way="walk")
+                clock.ms += 10.0
+                walk.tag(names_read=n, matched=1)
+                walk.finish()
+            clock.ms += 4.0
+            plan.finish()
+            ex.finish()
+        tracer.finish(ctx)
+        spans = tracer.get(ctx.trace_id).spans
+        resolved = [s for s in spans
+                    if s.name == "query.filter_resolve"]
+        assert [s.tags["way"] for s in resolved] \
+            == ["ids"] + ["walk"] * len(walks)
+        assert {s.parent_id for s in resolved} == {plan.span_id}
+        assert tracer.filter_names_read == read
+        assert tracer.idle_stage_ms["query.plan"] == pytest.approx(4.0)
+        assert tracer.idle_stage_ms["query.filter_resolve"] \
+            == pytest.approx(1.0 + 10.0 * len(walks))
+        rows = {r[0]: r[1] for r in _records(tracer)}
+        assert rows["tsd.query.filter.names_read"] == read
+        # the plan's self time is what no filter_resolve covers
+        assert stats.stage_self["query.plan"].sum == pytest.approx(4.0)
+        assert stats.stage_latency["query.filter_resolve"].count \
+            == 1 + len(walks)
+
     @pytest.mark.parametrize("host, dispatches", [(False, 1),
                                                   (True, 0)])
     def test_only_a_device_placed_program_occupies(self, host,
