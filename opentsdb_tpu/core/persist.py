@@ -302,14 +302,7 @@ def _load_uids(uids, data_dir: str) -> None:
     for kind in ("metric", "tagk", "tagv"):
         registry = uids.by_kind(kind)
         entry = doc.get(kind, {})
-        with registry._lock:
-            registry._name_to_id = {n: int(i)
-                                    for n, i in entry.get("names",
-                                                          {}).items()}
-            registry._id_to_name = {i: n
-                                    for n, i in
-                                    registry._name_to_id.items()}
-            registry._max_id = int(entry.get("max_id", 0))
+        registry.load(entry.get("names", {}), entry.get("max_id", 0))
 
 
 def _save_timeseries(store, directory: str) -> None:

@@ -74,6 +74,11 @@ class UniqueId:
         self._id_to_name: dict[int, str] = {}
         self._sorted_names: list[str] | None = None  # suggest index
         self._max_id = 0
+        # bumped, under the lock, by every change of what an ASSIGNED
+        # id is called (rename, delete, load): what versions a cache of
+        # names by id. An assignment leaves it: the new id is in no
+        # series yet
+        self._generation = 0
         self._rng = random.Random(0xC0FFEE)
         # cache-statistics parity with UniqueId.java:105-114
         self.cache_hits = 0
@@ -102,6 +107,13 @@ class UniqueId:
     def has_name(self, name: str) -> bool:
         with self._lock:
             return name in self._name_to_id
+
+    @property
+    def generation(self) -> int:
+        """Differs from an earlier reading once an id assigned before
+        that reading has changed its name or lost it. Names read by id
+        AFTER a reading are good for as long as it repeats."""
+        return self._generation
 
     # -- assignment (ref: UniqueId.java:596-625, :865) --------------------
 
@@ -163,6 +175,7 @@ class UniqueId:
             self._name_to_id[new_name] = uid
             self._id_to_name[uid] = new_name
             self._sorted_names = None
+            self._generation += 1
 
     def delete(self, name: str) -> None:
         """(ref: UniqueId.java deleteAsync, 2.2+)"""
@@ -172,6 +185,18 @@ class UniqueId:
             uid = self._name_to_id.pop(name)
             self._id_to_name.pop(uid, None)
             self._sorted_names = None
+            self._generation += 1
+
+    def load(self, names: dict[str, int], max_id: int) -> None:
+        """Replace the whole dictionary (a snapshot's, at start-up)."""
+        forward = {name: int(uid) for name, uid in names.items()}
+        with self._lock:
+            self._name_to_id = forward
+            self._id_to_name = {uid: name
+                                for name, uid in forward.items()}
+            self._max_id = int(max_id)
+            self._sorted_names = None
+            self._generation += 1
 
     # -- suggest (ref: UniqueId.java suggest / TSDB.java:1762-1816) -------
 

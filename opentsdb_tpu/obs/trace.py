@@ -779,12 +779,19 @@ class Tracer:
         # first), "bypass" (a selection that is not a whole metric)
         self.plans = {"hit": 0, "built": 0, "bypass": 0}
         # filters evaluated, by how each became a series mask: "ids"
-        # (the UIDs of the exact names it holds), "walk" (its
-        # predicate over the name of every distinct value of its
-        # key), "presence" (the key's column alone: *, .*, not_key)
-        self.filters = {"ids": 0, "walk": 0, "presence": 0}
-        # names of stored tag values those filters read (the
-        # ``query.filter_resolve`` spans' ``names_read``, summed)
+        # (the UIDs of the exact names it holds), "table" (its
+        # predicate over the plan index's table of the key's names,
+        # all at once), "walk" (its predicate over the name of every
+        # distinct value of its key, read one by one), "presence"
+        # (the key's column alone: *, .*, not_key)
+        self.filters = {"ids": 0, "table": 0, "walk": 0, "presence": 0}
+        # the "table" ones, by what the table cost them: "hit" (it
+        # was there, of the dictionary's generation) or "built" (this
+        # filter read the key's names to make it)
+        self.filter_tables = {"hit": 0, "built": 0}
+        # names of stored tag values those filters read from the UID
+        # dictionary (the ``query.filter_resolve`` spans'
+        # ``names_read``, summed)
         self.filter_names_read = 0
         # assemble stages, by where the groups' common and aggregated
         # tags were read: "index" (the plan index's cached layout),
@@ -1053,7 +1060,8 @@ class Tracer:
         ``query.plan`` that reached its filters (tag ``index``) in
         ``plans`` and its filters (tags ``resolve_<way>``) in
         ``filters``, every ``query.filter_resolve``'s ``names_read``
-        in ``filter_names_read``, every ``query.assemble`` by its tag
+        in ``filter_names_read`` and its tag ``table`` in
+        ``filter_tables``, every ``query.assemble`` by its tag
         ``tags`` in ``assembles``. Returns the histogram observations
         made."""
         kids: dict[str, list[SpanRecord]] = {}
@@ -1066,6 +1074,7 @@ class Tracer:
         plans = []
         filters = []
         names_read = 0
+        tables = []
         assembles = []
         for s in [root] + spans:
             self_ms, occupied = s.duration_ms, s.occupied_ms
@@ -1100,6 +1109,8 @@ class Tracer:
                             if "resolve_" + way in s.tags]
             elif s.name == "query.filter_resolve":
                 names_read += s.tags.get("names_read", 0)
+                if s.tags.get("table") in self.filter_tables:
+                    tables.append(s.tags["table"])
             elif s.name == "query.assemble" and s.tags.get("tags") \
                     in self.assembles:
                 assembles.append(s.tags["tags"])
@@ -1116,6 +1127,8 @@ class Tracer:
             for way, n in filters:
                 self.filters[way] += n
             self.filter_names_read += names_read
+            for state in tables:
+                self.filter_tables[state] += 1
             for way in assembles:
                 self.assembles[way] += 1
         return observed
@@ -1210,6 +1223,7 @@ class Tracer:
             plans = sorted(self.plans.items())
             filters = sorted(self.filters.items())
             names_read = self.filter_names_read
+            tables = sorted(self.filter_tables.items())
             assembles = sorted(self.assembles.items())
         for stage, ms in idle:
             collector.record("device.idle_stage_ms", ms, stage=stage)
@@ -1223,6 +1237,8 @@ class Tracer:
         for way, n in filters:
             collector.record("query.filter", n, resolve=way)
         collector.record("query.filter.names_read", names_read)
+        for state, n in tables:
+            collector.record("query.filter.table", n, state=state)
         for way, n in assembles:
             collector.record("query.assemble", n, tags=way)
 

@@ -245,6 +245,13 @@ class TagMatrix:
             return np.empty(0, dtype=np.int64)
         return np.unique(col[col >= 0])
 
+    def name_table(self, kid: int, tagv, folded: bool
+                   ) -> tuple[None, int, bool]:
+        """A matrix of one request has nowhere to keep the names of
+        its values (:meth:`PlanIndex.name_table` has): none, no name
+        read, nothing built."""
+        return None, 0, False
+
     def select(self, mask_or_idx) -> "TagMatrix":
         origin = self.origin
         if origin is not None:
@@ -517,9 +524,29 @@ class PlanIndex:
     The tag index only appends, so its series count versions all of
     it: the engine keeps one per (store, metric) in
     ``tsdb._tagmat_cache`` and drops it whole when ``version`` no
-    longer equals the index's length. UID names are not kept here: a
-    filter reads the live dictionary per request (its own names' ids,
-    or, a pattern, the name of every id of ``distinct``).
+    longer equals the index's length. One part has a second version:
+    the NAMES of a key's distinct ids (``name_table``: a
+    :class:`~opentsdb_tpu.query.filters.NameTable`, built by the first
+    filter that matches stored names of that key), which the series
+    count cannot vouch for, since ``rename`` and ``delete`` change a
+    name and no series. A table carries the UID dictionary's
+    ``generation`` as read before its names were, and a request that
+    reads another generation builds it again (one read a name, what
+    every such request cost before there was a table). A filter that
+    holds exact names reads the live dictionary's forward map and no
+    table.
+
+    What a table costs, a key: 8 bytes a name for the list of names
+    (references to the dictionary's own strings) and names x longest
+    name bytes for the byte matrix, which is refused (the key is then
+    walked, a request) where it would exceed
+    ``NameArrays.MAX_PAD`` = 8 times the names' own bytes; as much
+    again for the case-folded matrix once an ``i`` filter has asked,
+    and 4 bytes a name for each matrix's lengths, 8 for its ids where
+    the names are not all of one length. At ``fleet-1m``: ``host``
+    (1,000,000 names of 8 bytes) 8 MB of list, 8 MB of matrix, 4 MB of
+    lengths, 12 MB more once folded; ``dc``, ``rack``, ``fleet`` a few
+    KB each. Nothing is built for a key no pattern names.
 
     The lazy parts build under one lock (two sub-queries of a request
     plan side by side: the second waits and reads what the first
@@ -530,8 +557,8 @@ class PlanIndex:
 
     LABEL_SETS = 8
 
-    __slots__ = ("version", "tags", "_keys_of", "_distinct", "_labels",
-                 "_lock")
+    __slots__ = ("version", "tags", "_keys_of", "_distinct", "_names",
+                 "_labels", "_lock")
 
     def __init__(self, version: int, tags: TagMatrix):
         self.version = version
@@ -543,6 +570,9 @@ class PlanIndex:
         # tsdlint: allow[unbounded-growth] keyed by tag key: at most
         # one entry a column of ``tags``; gone with the index
         self._distinct: dict[int, np.ndarray] = {}
+        # tsdlint: allow[unbounded-growth] keyed by tag key, like
+        # ``_distinct``; an entry is replaced, never added to
+        self._names: dict[int, filters_mod.NameTable] = {}
         self._labels: OrderedDict[tuple, _LabelSet] = OrderedDict()
         self._lock = threading.Lock()
 
@@ -561,6 +591,33 @@ class PlanIndex:
                 if found is None:
                     found = self._distinct[kid] = self.tags.distinct(kid)
         return found
+
+    def name_table(self, kid: int, tagv, folded: bool
+                   ) -> tuple["filters_mod.NameTable", int, bool]:
+        """``(table, names read, built)``: the names of
+        ``distinct(kid)`` in ``tagv`` (the tagv UID dictionary) as of
+        its present generation, with the case-folded arrays when
+        ``folded`` asks for them; how many names this call read from
+        the dictionary (all of them, or 0), and whether it built
+        anything."""
+        found = self._names.get(kid)
+        if found is not None and found.generation == tagv.generation \
+                and found.has(folded):
+            return found, 0, False
+        ids, read = self.distinct(kid), 0
+        with self._lock:
+            # read before the names are: a rename during the build
+            # leaves a table the next request will not trust
+            generation = tagv.generation
+            found = self._names.get(kid)
+            if found is None or found.generation != generation:
+                found = self._names[kid] = filters_mod.NameTable(
+                    ids, tagv, generation)
+                read = len(ids)
+            fold = not found.has(folded)
+            if fold:
+                found.fold()
+        return found, read, read > 0 or fold
 
     def _label_set(self, key: tuple) -> _LabelSet:
         """Called with the lock held."""

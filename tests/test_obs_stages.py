@@ -306,6 +306,47 @@ class TestSelfTimeAndOccupancy:
             ("grid", prog.tags["placement"], "linear"): 1}
 
 
+    @pytest.mark.parametrize("states, want", [
+        ((), {"hit": 0, "built": 0}),
+        (("built",), {"hit": 0, "built": 1}),
+        (("hit", "hit", "built", "mended"), {"hit": 2, "built": 1})])
+    def test_a_name_tables_filters_count_by_what_it_cost(self, states,
+                                                         want):
+        # PR 41: way=table on the same stage; the tag ``table`` says
+        # whether the filter found the key's names or read them, and
+        # a value the tracer does not know counts nowhere
+        tracer, _stats = mk_tracer()
+        ctx = tracer.start_request("query.http")
+        with trace_mod.use(ctx):
+            plan = ctx.begin("query.plan", index="hit",
+                             resolve_table=len(states), resolve_walk=1)
+            for state in states:
+                ctx.begin("query.filter_resolve", way="table",
+                          table=state, matched=3,
+                          names_read=7 if state == "built" else 0
+                          ).finish()
+            ctx.begin("query.filter_resolve", way="walk",
+                      names_read=5, matched=0).finish()
+            plan.finish()
+        tracer.finish(ctx)
+        assert tracer.filter_tables == want
+        assert tracer.filters == {"ids": 0, "table": len(states),
+                                  "walk": 1, "presence": 0}
+        assert tracer.filter_names_read == 5 + 7 * want["built"]
+        rows = {(r[0], r[2].get("state") or r[2].get("resolve")): r[1]
+                for r in _records(tracer)
+                if r[0].startswith("tsd.query.filter")}
+        assert rows == {
+            ("tsd.query.filter", "ids"): 0,
+            ("tsd.query.filter", "table"): len(states),
+            ("tsd.query.filter", "walk"): 1,
+            ("tsd.query.filter", "presence"): 0,
+            ("tsd.query.filter.names_read", None):
+                5 + 7 * want["built"],
+            ("tsd.query.filter.table", "hit"): want["hit"],
+            ("tsd.query.filter.table", "built"): want["built"]}
+
+
 def _records(tracer):
     from opentsdb_tpu.stats.stats import StatsCollector
     c = StatsCollector("tsd")

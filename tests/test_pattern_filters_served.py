@@ -2,8 +2,9 @@
 
 ``host=wildcard(h00123*)`` as the benchmark's cell
 ``fleet-1m.wildcard-lookup`` sends it, and its four siblings that
-``FilterEvaluator.apply`` still resolves by walking the key's names
-(``iwildcard``, ``regexp``, ``iliteral_or``, ``not_iliteral_or``): a
+``FilterEvaluator.apply`` resolves against the plan index's table of
+the key's names (``iwildcard``, ``regexp``, ``iliteral_or``,
+``not_iliteral_or``; PR 41, before it a walk of the names): a
 TSD on a real socket, over a store of ten thousand series made by the
 cell's own generator (``benchmark/generators/pattern_draws.py``),
 answers in the configuration's float32, and every answer is held to
@@ -11,7 +12,7 @@ the cell's own judge (``benchmark/references/patterns.py``, loaded as
 the harness loads it) under the configuration's limits, on two seeds,
 with and without a group-by on ``dc``. The judge's matcher is held to
 a character-by-character one, the generator's patterns to one size of
-selection, the configuration to ``fleet-1m``'s store, and the walk to
+selection, the configuration to ``fleet-1m``'s store, and the table to
 its stage (``query.filter_resolve``) and its counter
 (``tsd.query.filter.names_read``). CPU only.
 """
@@ -318,42 +319,61 @@ def _stage_count(tsd) -> int:
                and h["labels"].get("stage") == "query.filter_resolve")
 
 
-@pytest.mark.parametrize("case, filters, spans", [
-    ("walk", [("host", "wildcard", "h00012*")],
-     [{"way": "walk", "names_read": SERIES, "matched": 100}]),
+def _table(state: str, read: int, matched: int) -> dict:
+    return {"way": "table", "table": state, "names_read": read,
+            "matched": matched}
+
+
+# (case, filters, the stage's tags on the first request after a value
+# was renamed, and on the second)
+@pytest.mark.parametrize("case, filters, cold, warm", [
+    ("table", [("host", "wildcard", "h00012*")],
+     [_table("built", SERIES, 100)], [_table("hit", 0, 100)]),
     ("ids", [("host", "literal_or", "h0000007|h0001234|nosuch")],
+     [{"way": "ids", "names_read": 0, "matched": 2}],
      [{"way": "ids", "names_read": 0, "matched": 2}]),
-    ("presence", [("dc", "wildcard", "*")], []),
-    ("two-walks-and-an-id",
+    ("presence", [("dc", "wildcard", "*")], [], []),
+    ("two-tables-and-an-id",
      [("host", "iwildcard", "*07"), ("dc", "regexp", "d0[0-7]"),
       ("rack", "not_literal_or", "r0007")],
-     [{"way": "walk", "names_read": SERIES, "matched": 100},
-      {"way": "walk", "names_read": 100, "matched": 8},
+     [_table("built", SERIES, 100), _table("built", 100, 8),
+      {"way": "ids", "names_read": 0, "matched": 1}],
+     [_table("hit", 0, 100), _table("hit", 0, 8),
       {"way": "ids", "names_read": 0, "matched": 1}])],
     ids=lambda v: v if isinstance(v, str) else "")
 def test_resolving_a_filter_is_a_stage_and_the_names_a_counter(
-        served, case, filters, spans):
+        served, case, filters, cold, warm):
     tsd, data, _ref = served
-    before, stages = _names_read(tsd), _stage_count(tsd)
-    _rows, headers = tsd.ask("POST", "/api/query", {
-        "start": data.t0 * 1000, "end": data.end * 1000, "queries": [{
-            "metric": data.metric, "aggregator": "sum",
-            "downsample": "1m-avg", "filters": [
-                {"type": kind, "tagk": tagk, "filter": expr,
-                 "groupBy": False} for tagk, kind, expr in filters]}]})
-    doc, _ = tsd.ask("GET", "/api/trace/" + headers["X-TSD-Trace-Id"])
-    (root,) = doc["tree"]
-    (execute,) = [c for c in root["children"]
-                  if c["name"] == "query.execute"]
-    (plan,) = [c for c in execute["children"]
-               if c["name"] == "query.plan"]
-    found = [c for c in plan.get("children", ())
-             if c["name"] == "query.filter_resolve"]
-    # children of the plan, one a filter that became tagv ids, in the
-    # order the filters were evaluated
-    assert [c["tags"] for c in found] == spans
-    assert all(c["durationMs"] <= plan["durationMs"] for c in found)
-    read = sum(s["names_read"] for s in spans)
-    assert plan["tags"]["names_read"] == read
-    assert _names_read(tsd) - before == read
-    assert _stage_count(tsd) - stages == len(spans)
+    # there and back: the names are what they were, the dictionary's
+    # generation is not, and no table of before it is trusted
+    tagv = tsd.tsdb.uids.tag_values
+    tagv.rename("d00", "elsewhere")
+    tagv.rename("elsewhere", "d00")
+    for spans in (cold, warm):
+        before, stages = _names_read(tsd), _stage_count(tsd)
+        _rows, headers = tsd.ask("POST", "/api/query", {
+            "start": data.t0 * 1000, "end": data.end * 1000,
+            "queries": [{
+                "metric": data.metric, "aggregator": "sum",
+                "downsample": "1m-avg", "filters": [
+                    {"type": kind, "tagk": tagk, "filter": expr,
+                     "groupBy": False}
+                    for tagk, kind, expr in filters]}]})
+        doc, _ = tsd.ask("GET",
+                         "/api/trace/" + headers["X-TSD-Trace-Id"])
+        (root,) = doc["tree"]
+        (execute,) = [c for c in root["children"]
+                      if c["name"] == "query.execute"]
+        (plan,) = [c for c in execute["children"]
+                   if c["name"] == "query.plan"]
+        found = [c for c in plan.get("children", ())
+                 if c["name"] == "query.filter_resolve"]
+        # children of the plan, one a filter that became tagv ids, in
+        # the order the filters were evaluated
+        assert [c["tags"] for c in found] == spans
+        assert all(c["durationMs"] <= plan["durationMs"] for c in found)
+        read = sum(s["names_read"] for s in spans)
+        assert plan["tags"]["names_read"] == read
+        assert _names_read(tsd) - before == read
+        assert _stage_count(tsd) - stages == len(spans)
+        assert "resolve_walk" not in plan["tags"]

@@ -224,3 +224,71 @@ class TestUidReferenceMatrix:
                      + reg.tag_values.int_to_uid(v)
                      + reg.tag_names.int_to_uid(max(k1, k2))
                      + reg.tag_values.int_to_uid(v))
+
+
+class TestGeneration:
+    """What versions a cache of names by id (the plan index's name
+    tables, PR 41): every change of what an ASSIGNED id is called."""
+
+    def test_an_assignment_leaves_it(self):
+        u = UniqueId("tagv")
+        before = u.generation
+        u.get_or_create_id("a")
+        u.assign_id("b")
+        assert u.get_or_create_id("a") == 1
+        assert u.generation == before
+
+    @pytest.mark.parametrize("change", [
+        lambda u: u.rename("a", "c"),
+        lambda u: u.delete("a"),
+        lambda u: u.load({"a": 1, "b": 2}, 2),
+    ], ids=["rename", "delete", "load"])
+    def test_a_change_of_an_assigned_name_moves_it(self, change):
+        u = UniqueId("tagv")
+        u.get_or_create_id("a")
+        u.get_or_create_id("b")
+        before = u.generation
+        change(u)
+        assert u.generation > before
+
+    @pytest.mark.parametrize("change", [
+        lambda u: u.rename("ghost", "c"),
+        lambda u: u.rename("a", "b"),
+        lambda u: u.delete("ghost"),
+    ], ids=["rename-missing", "rename-taken", "delete-missing"])
+    def test_a_refused_change_leaves_it(self, change):
+        u = UniqueId("tagv")
+        u.get_or_create_id("a")
+        u.get_or_create_id("b")
+        before = u.generation
+        with pytest.raises((LookupError, FailedToAssignUniqueIdError)):
+            change(u)
+        assert u.generation == before
+
+    def test_load_replaces_the_dictionary(self):
+        u = UniqueId("tagv")
+        u.get_or_create_id("old")
+        u.suggest("o")                  # fills the sorted index
+        u.load({"x": 3, "y": "7"}, 7)
+        assert (u.get_id("x"), u.get_id("y")) == (3, 7)
+        assert (u.get_name(7), u.max_id(), len(u)) == ("y", 7, 2)
+        assert u.suggest("") == ["x", "y"]
+        with pytest.raises(NoSuchUniqueName):
+            u.get_id("old")
+        assert u.get_or_create_id("z") == 8
+
+    def test_a_snapshot_load_moves_it(self, tmp_path):
+        from opentsdb_tpu import TSDB, Config
+        from opentsdb_tpu.core import persist
+        conf = {"tsd.core.auto_create_metrics": "true",
+                "tsd.tpu.warmup": "false"}
+        first = TSDB(Config(**conf))
+        first.add_point("m", 1356998400, 1, {"host": "web01"})
+        persist.save_store(first, str(tmp_path))
+        second = TSDB(Config(**conf))
+        before = {kind: second.uids.by_kind(kind).generation
+                  for kind in ("metric", "tagk", "tagv")}
+        assert persist.load_store(second, str(tmp_path))
+        assert second.uids.tag_values.get_name(1) == "web01"
+        for kind, was in before.items():
+            assert second.uids.by_kind(kind).generation > was
