@@ -20,6 +20,7 @@ Java-compatible by construction).
 from __future__ import annotations
 
 import struct
+import threading
 from typing import Sequence
 
 import numpy as np
@@ -150,6 +151,42 @@ class SimpleHistogram:
         }
 
 
+class HistogramStats:
+    """Counters of the histogram write and query paths, exported at
+    ``/api/stats`` by :meth:`TSDB.collect_stats`."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.bulk_points = 0     # landed by the columnar decode
+        self.slow_points = 0     # landed a point at a time
+        self.query_points = 0    # stored points merged by requests
+        self.upload_bytes = 0    # bytes device_put by requests
+        self.wide_counts = 0     # requests answered again in float64
+
+    def add(self, **grown: int) -> None:
+        """Grow counters by name; writers and query workers call side
+        by side, and ``+=`` alone would lose an update."""
+        with self._lock:
+            for name, n in grown.items():
+                setattr(self, name, getattr(self, name) + n)
+
+    def collect_stats(self, collector, cache) -> None:
+        collector.record("histogram.bulk_points", self.bulk_points)
+        collector.record("histogram.slow_points", self.slow_points)
+        collector.record("query.histogram.points", self.query_points)
+        collector.record("query.histogram.upload_bytes",
+                         self.upload_bytes)
+        collector.record("query.histogram.wide_counts",
+                         self.wide_counts)
+        collector.record(
+            "query.histogram.resident_bytes",
+            cache.bytes_of(RESIDENT_KEY) if cache is not None else 0)
+
+
+#: first element of a resident entry's key in the HBM cache
+RESIDENT_KEY = "hist"
+
+
 class HistogramArena:
     """Columnar store of one metric's histogram points.
 
@@ -175,19 +212,36 @@ class HistogramArena:
             self.sid = np.empty(cap, dtype=np.int64)
             # float64 rows: exact for counts up to 2^53 (the codec's
             # u64 realistic range); float32 would silently round past
-            # 2^24. Device kernels downcast to f32 at upload.
+            # 2^24. The device layout is float32, and the query
+            # program reports a merged total that reaches 2^24: such a
+            # request is answered from these rows
+            # (query/histogram_engine.py).
             self.rows = np.empty((cap, nb), dtype=np.float64)
             self.under = np.empty(cap, dtype=np.int64)
             self.over = np.empty(cap, dtype=np.int64)
             self.n = 0
 
         def _grow(self, need: int) -> None:
+            """Capacity for ``need`` points, doubling. An array nobody
+            holds a view of grows where it stands (``ndarray.resize``:
+            ``realloc``, which moves a large block by remapping its
+            pages, so the arena never holds two copies of itself: at
+            12M points x 64 bins the float64 rows are 6.1 GB). One
+            that a captured snapshot still reads cannot (numpy refuses
+            to resize it) and is REPLACED by a larger copy as before,
+            which leaves the snapshot intact."""
             cap = max(need, len(self.ts) * 2)
-            self.ts = np.resize(self.ts, cap)
-            self.sid = np.resize(self.sid, cap)
-            self.rows = np.resize(self.rows, (cap, self.rows.shape[1]))
-            self.under = np.resize(self.under, cap)
-            self.over = np.resize(self.over, cap)
+            for name in ("ts", "sid", "rows", "under", "over"):
+                shape = (cap,) + getattr(self, name).shape[1:]
+                try:
+                    # on the attribute itself: a local name for the
+                    # array would be one reference too many for numpy
+                    getattr(self, name).resize(shape)
+                except ValueError:
+                    old = getattr(self, name)
+                    new = np.empty(shape, dtype=old.dtype)
+                    new[:self.n] = old[:self.n]
+                    setattr(self, name, new)
 
         def append(self, ts_ms: int, sid: int, row: np.ndarray,
                    under: int = 0, over: int = 0) -> None:
@@ -219,8 +273,8 @@ class HistogramArena:
             MUST be captured under the owning TSDB's _histogram_lock
             (appends run under it): the refs + n are read atomically,
             and append-only semantics mean rows [0, n) of the captured
-            arrays never mutate afterwards (np.resize on growth
-            REPLACES the arrays, leaving captured ones intact)."""
+            arrays never mutate afterwards (growth REPLACES an array
+            that a snapshot still reads, see :meth:`_grow`)."""
             ts, sid, rows, n = self.ts, self.sid, self.rows, self.n
             return ts[:n], sid[:n], rows[:n]
 
@@ -242,6 +296,18 @@ class HistogramArena:
         sub.append(ts_ms, sid, hist.counts_array(),
                    hist.underflow, hist.overflow)
         self.total_points += 1
+
+    def append_run(self, ts_ms: np.ndarray, sid: int, bounds: tuple,
+                   counts: np.ndarray, under: np.ndarray,
+                   over: np.ndarray) -> None:
+        """The points of one series that :func:`decode_simple_run`
+        decoded together, landed by one ``append_many``."""
+        sub = self.groups.get(bounds)
+        if sub is None:
+            sub = self.groups[bounds] = HistogramArena._Sub(
+                bounds, max(1, len(bounds) - 1))
+        sub.append_many(ts_ms, sid, counts, under, over)
+        self.total_points += len(ts_ms)
 
     def iter_points(self):
         """(ts, sid, bounds, counts_row) over every point — the slow
@@ -321,6 +387,40 @@ class SimpleHistogramCodec(HistogramCodec):
         hist.underflow = under
         hist.overflow = over
         return hist
+
+
+def decode_simple_run(blobs: Sequence[bytes]):
+    """Blobs of the built-in codec (id 0x01, the id byte included)
+    that share one bounds header, decoded by one ``np.frombuffer``:
+    ``(bounds, counts [k, buckets] float64, underflow [k] int64,
+    overflow [k] int64)``, value for value what
+    :meth:`SimpleHistogramCodec.decode` gives each blob. None where
+    the blobs are no such run (another codec, another length or
+    header, a truncated or over-long blob, fewer than two edges, a NaN
+    edge, a counter past int64): the caller then decodes them one by
+    one, which also says what is wrong with which."""
+    first = blobs[0]
+    size = len(first)
+    if size < 3 or first[0] != SimpleHistogramCodec.id:
+        return None
+    edges = (first[1] << 8) | first[2]
+    head = 3 + 8 * edges
+    if edges < 2 or size != head + 8 * (edges - 1) + 16:
+        return None
+    header = first[:head]
+    if not all(len(b) == size and b.startswith(header) for b in blobs):
+        return None
+    bounds = struct.unpack_from(f">{edges}d", first, 3)
+    if any(b != b for b in bounds):
+        return None
+    body = np.frombuffer(b"".join(blobs), dtype=np.uint8) \
+        .reshape(len(blobs), size)[:, head:]
+    words = np.ascontiguousarray(body).view(">u8")
+    tail = words[:, edges - 1:]
+    if (tail >> np.uint64(63)).any():
+        return None
+    return (bounds, words[:, :edges - 1].astype(np.float64),
+            tail[:, 0].astype(np.int64), tail[:, 1].astype(np.int64))
 
 
 class HistogramCodecManager:

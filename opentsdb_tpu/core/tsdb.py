@@ -131,6 +131,11 @@ class TSDB:
         self._histogram_lock = threading.Lock()
         # write version for read-side caches of histogram batches
         self._histogram_version = 0
+        from opentsdb_tpu.core.histogram import HistogramStats
+        self.histogram_stats = HistogramStats()
+        # one request at a time lays a window's counts out for the
+        # device (query/histogram_engine.py)
+        self._histogram_resident_lock = threading.Lock()
         from opentsdb_tpu.meta.annotation import AnnotationStore
         self.annotations = AnnotationStore()
         from opentsdb_tpu.meta.meta_store import MetaStore
@@ -887,12 +892,21 @@ class TSDB:
     def add_histogram_batch(self, points, on_error=None
                             ) -> tuple[int, list[str]]:
         """Bulk write ``(metric, timestamp, raw_blob, tags)`` histogram
-        tuples, grouping by series so validation + UID resolution run
-        once per series instead of once per point (the histogram twin
-        of :meth:`add_point_batch`; per-point work that remains —
-        codec decode + arena append — is inherent). WAL-synced once
-        per batch. Returns (written, error strings)."""
-        from opentsdb_tpu.core.histogram import HistogramArena
+        tuples: the one entry of ``/api/histogram``, telnet
+        ``histogram`` and a bulk loader. Points are grouped by series,
+        so validation and UID resolution run once a series (the
+        histogram twin of :meth:`add_point_batch`), and a series'
+        blobs of the built-in codec that share one bounds header are
+        decoded by one ``np.frombuffer`` and landed by one
+        ``append_many`` (:func:`~opentsdb_tpu.core.histogram.
+        decode_simple_run`; ``tsd.histogram.bulk_points``). Blobs that
+        are no such run (another codec, differing bounds, a malformed
+        one among them) are decoded and appended a point at a time,
+        with that path's errors (``tsd.histogram.slow_points``).
+        WAL-synced once per batch. Returns (written, error strings)."""
+        from opentsdb_tpu.core.histogram import (HistogramArena,
+                                                 SimpleHistogramCodec,
+                                                 decode_simple_run)
         groups: dict[tuple, list] = {}
         errors: list[str] = []
         written = 0
@@ -902,9 +916,19 @@ class TSDB:
             if on_error is not None:
                 on_error(idx, e)
 
+        # consecutive points of one series (a loader's order) find
+        # their group by comparing tags with a copy of the last ones:
+        # a sort and a tuple a series, not a point
+        last_metric = last_tags = items = None
         for i, (metric, ts, blob, tags) in enumerate(points):
-            key = (metric, tuple(sorted(tags.items())))
-            groups.setdefault(key, []).append((i, ts, blob, tags))
+            if metric != last_metric or tags != last_tags:
+                last_metric, last_tags = metric, dict(tags)
+                items = groups.setdefault(
+                    (metric, tuple(sorted(tags.items()))), [])
+            items.append((i, ts, blob, tags))
+        bulk = type(self.histogram_manager.codec(
+            SimpleHistogramCodec.id)) is SimpleHistogramCodec
+        stats = self.histogram_stats
         with self._wal_scope():
             for (metric, _), items in groups.items():
                 tags = items[0][3]
@@ -919,40 +943,68 @@ class TSDB:
                 # UID space or create an empty series (matches
                 # add_histogram_point, which validates first and
                 # creates nothing on failure)
-                valid: list[tuple] = []
+                timed: list[tuple] = []
                 for idx, ts, blob, _t in items:
                     try:
                         self._check_timestamp(ts)
-                        hist = self.histogram_manager.decode(blob)
-                        valid.append((idx, ts, blob,
-                                      codec.to_ms(ts), hist))
+                        timed.append((idx, ts, blob, codec.to_ms(ts)))
                     except Exception as e:  # noqa: BLE001
                         fail(idx, metric, ts, e)
-                if not valid:
+                if not timed:
                     continue
+                run = decode_simple_run([t[2] for t in timed]) \
+                    if bulk else None
+                valid: list[tuple] = []
+                if run is None:
+                    for idx, ts, blob, ts_ms in timed:
+                        try:
+                            valid.append(
+                                (idx, ts, blob, ts_ms,
+                                 self.histogram_manager.decode(blob)))
+                        except Exception as e:  # noqa: BLE001
+                            fail(idx, metric, ts, e)
+                    if not valid:
+                        continue
                 try:
                     metric_id, tag_ids = self._resolve_write_uids(
                         metric, tags)
                     sid = self.histogram_store.get_or_create_series(
                         metric_id, tag_ids)
                 except Exception as e:  # noqa: BLE001
-                    for idx, ts, _b, _tm, _h in valid:
+                    for idx, ts, *_ in (timed if run else valid):
                         fail(idx, metric, ts, e)
                     continue
+                landed = timed
                 # one lock take for the whole group's appends
                 with self._histogram_lock:
                     arena = self._histogram_arenas.get(metric_id)
                     if arena is None:
                         arena = self._histogram_arenas[metric_id] = \
                             HistogramArena()
-                    for _idx, _ts, _b, ts_ms, hist in valid:
-                        arena.append(ts_ms, sid, hist)
+                    if run:
+                        arena.append_run(
+                            np.fromiter((t[3] for t in timed),
+                                        dtype=np.int64,
+                                        count=len(timed)), sid, *run)
+                    else:
+                        landed = []
+                        for point in valid:
+                            idx, ts, _b, ts_ms, hist = point
+                            try:
+                                # what decodes need not fit the arena
+                                # (a counter past int64, no bucket)
+                                arena.append(ts_ms, sid, hist)
+                                landed.append(point)
+                            except Exception as e:  # noqa: BLE001
+                                fail(idx, metric, ts, e)
                     self._histogram_version += 1
+                stats.add(**{"bulk_points" if run else "slow_points":
+                             len(landed)})
                 if self.wal is not None:
-                    for _idx, ts, blob, _tm, _h in valid:
+                    for _idx, ts, blob, *_ in landed:
                         self.wal.log_histogram(metric, tags, ts, blob)
-                self.datapoints_added += len(valid)
-                written += len(valid)
+                self.datapoints_added += len(landed)
+                written += len(landed)
             if written and self.wal is not None:
                 self.wal.sync()
         return written, errors
@@ -975,6 +1027,7 @@ class TSDB:
                     HistogramArena()
             arena.append(ts_ms, sid, hist)
             self._histogram_version += 1
+        self.histogram_stats.add(slow_points=1)
         if _wal and self.wal is not None:
             self.wal.log_histogram(metric, tags, timestamp, raw_blob)
             self.wal.sync()
@@ -1384,6 +1437,8 @@ class TSDB:
         collector.record("storage.cold_bytes",
                          cold.cold_bytes() if cold is not None else 0)
         collector.record("datapoints.added", self.datapoints_added)
+        self.histogram_stats.collect_stats(collector,
+                                           self._device_grid_cache)
         dev = self.device_info()
         dev_tags = {
             "platform": dev["platform"] or "none",

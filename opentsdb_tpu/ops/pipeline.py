@@ -278,7 +278,9 @@ def run_staged(path: str, program, operands,
     ``block_until_ready`` on its outputs, tagged with ``path``,
     ``placement`` (``host`` for a tail pinned to the CPU backend,
     ``spec.host``), ``class`` (``spec.tail_class``: ``rank`` |
-    ``linear``), the padded ``shape`` SxBxG and ``compiled`` when
+    ``linear``, or ``histogram`` for the percentile program's
+    :class:`~opentsdb_tpu.ops.histogram_kernels.HistogramSpec`), the
+    padded ``shape`` SxBxG and ``compiled`` when
     JAX compiled (or loaded from its cache) inside it; a
     device-placed program occupies :data:`RUNTIME`'s clock for that
     stretch. ``query.download`` is ``download`` (``np.asarray``) of

@@ -80,6 +80,12 @@ class DeviceGridCache:
                 _, (_, _, _, nb) = self._entries.popitem(last=False)
                 self._bytes -= nb
 
+    def bytes_of(self, kind) -> int:
+        """Bytes of the entries whose key begins with ``kind``."""
+        with self._lock:
+            return sum(e[3] for k, e in self._entries.items()
+                       if k[0] == kind)
+
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()

@@ -1088,7 +1088,7 @@ class QueryEngine:
                         "sketch partials requested but the sketch "
                         "subsystem is disabled (tsd.sketch.enable)")
                 return sk_rows
-            hist_rows = run_histogram_subquery(self.tsdb, tsq, sub)
+            hist_rows = run_histogram_subquery(self, tsq, sub)
             if sk_rows is not None:
                 # live arena rows + spilled/demoted sketch history
                 # splice by group (disjoint time windows)

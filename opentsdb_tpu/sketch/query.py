@@ -117,6 +117,17 @@ def _run_over_store(tsdb, tsq, sub, store, mid, alpha, max_buckets,
     sids = store.series_ids_for_metric(mid)
     if len(sids) == 0:
         return []
+    hist_zones = None
+    if hist:
+        # a histogram metric has sketches only where its arenas were
+        # spilled (or a shard is asked for partials): without any,
+        # nothing below can emit a row, and the plan over every series
+        # of the metric (a tag matrix of 200,000 rows a request) is
+        # left to the arena engine, which has an index for it
+        hist_zones = _hist_zones(tsdb, tsq, sub, mid, alpha,
+                                 max_buckets, partials)
+        if not hist_zones[0]:
+            return []
     idx = store.metric_index(mid)
     _, triples = idx.arrays()
     tag_mat = TagMatrix.from_triples(sids, triples)
@@ -134,9 +145,7 @@ def _run_over_store(tsdb, tsq, sub, store, mid, alpha, max_buckets,
 
     # ---- gather the three zones as (sid_pos, cell_ts, sketch) ------
     if hist:
-        items, raw_rng, cold_ok = _hist_zones(tsdb, tsq, sub, mid,
-                                              alpha, max_buckets,
-                                              partials)
+        items, raw_rng, cold_ok = hist_zones
     else:
         from opentsdb_tpu.lifecycle.stitch import sketch_zone_read
         items, raw_rng, cold_ok = sketch_zone_read(

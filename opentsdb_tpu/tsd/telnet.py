@@ -295,7 +295,13 @@ class TelnetRouter:
             ts = int(words[2])
             blob = base64.b64decode(words[3])
             tags = dict(tags_mod.parse(w) for w in words[4:])
-            self.tsdb.add_histogram_point(metric, ts, blob, tags)
+            # the entry /api/histogram uses; its first error, raised
+            failed: list[Exception] = []
+            self.tsdb.add_histogram_batch(
+                [(metric, ts, blob, tags)],
+                on_error=lambda _i, e: failed.append(e))
+            if failed:
+                raise failed[0]
             return ""
         except Exception as e:  # noqa: BLE001
             return f"histogram: {type(e).__name__}: {e}"

@@ -353,35 +353,55 @@ def _run_warmup(tsdb, report: WarmupReport, t0: float) -> None:
                             grid, grid, bts, gids, rp, fv, spec_div))
 
     # histogram percentile classes, only when histogram data is
-    # resident (the kernels' N / segment dims are bucketed by
-    # histogram_percentile_pipeline, so these pre-compiles are the
-    # keys real percentile queries hit)
+    # resident: the program of a metric's whole history as one window
+    # (one row a series, one slot a distinct timestamp, the dims the
+    # engine buckets), ungrouped and by a dashboard's handful of
+    # groups, without a downsample and merged into 12 buckets
     if stopped() or over_budget():
         return
     with tsdb._histogram_lock:
         some = next(
             (sub for arena in tsdb._histogram_arenas.values()
              for sub in arena.groups.values() if sub.n), None)
-        n_points = sum(a.total_points
-                       for a in tsdb._histogram_arenas.values())
+        if some is not None:
+            _ts, _sid, _rows = some.snapshot()
+            n_series = len(np.unique(_sid))
+            n_slots = len(np.unique(_ts))
     if some is None:
         return
-    from opentsdb_tpu.ops.histogram_kernels import \
-        histogram_percentile_pipeline
+    from functools import partial
+    from opentsdb_tpu.ops.histogram_kernels import (
+        HistogramSpec, histogram_percentiles)
+    from opentsdb_tpu.query.engine import host_tail_for_dims
     nb = some.rows.shape[1]
-    bounds = np.asarray(some.bounds, dtype=np.float64)
-    n = shapes.shape_bucket(n_points)
-    # segment dim = groups x time-points: warm the small
-    # (single-group) and dashboard-sized classes
-    for segs in (shapes.shape_bucket(2), shapes.shape_bucket(65),
-                 shapes.shape_bucket(min(n_points, 1000) + 1)):
-        for qs in ([95.0], [99.0, 99.9]):
-            attempt(f"histogram ({n}, {nb}) x {segs} segments",
-                    lambda segs=segs, qs=qs:
-                    histogram_percentile_pipeline(
-                        np.zeros((n, nb), dtype=np.float32),
-                        np.zeros(n, dtype=np.int32), segs - 1,
-                        bounds, qs))
+    s, p = shapes.shape_bucket(n_series), shapes.shape_bucket(n_slots)
+    if s * p * nb > _HISTOGRAM_WARM_CELLS:
+        return      # a layout this large is warmed by its first query
+    dev_hist = host_tail_for_dims(tsdb.config, s, p * nb, 1,
+                                  rank_class=False)
+    put = partial(jax.device_put, device=dev_hist)
+    counts = put(np.zeros((s, p * nb), np.float32))
+    present = put(np.zeros((s, p), np.float32))
+    labels, slot_bucket = put(np.zeros(s, np.int32)), \
+        put(np.zeros(p, np.int32))
+    mids = put(np.zeros(nb, np.float32))
+    for g in (shapes.shape_bucket(2), shapes.shape_bucket(65)):
+        for merge_time in (False, True):
+            for qs in ([95.0], [99.0, 99.9]):
+                spec = HistogramSpec(
+                    s, p, shapes.shape_bucket(13) if merge_time else p,
+                    g, nb, host=dev_hist is not None,
+                    merge_time=merge_time)
+                attempt(f"histogram {spec}", lambda spec=spec, qs=qs:
+                        histogram_percentiles(
+                            counts, present, labels, slot_bucket, mids,
+                            put(np.asarray(qs, np.float32) / 100),
+                            spec))
+
+
+# the largest resident layout (cells of one bin) warm-up lays out in
+# zeros to compile its program: 256 MB of float32
+_HISTOGRAM_WARM_CELLS = 1 << 26
 
 
 def start_warmup_thread(tsdb) -> threading.Thread | None:

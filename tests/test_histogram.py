@@ -142,33 +142,104 @@ class TestCodec:
 class TestDeviceKernels:
     """ops.histogram_kernels vs the host formulas (golden)."""
 
-    def test_merge_matches_manual_sum(self):
-        from opentsdb_tpu.ops.histogram_kernels import merge_histograms
+    @staticmethod
+    def run_kernel(counts, seg, num_segments, bounds, qs, host=False):
+        """``histogram_percentiles`` over rows that are one point
+        each (one slot): the segment is the group. Returns (values
+        [Q, segments], points [segments], widest)."""
+        import jax.numpy as jnp
+        from opentsdb_tpu.ops.histogram_kernels import (
+            HistogramSpec, histogram_percentiles)
+        n, nb = counts.shape
+        spec = HistogramSpec(num_series=n, num_slots=1, num_buckets=1,
+                             num_groups=num_segments, num_bins=nb,
+                             host=host, merge_time=False)
+        b = np.asarray(bounds, dtype=np.float64)
+        values, points, widest = histogram_percentiles(
+            jnp.asarray(counts, dtype=jnp.float32),
+            jnp.ones((n, 1), dtype=jnp.float32),
+            jnp.asarray(seg, dtype=jnp.int32),
+            jnp.zeros(1, dtype=jnp.int32),
+            jnp.asarray((b[:-1] + b[1:]) / 2.0, dtype=jnp.float32),
+            jnp.asarray(np.asarray(qs) / 100.0, dtype=jnp.float32),
+            spec)
+        return (np.asarray(values)[:, :, 0], np.asarray(points)[:, 0],
+                float(widest))
+
+    @pytest.mark.parametrize("host", [False, True])
+    def test_merge_matches_manual_sum(self, host):
+        """The group merge: the q that lands in every bin in turn
+        reads the merged counts back through the percentile."""
         rng = np.random.default_rng(0)
         counts = rng.integers(0, 50, (40, 8)).astype(np.float64)
         seg = rng.integers(0, 5, 40).astype(np.int32)
-        import jax.numpy as jnp
-        got = np.asarray(merge_histograms(jnp.asarray(counts),
-                                          jnp.asarray(seg), 5))
         gold = np.zeros((5, 8))
         for i, s in enumerate(seg):
             gold[s] += counts[i]
-        np.testing.assert_allclose(got, gold)
+        from opentsdb_tpu.query.histogram_engine import \
+            percentiles_from_counts
+        bounds = np.arange(9.0)
+        qs = [1.0, 10.0, 25.0, 50.0, 75.0, 90.0, 99.0, 100.0]
+        got, points, widest = self.run_kernel(counts, seg, 5, bounds,
+                                              qs, host=host)
+        np.testing.assert_allclose(
+            got, percentiles_from_counts(gold, bounds, qs))
+        np.testing.assert_array_equal(points,
+                                      np.bincount(seg, minlength=5))
+        assert widest == gold.sum(axis=1).max()
 
     def test_percentiles_match_host_path(self):
         from opentsdb_tpu.query.histogram_engine import \
             percentiles_from_counts
-        from opentsdb_tpu.ops.histogram_kernels import \
-            histogram_percentile_pipeline
         rng = np.random.default_rng(1)
         counts = rng.integers(0, 100, (7, 6)).astype(np.float64)
         counts[3] = 0  # an empty segment
         bounds = np.asarray([0.0, 1, 2, 4, 8, 16, 32])
         qs = [50.0, 95.0, 99.9]
         gold = percentiles_from_counts(counts, bounds, qs)
-        got = histogram_percentile_pipeline(
+        got, _points, _widest = self.run_kernel(
             counts, np.arange(7, dtype=np.int32), 7, bounds, qs)
         np.testing.assert_allclose(got, gold, rtol=1e-6)
+
+    def test_time_merge_and_dummies(self):
+        """Slots merge into their downsample bucket, and what lands
+        on the dummy group or the dummy bucket is in no answer."""
+        import jax.numpy as jnp
+        from opentsdb_tpu.ops.histogram_kernels import (
+            HistogramSpec, histogram_percentiles)
+        from opentsdb_tpu.query.histogram_engine import \
+            percentiles_from_counts
+        rng = np.random.default_rng(2)
+        s, p, nb, g, t = 6, 4, 5, 3, 3
+        counts = rng.integers(0, 30, (s, p, nb)).astype(np.float32)
+        present = np.ones((s, p), dtype=np.float32)
+        present[2, 1] = 0
+        counts[2, 1] = 0
+        labels = np.array([0, 1, 0, 2, 1, 2], dtype=np.int32)
+        slot_bucket = np.array([0, 0, 1, 2], dtype=np.int32)
+        bounds = np.arange(nb + 1.0)
+        qs = [50.0, 99.0]
+        spec = HistogramSpec(s, p, t, g, nb)
+        values, points, _w = histogram_percentiles(
+            jnp.asarray(counts.reshape(s, p * nb)),
+            jnp.asarray(present), jnp.asarray(labels),
+            jnp.asarray(slot_bucket),
+            jnp.asarray((bounds[:-1] + bounds[1:]) / 2.0,
+                        dtype=jnp.float32),
+            jnp.asarray(np.asarray(qs) / 100.0, dtype=jnp.float32),
+            spec)
+        gold = np.zeros((g, t, nb))
+        n = np.zeros((g, t))
+        for i in range(s):
+            for j in range(p):
+                gold[labels[i], slot_bucket[j]] += counts[i, j]
+                n[labels[i], slot_bucket[j]] += present[i, j]
+        np.testing.assert_array_equal(np.asarray(points), n)
+        want = percentiles_from_counts(
+            gold.reshape(g * t, nb), bounds, qs).reshape(2, g, t)
+        # group 2 and bucket 2 stand for the dummies: whatever they
+        # hold leaves the real cells as they are
+        np.testing.assert_allclose(np.asarray(values), want)
 
     def test_groupby_query_uses_device_path(self, tsdb):
         from opentsdb_tpu.query.model import TSQuery

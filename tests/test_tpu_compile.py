@@ -1,0 +1,57 @@
+"""Programs of the main path compiled for a TPU v5e that is described
+and not attached, at the sizes a deployment runs them (PR 42): what
+the chip's compiler refuses, it refuses here, at no chip time. Nothing
+runs, so nothing here says anything about results or times.
+
+The topology is described inside a fixture, never while a module is
+imported: one process at a time may load the TPU's library, and under
+several test workers every worker imports this file. Keep every such
+test in THIS file: a second file can go to another worker, whose
+fixture then skips.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever says "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_hist_200ks_percentile_program_fits_one_chip(one_chip):
+    """``hist-200k.percentiles``: 229,376 series x 64 slots x 64 bins
+    of float32 resident, 128 groups, 16 buckets, two percentiles. The
+    program holds its arguments and nothing of their size beside them
+    (the one-hot and the six bfloat16 passes of the exact contraction
+    are fused into it)."""
+    from opentsdb_tpu.ops.histogram_kernels import (HistogramSpec,
+                                                    histogram_percentiles)
+    s, p, nb, g, t, q = 229_376, 64, 64, 128, 16, 2
+    spec = HistogramSpec(s, p, t, g, nb)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = histogram_percentiles.lower(
+        shape((s, p * nb), jnp.float32), shape((s, p), jnp.float32),
+        shape((s,), jnp.int32), shape((p,), jnp.int32),
+        shape((nb,), jnp.float32), shape((q,), jnp.float32),
+        spec=spec).compile()
+    memory = compiled.memory_analysis()
+    resident = s * p * nb * 4
+    assert resident <= memory.argument_size_in_bytes < 1.05 * resident
+    assert memory.temp_size_in_bytes < 256 << 20
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes < 8 << 30
