@@ -155,6 +155,10 @@ class TSDB:
         # device-resident grid cache (HBM ≙ HBase block cache); lazy
         self._device_grid_cache = None
         self._device_cache_lock = threading.Lock()
+        # one request at a time builds a metric's resident grid
+        # (query/engine.py _resident_grid): two sub-queries that miss
+        # together would scan and put up the same grid twice
+        self._resident_grid_lock = threading.Lock()
         self._device_cache_mb = self.config.get_int(
             "tsd.query.device_cache_mb", 1024)
         # host-RAM twin for host-tail prepared batches: deliberately a

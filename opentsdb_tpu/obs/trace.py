@@ -774,6 +774,12 @@ class Tracer:
         # grids built, by who wrote the padded grid: "fused" (the
         # store's own pass) or "host" (fill_padded_grid)
         self.grid_builds = {"fused": 0, "host": 0}
+        # HBM cache look-ups of grid sub-queries, by what answered:
+        # "resident_hit" (the metric's resident grid was there),
+        # "resident_built" (this request built it) or "selection"
+        # (a grid of the request's own rows, keyed by their digest)
+        self.grids = {"resident_hit": 0, "resident_built": 0,
+                      "selection": 0}
         # plan stages, by what the engine's plan index did for them:
         # "hit" (planned from the cached index), "built" (built it
         # first), "bypass" (a selection that is not a whole metric)
@@ -1056,7 +1062,8 @@ class Tracer:
         span has children; the part of it with no program in flight
         on the device adds to ``idle_stage_ms`` of its stage; every
         ``query.program`` counts in ``tails``, every ``query.grid_build``
-        that built a grid (tag ``fused``) in ``grid_builds``, every
+        that built a grid (tag ``fused``) in ``grid_builds`` and that
+        looked one up (tag ``grid``) in ``grids``, every
         ``query.plan`` that reached its filters (tag ``index``) in
         ``plans`` and its filters (tags ``resolve_<way>``) in
         ``filters``, every ``query.filter_resolve``'s ``names_read``
@@ -1071,6 +1078,7 @@ class Tracer:
         observed = 0
         tails = []
         builds = []
+        grids = []
         plans = []
         filters = []
         names_read = 0
@@ -1099,8 +1107,12 @@ class Tracer:
                 tails.append((str(s.tags.get("path", "?")),
                               str(s.tags.get("placement", "?")),
                               str(s.tags.get("class", "?"))))
-            elif s.name == "query.grid_build" and "fused" in s.tags:
-                builds.append("fused" if s.tags["fused"] else "host")
+            elif s.name == "query.grid_build":
+                if "fused" in s.tags:
+                    builds.append("fused" if s.tags["fused"]
+                                  else "host")
+                elif s.tags.get("grid") in self.grids:
+                    grids.append(s.tags["grid"])
             elif s.name == "query.plan" and s.tags.get("index") \
                     in self.plans:
                 plans.append(s.tags["index"])
@@ -1122,6 +1134,8 @@ class Tracer:
                 self.tails[key] = self.tails.get(key, 0) + 1
             for mode in builds:
                 self.grid_builds[mode] += 1
+            for source in grids:
+                self.grids[source] += 1
             for state in plans:
                 self.plans[state] += 1
             for way, n in filters:
@@ -1220,6 +1234,7 @@ class Tracer:
             idle = sorted(self.idle_stage_ms.items())
             tails = sorted(self.tails.items())
             builds = sorted(self.grid_builds.items())
+            grids = sorted(self.grids.items())
             plans = sorted(self.plans.items())
             filters = sorted(self.filters.items())
             names_read = self.filter_names_read
@@ -1232,6 +1247,8 @@ class Tracer:
                              placement=placement, **{"class": cls})
         for mode, n in builds:
             collector.record("query.grid_build", n, mode=mode)
+        for source, n in grids:
+            collector.record("query.grid", n, source=source)
         for state, n in plans:
             collector.record("query.plan", n, index=state)
         for way, n in filters:

@@ -2,14 +2,50 @@
 
 The reference keeps hot HBase blocks in the region server's block
 cache so repeated scans don't touch disk; the TPU-native analogue
-keeps the query's pre-bucketized ``[S, B]`` grids resident in device
-HBM so repeated queries over the same window don't re-scan the host
-store or re-upload.
+keeps pre-bucketized ``[S, B]`` grids resident in device HBM so
+queries over the same window don't re-scan the host store or
+re-upload.
 
-Entries are keyed by the exact reduction parameters and invalidated by
-the store's mutation version (every write or delete bumps it), so a
-hit is always bit-identical to a fresh scan. Bounded LRU by device
-bytes (``tsd.query.device_cache_mb``).
+What an entry is, by its key's first item:
+
+``metricgrid`` (``engine._resident_grid``, PR 43): a METRIC's whole
+padded grid and presence mask for one (store, metric, plan-index
+version = the metric's series count, ``start_ms``, ``end_ms``, first
+bucket, interval, buckets, downsample function): scalars only, no
+digest. A request's filter is not in the key: it goes up as one int32
+group label a resident row, excluded rows on the dummy trailing group
+that padded rows already have (``ops/shapes.pad_group_ids``), so no
+program changes and every panel of a dashboard, every rule of an
+evaluator's pass and both sub-queries of a ``sum`` + ``max`` request
+read the entry the first one built (one build at a time, under
+``TSDB._resident_grid_lock``). Its meta holds each row's point count
+of the window (the store's ``count_range``), so the limits' check and
+the scan's stat points stay the selection's. Taken for a device-placed
+tail over a selection the plan index planned, no mesh, not
+``aggregator=none``, not ``delete``, the metric's grid within the cell
+budget and this cache's bytes, and a selection of at least half of the
+metric's rows (``engine.RESIDENT_GRID_MIN_SHARE``): the tail program
+costs the METRIC's rows over a resident grid and the scan, digest and
+upload it replaces cost the SELECTION's, so a tenth of a metric is
+cheaper scanned, and from a half up the two padded shapes are within
+one doubling and the resident grid wins. What it does not give is a
+window that moves: the window is in the key, so ``end=now`` traffic
+builds anew when it changes (time-blocked columns are the next step).
+
+``grid`` (``engine._grid_pipeline``): the grid of one request's own
+rows, keyed by a digest of its series ids: every other device-placed
+grid request, and the mesh twin's pre-sharded operands. ``avgdiv``
+(the rollup average's divided grid), ``prep`` (the point path's
+prepared batch) and ``hist`` (a histogram metric's window of counts,
+``histogram_engine``) keep their own keys.
+
+Every entry is stamped with the store's mutation version,
+``(points_written, mutation_epoch)`` read BEFORE the store was (every
+write, delete or lifecycle sweep bumps it), and dropped by the first
+look-up that reads another: a hit holds the cells a fresh scan would
+write. Bounded LRU by device bytes (``tsd.query.device_cache_mb``); an
+entry larger than the whole cache is never kept, and with the key at 0
+nothing is resident and every request scans.
 """
 
 from __future__ import annotations

@@ -277,6 +277,10 @@ class TagMatrix:
                 if v >= 0]
 
 
+#: first item of the HBM cache's key for a metric's resident grid
+RESIDENT_GRID_KEY = "metricgrid"
+
+
 def _store_id(store) -> int:
     """Monotonic per-process store identity for cache keys. id(store)
     could alias a freed store whose address was reused with a
@@ -306,6 +310,20 @@ HOST_TAIL_DEFAULT_CELLGROUPS = 1 << 25
 # 31). The panels' class (8 series x 60 buckets) takes 0.7 ms on the
 # host and 2.7 on the chip.
 HOST_TAIL_DEFAULT_CELLS_LINEAR = 1 << 16
+# the least share of its metric's series a selection must hold to run
+# over the metric's RESIDENT grid (QueryEngine._resident_grid) and not
+# over a grid of its own rows. The two costs follow different counts:
+# the tail program reads every row of the grid it is given (26 ms a
+# million rows of 12 buckets on a v5e, PERF.md section 5), so over the
+# resident grid it costs the METRIC's rows whatever the filter kept,
+# while the scan, the digest and the upload it replaces cost the
+# SELECTION's rows (~64 ms a million, same section). At a tenth of a
+# metric the selection's own grid is the cheaper by far; from a half
+# up the padded shapes are at most a doubling apart (so the placement
+# is the same and a dashboard's panels share one compiled program) and
+# the resident grid wins 2:1 or better. Between a tenth and a half
+# nothing was measured: the conservative end.
+RESIDENT_GRID_MIN_SHARE = 0.5
 
 
 def _rank_class_agg(agg_name: str) -> bool:
@@ -1134,6 +1152,7 @@ class QueryEngine:
             stats.add_stat(QueryStat.ROWS_PRE_FILTER, len(sids))
 
         # --- filters -> series mask (ref: findSpans post-scan filters)
+        metric_sids = sids
         sids, tag_mat, plan_tags = self._apply_filters(store, sub, sids)
         if _h_plan is not None:
             _h_plan.tag(**plan_tags)
@@ -1197,10 +1216,19 @@ class QueryEngine:
         # downsample functions the storage engine reduces the window to
         # the [S, B] grid in one native pass, so the device never sees
         # per-point data (SURVEY §7: HBM/transfer bandwidth is the
-        # bottleneck; here the "scan" IS the downsample)
+        # bottleneck; here the "scan" IS the downsample). A selection
+        # planned from the plan index knows its rows of the metric
+        # (filters, explicit_tags and the replica mask composed): what
+        # the metric's resident grid is labelled by
+        metric_rows = None
+        if tag_mat.origin is not None:
+            rows = tag_mat.origin[1]
+            metric_rows = (metric_sids,
+                           slice(None) if rows is None else rows)
         out = self._grid_pipeline(store, sids, tsq, sub, metric_name,
                                   group_ids, num_groups, emit_raw,
-                                  budget, stats, ds_fn_override)
+                                  budget, stats, ds_fn_override,
+                                  metric_rows)
         if out is not None:
             result, emit, bucket_ts = out
             if result is None:
@@ -1734,7 +1762,7 @@ class QueryEngine:
 
     def _reduce_to_grid(self, store, sids: np.ndarray, tsq: TSQuery,
                         bucket_ts: np.ndarray, interval_ms: int,
-                        stat: str, stats):
+                        stat: str, scanned):
         """One sub-query's storage pass -> ``(grid, has_data,
         num_points)``: the tail program's operands made ONCE, padded
         to the geometric shape buckets (cached device grids are
@@ -1748,7 +1776,10 @@ class QueryEngine:
         A store with a fused ``bucket_grid`` (the native one) writes
         them in its storage pass; any other reduces to f64 grids that
         :func:`fill_padded_grid` finishes. Chosen by what the store
-        offers: two paths that share the contract and no logic."""
+        offers: two paths that share the contract and no logic.
+        ``scanned(scan, num_points)`` closes the storage read
+        (:meth:`_record_scan`, with the rows the caller answers
+        for)."""
         from opentsdb_tpu.ops import shapes
         from opentsdb_tpu.ops.pipeline import pipeline_dtype
         b = len(bucket_ts)
@@ -1769,30 +1800,112 @@ class QueryEngine:
                 grid, has_data = alloc()
             scan = self._scan_begin()
             num_points = fused(*window, stat, grid, has_data)
-            self._record_scan(stats, scan, num_points, len(sids))
+            scanned(scan, num_points)
             return grid, has_data, num_points
         scan = self._scan_begin()
         reduced = store.bucket_reduce(
             *window, want_minmax=stat in ("min", "max"))
         num_points = int(reduced[1].sum())
-        self._record_scan(stats, scan, num_points, len(sids))
+        scanned(scan, num_points)
         with trace_span("query.grid_build", stage="fill_pad", **tags):
             grid, has_data = alloc()
             fill_padded_grid(stat, *reduced, grid, has_data)
+        return grid, has_data, num_points
+
+    def _resident_grid(self, cache, store, metric_sids: np.ndarray,
+                       rows, num_selected: int, tsq: TSQuery,
+                       bucket_ts: np.ndarray, interval_ms: int,
+                       fn: str, budget: int, stats):
+        """The whole metric's padded ``[series x bucket]`` grid of this
+        (window, downsample), resident in HBM, and what ``rows`` (the
+        request's rows of ``metric_sids``; ``num_selected`` of them)
+        hold of it: ``(grid, has_data, num_points)``, or None where
+        the request should scan its own rows instead.
+
+        One entry a (store, metric, plan-index version, window,
+        downsample): a request's filter is not part of the key, it
+        goes up as one group label a row (excluded rows on the dummy
+        group the padded rows already have), so every panel of a
+        dashboard and every rule of an evaluator's pass reads the
+        entry the first one built. Valid while the store's
+        ``(points_written, mutation_epoch)`` is what it was BEFORE the
+        build read the store: any write, delete or lifecycle sweep
+        makes the next request build again, so a hit holds the cells a
+        fresh scan would write. One build at a time: the second
+        sub-query of a request waits for the first's and hits.
+
+        Taken by what the request shows, no key: a selection of at
+        least :data:`RESIDENT_GRID_MIN_SHARE` of the metric, the
+        metric's grid within the cell ``budget`` and the cache's
+        bytes. The entry keeps each row's point count of the window
+        on the host, so ``num_points`` (the limits' check, the scan's
+        stat points) is the selection's, as on the path it
+        replaces."""
+        from opentsdb_tpu.ops import shapes
+        from opentsdb_tpu.ops.pipeline import pipeline_dtype, put_grid
+        n, b = len(metric_sids), len(bucket_ts)
+        cells = shapes.shape_bucket(n) * shapes.shape_bucket(b)
+        if num_selected < RESIDENT_GRID_MIN_SHARE * n or n * b > budget \
+                or cells * (np.dtype(pipeline_dtype()).itemsize + 1) \
+                > cache.max_bytes:
+            return None
+        metric_id = store.series(int(metric_sids[0])).metric_id
+        ckey = (RESIDENT_GRID_KEY, _store_id(store), metric_id, n,
+                tsq.start_ms, tsq.end_ms, int(bucket_ts[0]),
+                interval_ms, b, fn)
+        # the span is the look-up and, for the request that arrives
+        # during another's build, the wait for it
+        lookup = trace_begin("query.grid_build", stage="cache_lookup")
+        with self.tsdb._resident_grid_lock:
+            # read before the store is: a write during the build
+            # leaves an entry the next request will not trust
+            cver = (store.points_written,
+                    getattr(store, "mutation_epoch", 0))
+            hit = cache.get(ckey, cver)
+            if lookup is not None:
+                lookup.tag(grid="resident_hit" if hit is not None
+                           else "resident_built")
+            trace_end(lookup)
+            if hit is None:
+                counts = store.count_range(metric_sids, tsq.start_ms,
+                                           tsq.end_ms)
+                num_points = int(counts[rows].sum())
+                # the pass reads the metric; the request answers for
+                # its own rows of it, as a hit will
+                grid, has_data, _ = self._reduce_to_grid(
+                    store, metric_sids, tsq, bucket_ts, interval_ms,
+                    GRID_STATS[fn],
+                    lambda scan, _: self._record_scan(
+                        stats, scan, num_points, num_selected))
+                if counts.any():
+                    grid, has_data = put_grid(grid, has_data)
+                    cache.put(ckey, cver, (grid, has_data),
+                              {"counts": counts})
+                return grid, has_data, num_points
+        (grid, has_data), meta = hit
+        num_points = int(meta["counts"][rows].sum())
+        self._record_scan(stats, self._scan_begin(), num_points,
+                          num_selected)
         return grid, has_data, num_points
 
     def _grid_pipeline(self, store, sids: np.ndarray, tsq: TSQuery,
                        sub: TSSubQuery, metric_name: str,
                        group_ids: np.ndarray, num_groups: int,
                        emit_raw: bool, budget: int, stats,
-                       ds_fn_override: str | None = None):
+                       ds_fn_override: str | None = None,
+                       metric_rows: tuple | None = None):
         """Storage-side downsample: one fused native pass produces the
         [S, B] grid (ref analogue: the scan + Downsampler stages of
         TsdbQuery.java:795 + Downsampler.java:28 collapsed into the
         storage engine), then the device runs only the
         fill/rate/interpolate/aggregate tail. Returns None when
         ineligible (caller falls through to the point paths), or
-        (result, emit, bucket_ts) with result=None for no data."""
+        (result, emit, bucket_ts) with result=None for no data.
+
+        ``metric_rows``: ``(the metric's whole sids, the selection's
+        rows of them)`` where the plan index planned the selection;
+        such a request may run over the metric's resident grid
+        (:meth:`_resident_grid`) instead of scanning its own rows."""
         if not self._grid_eligible(sub):
             return None
         ds_spec = sub.ds_spec
@@ -1822,9 +1935,30 @@ class QueryEngine:
         ckey = cver = None
         grid = has_data = None
         mesh_args = mesh_meta = None
-        if cache is not None:
+        # what the tail program runs over: the selection's rows and
+        # group ids, or every row of the metric, labelled
+        tail_rows, tail_gids = len(sids), group_ids
+        resident = None
+        if cache is not None and mesh is None and not emit_raw \
+                and not tsq.delete and metric_rows is not None:
+            resident = self._resident_grid(
+                cache, store, *metric_rows, len(sids), tsq, bucket_ts,
+                ds_spec.interval_ms, fn, budget, stats)
+        if resident is not None:
+            grid, has_data, num_points = resident
+            if num_points:
+                # all that this request puts up: a label a resident
+                # row, the rows its filter dropped on the dummy group
+                # (the one shapes.pad_group_ids gives padded rows)
+                with trace_span("query.upload", stage="labels"):
+                    tail_rows = len(metric_rows[0])
+                    tail_gids = np.full(tail_rows, num_groups,
+                                        np.int32)
+                    tail_gids[metric_rows[1]] = group_ids
+        elif cache is not None:
             from opentsdb_tpu.query.device_cache import array_digest
-            with trace_span("query.grid_build", stage="cache_lookup"):
+            with trace_span("query.grid_build", stage="cache_lookup",
+                            grid="selection"):
                 ckey = ("grid", _store_id(store), array_digest(
                     np.ascontiguousarray(sids)), tsq.start_ms,
                     tsq.end_ms, int(bucket_ts[0]),
@@ -1844,8 +1978,10 @@ class QueryEngine:
         if built:
             grid, has_data, num_points = self._reduce_to_grid(
                 store, sids, tsq, bucket_ts, ds_spec.interval_ms,
-                GRID_STATS[fn], stats)
-        else:
+                GRID_STATS[fn],
+                lambda scan, points: self._record_scan(
+                    stats, scan, points, len(sids)))
+        elif resident is None:
             self._record_scan(stats, self._scan_begin(), num_points,
                               len(sids))
         self.tsdb.query_limits.check(metric_name, num_points)
@@ -1860,7 +1996,7 @@ class QueryEngine:
                       {"num_points": num_points})
         t2 = time.monotonic()
         spec = PipelineSpec(
-            num_series=len(sids), num_buckets=b, num_groups=num_groups,
+            num_series=tail_rows, num_buckets=b, num_groups=num_groups,
             # the grid TAIL never reads ds_function (downsampling
             # already happened storage-side) but it IS part of the jit
             # static key — normalize it so sum/avg/min/... grid queries
@@ -1931,14 +2067,14 @@ class QueryEngine:
 
             def host_retry():
                 return execute_grid(grid, has_data, bucket_ts,
-                                    group_ids,
+                                    tail_gids,
                                     replace(spec, host=True),
                                     sub.rate_options,
                                     device=self._host_cpu())
 
             result, emit = self._run_device(
                 lambda: execute_grid(grid, has_data, bucket_ts,
-                                     group_ids, spec,
+                                     tail_gids, spec,
                                      sub.rate_options,
                                      device=host_dev),
                 host_retry, on_device=host_dev is None)

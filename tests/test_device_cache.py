@@ -2,11 +2,19 @@
 storage-side bucket pre-reduction: correctness of invalidation (a hit
 must be bit-identical to a fresh scan) and backend equivalence."""
 
+import threading
+import time
+
+import jax
 import numpy as np
 import pytest
 
 from opentsdb_tpu import TSDB, Config
+from opentsdb_tpu.obs import trace as trace_mod
+from opentsdb_tpu.query import engine as engine_mod
+from opentsdb_tpu.query.limits import QueryLimitExceeded
 from opentsdb_tpu.query.model import TSQuery
+from opentsdb_tpu.stats.stats import QueryStat, QueryStats
 
 BASE = 1356998400
 
@@ -328,3 +336,423 @@ class TestRankPrepKeyGroupCount:
         cache = t.device_grid_cache
         prep_keys = [k for k in cache._entries if k[0] == "prep"]
         assert len(prep_keys) == 2, prep_keys
+
+
+# ---------------------------------------------------------------------
+# the metric's resident grid (engine._resident_grid, PR 43): one entry a
+# (store, metric, index version, window, downsample); a request's
+# filter goes up as one label a row
+# ---------------------------------------------------------------------
+
+RES_HOSTS = 40
+RES_END = BASE + 1800
+GROUP_DC = {"type": "wildcard", "tagk": "dc", "filter": "*",
+            "groupBy": True}
+NOT_RACK3 = {"type": "not_literal_or", "tagk": "rack", "filter": "r3",
+             "groupBy": False}
+
+
+def _seed_fleet(t, hosts=RES_HOSTS):
+    """A counter a host, a point every 30 s; a tenth of the hosts
+    gappy (half their points missing); tags host (unique), dc (4),
+    rack (8); every seventh host carries a key of its own."""
+    rng = np.random.default_rng(7)
+    ts = BASE + np.arange(60) * 30
+    for i in range(hosts):
+        keep = rng.random(60) > (0.5 if i % 10 == 0 else 0.0)
+        tags = {"host": f"h{i:02d}", "dc": f"d{i % 4}",
+                "rack": f"r{i % 8}"}
+        if i % 7 == 6:
+            tags["extra"] = "x"
+        t.add_points("m", ts[keep], np.cumsum(
+            rng.integers(1, 50, 60))[keep].astype(float), tags)
+
+
+def _rq(agg="sum", ds="1m-avg", rate=False, filters=(GROUP_DC,
+                                                    NOT_RACK3),
+        explicit=False, delete=False, subs=1, end=RES_END):
+    sub = {"metric": "m", "aggregator": agg, "downsample": ds,
+           "filters": list(filters), "explicitTags": explicit}
+    if rate:
+        sub["rate"] = True
+        sub["rateOptions"] = {"counter": True, "counterMax": 10000}
+    more = [dict(sub, aggregator="max")] * (subs - 1)
+    return TSQuery.from_json({
+        "start": BASE * 1000, "end": end * 1000, "delete": delete,
+        "queries": [sub] + more}).validate()
+
+
+def _kinds(t):
+    return sorted(k[0] for k in t.device_grid_cache._entries)
+
+
+def _scanned(t, monkeypatch, query):
+    """The answer of today's path over the same store: a grid of the
+    selection's own rows, scanned for this request."""
+    with monkeypatch.context() as m:
+        m.setattr(engine_mod, "RESIDENT_GRID_MIN_SHARE", 2.0)
+        before = _kinds(t).count(engine_mod.RESIDENT_GRID_KEY)
+        out = t.execute_query(query)
+        assert _kinds(t).count(engine_mod.RESIDENT_GRID_KEY) == before
+    return out
+
+
+def _same_answers(got, want, exact: bool, rtol: float):
+    assert [(r.metric, r.tags, r.aggregated_tags) for r in got] == \
+        [(r.metric, r.tags, r.aggregated_tags) for r in want]
+    for g, w in zip(got, want):
+        # the emit mask: the same timestamps on both sides
+        assert [ts for ts, _ in g.dps] == [ts for ts, _ in w.dps]
+        gv = np.asarray([v for _, v in g.dps], dtype=np.float64)
+        wv = np.asarray([v for _, v in w.dps], dtype=np.float64)
+        if exact:
+            np.testing.assert_array_equal(gv, wv)
+        else:
+            np.testing.assert_allclose(
+                gv, wv, rtol=0, atol=rtol * np.nanmax(np.abs(wv)))
+
+
+# max / min / percentiles pick a member's value: bit for bit. A sum
+# adds a group's rows in another order (the metric's rows, the
+# selection's): eight roundings of the compute dtype, set beforehand
+ROUTE_CASES = [
+    ("sum", "1m-avg", False, False),
+    ("max", "1m-avg", False, True),
+    ("min", "1m-max", False, True),
+    ("p95", "1m-avg", False, True),
+    ("sum", "1m-avg", True, False),
+    ("sum", "1m-avg-zero", False, False),
+    ("max", "1m-avg-zero", False, True),
+    ("p95", "1m-sum-zero", False, True),
+    ("avg", "5m-sum", True, False),
+]
+
+
+class TestResidentGridRoute:
+    @pytest.mark.parametrize("x64", [False, True], ids=["f32", "f64"])
+    @pytest.mark.parametrize(
+        "agg, ds, rate, exact", ROUTE_CASES,
+        ids=[f"{a}:{d}{':rate' if r else ''}"
+             for a, d, r, _ in ROUTE_CASES])
+    def test_answers_as_a_scan_of_the_selection(
+            self, monkeypatch, agg, ds, rate, exact, x64):
+        with jax.enable_x64(x64):
+            t = _tsdb()
+            _seed_fleet(t)
+            q = _rq(agg, ds, rate)
+            got = t.execute_query(q)
+            assert got and _kinds(t) == [engine_mod.RESIDENT_GRID_KEY]
+            want = _scanned(t, monkeypatch, q)
+            _same_answers(got, want, exact,
+                          8 * float(np.finfo(
+                              np.float64 if x64 else np.float32).eps))
+            cache = t.device_grid_cache
+            hits = cache.hits
+            again = t.execute_query(q)
+            assert cache.hits == hits + 1
+            assert [r.dps for r in again] == [r.dps for r in got]
+
+    def test_one_entry_serves_every_filter_and_aggregator(self):
+        t = _tsdb()
+        _seed_fleet(t)
+        cache = t.device_grid_cache
+        for rack in range(8):
+            for agg in ("sum", "max"):
+                t.execute_query(_rq(agg, filters=(GROUP_DC, dict(
+                    NOT_RACK3, filter=f"r{rack}"))))
+        assert (cache.misses, cache.hits) == (1, 15)
+        assert _kinds(t) == [engine_mod.RESIDENT_GRID_KEY]
+
+    def test_a_group_the_filter_left_without_members(self,
+                                                     monkeypatch):
+        t = _tsdb()
+        _seed_fleet(t)
+        q = _rq("sum", filters=(GROUP_DC, {
+            "type": "not_literal_or", "tagk": "dc", "filter": "d3",
+            "groupBy": False}))
+        got = t.execute_query(q)
+        assert _kinds(t) == [engine_mod.RESIDENT_GRID_KEY]
+        assert [r.tags["dc"] for r in got] == ["d0", "d1", "d2"]
+        _same_answers(got, _scanned(t, monkeypatch, q), False, 1e-12)
+
+    def test_explicit_tags(self, monkeypatch):
+        t = _tsdb()
+        _seed_fleet(t)
+        q = _rq("max", explicit=True, filters=(
+            GROUP_DC, NOT_RACK3, {"type": "wildcard", "tagk": "host",
+                                  "filter": "*", "groupBy": False}))
+        got = t.execute_query(q)
+        assert got and _kinds(t) == [engine_mod.RESIDENT_GRID_KEY]
+        want = _scanned(t, monkeypatch, q)
+        _same_answers(got, want, True, 0.0)
+        # the hosts with a key of their own are out of both
+        loose = t.execute_query(_rq("max"))
+        assert [r.dps for r in loose] != [r.dps for r in got]
+
+    def test_a_selection_under_half_the_metric_is_scanned(self):
+        t = _tsdb()
+        _seed_fleet(t)
+        few = {"type": "literal_or", "tagk": "rack",
+               "filter": "r1|r2|r3", "groupBy": False}
+        assert t.execute_query(_rq("sum", filters=(GROUP_DC, few)))
+        assert _kinds(t) == ["grid"]          # 15 of 40 rows
+        half = dict(few, filter="r1|r2|r3|r4")
+        assert t.execute_query(_rq("sum", filters=(GROUP_DC, half)))
+        assert _kinds(t) == ["grid", engine_mod.RESIDENT_GRID_KEY]
+
+    @pytest.mark.parametrize("warm", [False, True],
+                             ids=["built", "hit"])
+    def test_points_and_limits_read_the_selection(self, monkeypatch,
+                                                  warm):
+        t = _tsdb()
+        _seed_fleet(t)
+        q = _rq("sum")
+        mid = t.uids.metrics.get_id("m")
+        all_sids = t.store.series_ids_for_metric(mid)
+        picked = [s for s in all_sids if ("rack", "r3") not in {
+            (t.uids.tag_names.get_name(k), t.uids.tag_values.get_name(v))
+            for k, v in t.store.series(int(s)).tags}]
+        assert len(picked) == RES_HOSTS - 5
+        want = int(t.store.count_range(picked, BASE * 1000,
+                                       RES_END * 1000).sum())
+        whole = int(t.store.count_range(all_sids, BASE * 1000,
+                                        RES_END * 1000).sum())
+        if warm:
+            t.execute_query(q)
+        stats = QueryStats(remote="test", query=None)
+        t.new_query().run(q, stats)
+        stats.mark_complete()
+        assert _kinds(t) == [engine_mod.RESIDENT_GRID_KEY]
+        assert stats.stats[QueryStat.DPS_POST_FILTER.value] == want
+        assert stats.stats[QueryStat.ROWS_FROM_STORAGE.value] == \
+            len(picked)
+        # a limit between the selection's points and the metric's
+        # lets the request through; one under the selection's refuses
+        monkeypatch.setattr(t.query_limits, "default_data_points_limit",
+                            (want + whole) // 2)
+        assert t.execute_query(q)
+        monkeypatch.setattr(t.query_limits, "default_data_points_limit",
+                            want - 1)
+        with pytest.raises(QueryLimitExceeded):
+            t.execute_query(q)
+
+
+def _write_inside(t):
+    t.add_point("m", BASE + 45, 5000.0, {"host": "h01", "dc": "d1",
+                                         "rack": "r1"})
+
+
+def _write_outside(t):
+    t.add_point("m", RES_END + 600, 1.0, {"host": "h01", "dc": "d1",
+                                          "rack": "r1"})
+
+
+def _delete(t):
+    sids = t.store.series_ids_for_metric(t.uids.metrics.get_id("m"))
+    assert t.store.delete_range(sids[:3], BASE * 1000,
+                                (BASE + 600) * 1000)
+
+
+def _epoch_bump(t):
+    # what a lifecycle sweep or an fsck repair does to the store
+    t.store.mutation_epoch += 1
+
+
+def _new_series(t):
+    t.add_point("m", BASE + 60, 7.0, {"host": "h99", "dc": "d1",
+                                      "rack": "r1"})
+
+
+class TestResidentGridValidity:
+    @pytest.mark.parametrize("change, moves", [
+        (_write_inside, True), (_write_outside, False),
+        (_delete, True), (_epoch_bump, False), (_new_series, True)],
+        ids=["write_inside", "write_outside", "delete", "epoch_bump",
+             "new_series"])
+    def test_a_change_of_the_store_rebuilds(self, monkeypatch, change,
+                                            moves):
+        t = _tsdb()
+        _seed_fleet(t)
+        q = _rq("sum")
+        first = t.execute_query(q)
+        cache = t.device_grid_cache
+        change(t)
+        misses = cache.misses
+        got = t.execute_query(q)
+        assert cache.misses == misses + 1
+        assert ([r.dps for r in got] != [r.dps for r in first]) == moves
+        _same_answers(got, _scanned(t, monkeypatch, q), False, 1e-12)
+        hits = cache.hits
+        t.execute_query(q)
+        assert cache.hits == hits + 1
+
+    def test_a_delete_query_scans_its_own_rows(self):
+        t = _tsdb()
+        _seed_fleet(t)
+        t.execute_query(_rq("sum"))
+        cache = t.device_grid_cache
+        seen = (cache.hits, cache.misses)
+        removed = t.execute_query(_rq("sum", delete=True,
+                                      end=BASE + 600))
+        assert removed
+        assert not any(k[0] == engine_mod.RESIDENT_GRID_KEY
+                       and k[5] == (BASE + 600) * 1000
+                       for k in cache._entries)
+        assert cache.hits == seen[0]
+        # and what it removed is gone from the next answer
+        after = t.execute_query(_rq("sum", end=BASE + 600))
+        assert after == []
+
+    def test_two_sub_queries_released_together_build_one_entry(self):
+        t = _tsdb()
+        _seed_fleet(t)
+        engine = t.new_query()
+        scans = []
+        gate = threading.Barrier(2, timeout=30)
+        fused = t.store.bucket_grid
+
+        def counted(*args):
+            scans.append(len(args[0]))
+            return fused(*args)
+
+        t.store.bucket_grid = counted
+        out = [None, None]
+
+        def sub(i, agg):
+            gate.wait()
+            out[i] = engine.run(_rq(agg))
+
+        threads = [threading.Thread(target=sub, args=(0, "sum")),
+                   threading.Thread(target=sub, args=(1, "max"))]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+            assert not th.is_alive()
+        cache = t.device_grid_cache
+        assert (cache.misses, cache.hits) == (1, 1)
+        assert scans == [RES_HOSTS]
+        assert out[0] and out[1]
+        # one request of two sub-queries (the live dashboards'): the same
+        t.drop_caches()
+        both = t.execute_query(_rq("sum", subs=2))
+        assert (cache.misses, cache.hits) == (2, 2)
+        assert [r.dps for r in both] == \
+            [r.dps for r in out[0]] + [r.dps for r in out[1]]
+
+    def test_many_requests_side_by_side_build_once(self):
+        """More workers than cores, each with a filter of its own,
+        while a writer adds points in bursts: every burst costs one
+        build, never one a request, and once the writer has stopped
+        every worker's answer is the serial one."""
+        import sys
+        t = _tsdb()
+        _seed_fleet(t)
+        engine = t.new_query()
+        queries = [_rq(agg, filters=(GROUP_DC, dict(
+            NOT_RACK3, filter=f"r{i % 8}")))
+            for i, agg in enumerate(["sum", "max"] * 8)]
+        bursts = 5
+        stop = threading.Event()
+        errors = []
+
+        def worker(i):
+            try:
+                while not stop.is_set():
+                    engine.run(queries[i])
+            except Exception as exc:  # noqa: BLE001
+                errors.append(exc)
+
+        def writer():
+            try:
+                for j in range(bursts):
+                    t.add_point("m", BASE + 200 + j, 1.0 + j,
+                                {"host": "h02", "dc": "d2",
+                                 "rack": "r2"})
+                    time.sleep(0.05)
+            except Exception as exc:  # noqa: BLE001
+                errors.append(exc)
+            finally:
+                stop.set()
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=worker, args=(i,))
+                       for i in range(len(queries))]
+            threads.append(threading.Thread(target=writer))
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(120)
+                assert not th.is_alive()
+        finally:
+            stop.set()
+            sys.setswitchinterval(interval)
+        assert not errors, errors
+        cache = t.device_grid_cache
+        # a build a version of the store at most: the seeded one and
+        # one a burst
+        assert 1 <= cache.misses <= bursts + 1
+        assert _kinds(t) == [engine_mod.RESIDENT_GRID_KEY]
+        misses = cache.misses
+        got = [engine.run(q) for q in queries]
+        assert cache.misses in (misses, misses + 1)
+        t.drop_caches()
+        assert [[r.dps for r in rs] for rs in got] == \
+            [[r.dps for r in engine.run(q)] for q in queries]
+
+    def test_an_entry_larger_than_the_cache_is_not_resident(
+            self, monkeypatch):
+        t = _tsdb()
+        _seed_fleet(t)
+        q = _rq("sum")
+        want = [r.dps for r in t.execute_query(q)]
+        t.drop_caches()
+        # the metric's padded grid is 40 x 32 cells of 9 bytes; the
+        # selection's (35 rows) pads to the same shape, so nothing of
+        # this window fits and nothing is kept
+        monkeypatch.setattr(t.device_grid_cache, "max_bytes",
+                            40 * 32 * 9 - 1)
+        assert [r.dps for r in t.execute_query(q)] == want
+        assert _kinds(t) == []
+        monkeypatch.setattr(t.device_grid_cache, "max_bytes",
+                            40 * 32 * 9)
+        assert [r.dps for r in t.execute_query(q)] == want
+        assert _kinds(t) == [engine_mod.RESIDENT_GRID_KEY]
+
+    def test_no_cache_answers_as_before(self, monkeypatch):
+        a = _tsdb()
+        b = _tsdb(**{"tsd.query.device_cache_mb": "0"})
+        _seed_fleet(a)
+        _seed_fleet(b)
+        assert b.device_grid_cache is None
+        for agg, exact in (("sum", False), ("max", True)):
+            q = _rq(agg)
+            _same_answers(b.execute_query(q), a.execute_query(q),
+                          exact, 1e-12)
+            assert [r.dps for r in b.execute_query(q)] == \
+                [r.dps for r in _scanned(a, monkeypatch, q)]
+
+    def test_the_stats_count_look_ups_by_source(self):
+        t = _tsdb(**{"tsd.trace.sample": "1"})
+        _seed_fleet(t)
+        few = {"type": "literal_or", "tagk": "rack", "filter": "r1",
+               "groupBy": False}
+        for q in (_rq("sum"), _rq("max"), _rq("sum", subs=2),
+                  _rq("sum", filters=(GROUP_DC, few))):
+            ctx = t.tracer.start_request("query.http")
+            with trace_mod.use(ctx):
+                t.execute_query(q)
+            t.tracer.finish(ctx)
+        assert t.tracer.grids == {
+            "resident_built": 1, "resident_hit": 3, "selection": 1}
+        rows = {}
+
+        class Collector:
+            def record(self, name, value, **tags):
+                if name == "query.grid":
+                    rows[tags["source"]] = value
+
+        t.tracer.collect_stats(Collector())
+        assert rows == t.tracer.grids
