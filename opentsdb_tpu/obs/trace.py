@@ -771,6 +771,10 @@ class Tracer:
         # tags: the six paths its callers name x two placements x
         # two classes of group stage (rank | linear)
         self.tails: dict[tuple[str, str, str], int] = {}
+        # the rank class's programs, by how their group stage reads
+        # its order statistics: "select" (counting, the device's
+        # lowering) or "sort" (one sort of the grid)
+        self.ranks = {"select": 0, "sort": 0}
         # grids built, by who wrote the padded grid: "fused" (the
         # store's own pass) or "host" (fill_padded_grid)
         self.grid_builds = {"fused": 0, "host": 0}
@@ -1061,7 +1065,8 @@ class Tracer:
         children's intervals) feeds ``tsd_stage_self_ms`` where the
         span has children; the part of it with no program in flight
         on the device adds to ``idle_stage_ms`` of its stage; every
-        ``query.program`` counts in ``tails``, every ``query.grid_build``
+        ``query.program`` counts in ``tails`` (and by its tag ``rank``
+        in ``ranks``), every ``query.grid_build``
         that built a grid (tag ``fused``) in ``grid_builds`` and that
         looked one up (tag ``grid``) in ``grids``, every
         ``query.plan`` that reached its filters (tag ``index``) in
@@ -1077,6 +1082,7 @@ class Tracer:
         idle: dict[str, float] = {}
         observed = 0
         tails = []
+        ranks = []
         builds = []
         grids = []
         plans = []
@@ -1107,6 +1113,8 @@ class Tracer:
                 tails.append((str(s.tags.get("path", "?")),
                               str(s.tags.get("placement", "?")),
                               str(s.tags.get("class", "?"))))
+                if s.tags.get("rank") in self.ranks:
+                    ranks.append(s.tags["rank"])
             elif s.name == "query.grid_build":
                 if "fused" in s.tags:
                     builds.append("fused" if s.tags["fused"]
@@ -1132,6 +1140,8 @@ class Tracer:
                     self.idle_stage_ms.get(name, 0.0) + ms
             for key in tails:
                 self.tails[key] = self.tails.get(key, 0) + 1
+            for method in ranks:
+                self.ranks[method] += 1
             for mode in builds:
                 self.grid_builds[mode] += 1
             for source in grids:
@@ -1233,6 +1243,7 @@ class Tracer:
             collector.record("trace.observations", self.observations)
             idle = sorted(self.idle_stage_ms.items())
             tails = sorted(self.tails.items())
+            ranks = sorted(self.ranks.items())
             builds = sorted(self.grid_builds.items())
             grids = sorted(self.grids.items())
             plans = sorted(self.plans.items())
@@ -1245,6 +1256,8 @@ class Tracer:
         for (path, placement, cls), n in tails:
             collector.record("query.tail", n, path=path,
                              placement=placement, **{"class": cls})
+        for method, n in ranks:
+            collector.record("query.rank", n, method=method)
         for mode, n in builds:
             collector.record("query.grid_build", n, mode=mode)
         for source, n in grids:

@@ -7,9 +7,17 @@ runs the AggregationIterator merge loop lazily during serialization)
 Here a group is a segment id per series: after interpolation fill
 (:mod:`opentsdb_tpu.ops.interp`), one segment reduction over axis 0 of
 the ``[series, bucket]`` grid aggregates every group and every bucket at
-once. Order-statistic aggregators (median / percentiles) use a single
-lexicographic ``lax.sort`` keyed by (group, NaN-last, value) — the
-across-series analogue of the bucketize sort path.
+once. Order-statistic aggregators (median / percentiles) read the two
+neighbours of their position a (group, bucket) one of two ways
+(:func:`rank_lowering`): on the device, for float32 cells under 2**24
+rows and at most 256 groups, a radix selection that counts the
+candidates a key digit with the group-sum's one-hot contraction and
+moves no cell (since PR 44); anywhere else (a tail placed on the host
+CPU backend, float64 under x64, more groups than the selection wins
+at) a
+single lexicographic ``lax.sort`` keyed by (group, NaN-last, value) —
+the across-series analogue of the bucketize sort path. Both give the
+same bits.
 """
 
 from __future__ import annotations
@@ -173,23 +181,50 @@ def _group_reduce(filled, group_ids, num_groups: int, agg_name: str,
             raise ValueError(f"unsupported group aggregator {agg_name}")
         with jax.named_scope("tail.group_rank"):
             out = _group_rank(filled, valid, cnt, group_ids, num_groups,
-                              q, est)
+                              q, est, prefer_segment)
     return jnp.where(any_valid, out, jnp.nan)
 
 
+# Bits of the key a step of the selection resolves: 2**bits digit
+# values, one column of counts a (rank, bucket, digit value below the
+# top one) on the MXU. The stage alone on a v5e at [1,048,576 x 12],
+# 112 groups: 10.9 ms at 2 bits, 13.5 at 1, 12.7 at 4 (PR 44).
+_SELECT_DIGIT_BITS = 2
+
+
+# Past this many (padded) groups the selection's two contractions a
+# step cost more than the sort, which does not see the group count:
+# the stage alone on a v5e at [1,048,576 x 12], ms, selection against
+# sort: 10.9 / 52.8 at 112 groups, 45.2 at 256, 49.6 at 512, 60.8 at
+# 1,024, 86.9 / 51.2 at 1,792 (PERF.md section 6, PR 44). Smaller
+# grids sort in fewer passes, so the line is drawn on the near side.
+_SELECT_MAX_GROUPS = 256
+
+
+def rank_lowering(num_series: int, num_groups: int, dtype,
+                  prefer_segment: bool = False) -> str:
+    """Which lowering :func:`_group_rank` takes for a padded shape:
+    ``select`` (counting, where :func:`_group_sum` would take its
+    one-hot contraction, a count is exact and the groups are few:
+    float32 cells, under 2**24 rows, at most
+    :data:`_SELECT_MAX_GROUPS` groups) or ``sort`` (a host-placed
+    tail, float64 under x64, a group count past the one-hot budget or
+    past what the selection wins at)."""
+    if not prefer_segment and jnp.dtype(dtype) == jnp.float32 \
+            and num_series < (1 << 24) \
+            and num_groups <= _SELECT_MAX_GROUPS \
+            and num_series * num_groups <= _MATMUL_GROUP_MAX_ELEMS:
+        return "select"
+    return "sort"
+
+
 def _group_rank(filled, valid, cnt, group_ids, num_groups, q: float,
-                est: str):
-    """Order statistics per (group, bucket) via one lax.sort along the
-    series axis keyed lexicographically by (group, NaN-last, value)."""
-    s, b = filled.shape
-    gkey = jnp.broadcast_to(group_ids[:, None], (s, b)).astype(jnp.int32)
-    # lax.sort's total order puts NaN after every number, so missing
-    # cells land at the end of their group without a separate NaN key
-    _, sorted_vals = jax.lax.sort((gkey, filled), num_keys=2,
-                                  dimension=0)
-    sizes = jax.ops.segment_sum(jnp.ones_like(group_ids), group_ids,
-                                num_groups)
-    starts = jnp.cumsum(sizes) - sizes  # [G]
+                est: str, prefer_segment: bool = False):
+    """Order statistics per (group, bucket): the two neighbours of the
+    position ``h`` among a group's valid cells of a bucket, read by
+    :func:`_ranks_by_selection` or out of :func:`_ranks_by_sort`'s
+    sorted copy of the grid (:func:`rank_lowering` says which)."""
+    s, _b = filled.shape
     n = cnt  # [G,B] valid counts
     p = q / 100.0
     if est == "median":
@@ -208,12 +243,96 @@ def _group_rank(filled, valid, cnt, group_ids, num_groups, q: float,
     lo_off = jnp.clip(h_floor.astype(jnp.int32) - 1, 0, None)
     max_off = jnp.maximum(n.astype(jnp.int32) - 1, 0)
     hi_off = jnp.minimum(lo_off + 1, max_off)
-    lo_row = jnp.clip(starts[:, None] + jnp.minimum(lo_off, max_off),
-                      0, s - 1)
+    lo_off = jnp.minimum(lo_off, max_off)
+    if rank_lowering(s, num_groups, filled.dtype,
+                     prefer_segment) == "select":
+        lo, hi = _ranks_by_selection(filled, valid, group_ids,
+                                     num_groups, lo_off, hi_off)
+    else:
+        lo, hi = _ranks_by_sort(filled, group_ids, num_groups, lo_off,
+                                hi_off)
+    return lo + frac * (hi - lo)
+
+
+def _ranks_by_sort(filled, group_ids, num_groups, lo_off, hi_off):
+    """One lax.sort along the series axis keyed lexicographically by
+    (group, NaN-last, value), then one gather a rank."""
+    s, b = filled.shape
+    gkey = jnp.broadcast_to(group_ids[:, None], (s, b)).astype(jnp.int32)
+    # lax.sort's total order puts NaN after every number, so missing
+    # cells land at the end of their group without a separate NaN key
+    _, sorted_vals = jax.lax.sort((gkey, filled), num_keys=2,
+                                  dimension=0)
+    sizes = jax.ops.segment_sum(jnp.ones_like(group_ids), group_ids,
+                                num_groups)
+    starts = jnp.cumsum(sizes) - sizes  # [G]
+    lo_row = jnp.clip(starts[:, None] + lo_off, 0, s - 1)
     hi_row = jnp.clip(starts[:, None] + hi_off, 0, s - 1)
     lo = jnp.take_along_axis(sorted_vals, lo_row, axis=0)
     hi = jnp.take_along_axis(sorted_vals, hi_row, axis=0)
-    return lo + frac * (hi - lo)
+    return lo, hi
+
+
+def _flip_order_bits(bits):
+    """int32 bits of a float32 <-> an int32 of the same total order
+    (-inf < ... < -0 < +0 < ... < +inf): its own inverse."""
+    return bits ^ ((bits >> 31) & jnp.int32(0x7fffffff))
+
+
+def _ranks_by_selection(filled, valid, group_ids, num_groups, lo_off,
+                        hi_off):
+    """The same two values by a radix selection: no cell moves.
+
+    A float32 maps to a uint32 key of the same total order
+    (:func:`_flip_order_bits`, the sign bit flipped for an unsigned
+    walk). The key's digits are walked
+    from the top: a step counts, per (rank, group, bucket), the
+    candidates whose digit is at most d (a 0/1 indicator contracted
+    with the one-hot of the group label over the series axis:
+    :func:`_group_sum`'s lowering, bfloat16 operands, float32
+    accumulation, exact under 2**24 rows), picks the digit the wanted
+    rank falls in, sends it back to the rows (the same one-hot
+    contracted the other way) and keeps the rows that agree. The
+    walked digits are the value: only flags and counts pass through
+    the MXU. The walk is written over [bucket, series], the series
+    contracted (the compiled grid lies series-minor already: the
+    transposes move nothing); both ranks walk side by side."""
+    keys = _flip_order_bits(
+        jax.lax.bitcast_convert_type(filled, jnp.int32))
+    keys = jax.lax.bitcast_convert_type(keys, jnp.uint32).T \
+        ^ jnp.uint32(0x80000000)                        # [B,S]
+    want = jnp.stack([lo_off.T, hi_off.T]) + 1          # [2,B,G]
+    cand = jnp.broadcast_to(valid.T, (2,) + keys.shape)  # [2,B,S]
+    width = 1 << _SELECT_DIGIT_BITS
+    below = jnp.arange(width - 1, dtype=jnp.uint32)
+
+    def step(i, carry):
+        cand, want, found = carry
+        onehot = jax.nn.one_hot(group_ids, num_groups,
+                                dtype=jnp.bfloat16)     # [S,G], fused
+        shift = (32 - _SELECT_DIGIT_BITS * (i + 1)).astype(jnp.uint32)
+        digit = (keys >> shift) & jnp.uint32(width - 1)  # [B,S]
+        at_most = cand[:, None] \
+            & (digit[None, None] <= below[None, :, None, None])
+        counts = jnp.einsum(
+            "rdbs,sg->rdbg", at_most.astype(jnp.bfloat16), onehot,
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+        under = counts < want[:, None]                  # [2,D-1,B,G]
+        chosen = jnp.sum(under, axis=1, dtype=jnp.int32)
+        want = want - jnp.max(jnp.where(under, counts, 0), axis=1)
+        sent = jnp.einsum(
+            "rbg,sg->rbs", chosen.astype(jnp.bfloat16), onehot,
+            preferred_element_type=jnp.float32)
+        cand = cand & (digit[None].astype(jnp.float32) == sent)
+        return cand, want, found | (chosen.astype(jnp.uint32) << shift)
+
+    _, _, found = jax.lax.fori_loop(
+        0, 32 // _SELECT_DIGIT_BITS, step,
+        (cand, want, jnp.zeros(want.shape, jnp.uint32)))
+    vals = jax.lax.bitcast_convert_type(
+        _flip_order_bits(jax.lax.bitcast_convert_type(
+            found ^ jnp.uint32(0x80000000), jnp.int32)), jnp.float32)
+    return vals[0].T, vals[1].T
 
 
 def group_aggregate(grid, bucket_ts, group_ids, num_groups: int,

@@ -60,8 +60,10 @@ class PipelineSpec:
 
     @property
     def tail_class(self) -> str:
-        """Which class of group stage the program runs: ``rank`` (one
-        sort of the grid: median, percentiles) or ``linear`` (a
+        """Which class of group stage the program runs: ``rank`` (an
+        order statistic a group and bucket: median, percentiles; by
+        selection or by one sort of the grid,
+        :func:`opentsdb_tpu.ops.groupby.rank_lowering`) or ``linear`` (a
         contraction or a segment reduction; ``none`` has no group
         stage and counts here)."""
         return "rank" if aggs_mod.get(self.agg_name).rank_class \
@@ -279,7 +281,11 @@ def run_staged(path: str, program, operands,
     ``placement`` (``host`` for a tail pinned to the CPU backend,
     ``spec.host``), ``class`` (``spec.tail_class``: ``rank`` |
     ``linear``, or ``histogram`` for the percentile program's
-    :class:`~opentsdb_tpu.ops.histogram_kernels.HistogramSpec`), the
+    :class:`~opentsdb_tpu.ops.histogram_kernels.HistogramSpec`),
+    ``rank`` (``select`` | ``sort``: which lowering a ``class=rank``
+    program's group stage takes, from the predicate the jitted code
+    applies to the same padded shape; the mesh step has an estimator
+    of its own and carries none), the
     padded ``shape`` SxBxG and ``compiled`` when
     JAX compiled (or loaded from its cache) inside it; a
     device-placed program occupies :data:`RUNTIME`'s clock for that
@@ -291,12 +297,16 @@ def run_staged(path: str, program, operands,
     if spec is None:
         spec = next(a for a in args if isinstance(a, PipelineSpec))
     on_device = not spec.host
+    tags = {"class": spec.tail_class}
+    if spec.tail_class == "rank" and path != "mesh":
+        tags["rank"] = gb_mod.rank_lowering(
+            spec.num_series, spec.num_groups, pipeline_dtype(),
+            spec.host)
     with trace_span(
             "query.program", path=path,
             placement="device" if on_device else "host",
             shape=f"{spec.num_series}x{spec.num_buckets}"
-                  f"x{spec.num_groups}",
-            **{"class": spec.tail_class}) as span:
+                  f"x{spec.num_groups}", **tags) as span:
         compiles = RUNTIME.compiles
         if on_device:
             RUNTIME.clock.enter()

@@ -1,10 +1,12 @@
 """Group-by + fused pipeline tests
 (ref: test/core/TestSpanGroup.java, TestTsdbQueryAggregators.java)."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from opentsdb_tpu.ops import aggregators as aggs
+from opentsdb_tpu.ops import groupby as gb
 from opentsdb_tpu.ops.groupby import group_aggregate
 from opentsdb_tpu.ops.pipeline import PipelineSpec, execute
 from opentsdb_tpu.ops.downsample import FillPolicy
@@ -188,3 +190,116 @@ class TestFusedPipeline:
         result, _ = execute(values, sidx, bidx, bts,
                             np.arange(2, dtype=np.int32), spec)
         np.testing.assert_allclose(result, [[2.0, 5.0], [20.0, 50.0]])
+
+
+RANK_AGGS = ("median", "p50", "p95", "p99", "p999", "ep95r3", "ep99r7")
+
+
+def _rank_grid(case: str):
+    """(float32 grid [S, B], labels [S], groups) for one case of the
+    selection against the sort. The last label is the dummy trailing
+    group of padded and excluded rows, as the engine pads."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    s, b, g = 1003, 5, 7          # S no multiple of a tile or chunk
+    vals = rng.normal(0.0, 50.0, (s, b)).astype(np.float32)
+    gids = rng.integers(0, g - 1, s).astype(np.int32)
+    if case == "mixed":
+        special = np.array([-np.inf, np.inf, -0.0, 0.0, 1.5, 1.5, 1e-40,
+                            -1e-40, 3e38, -3e38], np.float32)
+        pick = rng.random((s, b)) < 0.4
+        vals[pick] = rng.choice(special, int(pick.sum()))
+        vals[rng.random((s, b)) < 0.1] = np.nan
+    elif case == "ties":
+        # every member of a group holds the same value in a bucket
+        vals = (gids[:, None] * 10 + np.arange(b)[None, :]) \
+            .astype(np.float32)
+    elif case == "zeros":
+        # both zeros in no order of rows: -0 < +0 in the sort's order
+        vals = rng.choice(np.array([-0.0, 0.0, -1.0, 1.0], np.float32),
+                          (s, b))
+    elif case == "infs":
+        vals = rng.choice(np.array([-np.inf, np.inf, 2.0], np.float32),
+                          (s, b), p=[0.3, 0.3, 0.4])
+        vals[gids == 2] = np.inf
+    elif case == "missing":
+        vals[rng.random((s, b)) < 0.1] = np.nan
+        vals[gids == 1, 2] = np.nan     # a bucket a group has nothing in
+        vals[gids == 4] = np.nan        # a group with no value at all
+    elif case == "one_row":
+        gids[gids == 3] = 2
+        gids[17] = 3                    # a group of one row
+        vals[17, 1] = np.nan            # and nothing of it in a bucket
+    elif case == "emptied":
+        gids[gids == 0] = g - 1         # the filter dropped a whole group
+        gids[gids == 5] = g - 1
+    elif case == "tiny":
+        s = 3
+        vals, gids = vals[:s], np.array([0, 0, g - 1], np.int32)
+    else:
+        raise AssertionError(case)
+    return vals, gids, g
+
+
+class TestRankBySelection:
+    """The device's lowering of the rank group stage (a radix
+    selection over one-hot counts) against the ``lax.sort`` body it
+    stands in for: the same cells, bit for bit."""
+
+    @pytest.mark.parametrize("agg", RANK_AGGS)
+    @pytest.mark.parametrize("case", [
+        "mixed", "ties", "zeros", "infs", "missing", "one_row",
+        "emptied", "tiny"])
+    def test_the_selection_is_the_sorts_bit_for_bit(self, case, agg):
+        vals, gids, g = _rank_grid(case)
+        assert gb.rank_lowering(len(vals), g, vals.dtype) == "select"
+        picked = np.asarray(gb._group_reduce(
+            jnp.asarray(vals), gids, g, agg))
+        # a host-placed tail keeps the sort (and counts by scatter)
+        sorted_ = np.asarray(gb._group_reduce(
+            jnp.asarray(vals), gids, g, agg, prefer_segment=True))
+        assert picked.dtype == sorted_.dtype == np.float32
+        np.testing.assert_array_equal(picked.view(np.int32),
+                                      sorted_.view(np.int32))
+        if agg == "median" and case not in ("mixed", "infs"):
+            # and both are NumPy's upper median of the valid cells
+            for k in range(g):
+                for j in range(vals.shape[1]):
+                    cell = vals[gids == k, j]
+                    cell = np.sort(cell[~np.isnan(cell)])
+                    if len(cell):
+                        assert picked[k, j] == cell[len(cell) // 2]
+                    else:
+                        assert np.isnan(picked[k, j])
+
+    @pytest.mark.parametrize("refusal", ["prefer_segment", "float64",
+                                         "over_budget", "groups",
+                                         "rows"])
+    def test_what_the_selection_cannot_count_is_sorted(
+            self, refusal, monkeypatch):
+        vals, gids, g = _rank_grid("missing")
+        rows = {"prefer_segment": 1003, "float64": 1002,
+                "over_budget": 1001, "groups": 998,
+                "rows": 1000}[refusal]
+        vals, gids = vals[:rows], gids[:rows]     # a trace of its own
+        dtype = np.float64 if refusal == "float64" else np.float32
+        if refusal == "over_budget":
+            monkeypatch.setattr(gb, "_MATMUL_GROUP_MAX_ELEMS",
+                                rows * g - 1)
+        if refusal == "groups":
+            g = gb._SELECT_MAX_GROUPS + 1     # most of them empty
+            assert gb.rank_lowering(rows, g - 1, dtype) == "select"
+        assert gb.rank_lowering(
+            1 << 24 if refusal == "rows" else rows, g, dtype,
+            refusal == "prefer_segment") == "sort"
+        assert gb.rank_lowering(rows, g, np.float32) == (
+            "sort" if refusal in ("over_budget", "groups") else "select")
+        if refusal == "rows":
+            return     # 2**24 rows: the predicate alone, no grid
+        lowered = gb._group_reduce.lower(
+            jnp.asarray(vals.astype(dtype)), gids, g, "p95",
+            prefer_segment=refusal == "prefer_segment").as_text()
+        assert "sort" in lowered and "while" not in lowered
+        monkeypatch.undo()
+        taken = gb._group_reduce.lower(     # the same grid, unrefused
+            jnp.asarray(vals[:999]), gids[:999], 7, "p95").as_text()
+        assert "while" in taken and "sort" not in taken

@@ -14,6 +14,11 @@ of one value under 10,000 and of the interpolation; in the suite's
 float64 they are the oracle's to 1e-9. One request of each class of
 group stage leaves ``class=rank`` or ``class=linear`` on its
 ``query.program`` span and moves ``tsd.query.tail{class}`` by one.
+Since PR 44 a third server runs float32 with its tails forced to the
+device's branch (as ``tests/test_device_cache.py`` forces it), where
+the rank group stage selects by counting: the same answers, and the
+span's ``rank=select|sort`` and ``tsd.query.rank{method}`` say which
+lowering ran (a host-placed tail and float64 keep the sort).
 CPU only.
 """
 
@@ -81,11 +86,14 @@ def _values(seed: int) -> np.ndarray:
 class Tsd:
     """A TSD serving on a real socket, its loop on a thread."""
 
-    def __init__(self, vals: np.ndarray):
+    def __init__(self, vals: np.ndarray, on_device: bool = False):
+        placed = {"tsd.query.host_tail_max_cells": "-1",
+                  "tsd.query.host_tail_max_cells_linear": "-1"} \
+            if on_device else {}
         self.tsdb = TSDB(Config(**{
             "tsd.core.auto_create_metrics": "true",
             "tsd.tpu.warmup": "false", "tsd.trace.sample": "1",
-            "tsd.query.cache.enable": "false"}))
+            "tsd.query.cache.enable": "false", **placed}))
         dc, rack = _dc_of(), _rack_of()
         lines = []
         for i in range(SERIES):
@@ -130,16 +138,21 @@ class Tsd:
         self.tsdb.shutdown()
 
 
-@pytest.fixture(scope="module", params=["float32", "float64"])
+@pytest.fixture(scope="module",
+                params=["float32", "float64", "float32-device"])
 def served(request):
     """(the TSD, its values, the tolerance) in the configuration's
-    precision and in the suite's; x64 is set for every thread (the
+    precision and in the suite's, and in the configuration's with the
+    tails placed as the chip's are; x64 is set for every thread (the
     server answers on its workers) and put back afterwards."""
     was = jax.config.read("jax_enable_x64")
     jax.config.update("jax_enable_x64", request.param == "float64")
     vals = _values(seed=34)
-    tsd = Tsd(vals)
-    yield tsd, vals, RANK_ATOL if request.param == "float32" else 1e-9
+    tsd = Tsd(vals, on_device=request.param.endswith("-device"))
+    # what a rank program of this server has to say of itself
+    tsd.rank_method = "select" if request.param == "float32-device" \
+        else "sort"
+    yield tsd, vals, 1e-9 if request.param == "float64" else RANK_ATOL
     tsd.stop()
     jax.config.update("jax_enable_x64", was)
 
@@ -184,19 +197,17 @@ def test_a_percentile_by_dc_is_the_oracles(served, agg):
             assert (want == top) == (agg != "p50" or size == 1)
 
 
-@pytest.mark.parametrize("agg, cls", [("p95", "rank"), ("sum", "linear")])
-def test_a_request_names_its_class_of_group_stage(served, agg, cls):
-    tsd, _vals, _tol = served
+def _counted(tsd, metric: str, tag: str) -> dict:
+    rows, _ = tsd.ask("GET", "/api/stats")
+    out: dict = {}
+    for r in rows:
+        if r["metric"] == metric:
+            out[r["tags"][tag]] = out.get(r["tags"][tag], 0) + r["value"]
+    return out
 
-    def tails():
-        rows, _ = tsd.ask("GET", "/api/stats")
-        out = {"rank": 0, "linear": 0}
-        for r in rows:
-            if r["metric"] == "tsd.query.tail":
-                out[r["tags"]["class"]] += r["value"]
-        return out
 
-    before = tails()
+def _program_of(tsd, agg: str) -> dict:
+    """The ``query.program`` span of one request for ``agg``."""
     _rows, headers = tsd.ask("POST", "/api/query", _query(agg))
     doc, _ = tsd.ask("GET", "/api/trace/" + headers["X-TSD-Trace-Id"])
     (root,) = doc["tree"]
@@ -208,9 +219,41 @@ def test_a_request_names_its_class_of_group_stage(served, agg, cls):
         return found
 
     (program,) = nodes(root, "query.program")
+    return program
+
+
+@pytest.mark.parametrize("agg, cls", [("p95", "rank"), ("sum", "linear")])
+def test_a_request_names_its_class_of_group_stage(served, agg, cls):
+    tsd, _vals, _tol = served
+    before = {"rank": 0, "linear": 0,
+              **_counted(tsd, "tsd.query.tail", "class")}
+    program = _program_of(tsd, agg)
     assert program["tags"]["class"] == cls
     assert program["tags"]["path"] == "grid"
-    after = tails()
+    assert program["tags"]["placement"] == (
+        "device" if tsd.rank_method == "select" else "host")
+    after = _counted(tsd, "tsd.query.tail", "class")
     other = "linear" if cls == "rank" else "rank"
     assert after[cls] == before[cls] + 1
+    assert after.get(other, 0) == before[other]
+
+
+@pytest.mark.parametrize("agg", ["p99", "median", "ep95r7", "sum"])
+def test_a_rank_program_says_how_it_read_its_ranks(served, agg):
+    """``rank=select`` where the group stage counted (float32 on the
+    device's branch), ``rank=sort`` where it sorted (a host-placed
+    tail; float64), no tag on a linear program; the counter moves by
+    one a sub-query, the other method's not at all."""
+    tsd, _vals, _tol = served
+    before = _counted(tsd, "tsd.query.rank", "method")
+    assert set(before) == {"select", "sort"}
+    program = _program_of(tsd, agg)
+    after = _counted(tsd, "tsd.query.rank", "method")
+    if agg == "sum":
+        assert "rank" not in program["tags"]
+        assert after == before
+        return
+    assert program["tags"]["rank"] == tsd.rank_method
+    other = "sort" if tsd.rank_method == "select" else "select"
+    assert after[tsd.rank_method] == before[tsd.rank_method] + 1
     assert after[other] == before[other]

@@ -55,3 +55,39 @@ def test_hist_200ks_percentile_program_fits_one_chip(one_chip):
     assert memory.temp_size_in_bytes < 256 << 20
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         + memory.output_size_in_bytes < 8 << 30
+
+
+def test_rank_p95s_program_selects_and_builds_no_one_hot(one_chip):
+    """``fleet-1m.rank-p95`` (PR 44): 1,048,576 x 12 float32 cells,
+    112 padded groups, ``p95``. The group stage is the selection's
+    loop and no ``sort``; the one-hot of the group label is built
+    inside each contraction's fusion, 32 times a program, and is no
+    array of its own (``[series x groups]`` in bfloat16 is 235 MB,
+    and would be read twice a step if the compiler hoisted it out of
+    the loop)."""
+    from opentsdb_tpu.ops.pipeline import PipelineSpec, run_pipeline_grid
+    s, b, g = 1 << 20, 12, 112
+    spec = PipelineSpec(num_series=s, num_buckets=b, num_groups=g,
+                        ds_function="avg", agg_name="p95")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = run_pipeline_grid.lower(
+        shape((s, b), jnp.float32), shape((s, b), jnp.bool_),
+        shape((b,), jnp.int32), shape((s,), jnp.int32),
+        (shape((), jnp.float32), shape((), jnp.float32)),
+        shape((), jnp.float32), spec=spec).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text and " while(" in text
+    # an instruction outside a fusion's body writes its result to
+    # memory: none of them has the one-hot's shape
+    fused, stored = False, []
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = line.startswith("%fused_computation")
+        elif not fused and " = " in line and \
+                f"[{s},{g}" in line.split(" = ", 1)[1].split("(")[0]:
+            stored.append(line.strip()[:120])
+    assert not stored, stored
+    assert compiled.memory_analysis().temp_size_in_bytes < 640 << 20
