@@ -133,9 +133,6 @@ class TSDB:
         self._histogram_version = 0
         from opentsdb_tpu.core.histogram import HistogramStats
         self.histogram_stats = HistogramStats()
-        # one request at a time lays a window's counts out for the
-        # device (query/histogram_engine.py)
-        self._histogram_resident_lock = threading.Lock()
         from opentsdb_tpu.meta.annotation import AnnotationStore
         self.annotations = AnnotationStore()
         from opentsdb_tpu.meta.meta_store import MetaStore
@@ -155,10 +152,6 @@ class TSDB:
         # device-resident grid cache (HBM ≙ HBase block cache); lazy
         self._device_grid_cache = None
         self._device_cache_lock = threading.Lock()
-        # one request at a time builds a metric's resident grid
-        # (query/engine.py _resident_grid): two sub-queries that miss
-        # together would scan and put up the same grid twice
-        self._resident_grid_lock = threading.Lock()
         self._device_cache_mb = self.config.get_int(
             "tsd.query.device_cache_mb", 1024)
         # host-RAM twin for host-tail prepared batches: deliberately a
@@ -203,8 +196,8 @@ class TSDB:
         # tsdlint: allow[unbounded-growth] keyed by hook name — a
         # closed, code-defined registry of ~6 hooks
         self.hook_errors: dict[str, int] = {}
-        # host-side per-(store, metric) TagMatrix cache, invalidated by
-        # series count (the metric index is append-only)
+        # host-side per-(store, metric) query.plan.PlanIndex cache,
+        # invalidated by series count (the metric index is append-only)
         self._tagmat_cache: dict = {}
         from opentsdb_tpu.stats.stats import (ServePayloadStats,
                                               StatsCollectorRegistry)

@@ -218,7 +218,7 @@ class StitchedStore:
         """Tier sid per raw sid (-1 when the tier never saw the
         series). Cached over the full metric, invalidated by either
         index growing."""
-        from opentsdb_tpu.query.engine import _match_series_by_tags
+        from opentsdb_tpu.query.plan import _match_series_by_tags
         key = (self.raw.num_series(), self.tier.num_series())
         with self._map_lock:
             cached = self._sid_map

@@ -1,64 +1,142 @@
-"""Device-resident grid cache: HBM as the tier/block cache.
+"""What is resident in HBM: under which key, valid until when, built
+by whom.
 
 The reference keeps hot HBase blocks in the region server's block
-cache so repeated scans don't touch disk; the TPU-native analogue
-keeps pre-bucketized ``[S, B]`` grids resident in device HBM so
-queries over the same window don't re-scan the host store or
-re-upload.
+cache so repeated scans don't touch disk; here a window's operands
+stay in device HBM so that queries over it neither scan the host store
+nor upload again. :meth:`DeviceGridCache.resident` is the one door:
+every kind of entry is looked up, built, kept and dropped by the same
+four rules.
 
-What an entry is, by its key's first item:
+**The version rule.** An entry is stamped with the version of what it
+was built from, read BEFORE the build read it, and dropped by the
+first look-up that reads another: a write during the build leaves an
+entry the next request will not trust, and a hit holds what a fresh
+build would. ``resident`` calls ``version_of()`` and then ``build()``,
+so the order is the order of two calls in one function and no caller
+can get it wrong. For a store the version is :func:`store_version`,
+``(points_written, mutation_epoch)`` of each store read (every write,
+delete and lifecycle sweep bumps one of them); the histogram arenas
+have ``TSDB._histogram_version``.
 
-``metricgrid`` (``engine._resident_grid``, PR 43): a METRIC's whole
-padded grid and presence mask for one (store, metric, plan-index
-version = the metric's series count, ``start_ms``, ``end_ms``, first
+**The flight.** One build a key at a time: the first caller builds,
+callers of the same key wait for it and hit, callers of another key
+do not wait. A build that raises keeps nothing and lets the waiters
+through, and the next of them builds. An entry that is there is read
+under the cache's one lock, without a flight.
+
+**The turn.** The builds of a kind in :data:`SERIAL_BUILD_KINDS` run
+one at a time whatever their keys, with or without a cache: what
+bounds HBM's peak where one layout is a large share of the chip. The
+window is in a key, so ``end=now`` requests have a key each and no
+flight makes one wait for another; eight query workers laying out
+3.8 GB each (``hist`` at 12M points) would not fit a 16 GB chip,
+while one after another they peak at two entries, the one the LRU is
+about to drop and the one being built.
+
+**The bytes.** LRU by the bytes of the arrays
+(``tsd.query.device_cache_mb``). ``build`` returns ``(arrays, meta)``;
+``arrays`` None says there is nothing to keep (an empty window), an
+entry larger than the whole cache is answered and not kept, and with
+no cache at all (the key at 0, or a tail placed on the host, which
+must neither evict HBM's entries nor count as device bytes) the
+module's :func:`resident` just builds.
+
+**The kinds**, by the first item of the key:
+
+``metricgrid`` (``QueryEngine._resident_grid``): a METRIC's whole
+padded ``[series x bucket]`` grid and presence mask of one (store,
+metric, plan-index version = the metric's series count, window, first
 bucket, interval, buckets, downsample function): scalars only, no
 digest. A request's filter is not in the key: it goes up as one int32
 group label a resident row, excluded rows on the dummy trailing group
-that padded rows already have (``ops/shapes.pad_group_ids``), so no
-program changes and every panel of a dashboard, every rule of an
-evaluator's pass and both sub-queries of a ``sum`` + ``max`` request
-read the entry the first one built (one build at a time, under
-``TSDB._resident_grid_lock``). Its meta holds each row's point count
-of the window (the store's ``count_range``), so the limits' check and
-the scan's stat points stay the selection's. Taken for a device-placed
-tail over a selection the plan index planned, no mesh, not
-``aggregator=none``, not ``delete``, the metric's grid within the cell
-budget and this cache's bytes, and a selection of at least half of the
-metric's rows (``engine.RESIDENT_GRID_MIN_SHARE``): the tail program
-costs the METRIC's rows over a resident grid and the scan, digest and
-upload it replaces cost the SELECTION's, so a tenth of a metric is
-cheaper scanned, and from a half up the two padded shapes are within
-one doubling and the resident grid wins. What it does not give is a
-window that moves: the window is in the key, so ``end=now`` traffic
-builds anew when it changes (time-blocked columns are the next step).
+that padded rows already have (``ops/shapes.pad_group_ids``), so every
+panel of a dashboard, every rule of an evaluator's pass and both
+sub-queries of a ``sum`` + ``max`` request read the entry the first
+one built. Its meta holds each row's point count of the window, so the
+limits' check and the scan's stat points stay the selection's. Taken
+for a device-placed tail over a selection the plan index planned, no
+mesh, not ``aggregator=none``, not ``delete``, the grid within the
+cell budget and this cache's bytes, and at least half of the metric's
+rows selected (``engine.RESIDENT_GRID_MIN_SHARE``: the tail costs the
+METRIC's rows, the scan, digest and upload it replaces the
+SELECTION's; the measured crossover is in PERF.md section 5). The
+window is in the key, so ``end=now`` traffic builds anew when it moves
+(time-blocked columns are the next step).
 
-``grid`` (``engine._grid_pipeline``): the grid of one request's own
-rows, keyed by a digest of its series ids: every other device-placed
-grid request, and the mesh twin's pre-sharded operands. ``avgdiv``
-(the rollup average's divided grid), ``prep`` (the point path's
-prepared batch) and ``hist`` (a histogram metric's window of counts,
-``histogram_engine``) keep their own keys.
+``grid`` (``QueryEngine._grid_pipeline``): the grid of one request's
+own rows, keyed by a digest of its series ids: every other
+device-placed grid request. Under a mesh the same key (the mesh in it)
+holds the pre-sharded operands.
 
-Every entry is stamped with the store's mutation version,
-``(points_written, mutation_epoch)`` read BEFORE the store was (every
-write, delete or lifecycle sweep bumps it), and dropped by the first
-look-up that reads another: a hit holds the cells a fresh scan would
-write. Bounded LRU by device bytes (``tsd.query.device_cache_mb``); an
-entry larger than the whole cache is never kept, and with the key at 0
-nothing is resident and every request scans.
+``avgdiv`` (``QueryEngine._avg_rollup_pipeline``): the sum and count
+grids a rollup average divides, versioned by both tiers' stores.
+
+``hist`` (``histogram_engine``): a histogram metric's window of counts
+(``ResidentCounts``), by (metric, window), versioned by
+``TSDB._histogram_version``. Its builds take turns.
+
+``prep`` (``QueryEngine._run_sub``): the point path's prepared batch,
+in this cache or, for a host-placed tail, in its host-RAM twin
+(``tsd.query.host_cache_mb``, ``stat_prefix`` ``query.hostcache``: the
+same class, a pool of its own). The one kind that does not come
+through ``resident``: its look-up reads two pools, an open breaker
+skips the device's, a hit that fails on the device falls back to the
+cold path, and its put happens inside the dispatch the breaker guards,
+so it calls :meth:`~DeviceGridCache.get` and
+:meth:`~DeviceGridCache.put`, the two halves ``resident`` is made of,
+with :func:`store_version` read before its scan.
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import threading
 from collections import OrderedDict
 from typing import Any
 
 
+#: what :func:`resident` says of the arrays it returns: they were
+#: there, this call built and kept them, or it built them and nothing
+#: is kept (no cache, nothing to keep, or larger than the cache)
+HIT, BUILT, NOT_KEPT = "hit", "built", "not_kept"
+
+
 def array_digest(arr) -> bytes:
     """Content fingerprint of an index array (sids, group_ids)."""
     return hashlib.blake2b(memoryview(arr), digest_size=16).digest()
+
+
+def store_version(*stores) -> tuple:
+    """The version of what a build reads from ``stores``: each one's
+    ``(points_written, mutation_epoch)``, in order."""
+    return tuple(v for store in stores
+                 for v in (store.points_written,
+                           getattr(store, "mutation_epoch", 0)))
+
+
+#: the kinds whose builds take turns (the module's docstring, "The
+#: turn"): one lock a process, since the chip is one a process
+SERIAL_BUILD_KINDS = frozenset({"hist"})
+_SERIAL_BUILD = threading.Lock()
+
+
+def _build_turn(key):
+    """What a build of ``key`` holds while it runs."""
+    if key is not None and key[0] in SERIAL_BUILD_KINDS:
+        return _SERIAL_BUILD
+    return contextlib.nullcontext()
+
+
+def resident(cache: "DeviceGridCache | None", key, version_of, build):
+    """:meth:`DeviceGridCache.resident` of ``cache``; with no cache,
+    what ``build`` makes (in its turn, where ``key``'s kind takes
+    turns), and nothing kept."""
+    if cache is None:
+        with _build_turn(key):
+            return (*build(), NOT_KEPT)
+    return cache.resident(key, version_of, build)
 
 
 class DeviceGridCache:
@@ -75,9 +153,61 @@ class DeviceGridCache:
         self._lock = threading.Lock()
         # key -> (version, arrays: tuple, meta: dict, nbytes: int)
         self._entries: OrderedDict[Any, tuple] = OrderedDict()
+        # key -> [its flight's lock, the calls inside resident() for
+        # it]: an entry a key somebody is looking up or building now
+        self._flights: dict[Any, list] = {}
         self._bytes = 0
         self.hits = 0
         self.misses = 0
+
+    def resident(self, key, version_of, build):
+        """``(arrays, meta, how)``: the entry under ``key`` if it is of
+        the version ``version_of()`` reads now (:data:`HIT`), else
+        what ``build()`` makes, ``(arrays: tuple | None, meta)``, kept
+        under that version (:data:`BUILT`) unless ``arrays`` is None
+        or larger than the whole cache (:data:`NOT_KEPT`).
+
+        ``version_of`` is called before ``build``, both under the
+        key's flight (and the kind's turn, where it takes turns):
+        whoever else asks for ``key`` meanwhile waits, then reads the
+        version for itself and hits. A ``build`` that raises keeps
+        nothing and the waiters go on. An entry that is there costs a
+        hit what it always did, one lock and no flight."""
+        hit = self._hit(key, version_of())
+        if hit is not None:
+            return (*hit, HIT)
+        with self._lock:
+            flight = self._flights.get(key)
+            if flight is None:
+                flight = self._flights[key] = [threading.Lock(), 0]
+            flight[1] += 1
+        try:
+            with flight[0], _build_turn(key):
+                version = version_of()
+                hit = self.get(key, version)
+                if hit is not None:
+                    return (*hit, HIT)
+                arrays, meta = build()
+                kept = arrays is not None \
+                    and self.put(key, version, arrays, meta)
+                return arrays, meta, BUILT if kept else NOT_KEPT
+        finally:
+            with self._lock:
+                flight[1] -= 1
+                if not flight[1]:
+                    del self._flights[key]
+
+    def _hit(self, key, version):
+        """(arrays, meta) of a matching entry, counted as a hit; else
+        None, and nothing counted or dropped (:meth:`get` does that,
+        once the caller holds the key's flight)."""
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None or entry[0] != version:
+                return None
+            self._entries.move_to_end(key)
+            self.hits += 1
+            return entry[1], entry[2]
 
     def get(self, key, version):
         """(arrays, meta) on hit with a matching version, else None."""
@@ -102,10 +232,12 @@ class DeviceGridCache:
             return sum(getattr(x, "nbytes", 0) for x in inner)
         return getattr(a, "nbytes", 0)
 
-    def put(self, key, version, arrays: tuple, meta: dict) -> None:
+    def put(self, key, version, arrays: tuple, meta: dict) -> bool:
+        """Keep ``arrays`` under ``key``; False where they are larger
+        than the whole cache (nothing kept: don't thrash)."""
         nbytes = sum(self._entry_nbytes(a) for a in arrays)
         if nbytes > self.max_bytes:
-            return  # larger than the whole cache: don't thrash
+            return False
         with self._lock:
             old = self._entries.pop(key, None)
             if old is not None:
@@ -115,6 +247,7 @@ class DeviceGridCache:
             while self._bytes > self.max_bytes and self._entries:
                 _, (_, _, _, nb) = self._entries.popitem(last=False)
                 self._bytes -= nb
+        return True
 
     def bytes_of(self, kind) -> int:
         """Bytes of the entries whose key begins with ``kind``."""

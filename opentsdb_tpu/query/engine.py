@@ -21,11 +21,10 @@ Compiles one validated :class:`TSQuery` into the array pipeline:
 from __future__ import annotations
 
 import logging
-import threading
 import time
-from collections import Counter, OrderedDict
+from collections import Counter
 from dataclasses import replace
-from typing import Any, Sequence
+from typing import Any
 
 import numpy as np
 
@@ -40,12 +39,17 @@ from opentsdb_tpu.ops.blocked import (DEFAULT_CELL_BUDGET,
 from opentsdb_tpu.ops.pipeline import (PipelineSpec, execute_avg_divide,
                                        flatten_padded, prepare_auto,
                                        prepare_flat, run_prepared)
+from opentsdb_tpu.query import device_cache
 from opentsdb_tpu.query import filters as filters_mod
 from opentsdb_tpu.query.limits import QueryLimitExceeded
 from opentsdb_tpu.query.model import (BadRequestError, TSQuery,
                                       TSSubQuery,
                                       effective_pixels as
                                       model_effective_pixels)
+from opentsdb_tpu.query.plan import (PlanIndex, TagMatrix,
+                                     _match_series_by_tags,
+                                     _UidNameCache, group_labels,
+                                     group_tag_summary)
 from opentsdb_tpu.stats.stats import QueryStat, QueryStats
 from opentsdb_tpu.utils.faults import DegradedError
 
@@ -154,137 +158,18 @@ class NoSuchMetricError(BadRequestError):
     pass
 
 
-class TagMatrix:
-    """Columnar per-series tags for one sub-query's selected series.
-
-    ``vids[i, j]`` is the tagv id of tag key ``kids[j]`` on series i, or
-    -1 when the series lacks that key. Every engine consumer of
-    per-series tags (group keys, SpanGroup common-tag semantics,
-    explicit_tags, tsuids) reads this matrix with array ops — the
-    previous list-of-dicts walk cost ~0.4 s per 200k series and showed
-    up directly in the north-star query budget.
-
-    ``origin`` is ``(index, rows)`` on a matrix selected out of a
-    cached :class:`PlanIndex`: its row i is row ``rows[i]`` of the
-    index (``rows`` None: every row), so what the index keeps per
-    series of the metric (group labels, the tag columns group by
-    group) is read there instead of derived again. Such a matrix
-    gathers its ``vids`` out of the index only when somebody reads
-    them.
-    """
-
-    __slots__ = ("kids", "_vids", "origin")
-
-    def __init__(self, kids: np.ndarray, vids: np.ndarray | None,
-                 origin: "tuple[PlanIndex, np.ndarray | None] | None"
-                 = None):
-        self.kids = kids        # int64 [K] sorted distinct tagk ids
-        self._vids = vids       # int64 [S, K]; -1 = key absent
-        self.origin = origin
-
-    @property
-    def vids(self) -> np.ndarray:
-        if self._vids is None:
-            index, rows = self.origin
-            self._vids = index.tags.vids[rows]
-        return self._vids
-
-    @classmethod
-    def from_triples(cls, sids: np.ndarray, triples: np.ndarray,
-                     kids: np.ndarray | None = None) -> "TagMatrix":
-        """Build from the metric index's (sid, kid, vid) rows; triples
-        for sids outside ``sids`` are ignored. ``kids`` optionally fixes
-        the column space (for cross-store alignment)."""
-        sids = np.asarray(sids, dtype=np.int64)
-        if kids is None:
-            kids = (np.unique(triples[:, 1]) if len(triples)
-                    else np.empty(0, dtype=np.int64))
-        vids = np.full((len(sids), len(kids)), -1, dtype=np.int64)
-        if len(triples) and len(sids) and len(kids):
-            order = np.argsort(sids, kind="stable")
-            ssorted = sids[order]
-            pos = np.searchsorted(ssorted, triples[:, 0])
-            pos = np.minimum(pos, len(ssorted) - 1)
-            keep = ssorted[pos] == triples[:, 0]
-            kcol = np.searchsorted(kids, triples[:, 1])
-            kcol_ok = np.minimum(kcol, len(kids) - 1)
-            keep &= kids[kcol_ok] == triples[:, 1]
-            rows = order[pos[keep]]
-            vids[rows, kcol_ok[keep]] = triples[keep, 2]
-        return cls(kids, vids)
-
-    @classmethod
-    def from_pairs(cls, tag_tuples: Sequence[Sequence[tuple[int, int]]]
-                   ) -> "TagMatrix":
-        """Build from per-series ((kid, vid), ...) tuples (small paths:
-        tsuid queries, histogram series)."""
-        rows = [(i, kid, vid) for i, tags in enumerate(tag_tuples)
-                for kid, vid in tags]
-        triples = (np.asarray(rows, dtype=np.int64).reshape(-1, 3)
-                   if rows else np.empty((0, 3), dtype=np.int64))
-        return cls.from_triples(np.arange(len(tag_tuples)), triples)
-
-    @property
-    def num_series(self) -> int:
-        if self._vids is None:
-            return len(self.origin[1])
-        return self._vids.shape[0]
-
-    def col(self, kid: int) -> np.ndarray | None:
-        """[S] tagv ids for one key (-1 absent), or None if no series
-        has the key at all."""
-        j = int(np.searchsorted(self.kids, kid))
-        if j < len(self.kids) and self.kids[j] == kid:
-            return self.vids[:, j]
-        return None
-
-    def distinct(self, kid: int) -> np.ndarray:
-        """Sorted distinct tagv ids present in one key's column."""
-        col = self.col(kid)
-        if col is None:
-            return np.empty(0, dtype=np.int64)
-        return np.unique(col[col >= 0])
-
-    def name_table(self, kid: int, tagv, folded: bool
-                   ) -> tuple[None, int, bool]:
-        """A matrix of one request has nowhere to keep the names of
-        its values (:meth:`PlanIndex.name_table` has): none, no name
-        read, nothing built."""
-        return None, 0, False
-
-    def select(self, mask_or_idx) -> "TagMatrix":
-        origin = self.origin
-        if origin is not None:
-            index, rows = origin
-            if rows is None:
-                rows = np.arange(index.num_series)
-            origin = (index, rows[mask_or_idx])
-        vids = self._vids
-        return TagMatrix(self.kids,
-                         None if vids is None else vids[mask_or_idx],
-                         origin)
-
-    def num_pairs(self) -> int:
-        """Present (key, value) pairs over all rows."""
-        if self.origin is not None:
-            return self.origin[0].num_pairs(self.origin[1])
-        return int((self.vids >= 0).sum())
-
-    def tags_of(self, i: int) -> list[tuple[int, int]]:
-        """Series i's present (kid, vid) pairs, kid-ascending."""
-        row = self.vids[i]
-        return [(int(k), int(v)) for k, v in zip(self.kids, row)
-                if v >= 0]
-
-
 #: first item of the HBM cache's key for a metric's resident grid
 RESIDENT_GRID_KEY = "metricgrid"
+#: the ``grid`` tag of the ``query.grid_build stage=cache_lookup`` span
+#: of the kinds that have one: (a hit's, a build's)
+_LOOKUP_GRID = {RESIDENT_GRID_KEY: ("resident_hit", "resident_built"),
+                "grid": ("selection", "selection")}
 
 
 def _store_id(store) -> int:
     """Monotonic per-process store identity for cache keys. id(store)
     could alias a freed store whose address was reused with a
-    coincidentally equal (points_written, mutation_epoch)."""
+    coincidentally equal ``device_cache.store_version``."""
     return getattr(store, "instance_id", id(store))
 
 
@@ -312,17 +197,8 @@ HOST_TAIL_DEFAULT_CELLGROUPS = 1 << 25
 HOST_TAIL_DEFAULT_CELLS_LINEAR = 1 << 16
 # the least share of its metric's series a selection must hold to run
 # over the metric's RESIDENT grid (QueryEngine._resident_grid) and not
-# over a grid of its own rows. The two costs follow different counts:
-# the tail program reads every row of the grid it is given (26 ms a
-# million rows of 12 buckets on a v5e, PERF.md section 5), so over the
-# resident grid it costs the METRIC's rows whatever the filter kept,
-# while the scan, the digest and the upload it replaces cost the
-# SELECTION's rows (~64 ms a million, same section). At a tenth of a
-# metric the selection's own grid is the cheaper by far; from a half
-# up the padded shapes are at most a doubling apart (so the placement
-# is the same and a dashboard's panels share one compiled program) and
-# the resident grid wins 2:1 or better. Between a tenth and a half
-# nothing was measured: the conservative end.
+# over a grid of its own rows: the measured costs behind the half are
+# with the ``metricgrid`` kind in query/device_cache.py
 RESIDENT_GRID_MIN_SHARE = 0.5
 
 
@@ -381,8 +257,8 @@ def host_tail_for_dims(config, s: int, b: int, num_groups: int,
                        rank_class: bool | None = None):
     """:func:`host_tail_device` from RAW query dims — the ONE place the
     decision inputs are shape-bucketed, shared by the engine paths and
-    tsd.warmup so a warmed placement cannot drift from the engine's
-    (ADVICE r04). emit_raw has no group contraction: group factor 1.
+    tsd.warmup so a warmed placement cannot drift from the engine's.
+    emit_raw has no group contraction: group factor 1.
     ``rank_class`` (the sub-query's ``agg.rank_class``) picks the
     linear vs rank-class budget; without it ``agg_name`` does, and the
     default is a rank-class name so legacy callers keep the
@@ -395,351 +271,6 @@ def host_tail_for_dims(config, s: int, b: int, num_groups: int,
         _shapes.shape_bucket(s) * _shapes.shape_bucket(b),
         1 if emit_raw else _shapes.shape_bucket(num_groups + 1),
         linear_agg=not rank_class)
-
-
-def compact_row_labels(mat: np.ndarray) -> tuple[np.ndarray, int]:
-    """``np.unique(mat, axis=0, return_inverse=True)`` equivalent via
-    per-column factorization — the void-dtype row sort behind
-    unique(axis=0) is ~10x slower at 1M rows. Labels preserve the
-    lexicographic row order (the reference's ByteMap group-key order).
-    """
-    n_rows, n_cols = mat.shape
-    if n_cols == 0 or n_rows == 0:
-        return (np.zeros(n_rows, dtype=np.int32),
-                1 if n_rows else 0)
-    labels = None
-    count = 1
-    for j in range(n_cols):
-        u, inv = np.unique(mat[:, j], return_inverse=True)
-        if labels is None:
-            labels, count = inv.astype(np.int64), len(u)
-        else:
-            # composite stays < count * len(u) <= n_rows^2: int64-safe,
-            # re-compacted each step so it never grows further
-            labels = labels * len(u) + inv
-            u2, labels = np.unique(labels, return_inverse=True)
-            count = len(u2)
-    return labels.astype(np.int32), count
-
-
-def group_labels(tags: TagMatrix, gb_kids: Sequence[int]
-                 ) -> tuple[np.ndarray, int]:
-    """Group label per row of ``tags`` + group count for the group-by
-    keys ``gb_kids``: rows with equal tagv-id tuples share a label, and
-    labels ascend with the tuple (-1 = key absent sorts first)."""
-    mat = np.empty((tags.num_series, len(gb_kids)), dtype=np.int64)
-    for j, k in enumerate(gb_kids):
-        col = tags.col(k)
-        mat[:, j] = col if col is not None else -1
-    return compact_row_labels(mat)
-
-
-class GroupLayout:
-    """The rows of a tag matrix group by group, and what the SpanGroup
-    tag rule needs of each group: per tag key the minimum and maximum
-    tagv id over its members. A minimum below 0 says the key is absent
-    on a member (it vanishes), minimum == maximum that all members
-    agree (a common tag), anything else that they differ (an
-    aggregated tag).
-
-    ``order`` is the stable argsort of a compact labelling (every
-    label 0..G-1 has a member), ``starts`` [G + 1] its group
-    boundaries, ``cols`` [K, S] the tag columns read in that order, a
-    column a row. The
-    engine makes one per request from a matrix that came from nowhere,
-    and a :class:`PlanIndex` keeps one per cached labelling of the
-    whole metric, of which a request reads its selection
-    (:meth:`selected`).
-    """
-
-    #: members a block of whole groups holds before the next begins:
-    #: what :meth:`selected` copies at a time is 512 KB a column, not
-    #: the columns of the whole metric
-    BLOCK = 1 << 17
-
-    __slots__ = ("order", "starts", "cols", "minv", "maxv")
-
-    def __init__(self, order: np.ndarray, starts: np.ndarray,
-                 cols: np.ndarray):
-        self.order, self.starts, self.cols = order, starts, cols
-        # int64 [G, K] over whole groups
-        self.minv, self.maxv = (
-            reduce.reduceat(cols, starts[:-1], axis=1).T.astype(np.int64)
-            for reduce in (np.minimum, np.maximum))
-
-    def members(self, group: int) -> np.ndarray:
-        """The rows of one group, ascending."""
-        return self.order[self.starts[group]:self.starts[group + 1]]
-
-    def selected(self, mask: np.ndarray):
-        """``(minv, maxv, members)`` over the rows ``mask`` keeps, for
-        the groups that keep any, renumbered in label order:
-        ``members(g)`` gives the kept rows of the g-th of them.
-
-        A group that keeps every member reads the whole group's
-        minimum and maximum; the others are reduced over their kept
-        members, a block of whole groups at a time."""
-        starts = self.starts
-        chosen = mask[self.order]
-        kept = np.add.reduceat(chosen, starts[:-1], dtype=np.int64)
-        minv, maxv = self.minv.copy(), self.maxv.copy()
-        partial = (kept > 0) & (kept < np.diff(starts))
-        # the first group to start at or after each multiple of BLOCK
-        cuts = np.unique(np.append(
-            np.searchsorted(starts,
-                            np.arange(0, len(chosen), self.BLOCK)),
-            len(kept)))
-        for g0, g1 in zip(cuts[:-1], cuts[1:]):
-            if not partial[g0:g1].any():
-                continue
-            lo, hi = starts[g0], starts[g1]
-            picked = chosen[lo:hi]
-            live = np.flatnonzero(kept[g0:g1])
-            seg = (np.cumsum(kept[g0:g1]) - kept[g0:g1])[live]
-            live += g0
-            for j, col in enumerate(self.cols):
-                col = col[lo:hi][picked]
-                minv[live, j] = np.minimum.reduceat(col, seg)
-                maxv[live, j] = np.maximum.reduceat(col, seg)
-        present = np.flatnonzero(kept)
-
-        def members(group: int) -> np.ndarray:
-            at = slice(starts[present[group]],
-                       starts[present[group] + 1])
-            return self.order[at][chosen[at]]
-
-        return minv[present], maxv[present], members
-
-
-def _group_starts(labels: np.ndarray, count: int) -> np.ndarray:
-    """[count + 1] boundaries of the groups of a compact labelling in
-    its stable argsort."""
-    starts = np.zeros(count + 1, dtype=np.int64)
-    np.cumsum(np.bincount(labels, minlength=count), out=starts[1:])
-    return starts
-
-
-class _LabelSet:
-    """One cached labelling of a :class:`PlanIndex` and, built by the
-    first assemble stage that asks, its :class:`GroupLayout`."""
-
-    __slots__ = ("labels", "count", "layout")
-
-    def __init__(self, labels: np.ndarray, count: int):
-        self.labels, self.count = labels, count
-        self.layout: GroupLayout | None = None
-
-
-class PlanIndex:
-    """What the plan and assemble stages need of one metric's tag
-    index and that depends on nothing else: the metric's whole
-    :class:`TagMatrix`, and, built by the first request that asks,
-    each tag key's distinct tagv ids, each group-by key set's label
-    for every series, and that labelling's :class:`GroupLayout` (the
-    member order, the group boundaries and a group-major copy of the
-    tag columns, int32 where the ids fit: 4 + 4 K bytes a series).
-
-    The tag index only appends, so its series count versions all of
-    it: the engine keeps one per (store, metric) in
-    ``tsdb._tagmat_cache`` and drops it whole when ``version`` no
-    longer equals the index's length. One part has a second version:
-    the NAMES of a key's distinct ids (``name_table``: a
-    :class:`~opentsdb_tpu.query.filters.NameTable`, built by the first
-    filter that matches stored names of that key), which the series
-    count cannot vouch for, since ``rename`` and ``delete`` change a
-    name and no series. A table carries the UID dictionary's
-    ``generation`` as read before its names were, and a request that
-    reads another generation builds it again (one read a name, what
-    every such request cost before there was a table). A filter that
-    holds exact names reads the live dictionary's forward map and no
-    table.
-
-    What a table costs, a key: 8 bytes a name for the list of names
-    (references to the dictionary's own strings) and names x longest
-    name bytes for the byte matrix, which is refused (the key is then
-    walked, a request) where it would exceed
-    ``NameArrays.MAX_PAD`` = 8 times the names' own bytes; as much
-    again for the case-folded matrix once an ``i`` filter has asked,
-    and 4 bytes a name for each matrix's lengths, 8 for its ids where
-    the names are not all of one length. At ``fleet-1m``: ``host``
-    (1,000,000 names of 8 bytes) 8 MB of list, 8 MB of matrix, 4 MB of
-    lengths, 12 MB more once folded; ``dc``, ``rack``, ``fleet`` a few
-    KB each. Nothing is built for a key no pattern names.
-
-    The lazy parts build under one lock (two sub-queries of a request
-    plan side by side: the second waits and reads what the first
-    built). At most :data:`LABEL_SETS` labellings are kept, least
-    recently used out, each with its layout (4 bytes a series, and
-    4 + 4 K more once assembled from).
-    """
-
-    LABEL_SETS = 8
-
-    __slots__ = ("version", "tags", "_keys_of", "_distinct", "_names",
-                 "_labels", "_lock")
-
-    def __init__(self, version: int, tags: TagMatrix):
-        self.version = version
-        self.tags = tags
-        # present keys a row, or None where every row holds them all
-        present = tags.vids >= 0
-        self._keys_of = None if present.all() else \
-            present.sum(axis=1, dtype=np.int32)
-        # tsdlint: allow[unbounded-growth] keyed by tag key: at most
-        # one entry a column of ``tags``; gone with the index
-        self._distinct: dict[int, np.ndarray] = {}
-        # tsdlint: allow[unbounded-growth] keyed by tag key, like
-        # ``_distinct``; an entry is replaced, never added to
-        self._names: dict[int, filters_mod.NameTable] = {}
-        self._labels: OrderedDict[tuple, _LabelSet] = OrderedDict()
-        self._lock = threading.Lock()
-
-    @property
-    def num_series(self) -> int:
-        return self.tags.num_series
-
-    def col(self, kid: int) -> np.ndarray | None:
-        return self.tags.col(kid)
-
-    def distinct(self, kid: int) -> np.ndarray:
-        found = self._distinct.get(kid)
-        if found is None:
-            with self._lock:
-                found = self._distinct.get(kid)
-                if found is None:
-                    found = self._distinct[kid] = self.tags.distinct(kid)
-        return found
-
-    def name_table(self, kid: int, tagv, folded: bool
-                   ) -> tuple["filters_mod.NameTable", int, bool]:
-        """``(table, names read, built)``: the names of
-        ``distinct(kid)`` in ``tagv`` (the tagv UID dictionary) as of
-        its present generation, with the case-folded arrays when
-        ``folded`` asks for them; how many names this call read from
-        the dictionary (all of them, or 0), and whether it built
-        anything."""
-        found = self._names.get(kid)
-        if found is not None and found.generation == tagv.generation \
-                and found.has(folded):
-            return found, 0, False
-        ids, read = self.distinct(kid), 0
-        with self._lock:
-            # read before the names are: a rename during the build
-            # leaves a table the next request will not trust
-            generation = tagv.generation
-            found = self._names.get(kid)
-            if found is None or found.generation != generation:
-                found = self._names[kid] = filters_mod.NameTable(
-                    ids, tagv, generation)
-                read = len(ids)
-            fold = not found.has(folded)
-            if fold:
-                found.fold()
-        return found, read, read > 0 or fold
-
-    def _label_set(self, key: tuple) -> _LabelSet:
-        """Called with the lock held."""
-        found = self._labels.get(key)
-        if found is None:
-            found = self._labels[key] = _LabelSet(
-                *group_labels(self.tags, key))
-            while len(self._labels) > self.LABEL_SETS:
-                self._labels.popitem(last=False)
-        else:
-            self._labels.move_to_end(key)
-        return found
-
-    def labels(self, gb_kids: Sequence[int]) -> tuple[np.ndarray, int]:
-        """:func:`group_labels` of the whole metric (int32 [S], count);
-        the array is shared between requests: read it, never write."""
-        with self._lock:
-            found = self._label_set(tuple(gb_kids))
-        return found.labels, found.count
-
-    def layout(self, gb_kids: Sequence[int]) -> GroupLayout:
-        """The :class:`GroupLayout` of ``labels(gb_kids)`` over the
-        whole metric; shared between requests like the labels."""
-        with self._lock:
-            found = self._label_set(tuple(gb_kids))
-            if found.layout is None:
-                labels, count = found.labels, found.count
-                # 16-bit keys sort by radix: a sixth of the time
-                order = np.argsort(
-                    labels.astype(np.uint16) if count <= 1 << 16
-                    else labels, kind="stable").astype(np.int32)
-                vids = self.tags.vids
-                if not vids.size or \
-                        vids.max() <= np.iinfo(np.int32).max:
-                    vids = vids.astype(np.int32)
-                cols = np.empty(vids.shape[::-1], dtype=vids.dtype)
-                for j, col in enumerate(cols):
-                    np.take(vids[:, j], order, out=col)
-                found.layout = GroupLayout(
-                    order, _group_starts(labels, count), cols)
-        return found.layout
-
-    def num_pairs(self, rows: np.ndarray | None) -> int:
-        """Present (key, value) pairs over ``rows`` (None: all)."""
-        if self._keys_of is None:
-            n = self.num_series if rows is None else len(rows)
-            return n * len(self.tags.kids)
-        return int((self._keys_of if rows is None
-                    else self._keys_of[rows]).sum())
-
-    def select(self, rows: np.ndarray | None) -> TagMatrix:
-        """The matrix of the index's rows ``rows`` (ascending
-        positions; None: all of them), remembering where it came
-        from. Its ``vids`` are gathered when first read."""
-        if rows is None:
-            return TagMatrix(self.tags.kids, self.tags.vids,
-                             (self, None))
-        return TagMatrix(self.tags.kids, None, (self, rows))
-
-
-#: a selection of fewer than one row in SMALL_SELECTION of its metric
-#: is summarized from its own rows (a sort of n group ids and two
-#: gathers, ~0.1 us a selected row) and not from the index's layout (a
-#: mask over all S rows read in member order, ~0.005 us a row of the
-#: METRIC, and up to as much again for the groups a filter cut): the
-#: costs cross near one row in ten. A panel of 8 hosts of a million
-#: never reads, or builds, the layout.
-SMALL_SELECTION = 8
-
-
-def group_tag_summary(tags: TagMatrix, group_ids: np.ndarray,
-                      num_groups: int, gb_kids: Sequence[int] | None):
-    """``(way, minv, maxv, members, source)`` for the groups
-    ``group_ids`` makes of the rows of ``tags``: int64 [G, K] minimum
-    and maximum tagv id a group and key (:class:`GroupLayout` has the
-    rule they decide), ``members(g)`` the rows of ``source`` (a
-    :class:`TagMatrix`) in group g, ascending.
-
-    ``way`` says where they were read: ``index`` when ``group_ids``
-    are the labels of ``gb_kids`` gathered from the :class:`PlanIndex`
-    the matrix was selected from (``gb_kids`` None says they are not),
-    and the selection is no :data:`SMALL_SELECTION`: the index's
-    cached layout, of which an unfiltered request reads the whole
-    groups as they stand. Otherwise a layout of the matrix's own
-    rows, made here: ``small`` where that was the cheaper of two ways
-    (a :data:`SMALL_SELECTION` of an index), ``matrix`` where there
-    was no index to read (``path.fallbacks`` counts it)."""
-    way = "matrix"
-    if tags.origin is not None and gb_kids is not None:
-        index, rows = tags.origin
-        if rows is None or len(rows) == index.num_series:
-            layout = index.layout(gb_kids)
-            return ("index", layout.minv, layout.maxv, layout.members,
-                    index.tags)
-        if len(rows) * SMALL_SELECTION >= index.num_series:
-            mask = np.zeros(index.num_series, dtype=bool)
-            mask[rows] = True
-            return ("index", *index.layout(gb_kids).selected(mask),
-                    index.tags)
-        way = "small"
-    order = np.argsort(group_ids, kind="stable")
-    layout = GroupLayout(order, _group_starts(group_ids, num_groups),
-                         tags.vids[order].T)
-    return way, layout.minv, layout.maxv, layout.members, tags
 
 
 #: downsample functions the storage-side pre-reduction can serve, by
@@ -776,23 +307,6 @@ def fill_padded_grid(stat: str, sums: np.ndarray, cnts: np.ndarray,
     grid[:s, b:] = np.nan
     has_data[s:] = False
     has_data[:s, b:] = False
-
-
-class _UidNameCache:
-    """Memoized UID->name lookups for result assembly (one cache per
-    query; group loops hit the same few names over and over)."""
-
-    def __init__(self, registry):
-        self._reg = registry
-        # tsdlint: allow[unbounded-growth] one cache per query,
-        # garbage with the query; bounded by its result's UID count
-        self._cache: dict[int, str] = {}
-
-    def __call__(self, uid: int) -> str:
-        name = self._cache.get(uid)
-        if name is None:
-            name = self._cache[uid] = self._reg.get_name(uid)
-        return name
 
 
 # Padded-layout guards: padding inflation is bounded by the skew factor
@@ -1245,7 +759,6 @@ class QueryEngine:
         prep_cache = self.tsdb.device_grid_cache
         pkey = pver = None
         if prep_cache is not None:
-            from opentsdb_tpu.query.device_cache import array_digest
             from opentsdb_tpu.parallel.sharded_pipeline import \
                 agg_mesh_class
             # the aggregator's memory CLASS is part of the key: the
@@ -1263,26 +776,24 @@ class QueryEngine:
                 # single-device: the linear-vs-rank PLACEMENT class is
                 # the key dimension — a host-pool entry cached by a
                 # linear agg must not serve a rank-class query whose
-                # budget would have placed it on the accelerator
-                # (their group stages differ by orders of magnitude on
-                # one CPU core). The rank-class budget is
-                # cells * groups, so the bucketed group count is part
-                # of the key — two group-by cardinalities of the same
-                # series set must not share a placement (mirrors the
-                # mesh ('pct', num_groups) key above)
+                # budget would have placed it on the accelerator. The
+                # rank-class budget is cells * groups, so the bucketed
+                # group count is part of the key: two group-by
+                # cardinalities of one series set must not share a
+                # placement (as the mesh's ('pct', num_groups) above)
                 if not sub.agg.rank_class:
                     acls = "lin"
                 else:
                     from opentsdb_tpu.ops import shapes as _shapes
                     acls = ("rank",
                             _shapes.shape_bucket(num_groups + 1))
-            pkey = ("prep", _store_id(store),
-                    array_digest(np.ascontiguousarray(sids)),
-                    tsq.start_ms, tsq.end_ms, sub.downsample or "union",
-                    getattr(sub.ds_spec, "timezone", None), mesh,
-                    acls)
-            pver = (store.points_written,
-                    getattr(store, "mutation_epoch", 0))
+            pkey = ("prep", _store_id(store), device_cache.array_digest(
+                np.ascontiguousarray(sids)), tsq.start_ms, tsq.end_ms,
+                sub.downsample or "union",
+                getattr(sub.ds_spec, "timezone", None), mesh, acls)
+            # read before the store is (the rule resident() enforces
+            # for every other kind)
+            pver = device_cache.store_version(store)
             # degraded (breaker open): skip the DEVICE pool — a hit
             # would re-dispatch to the failing accelerator; host-pool
             # hits below remain valid
@@ -1303,10 +814,9 @@ class QueryEngine:
                     raise
                 except Exception as exc:  # noqa: BLE001
                     # a warm entry failing on the device must not make
-                    # warm queries 500 while cold ones fall back:
-                    # breaker bookkeeping already happened inside
-                    # _run_device — drop to the cold path below, which
-                    # carries the full host-fallback discipline
+                    # warm queries 500 while cold ones fall back: the
+                    # breaker's bookkeeping happened in _run_device;
+                    # drop to the cold path and its host fallback
                     LOG.warning("cached device batch failed (%s: %s); "
                                 "re-running the query cold",
                                 type(exc).__name__, exc)
@@ -1479,6 +989,10 @@ class QueryEngine:
                                 group_ids, replace(spec, host=True),
                                 sub.rate_options)
 
+        # what a kept prepared batch says of itself (_run_prep_hit)
+        pmeta = {"num_points": num_points, "bucket_ts": bucket_ts,
+                 "ds_function": ds_function, "fill_policy": fill_policy,
+                 "fill_value": fill_value}
         if use_blocked:
             # long-range streaming: bound memory at [S x block] cells
             # (SURVEY.md §5.7 time-axis blocking)
@@ -1522,15 +1036,10 @@ class QueryEngine:
                 margs = sharded_device_args(mesh, sbatch,
                                             pipeline_dtype())
                 if prep_cache is not None and pkey is not None:
-                    prep_cache.put(
-                        pkey, pver, margs[:4],
-                        {"num_points": num_points,
-                         "bucket_ts": bucket_ts,
-                         "ds_function": ds_function,
-                         "fill_policy": fill_policy,
-                         "fill_value": fill_value,
-                         "s_loc": sbatch.s_loc, "b_loc": sbatch.b_loc,
-                         "s_pad": sbatch.s_loc * mesh.shape["series"]})
+                    prep_cache.put(pkey, pver, margs[:4], {
+                        **pmeta, "s_loc": sbatch.s_loc,
+                        "b_loc": sbatch.b_loc,
+                        "s_pad": sbatch.s_loc * mesh.shape["series"]})
                 return run_sharded_device(
                     mesh, spec, margs, sbatch.s_loc, sbatch.b_loc,
                     num_groups, sub.rate_options)
@@ -1551,11 +1060,7 @@ class QueryEngine:
                 prep = prepare(host_dev)
                 if pool is not None and pkey is not None:
                     pool.put(pkey, pver, (prep,), {
-                        "num_points": num_points,
-                        "bucket_ts": bucket_ts,
-                        "ds_function": ds_function,
-                        "fill_policy": fill_policy,
-                        "fill_value": fill_value, "host": on_host,
+                        **pmeta, "host": on_host,
                         "complete": grid_complete})
                 return run_prepared(prep, bucket_ts, group_ids, spec,
                                     sub.rate_options)
@@ -1751,12 +1256,16 @@ class QueryEngine:
         stats.add_stat(QueryStat.BYTES_FROM_STORAGE, num_points * 17)
         stats.add_stat(QueryStat.SUCCESSFUL_SCAN, 1)
 
+    @staticmethod
+    def _fixed_interval(spec) -> bool:
+        """Buckets of one fixed width: what lays out as a grid."""
+        return (not spec.run_all and not spec.use_calendar
+                and spec.unit not in ("n", "y") and spec.interval_ms > 0)
+
     def _grid_eligible(self, sub: TSSubQuery) -> bool:
         spec = sub.ds_spec
-        return (spec is not None and not spec.run_all
-                and not spec.use_calendar and spec.unit not in ("n", "y")
+        return (spec is not None and self._fixed_interval(spec)
                 and spec.function in GRID_STATS
-                and spec.interval_ms > 0
                 and self.tsdb.config.get_bool("tsd.query.grid_reduce",
                                               True))
 
@@ -1775,11 +1284,10 @@ class QueryEngine:
 
         A store with a fused ``bucket_grid`` (the native one) writes
         them in its storage pass; any other reduces to f64 grids that
-        :func:`fill_padded_grid` finishes. Chosen by what the store
-        offers: two paths that share the contract and no logic.
-        ``scanned(scan, num_points)`` closes the storage read
-        (:meth:`_record_scan`, with the rows the caller answers
-        for)."""
+        :func:`fill_padded_grid` finishes: two paths that share the
+        contract and no logic. ``scanned(scan, num_points)`` closes
+        the storage read (:meth:`_record_scan`, with the rows the
+        caller answers for)."""
         from opentsdb_tpu.ops import shapes
         from opentsdb_tpu.ops.pipeline import pipeline_dtype
         b = len(bucket_ts)
@@ -1812,81 +1320,118 @@ class QueryEngine:
             fill_padded_grid(stat, *reduced, grid, has_data)
         return grid, has_data, num_points
 
+    def _resident_operands(self, cache, kind: str, key_of, stores, build,
+                           stats, metric_name: str, n_rows: int,
+                           delete=None, points_of=None):
+        """What a grid-shaped kind of the HBM cache does around its
+        build, once: the operands through :func:`device_cache.resident`
+        under ``(kind, *key_of())``, versioned by ``stores`` (``cache``
+        None: built, nothing kept), the scan's stat points, the limits'
+        check, ``delete`` (the response still carries what it
+        removes), the empty window. ``(arrays, meta)``, or None where
+        the ``n_rows`` rows hold no point.
+
+        ``build(checked) -> (arrays | None, meta)`` reads ``stores``
+        and closes its own scan (:meth:`_record_scan`); a hit records
+        a scan of no length with the same stat points,
+        ``meta["num_points"]`` or ``points_of(meta)``. ``checked`` is
+        the limits' check and ``delete``: a build of what only this
+        request reads calls it between its scan and its upload, so
+        that a refused request puts nothing up and keeps nothing;
+        else, and on a hit, it follows here. A kind in
+        :data:`_LOOKUP_GRID` has a ``cache_lookup`` span: the key, the
+        look-up and the wait for another's build of it, no more."""
+        tags = _LOOKUP_GRID.get(kind) if cache is not None else None
+        lookup = trace_begin("query.grid_build", stage="cache_lookup") \
+            if tags else None
+        pending = True
+
+        def end_lookup(built: bool):
+            if lookup is not None:
+                lookup.tag(grid=tags[built])
+                lookup.finish()
+
+        def checked(num_points: int):
+            nonlocal pending
+            pending = False
+            # byte/dp guardrails (ref: SaltScanner's QueryLimitOverride)
+            self.tsdb.query_limits.check(metric_name, num_points)
+            if delete is not None:
+                delete()
+
+        def build_after_lookup():
+            end_lookup(True)
+            return build(checked)
+
+        arrays, meta, how = device_cache.resident(
+            cache, (kind, *key_of()) if cache is not None else None,
+            lambda: device_cache.store_version(*stores),
+            build_after_lookup)
+        if how == device_cache.HIT:
+            end_lookup(False)  # before the points are counted
+        num_points = points_of(meta) if points_of else meta["num_points"]
+        if how == device_cache.HIT:
+            self._record_scan(stats, self._scan_begin(), num_points,
+                              n_rows)
+        if pending:
+            checked(num_points)
+        return (arrays, meta) if num_points else None
+
+    @staticmethod
+    def _resident_grid_fits(cache, n: int, num_selected: int, b: int,
+                            budget: int) -> bool:
+        """Whether ``num_selected`` of a metric's ``n`` series over
+        ``b`` buckets run over the metric's resident grid: by what the
+        request shows, no key (:data:`RESIDENT_GRID_MIN_SHARE`, the
+        metric's grid within the cell ``budget`` and the cache)."""
+        from opentsdb_tpu.ops import shapes
+        from opentsdb_tpu.ops.pipeline import pipeline_dtype
+        cells = shapes.shape_bucket(n) * shapes.shape_bucket(b)
+        return num_selected >= RESIDENT_GRID_MIN_SHARE * n \
+            and n * b <= budget \
+            and cells * (np.dtype(pipeline_dtype()).itemsize + 1) \
+            <= cache.max_bytes
+
     def _resident_grid(self, cache, store, metric_sids: np.ndarray,
                        rows, num_selected: int, tsq: TSQuery,
                        bucket_ts: np.ndarray, interval_ms: int,
-                       fn: str, budget: int, stats):
-        """The whole metric's padded ``[series x bucket]`` grid of this
-        (window, downsample), resident in HBM, and what ``rows`` (the
-        request's rows of ``metric_sids``; ``num_selected`` of them)
-        hold of it: ``(grid, has_data, num_points)``, or None where
-        the request should scan its own rows instead.
+                       fn: str, metric_name: str, stats):
+        """:meth:`_resident_operands` of the whole metric's padded
+        ``[series x bucket]`` grid of this (window, downsample)
+        (``metricgrid`` in :mod:`~opentsdb_tpu.query.device_cache`)
+        for ``rows``, the request's ``num_selected`` rows of
+        ``metric_sids``. The entry keeps each row's point count of the
+        window, so the limits' check and the scan's stat points are
+        the selection's, as on the path it replaces."""
+        from opentsdb_tpu.ops.pipeline import put_grid
 
-        One entry a (store, metric, plan-index version, window,
-        downsample): a request's filter is not part of the key, it
-        goes up as one group label a row (excluded rows on the dummy
-        group the padded rows already have), so every panel of a
-        dashboard and every rule of an evaluator's pass reads the
-        entry the first one built. Valid while the store's
-        ``(points_written, mutation_epoch)`` is what it was BEFORE the
-        build read the store: any write, delete or lifecycle sweep
-        makes the next request build again, so a hit holds the cells a
-        fresh scan would write. One build at a time: the second
-        sub-query of a request waits for the first's and hits.
+        def key_of():
+            return (_store_id(store),
+                    store.series(int(metric_sids[0])).metric_id,
+                    len(metric_sids), tsq.start_ms, tsq.end_ms,
+                    int(bucket_ts[0]), interval_ms, len(bucket_ts), fn)
 
-        Taken by what the request shows, no key: a selection of at
-        least :data:`RESIDENT_GRID_MIN_SHARE` of the metric, the
-        metric's grid within the cell ``budget`` and the cache's
-        bytes. The entry keeps each row's point count of the window
-        on the host, so ``num_points`` (the limits' check, the scan's
-        stat points) is the selection's, as on the path it
-        replaces."""
-        from opentsdb_tpu.ops import shapes
-        from opentsdb_tpu.ops.pipeline import pipeline_dtype, put_grid
-        n, b = len(metric_sids), len(bucket_ts)
-        cells = shapes.shape_bucket(n) * shapes.shape_bucket(b)
-        if num_selected < RESIDENT_GRID_MIN_SHARE * n or n * b > budget \
-                or cells * (np.dtype(pipeline_dtype()).itemsize + 1) \
-                > cache.max_bytes:
-            return None
-        metric_id = store.series(int(metric_sids[0])).metric_id
-        ckey = (RESIDENT_GRID_KEY, _store_id(store), metric_id, n,
-                tsq.start_ms, tsq.end_ms, int(bucket_ts[0]),
-                interval_ms, b, fn)
-        # the span is the look-up and, for the request that arrives
-        # during another's build, the wait for it
-        lookup = trace_begin("query.grid_build", stage="cache_lookup")
-        with self.tsdb._resident_grid_lock:
-            # read before the store is: a write during the build
-            # leaves an entry the next request will not trust
-            cver = (store.points_written,
-                    getattr(store, "mutation_epoch", 0))
-            hit = cache.get(ckey, cver)
-            if lookup is not None:
-                lookup.tag(grid="resident_hit" if hit is not None
-                           else "resident_built")
-            trace_end(lookup)
-            if hit is None:
-                counts = store.count_range(metric_sids, tsq.start_ms,
-                                           tsq.end_ms)
-                num_points = int(counts[rows].sum())
-                # the pass reads the metric; the request answers for
-                # its own rows of it, as a hit will
-                grid, has_data, _ = self._reduce_to_grid(
-                    store, metric_sids, tsq, bucket_ts, interval_ms,
-                    GRID_STATS[fn],
-                    lambda scan, _: self._record_scan(
-                        stats, scan, num_points, num_selected))
-                if counts.any():
-                    grid, has_data = put_grid(grid, has_data)
-                    cache.put(ckey, cver, (grid, has_data),
-                              {"counts": counts})
-                return grid, has_data, num_points
-        (grid, has_data), meta = hit
-        num_points = int(meta["counts"][rows].sum())
-        self._record_scan(stats, self._scan_begin(), num_points,
-                          num_selected)
-        return grid, has_data, num_points
+        def points_of(meta) -> int:
+            return int(meta["counts"][rows].sum())
+
+        def build(_checked):
+            meta = {"counts": store.count_range(
+                metric_sids, tsq.start_ms, tsq.end_ms)}
+            # the pass reads the metric; the request answers for its
+            # own rows of it, as a hit will
+            grid, has_data, _ = self._reduce_to_grid(
+                store, metric_sids, tsq, bucket_ts, interval_ms,
+                GRID_STATS[fn],
+                lambda scan, _: self._record_scan(
+                    stats, scan, points_of(meta), num_selected))
+            # a window without a point: nothing to keep. Else kept
+            # before THIS selection is checked: others read the entry
+            return (put_grid(grid, has_data)
+                    if meta["counts"].any() else None), meta
+
+        return self._resident_operands(
+            cache, RESIDENT_GRID_KEY, key_of, (store,), build, stats,
+            metric_name, num_selected, points_of=points_of)
 
     def _grid_pipeline(self, store, sids: np.ndarray, tsq: TSQuery,
                        sub: TSSubQuery, metric_name: str,
@@ -1908,6 +1453,7 @@ class QueryEngine:
         (:meth:`_resident_grid`) instead of scanning its own rows."""
         if not self._grid_eligible(sub):
             return None
+        from opentsdb_tpu.ops import shapes
         ds_spec = sub.ds_spec
         bucket_ts = ds_mod.fixed_bucket_edges(
             tsq.start_ms, tsq.end_ms, ds_spec.interval_ms)
@@ -1922,31 +1468,26 @@ class QueryEngine:
         if mesh is None:
             host_dev = self._tail_device(len(sids), b, num_groups,
                                          emit_raw, sub.agg.rank_class)
-        # device-resident cache: a warm repeat of this reduction skips
-        # the host scan AND the upload (HBM ≙ HBase block cache).
-        # Under a mesh the cached value is the pre-SHARDED device args
-        # (grid + mask + bucket_ts + gids placed per the mesh specs).
-        # Host-tail queries skip it: their native re-scan costs
-        # milliseconds, and host-RAM entries must not evict
-        # HBM-resident grids whose re-upload the cache exists to avoid
-        # (nor report host bytes as device bytes).
+        # a warm repeat skips the host scan AND the upload (HBM ≙
+        # HBase block cache). Host-tail queries skip the cache: their
+        # native re-scan costs milliseconds, and host-RAM entries must
+        # not evict HBM-resident grids (nor count as device bytes)
         cache = self.tsdb.device_grid_cache if host_dev is None \
             else None
-        ckey = cver = None
-        grid = has_data = None
-        mesh_args = mesh_meta = None
         # what the tail program runs over: the selection's rows and
         # group ids, or every row of the metric, labelled
         tail_rows, tail_gids = len(sids), group_ids
-        resident = None
+        # under a mesh, the host grid this request scanned itself (no
+        # hit): what the single-device host tail re-answers from
+        fresh = None
         if cache is not None and mesh is None and not emit_raw \
-                and not tsq.delete and metric_rows is not None:
-            resident = self._resident_grid(
+                and not tsq.delete and metric_rows is not None \
+                and self._resident_grid_fits(
+                    cache, len(metric_rows[0]), len(sids), b, budget):
+            operands = self._resident_grid(
                 cache, store, *metric_rows, len(sids), tsq, bucket_ts,
-                ds_spec.interval_ms, fn, budget, stats)
-        if resident is not None:
-            grid, has_data, num_points = resident
-            if num_points:
+                ds_spec.interval_ms, fn, metric_name, stats)
+            if operands is not None:
                 # all that this request puts up: a label a resident
                 # row, the rows its filter dropped on the dummy group
                 # (the one shapes.pad_group_ids gives padded rows)
@@ -1955,45 +1496,50 @@ class QueryEngine:
                     tail_gids = np.full(tail_rows, num_groups,
                                         np.int32)
                     tail_gids[metric_rows[1]] = group_ids
-        elif cache is not None:
-            from opentsdb_tpu.query.device_cache import array_digest
-            with trace_span("query.grid_build", stage="cache_lookup",
-                            grid="selection"):
-                ckey = ("grid", _store_id(store), array_digest(
+        else:
+            def key_of():
+                return (_store_id(store), device_cache.array_digest(
                     np.ascontiguousarray(sids)), tsq.start_ms,
-                    tsq.end_ms, int(bucket_ts[0]),
-                    ds_spec.interval_ms, b, fn, mesh)
-                cver = (store.points_written,
-                        getattr(store, "mutation_epoch", 0))
-                hit = cache.get(ckey, cver)
-            if hit is not None:
+                    tsq.end_ms, int(bucket_ts[0]), ds_spec.interval_ms,
+                    b, fn, mesh)
+
+            def build(checked):
+                nonlocal fresh
+                grid, has_data, num_points = self._reduce_to_grid(
+                    store, sids, tsq, bucket_ts, ds_spec.interval_ms,
+                    GRID_STATS[fn],
+                    lambda scan, points: self._record_scan(
+                        stats, scan, points, len(sids)))
+                meta = {"num_points": num_points}
+                checked(num_points)
+                if not num_points:
+                    return None, meta
                 if mesh is not None:
-                    mesh_args, mesh_meta = hit
-                    num_points = mesh_meta["num_points"]
-                    grid = True  # skip the host scan below
-                else:
-                    (grid, has_data), meta = hit
-                    num_points = meta["num_points"]
-        built = grid is None
-        if built:
-            grid, has_data, num_points = self._reduce_to_grid(
-                store, sids, tsq, bucket_ts, ds_spec.interval_ms,
-                GRID_STATS[fn],
-                lambda scan, points: self._record_scan(
-                    stats, scan, points, len(sids)))
-        elif resident is None:
-            self._record_scan(stats, self._scan_begin(), num_points,
-                              len(sids))
-        self.tsdb.query_limits.check(metric_name, num_points)
-        if tsq.delete and hasattr(store, "delete_range"):
-            store.delete_range(sids, tsq.start_ms, tsq.end_ms)
-        if num_points == 0:
+                    from opentsdb_tpu.parallel.sharded_pipeline import \
+                        prepare_sharded_grid
+                    fresh = grid, has_data
+                    # padded like execute_grid pads (bucket_grid_shapes)
+                    data_args, meta["s_loc"], meta["b_loc"], \
+                        meta["s_pad"] = prepare_sharded_grid(
+                            mesh, grid, has_data, shapes.pad_bucket_ts(
+                                np.asarray(bucket_ts),
+                                shapes.shape_bucket(b)))
+                    return data_args, meta
+                if cache is not None:
+                    from opentsdb_tpu.ops.pipeline import put_grid
+                    return put_grid(grid, has_data), meta
+                return (grid, has_data), meta
+
+            operands = self._resident_operands(
+                cache, "grid", key_of, (store,), build, stats,
+                metric_name, len(sids),
+                delete=(lambda: store.delete_range(
+                    sids, tsq.start_ms, tsq.end_ms))
+                if tsq.delete and hasattr(store, "delete_range")
+                else None)
+        if operands is None:
             return (None, None, bucket_ts)
-        if built and cache is not None and mesh is None:
-            from opentsdb_tpu.ops.pipeline import put_grid
-            grid, has_data = put_grid(grid, has_data)
-            cache.put(ckey, cver, (grid, has_data),
-                      {"num_points": num_points})
+        arrays, meta = operands
         t2 = time.monotonic()
         spec = PipelineSpec(
             num_series=tail_rows, num_buckets=b, num_groups=num_groups,
@@ -2017,53 +1563,33 @@ class QueryEngine:
             # (bucket_grid_shapes), so the compiled shard_map program
             # set is bounded and tsd.tpu.warmup's mesh pre-compiles
             # are the programs real queries hit.
-            from opentsdb_tpu.ops import shapes
-            from opentsdb_tpu.ops.pipeline import _bucket_dims_and_aux
+            from opentsdb_tpu.ops.pipeline import (_bucket_dims_and_aux,
+                                                   execute_grid)
             from opentsdb_tpu.parallel.sharded_pipeline import (
-                prepare_sharded_grid, run_sharded_grid,
-                sharded_grid_gids)
-            # dims from the RAW query shape (grid may be `True` on a
-            # mesh-cache hit): identical to the fresh-grid pad above,
-            # since shape_bucket is idempotent
-            s_bk, b_bk, bts_bk, gids_bk, pspec = _bucket_dims_and_aux(
+                run_sharded_grid, sharded_grid_gids)
+            _, _, _, gids_bk, pspec = _bucket_dims_and_aux(
                 bucket_ts, group_ids, spec,
-                shapes.shape_bucket(len(sids)),
-                shapes.shape_bucket(len(bucket_ts)))
-            if mesh_args is None:
-                data_args, s_loc, b_loc, s_pad = prepare_sharded_grid(
-                    mesh, np.asarray(grid), np.asarray(has_data),
-                    bts_bk)
-                if cache is not None:
-                    cache.put(ckey, cver, data_args,
-                              {"num_points": num_points,
-                               "s_loc": s_loc, "b_loc": b_loc,
-                               "s_pad": s_pad})
-            else:
-                data_args = mesh_args
-                s_loc = mesh_meta["s_loc"]
-                b_loc = mesh_meta["b_loc"]
-                s_pad = mesh_meta["s_pad"]
-            gids_dev = sharded_grid_gids(mesh, gids_bk, s_pad,
+                shapes.shape_bucket(len(sids)), shapes.shape_bucket(b))
+            gids_dev = sharded_grid_gids(mesh, gids_bk, meta["s_pad"],
                                          pspec.num_groups)
             host_retry = None
-            if isinstance(grid, np.ndarray):
-                # fresh (non-cache-hit) grid: the single-device host
-                # tail can re-answer the same reduction on failure
+            if fresh is not None:
                 def host_retry():
-                    from opentsdb_tpu.ops.pipeline import execute_grid
                     return execute_grid(
-                        grid, has_data, bucket_ts, group_ids,
+                        *fresh, bucket_ts, group_ids,
                         replace(spec, host=True), sub.rate_options,
                         device=self._host_cpu())
             result, emit = self._run_device(
                 lambda: run_sharded_grid(
-                    mesh, pspec, data_args + (gids_dev,), s_loc,
-                    b_loc, num_groups, sub.rate_options), host_retry)
+                    mesh, pspec, arrays + (gids_dev,), meta["s_loc"],
+                    meta["b_loc"], num_groups, sub.rate_options),
+                host_retry)
             rows = len(sids) if emit_raw else num_groups
             result = result[:rows, :len(bucket_ts)]
             emit = emit[:rows, :len(bucket_ts)]
         else:
             from opentsdb_tpu.ops.pipeline import execute_grid
+            grid, has_data = arrays
 
             def host_retry():
                 return execute_grid(grid, has_data, bucket_ts,
@@ -2093,7 +1619,6 @@ class QueryEngine:
         average, not a mean of per-tier-point averages (ref: RollupSpan
         reading agg-prefixed sum+count qualifiers from one row).
         Returns (result, emit, bucket_ts) or None for no data."""
-        scan = self._scan_begin()
         # count series aligned to sum series by (metric, tags)
         # identity — computed lazily: a device-cache hit never needs it
         csids = present = None
@@ -2107,67 +1632,55 @@ class QueryEngine:
                 present = np.nonzero(csids >= 0)[0]
             return csids, present
 
+        def delete():
+            csids, present = align()
+            sum_store.delete_range(sids, tsq.start_ms, tsq.end_ms)
+            cnt_store.delete_range(csids[present], tsq.start_ms,
+                                   tsq.end_ms)
+
         ds_spec = sub.ds_spec
-        fixed = (not ds_spec.run_all and not ds_spec.use_calendar
-                 and ds_spec.unit not in ("n", "y")
-                 and ds_spec.interval_ms > 0)
-        host_dev = None
-        if fixed:
+        mesh = self.tsdb.query_mesh
+        host_dev = cache = None
+        if self._fixed_interval(ds_spec):
             # native pre-reduction: both tiers collapse to [S, B] sums
             # in one storage pass each — no per-point upload
             bucket_ts = ds_mod.fixed_bucket_edges(
                 tsq.start_ms, tsq.end_ms, ds_spec.interval_ms)
             s, b = len(sids), len(bucket_ts)
-            t0_ms = int(bucket_ts[0])
-            mesh = self.tsdb.query_mesh
+            window = (tsq.start_ms, tsq.end_ms, int(bucket_ts[0]),
+                      ds_spec.interval_ms, b)
             if mesh is None:
                 host_dev = self._tail_device(s, b, num_groups,
                                              emit_raw,
                                              sub.agg.rank_class)
-            # host-tail queries skip the device cache (see
-            # _grid_pipeline: cheap native re-scan; host RAM must not
-            # evict HBM-resident grids)
-            cache = self.tsdb.device_grid_cache \
-                if mesh is None and host_dev is None else None
-            ckey = cver = None
-            gs = gc = None
-            if cache is not None:
-                from opentsdb_tpu.query.device_cache import \
-                    array_digest
-                ckey = ("avgdiv", _store_id(sum_store),
-                        _store_id(cnt_store),
-                        array_digest(np.ascontiguousarray(sids)),
-                        tsq.start_ms, tsq.end_ms, t0_ms,
-                        ds_spec.interval_ms, b)
-                cver = (sum_store.points_written,
-                        getattr(sum_store, "mutation_epoch", 0),
-                        cnt_store.points_written,
-                        getattr(cnt_store, "mutation_epoch", 0))
-                hit = cache.get(ckey, cver)
-                if hit is not None:
-                    (gs, gc), meta = hit
-                    num_points = meta["num_points"]
-            if gs is None:
+                # host-tail queries skip the device cache (see
+                # _grid_pipeline: cheap native re-scan; host RAM must
+                # not evict HBM-resident grids)
+                if host_dev is None:
+                    cache = self.tsdb.device_grid_cache
+
+            def key_of():
+                return (_store_id(sum_store), _store_id(cnt_store),
+                        device_cache.array_digest(
+                            np.ascontiguousarray(sids)), *window)
+
+            def build(_checked):
+                scan = self._scan_begin()
                 csids, present = align()
-                sum_s, cnt_s, _, _ = sum_store.bucket_reduce(
-                    sids, tsq.start_ms, tsq.end_ms, t0_ms,
-                    ds_spec.interval_ms, b)
+                sum_s, cnt_s, _, _ = sum_store.bucket_reduce(sids,
+                                                             *window)
                 if len(present) == s:
                     sum_c, cnt_c, _, _ = cnt_store.bucket_reduce(
-                        csids, tsq.start_ms, tsq.end_ms, t0_ms,
-                        ds_spec.interval_ms, b)
+                        csids, *window)
                 else:
-                    sum_c = np.zeros((s, b))
-                    cnt_c = np.zeros((s, b))
+                    sum_c, cnt_c = np.zeros((2, s, b))
                     if len(present):
-                        sc, cc, _, _ = cnt_store.bucket_reduce(
-                            csids[present], tsq.start_ms, tsq.end_ms,
-                            t0_ms, ds_spec.interval_ms, b)
-                        sum_c[present] = sc
-                        cnt_c[present] = cc
+                        sum_c[present], cnt_c[present], _, _ = \
+                            cnt_store.bucket_reduce(csids[present],
+                                                    *window)
                 num_points = int(cnt_s.sum() + cnt_c.sum())
                 self._record_scan(stats, scan, num_points, len(sids))
-                scan = None
+                meta = {"num_points": num_points}
                 with trace_span("query.grid_build", cells=s * b,
                                 bytes=sum_s.nbytes + sum_c.nbytes):
                     # write NaN holes in place (np.where would copy
@@ -2175,7 +1688,7 @@ class QueryEngine:
                     sum_s[cnt_s == 0] = np.nan
                     sum_c[cnt_c == 0] = np.nan
                     gs, gc = sum_s, sum_c
-                    if self.tsdb.query_mesh is None:
+                    if mesh is None:
                         # pre-pad to the shape buckets (host, once;
                         # the cache then holds padded device grids —
                         # no per-query device pads on the warm path)
@@ -2184,7 +1697,9 @@ class QueryEngine:
                         bp = shapes.shape_bucket(b)
                         gs = shapes.pad_2d_host(gs, sp, bp, np.nan)
                         gc = shapes.pad_2d_host(gc, sp, bp, np.nan)
-                if cache is not None and num_points:
+                if not num_points:
+                    return None, meta
+                if cache is not None:
                     from opentsdb_tpu.ops.pipeline import pipeline_dtype
                     import jax
                     import jax.numpy as jnp
@@ -2192,43 +1707,46 @@ class QueryEngine:
                     with trace_span("query.upload"):
                         gs = jax.device_put(jnp.asarray(gs, dtype=dt))
                         gc = jax.device_put(jnp.asarray(gc, dtype=dt))
-                    cache.put(ckey, cver, (gs, gc),
-                              {"num_points": num_points})
+                return (gs, gc), meta
+
+            operands = self._resident_operands(
+                cache, "avgdiv", key_of, (sum_store, cnt_store), build,
+                stats, metric_name, len(sids),
+                delete=delete if tsq.delete else None)
+            if operands is None:
+                return None
+            (gs, gc), _ = operands
+            t2 = time.monotonic()
         else:
+            scan = self._scan_begin()
             csids, present = align()
             batch_s = sum_store.materialize(sids, tsq.start_ms,
                                             tsq.end_ms)
             batch_c = cnt_store.materialize(csids[present],
                                             tsq.start_ms, tsq.end_ms)
             num_points = batch_s.num_points + batch_c.num_points
-        if scan is not None:
             self._record_scan(stats, scan, num_points, len(sids))
-        self.tsdb.query_limits.check(metric_name, num_points)
-        if tsq.delete:
-            csids, present = align()
-            sum_store.delete_range(sids, tsq.start_ms, tsq.end_ms)
-            cnt_store.delete_range(csids[present], tsq.start_ms,
-                                   tsq.end_ms)
-        if num_points == 0:
-            return None
-        t2 = time.monotonic()
-        if not fixed:
+            self.tsdb.query_limits.check(metric_name, num_points)
+            if tsq.delete:
+                delete()
             if batch_s.num_points == 0:
                 return None
-            _h_build = trace_begin("query.grid_build")
-            bidx_s, bucket_ts = ds_mod.assign_buckets(
-                batch_s.ts_ms, sub.ds_spec, tsq.start_ms, tsq.end_ms)
-            bidx_c, _ = ds_mod.assign_buckets(
-                batch_c.ts_ms, sub.ds_spec, tsq.start_ms, tsq.end_ms)
-            s, b = len(sids), len(bucket_ts)
-            # both grids stay on device: bucketize returns device
-            # arrays and the division happens in the same trace
-            gs, _ = ds_mod.bucketize(batch_s.values, batch_s.series_idx,
-                                     bidx_s, s, b, "sum")
-            sidx_c = present[batch_c.series_idx].astype(np.int32)
-            gc, _ = ds_mod.bucketize(batch_c.values, sidx_c, bidx_c, s,
-                                     b, "sum")
-            trace_end(_h_build)
+            t2 = time.monotonic()
+            with trace_span("query.grid_build"):
+                bidx_s, bucket_ts = ds_mod.assign_buckets(
+                    batch_s.ts_ms, ds_spec, tsq.start_ms, tsq.end_ms)
+                bidx_c, _ = ds_mod.assign_buckets(
+                    batch_c.ts_ms, ds_spec, tsq.start_ms, tsq.end_ms)
+                s, b = len(sids), len(bucket_ts)
+                # both grids stay on device: bucketize returns device
+                # arrays and the division happens in the same trace
+                gs, _ = ds_mod.bucketize(
+                    batch_s.values, batch_s.series_idx, bidx_s, s, b,
+                    "sum")
+                gc, _ = ds_mod.bucketize(
+                    batch_c.values,
+                    present[batch_c.series_idx].astype(np.int32),
+                    bidx_c, s, b, "sum")
         spec = PipelineSpec(
             num_series=s, num_buckets=b, num_groups=num_groups,
             ds_function="avg", agg_name=sub.agg.name,
@@ -2237,7 +1755,6 @@ class QueryEngine:
             rate_counter=sub.rate_options.counter,
             rate_drop_resets=sub.rate_options.drop_resets,
             emit_raw=emit_raw, host=host_dev is not None)
-        mesh = self.tsdb.query_mesh
         if mesh is not None:
             # divide host-side, then run the rate/fill/agg tail over
             # the mesh with one point per present grid cell (bucketize
@@ -2530,59 +2047,3 @@ class QueryEngine:
                 global_annotations=global_annotations,
                 sub_query_index=sub.index, dps_arrays=dps_arrays))
         return out
-
-
-def _match_series_by_tags(src_store, dst_store, sids: np.ndarray,
-                          metric_id: int) -> np.ndarray:
-    """For each src-store series id, the dst-store series id with the
-    identical (metric, tags) key, or -1 — fully vectorized (the rollup
-    avg path aligns the count tier to the sum tier this way; a
-    dict-lookup walk costs seconds at 1M series).
-
-    Exact match: both stores' tag matrices are built over the union key
-    space, so equal rows <=> equal tag sets (ref: RollupSpan reading
-    sum+count qualifiers of one row — same series identity)."""
-    dst_sids = dst_store.series_ids_for_metric(metric_id)
-    if len(dst_sids) == 0 or len(sids) == 0:
-        return np.full(len(sids), -1, dtype=np.int64)
-    _, src_triples = src_store.metric_index(metric_id).arrays()
-    _, dst_triples = dst_store.metric_index(metric_id).arrays()
-    kids = np.union1d(
-        np.unique(src_triples[:, 1]) if len(src_triples)
-        else np.empty(0, dtype=np.int64),
-        np.unique(dst_triples[:, 1]) if len(dst_triples)
-        else np.empty(0, dtype=np.int64))
-    a = TagMatrix.from_triples(sids, src_triples, kids=kids).vids
-    b = TagMatrix.from_triples(dst_sids, dst_triples, kids=kids).vids
-    both = np.concatenate([a, b], axis=0)
-    labels, _ = compact_row_labels(both)
-    la, lb = labels[:len(a)], labels[len(a):]
-    order = np.argsort(lb, kind="stable")
-    lb_sorted = lb[order]
-    pos = np.searchsorted(lb_sorted, la)
-    pos_c = np.minimum(pos, len(lb_sorted) - 1)
-    hit = lb_sorted[pos_c] == la
-    return np.where(hit, dst_sids[order[pos_c]], -1)
-
-
-def _common_tags(tags: TagMatrix, members: np.ndarray, uids
-                 ) -> tuple[dict[str, str], list[str]]:
-    """SpanGroup semantics for ONE group (small paths — the engine's
-    main loop computes all groups at once in ``_build_results``):
-    ``tags`` = k=v pairs identical across every member series;
-    ``aggregateTags`` = keys present everywhere with differing values
-    (keys missing from some series vanish)."""
-    sub = tags.vids[members]
-    out_tags: dict[str, str] = {}
-    agg_tags: list[str] = []
-    for j, kid in enumerate(tags.kids):
-        col = sub[:, j]
-        lo = int(col.min()) if len(col) else -1
-        if lo < 0:
-            continue
-        kname = uids.tag_names.get_name(int(kid))
-        if lo == int(col.max()):
-            out_tags[kname] = uids.tag_values.get_name(lo)
-        else:
-            agg_tags.append(kname)
-    return out_tags, agg_tags

@@ -14,7 +14,7 @@ group label a series. A write bumps the version and drops them.
 
 A request is the five stages of every other query under
 ``query.execute``: ``query.plan`` (the filters and the group labels
-from the histogram store's :class:`~opentsdb_tpu.query.engine.PlanIndex`,
+from the histogram store's :class:`~opentsdb_tpu.query.plan.PlanIndex`,
 as the scalar path), ``query.upload`` / ``query.program`` /
 ``query.download`` (:func:`~opentsdb_tpu.ops.pipeline.run_staged` around
 :func:`~opentsdb_tpu.ops.histogram_kernels.histogram_percentiles`,
@@ -46,7 +46,10 @@ import numpy as np
 
 from opentsdb_tpu.core.histogram import RESIDENT_KEY
 from opentsdb_tpu.obs.trace import trace_span
+from opentsdb_tpu.query import device_cache
 from opentsdb_tpu.query.model import BadRequestError, TSQuery, TSSubQuery
+from opentsdb_tpu.query.plan import (_common_tags, _UidNameCache,
+                                     group_tag_summary)
 
 #: a dense layout may hold this many cells a stored point (a gappy
 #: fleet fills nearly all of its cells; series that share no
@@ -292,40 +295,38 @@ def run_histogram_subquery(engine, tsq: TSQuery, sub: TSSubQuery
 
     stats = tsdb.histogram_stats
     cache = tsdb.device_grid_cache
-    ckey = (RESIDENT_KEY, metric_id, tsq.start_ms, tsq.end_ms)
-    # read before the arena is: a write during the build leaves an
-    # entry the next request will not trust
-    cver = tsdb._histogram_version
     window = None
 
-    def resident_counts():
-        """The window's counts on the device: the cache's, or laid out
-        now (None: the window has no dense layout)."""
+    def lay_out():
+        """The window's counts laid out on the device now: the cache's
+        ``hist`` kind (nothing to keep: the window has no dense
+        layout)."""
         nonlocal window
-        hit = cache.get(ckey, cver) if cache is not None else None
-        if hit is not None:
-            return hit[1]["resident"]
         window = _window_points(tsdb, metric_id, tsq.start_ms,
                                 tsq.end_ms)
-        if len(window) != 1:
-            return None
-        bounds, ts_a, sid_a, rows_a, inside = window[0]
-        with trace_span("query.upload", resident="built"):
-            made = _make_resident(
-                engine, bounds, *_windowed(ts_a, sid_a, rows_a, inside),
-                cache.max_bytes if cache is not None
-                else DEFAULT_DENSE_BYTES)
-        if made is not None:
-            stats.add(upload_bytes=made.nbytes)
-            if cache is not None:
-                cache.put(ckey, cver, (made.counts, made.present),
-                          {"resident": made})
-        return made
+        made = None
+        if len(window) == 1:
+            bounds, ts_a, sid_a, rows_a, inside = window[0]
+            with trace_span("query.upload", resident="built"):
+                made = _make_resident(
+                    engine, bounds,
+                    *_windowed(ts_a, sid_a, rows_a, inside),
+                    cache.max_bytes if cache is not None
+                    else DEFAULT_DENSE_BYTES)
+        if made is None:
+            return None, {"resident": None}
+        stats.add(upload_bytes=made.nbytes)
+        return (made.counts, made.present), {"resident": made}
 
     # one layout at a time: two requests that miss together would put
-    # the counts up twice (3.8 GB each at 12M points)
-    with tsdb._histogram_resident_lock:
-        resident = resident_counts()
+    # the counts up twice (3.8 GB each at 12M points). Of one window,
+    # the second waits for the first's and reads it (the key's
+    # flight); of two windows, for its turn (the kind takes turns:
+    # device_cache.SERIAL_BUILD_KINDS)
+    _, meta, _ = device_cache.resident(
+        cache, (RESIDENT_KEY, metric_id, tsq.start_ms, tsq.end_ms),
+        lambda: tsdb._histogram_version, lay_out)
+    resident = meta["resident"]
     if window is not None and not window:
         return []
     if window is not None and len(window) > 1:
@@ -434,11 +435,10 @@ def _on_resident(engine, resident: ResidentCounts, sids, group_ids,
 def _emit_groups(tsdb, tsq, sub, tag_mat, group_ids, num_groups,
                  gb_kids, ts_arr, present, pcts, span) -> list:
     """One QueryResult per (group, percentile), a group's tags by the
-    SpanGroup rule from :func:`~opentsdb_tpu.query.engine.
+    SpanGroup rule from :func:`~opentsdb_tpu.query.plan.
     group_tag_summary` (the plan index's cached layout where the
     selection came from one), as the scalar path's assemble stage."""
-    from opentsdb_tpu.query.engine import (QueryResult, _UidNameCache,
-                                           group_tag_summary)
+    from opentsdb_tpu.query.engine import QueryResult
     way, minv, maxv, _members, _source = group_tag_summary(
         tag_mat, group_ids, num_groups, gb_kids)
     if span is not None:
@@ -485,7 +485,7 @@ def _run_mixed_bounds(tsdb, tsq, sub, active, sids, tag_mat, group_ids,
     ``active`` is :func:`_window_points`' list:
     [(bounds, ts, sid, rows, window mask), ...].
     """
-    from opentsdb_tpu.query.engine import QueryResult, _common_tags
+    from opentsdb_tpu.query.engine import QueryResult
     from opentsdb_tpu.ops import downsample as ds_mod
     uids = tsdb.uids
     sids = np.asarray(sids)

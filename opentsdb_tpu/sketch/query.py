@@ -111,8 +111,9 @@ def run_sketch_percentiles(tsdb, tsq: TSQuery, sub: TSSubQuery,
 
 def _run_over_store(tsdb, tsq, sub, store, mid, alpha, max_buckets,
                     partials, hist):
-    from opentsdb_tpu.query.engine import QueryEngine, TagMatrix
+    from opentsdb_tpu.query.engine import QueryEngine
     from opentsdb_tpu.query.filters import FilterEvaluator
+    from opentsdb_tpu.query.plan import TagMatrix
     uids = tsdb.uids
     sids = store.series_ids_for_metric(mid)
     if len(sids) == 0:
@@ -275,7 +276,8 @@ def arena_sketch_items(tsdb, mid: int, start_ms: int, end_ms: int,
 
 def _emit(tsdb, tsq, sub, tag_mat, group_ids, num_groups, acc,
           partials, cold_ok):
-    from opentsdb_tpu.query.engine import QueryResult, _common_tags
+    from opentsdb_tpu.query.engine import QueryResult
+    from opentsdb_tpu.query.plan import _common_tags
     uids = tsdb.uids
     order = np.argsort(group_ids, kind="stable")
     sorted_gids = np.asarray(group_ids)[order]

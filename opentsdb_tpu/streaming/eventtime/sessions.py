@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from opentsdb_tpu.query.engine import TagMatrix
+from opentsdb_tpu.query.plan import TagMatrix
 from opentsdb_tpu.streaming.plan import SharedPartial
 
 

@@ -51,7 +51,8 @@ import numpy as np
 from opentsdb_tpu.ops import downsample as ds_mod
 from opentsdb_tpu.ops import stream_fold
 from opentsdb_tpu.query import filters as filters_mod
-from opentsdb_tpu.query.engine import QueryEngine, TagMatrix
+from opentsdb_tpu.query.engine import QueryEngine
+from opentsdb_tpu.query.plan import TagMatrix
 from opentsdb_tpu.query.model import BadRequestError, TSSubQuery
 from opentsdb_tpu.utils import datetime_util
 

@@ -110,7 +110,7 @@ def warmup_shapes(tsdb) -> list[tuple]:
     compiled-shape class. G stays RAW here: the engine buckets groups
     as shape_bucket(G+1), so run_warmup routes these through the SAME
     helper (engine.host_tail_for_dims / shapes.shape_bucket) the real
-    query path uses — bucketing in two places drifted (ADVICE r04)."""
+    query path uses (bucketing in two places drifted once)."""
     from opentsdb_tpu.ops import shapes
     per_store = []                       # (series_count, group classes)
     for store in _resident_stores(tsdb):

@@ -19,8 +19,8 @@ Where the cache lives, in order:
    and no config key overrides it.
 2. ``tsd.query.compile_cache_dir`` when it names a directory.
 3. :data:`DEFAULT_CACHE_DIR`, one fixed git-ignored directory inside
-   the checkout, shared by the server, the CLI tools,
-   ``bench_e2e.py`` and ``chip_smoke.py``.
+   the checkout, shared by the server, the CLI tools and
+   ``chip_smoke.py``.
 
 The directory is part of the cache key (JAX embeds it in the compile
 options), so nothing that moves — a data dir, a temp dir, a pid —

@@ -11,6 +11,7 @@ import pytest
 
 from opentsdb_tpu import TSDB, Config
 from opentsdb_tpu.obs import trace as trace_mod
+from opentsdb_tpu.query import device_cache as dc_mod
 from opentsdb_tpu.query import engine as engine_mod
 from opentsdb_tpu.query.limits import QueryLimitExceeded
 from opentsdb_tpu.query.model import TSQuery
@@ -242,7 +243,7 @@ class TestBucketReduceBackends:
 
 class TestCompactRowLabels:
     def test_matches_numpy_unique_axis0(self):
-        from opentsdb_tpu.query.engine import compact_row_labels
+        from opentsdb_tpu.query.plan import compact_row_labels
         rng = np.random.default_rng(2)
         for cols in (1, 2, 4):
             mat = rng.integers(-1, 5, (300, cols)).astype(np.int64)
@@ -252,7 +253,7 @@ class TestCompactRowLabels:
             np.testing.assert_array_equal(labels, inv)
 
     def test_empty(self):
-        from opentsdb_tpu.query.engine import compact_row_labels
+        from opentsdb_tpu.query.plan import compact_row_labels
         labels, n = compact_row_labels(np.empty((0, 3), dtype=np.int64))
         assert n == 0 and len(labels) == 0
         labels, n = compact_row_labels(np.empty((4, 0), dtype=np.int64))
@@ -261,7 +262,7 @@ class TestCompactRowLabels:
 
 class TestMatchSeriesByTags:
     def test_alignment(self):
-        from opentsdb_tpu.query.engine import _match_series_by_tags
+        from opentsdb_tpu.query.plan import _match_series_by_tags
         a = _tsdb()
         # two stores with the same metric/tag universe, different order
         s1, s2 = a.store, type(a.store)()
@@ -277,7 +278,7 @@ class TestMatchSeriesByTags:
                 int(sids1[i])).tags
 
     def test_missing_marked(self):
-        from opentsdb_tpu.query.engine import _match_series_by_tags
+        from opentsdb_tpu.query.plan import _match_series_by_tags
         a = _tsdb()
         s1, s2 = a.store, type(a.store)()
         mid = 1
@@ -291,7 +292,7 @@ class TestMatchSeriesByTags:
 
 
 class TestRankPrepKeyGroupCount:
-    """Single-device prep-cache key regression (ADVICE r05 medium):
+    """Single-device prep-cache key regression:
     the rank-class budget is cells * groups, so two group-by
     cardinalities over the same series set must NOT share a
     PreparedBatch placement — the bucketed group count is part of the
@@ -756,3 +757,476 @@ class TestResidentGridValidity:
 
         t.tracer.collect_stats(Collector())
         assert rows == t.tracer.grids
+
+    def test_the_look_up_span_ends_before_a_hit_counts_its_points(
+            self, monkeypatch):
+        """``grid_build.ms`` reads the look-up span: the key, the
+        look-up and the wait, not the selection's point count (1.3 ms
+        a million rows), which is the request's own work."""
+        t = _tsdb()
+        _seed_fleet(t)
+        t.execute_query(_rq("sum"))
+        events = []
+
+        class Counts(np.ndarray):
+            def __getitem__(self, item):
+                events.append("counted")
+                return np.asarray(self)[item]
+
+        class Lookup:
+            def tag(self, **tags):
+                events.append(tags["grid"])
+
+            def finish(self):
+                events.append("ended")
+
+        (entry,) = t.device_grid_cache._entries.values()
+        entry[2]["counts"] = entry[2]["counts"].view(Counts)
+        monkeypatch.setattr(
+            engine_mod, "trace_begin",
+            lambda name, **tags: Lookup()
+            if tags.get("stage") == "cache_lookup" else None)
+        assert t.execute_query(_rq("max"))
+        assert events == ["resident_hit", "ended", "counted"]
+
+
+# ---------------------------------------------------------------------
+# the one entry (device_cache.resident, PR 45): every kind of entry is
+# looked up, built, kept and dropped through it
+# ---------------------------------------------------------------------
+
+class _Versioned:
+    """What ``store_version`` reads of a store."""
+
+    def __init__(self):
+        self.points_written = 0
+        self.mutation_epoch = 0
+
+
+class _Arena:
+    """The histogram arenas' one counter (``TSDB._histogram_version``)."""
+
+    def __init__(self):
+        self.version = 0
+
+
+def _one_store():
+    store = _Versioned()
+
+    def write():
+        store.points_written += 1
+    return (lambda: dc_mod.store_version(store)), write
+
+
+def _two_stores():
+    stores = _Versioned(), _Versioned()
+
+    def write():
+        stores[1].mutation_epoch += 1
+    return (lambda: dc_mod.store_version(*stores)), write
+
+
+def _arena():
+    arena = _Arena()
+
+    def write():
+        arena.version += 1
+    return (lambda: arena.version), write
+
+
+# kind -> (the rest of a key of its shape, what versions it)
+KINDS = {
+    "metricgrid": ((1, 7, 40, 0, 1800, 0, 60, 30, "avg"), _one_store),
+    "grid": ((1, b"digest", 0, 1800, 0, 60, 30, "avg", None),
+             _one_store),
+    "avgdiv": ((1, 2, b"digest", 0, 1800, 0, 60, 30), _two_stores),
+    "prep": ((1, b"digest", 0, 1800, "union", None, None, "lin"),
+             _one_store),
+    "hist": ((7, 0, 1800), _arena),
+}
+by_kind = pytest.mark.parametrize("kind", sorted(KINDS))
+
+
+def _entry(kind, which=0):
+    """(key, version_of, write) of one entry of ``kind``."""
+    rest, versioned = KINDS[kind]
+    return ((kind, which) + rest, *versioned())
+
+
+def _arrays(nbytes=64):
+    return (np.zeros(nbytes // 8), ), {"num_points": 3}
+
+
+class TestResidentEntry:
+    @by_kind
+    def test_the_version_is_read_before_the_build(self, kind):
+        """A write made inside ``build`` leaves an entry the next call
+        rebuilds: the entry carries the version from before it."""
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        key, version_of, write = _entry(kind)
+        order = []
+
+        def read():
+            order.append("version")
+            return version_of()
+
+        def build():
+            order.append("build")
+            write()
+            return _arrays()
+
+        # (a look-up without the flight reads the version too, first)
+        assert cache.resident(key, read, build)[2] == dc_mod.BUILT
+        assert order[-2:] == ["version", "build"]
+        assert cache.resident(key, read, build)[2] == dc_mod.BUILT
+        assert order[-2:] == ["version", "build"]
+        assert order.count("build") == 2
+
+        def quiet():
+            order.append("build")
+            return _arrays()
+
+        made = cache.resident(key, read, quiet)
+        assert made[2] == dc_mod.BUILT
+        hit = cache.resident(key, read, quiet)
+        assert hit[2] == dc_mod.HIT and hit[0] is made[0] \
+            and hit[1] is made[1]
+        assert order.count("build") == 3
+        assert (cache.misses, cache.hits) == (3, 1)
+        assert cache.bytes_of(kind) == 64
+
+    @by_kind
+    def test_threads_on_one_key_build_once(self, kind):
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        key, version_of, _ = _entry(kind)
+        gate = threading.Barrier(8, timeout=30)
+        builds, got = [], [None] * 8
+
+        def build():
+            builds.append(1)
+            time.sleep(0.05)
+            return _arrays()
+
+        def ask(i):
+            gate.wait()
+            got[i] = cache.resident(key, version_of, build)
+
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(8)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(30)
+            assert not th.is_alive()
+        assert len(builds) == 1
+        assert sorted(g[2] for g in got) == \
+            [dc_mod.BUILT] + [dc_mod.HIT] * 7
+        assert all(g[0] is got[0][0] for g in got)
+        # the waiters count as hits, and nobody is left in flight
+        assert (cache.misses, cache.hits) == (1, 7)
+        assert cache._flights == {}
+
+    @by_kind
+    @pytest.mark.parametrize("cached", [True, False])
+    def test_two_keys_build_side_by_side_unless_the_kind_takes_turns(
+            self, kind, cached):
+        """Two keys do not wait for each other, except where one
+        layout is a large share of HBM (``hist``): there the second
+        build starts when the first has ended, cache or no cache."""
+        cache = dc_mod.DeviceGridCache(1 << 20) if cached else None
+        slow_key, version_of, _ = _entry(kind, 0)
+        other_key = _entry(kind, 1)[0]
+        building, release = threading.Event(), threading.Event()
+        other_began, slow_inside = threading.Event(), threading.Event()
+
+        def slow():
+            slow_inside.set()
+            building.set()
+            assert release.wait(30)
+            slow_inside.clear()
+            return _arrays()
+
+        def other():
+            """Ones where it ran inside the slow build, else zeros."""
+            other_began.set()
+            return ((np.full(8, float(slow_inside.is_set())), ),
+                    {"num_points": 3})
+
+        got = []
+        first = threading.Thread(target=dc_mod.resident, args=(
+            cache, slow_key, version_of, slow))
+        second = threading.Thread(target=lambda: got.append(
+            dc_mod.resident(cache, other_key, version_of, other)))
+        first.start()
+        try:
+            assert building.wait(30)
+            second.start()
+            takes_turns = kind in dc_mod.SERIAL_BUILD_KINDS
+            # the other key's build runs to its end meanwhile, or not
+            # at all until the first has ended
+            assert other_began.wait(0.2 if takes_turns else 30) \
+                != takes_turns
+            if not takes_turns:
+                second.join(30)
+                assert not second.is_alive()
+        finally:
+            release.set()
+            first.join(30)
+            second.join(30)
+        assert not first.is_alive() and not second.is_alive()
+        assert got[0][2] == (dc_mod.BUILT if cached else dc_mod.NOT_KEPT)
+        assert bool(got[0][0][0].all()) != takes_turns
+        if cached:
+            assert sorted(k[1] for k in cache._entries) == [0, 1]
+            assert cache._flights == {}
+
+    @by_kind
+    def test_a_build_that_raises_frees_the_waiters(self, kind):
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        key, version_of, _ = _entry(kind)
+        building, release = threading.Event(), threading.Event()
+        errors, got = [], []
+
+        def failing():
+            building.set()
+            assert release.wait(30)
+            raise RuntimeError("the upload failed")
+
+        def leader():
+            try:
+                cache.resident(key, version_of, failing)
+            except RuntimeError as exc:
+                errors.append(exc)
+
+        def waiter():
+            got.append(cache.resident(key, version_of, _arrays))
+
+        first = threading.Thread(target=leader)
+        first.start()
+        assert building.wait(30)
+        rest = [threading.Thread(target=waiter) for _ in range(3)]
+        for th in rest:
+            th.start()
+        time.sleep(0.05)
+        assert not got and not cache._entries
+        release.set()
+        for th in [first] + rest:
+            th.join(30)
+            assert not th.is_alive()
+        # the failure is the leader's alone and nothing of it is kept:
+        # the first waiter through builds, the others hit
+        assert len(errors) == 1
+        assert sorted(g[2] for g in got) == \
+            [dc_mod.BUILT, dc_mod.HIT, dc_mod.HIT]
+        assert cache._flights == {}
+
+    @by_kind
+    def test_what_is_not_kept_is_still_answered(self, kind):
+        cache = dc_mod.DeviceGridCache(64)
+        key, version_of, _ = _entry(kind)
+        # larger than the whole cache
+        arrays, meta, how = cache.resident(key, version_of,
+                                           lambda: _arrays(72))
+        assert how == dc_mod.NOT_KEPT and arrays[0].nbytes == 72 \
+            and meta == {"num_points": 3}
+        # the builder's say: nothing to keep of an empty window
+        arrays, meta, how = cache.resident(
+            key, version_of, lambda: (None, {"num_points": 0}))
+        assert (arrays, meta, how) == (None, {"num_points": 0},
+                                       dc_mod.NOT_KEPT)
+        assert not cache._entries and cache.bytes_of(kind) == 0
+        assert cache.resident(key, version_of, _arrays)[2] == \
+            dc_mod.BUILT
+        assert cache.bytes_of(kind) == 64
+
+    @by_kind
+    def test_no_cache_same_answer(self, kind):
+        key, version_of, _ = _entry(kind)
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        for _ in range(2):
+            with_cache = cache.resident(key, version_of, _arrays)
+            without = dc_mod.resident(None, key, version_of, _arrays)
+            np.testing.assert_array_equal(without[0][0],
+                                          with_cache[0][0])
+            assert without[1] == with_cache[1]
+            assert without[2] == dc_mod.NOT_KEPT
+        assert dc_mod.resident(cache, key, version_of, _arrays)[2] == \
+            dc_mod.HIT
+
+
+def _seed_rollup(t):
+    for i in range(6):
+        for j in range(30):
+            for agg, v in (("sum", float(i + j)), ("count", 3.0)):
+                t.add_aggregate_point("m", BASE + j * 60, v,
+                                      {"host": f"h{i}"}, False, "1m",
+                                      agg)
+
+
+def _seed_hist(t):
+    from opentsdb_tpu.core.histogram import SimpleHistogram
+    for i in range(6):
+        for j in range(10):
+            h = SimpleHistogram([0.0, 1.0, 2.0, 4.0])
+            h.counts = [1 + i, 2 + j, 3]
+            t.add_histogram_point("m", BASE + j * 60,
+                                  t.histogram_manager.encode(h),
+                                  {"host": f"h{i}"})
+
+
+def _write_scalar(t):
+    t.add_point("m", BASE + 90, 1e6, {"host": "h01", "dc": "d1",
+                                      "rack": "r1"})
+
+
+def _write_rollup(t):
+    t.add_aggregate_point("m", BASE, 500.0, {"host": "h0"}, False,
+                          "1m", "sum")
+
+
+def _write_hist(t):
+    from opentsdb_tpu.core.histogram import SimpleHistogram
+    h = SimpleHistogram([0.0, 1.0, 2.0, 4.0])
+    h.counts = [0, 0, 500]
+    t.add_histogram_point("m", BASE + 60,
+                          t.histogram_manager.encode(h), {"host": "h0"})
+
+
+FEW = {"type": "literal_or", "tagk": "rack", "filter": "r1",
+       "groupBy": False}
+
+# kind -> (config, seed, the query that keeps an entry of it, a write
+# inside its window)
+SITES = {
+    "metricgrid": ({}, _seed_fleet, lambda: _rq("max"), _write_scalar),
+    "grid": ({}, _seed_fleet,
+             lambda: _rq("max", filters=(GROUP_DC, FEW)), _write_scalar),
+    "avgdiv": ({"tsd.rollups.enable": "true"}, _seed_rollup,
+               lambda: _q("sum", "5m-avg", end=BASE + 1800),
+               _write_rollup),
+    "prep": ({}, _seed_fleet, lambda: _rq("max", ds=None),
+             _write_scalar),
+    "hist": ({}, _seed_hist, lambda: TSQuery.from_json({
+        "start": BASE * 1000, "end": (BASE + 1800) * 1000,
+        "queries": [{"metric": "m", "aggregator": "sum",
+                     "percentiles": [50.0, 99.0]}]}).validate(),
+        _write_hist),
+}
+
+
+class TestEveryKindThroughTheOneEntry:
+    @pytest.mark.parametrize("kind", sorted(SITES))
+    def test_built_once_hit_on_repeat_rebuilt_after_a_write(self,
+                                                            kind):
+        config, seed, query, write = SITES[kind]
+        t = _tsdb(**config)
+        seed(t)
+        cache = t.device_grid_cache
+        cold = t.execute_query(query())
+        assert cold and _kinds(t) == [kind]
+        assert (cache.misses, cache.hits) == (1, 0)
+        warm = t.execute_query(query())
+        assert (cache.misses, cache.hits) == (1, 1)
+        assert [r.dps for r in warm] == [r.dps for r in cold]
+        write(t)
+        moved = t.execute_query(query())
+        assert (cache.misses, cache.hits) == (2, 1)
+        assert [r.dps for r in moved] != [r.dps for r in cold]
+        assert _kinds(t) == [kind] and cache._flights == {}
+        # and the same answers with nothing resident at all
+        bare = _tsdb(**{**config, "tsd.query.device_cache_mb": "0"})
+        seed(bare)
+        write(bare)
+        _same_answers(bare.execute_query(query()), moved, True, 0.0)
+
+
+    @pytest.mark.parametrize("kind,kept", [
+        ("metricgrid", True), ("grid", False), ("avgdiv", True)])
+    def test_a_request_the_limits_refuse_keeps_what_it_always_did(
+            self, monkeypatch, kind, kept):
+        """The selection's own grid is checked between its scan and
+        its upload: a refused request puts nothing up and keeps
+        nothing. The metric's grid (of use to the selections the
+        limits let through) and the rollup's pair are kept first."""
+        from opentsdb_tpu.ops import pipeline as pipeline_mod
+        config, seed, query, _ = SITES[kind]
+        t = _tsdb(**config)
+        seed(t)
+        uploads = []
+        real_put = pipeline_mod.put_grid
+        monkeypatch.setattr(
+            pipeline_mod, "put_grid",
+            lambda *a: uploads.append(1) or real_put(*a))
+        monkeypatch.setattr(t.query_limits, "default_data_points_limit",
+                            1)
+        for _ in range(2):
+            with pytest.raises(QueryLimitExceeded):
+                t.execute_query(query())
+        assert _kinds(t) == ([kind] if kept else [])
+        assert len(uploads) == (kind == "metricgrid")
+        assert t.device_grid_cache._flights == {}
+        # and the request goes through once the limit lets it
+        monkeypatch.setattr(t.query_limits, "default_data_points_limit",
+                            0)
+        assert t.execute_query(query()) and _kinds(t) == [kind]
+
+    def test_two_histogram_windows_are_laid_out_one_at_a_time(
+            self, monkeypatch):
+        """The window is in the ``hist`` key, so two windows have a
+        flight each: the kind's turn keeps their layouts (3.8 GB each
+        at 12M points) from standing in HBM side by side."""
+        from opentsdb_tpu.query import histogram_engine as hist_mod
+        t = _tsdb()
+        _seed_hist(t)
+        real = hist_mod._make_resident
+        gate = threading.Barrier(4, timeout=30)
+        inside, most = [], []
+
+        def counted(*args):
+            inside.append(1)
+            most.append(len(inside))
+            time.sleep(0.05)
+            try:
+                return real(*args)
+            finally:
+                inside.pop()
+
+        monkeypatch.setattr(hist_mod, "_make_resident", counted)
+
+        def ask(i):
+            gate.wait()
+            t.execute_query(TSQuery.from_json({
+                "start": BASE * 1000, "end": (BASE + 1800 + i) * 1000,
+                "queries": [{"metric": "m", "aggregator": "sum",
+                             "percentiles": [50.0]}]}).validate())
+
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(4)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+            assert not th.is_alive()
+        assert most == [1] * 4 and _kinds(t) == ["hist"] * 4
+
+
+def test_the_plan_formats_import_no_engine():
+    """``query/plan.py`` and ``query/filters.py`` stand below the
+    engine: importing them loads none."""
+    import subprocess
+    import sys
+    done = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; import opentsdb_tpu.query.plan; "
+         "import opentsdb_tpu.query.filters; "
+         "import opentsdb_tpu.query.device_cache; "
+         "sys.exit('opentsdb_tpu.query.engine' in sys.modules)"],
+        capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+
+
+def test_the_store_holds_no_lock_of_the_query_engine():
+    t = _tsdb()
+    assert not [name for name in vars(t)
+                if name.endswith(("_resident_lock",
+                                  "_resident_grid_lock"))]

@@ -14,7 +14,7 @@ import pytest
 
 from opentsdb_tpu import TSDB, Config
 from opentsdb_tpu.core.uid import NoSuchUniqueId
-from opentsdb_tpu.query.engine import PlanIndex, TagMatrix
+from opentsdb_tpu.query.plan import PlanIndex, TagMatrix
 from opentsdb_tpu.query.filters import (FilterEvaluator, build_filter,
                                         filter_types, get_filter,
                                         tags_to_filters)

@@ -425,7 +425,7 @@ class TestSiblingPrefixStaticContainment:
     """Static containment must compare with a trailing separator: a
     SIBLING directory sharing the root's name prefix (static_private
     next to static) defeats a bare startswith check (RFC-agnostic
-    path-traversal hardening; ADVICE r05)."""
+    path-traversal hardening)."""
 
     @pytest.fixture()
     def sibling_router(self, tmp_path):

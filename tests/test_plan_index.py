@@ -23,10 +23,10 @@ import numpy as np
 import pytest
 
 from opentsdb_tpu import TSDB, Config
-from opentsdb_tpu.query import engine as engine_mod
-from opentsdb_tpu.query.engine import (GroupLayout, PlanIndex,
-                                       QueryEngine, TagMatrix,
-                                       group_labels, group_tag_summary)
+from opentsdb_tpu.query import plan as plan_mod
+from opentsdb_tpu.query.engine import QueryEngine
+from opentsdb_tpu.query.plan import (GroupLayout, PlanIndex, TagMatrix,
+                                     group_labels, group_tag_summary)
 from opentsdb_tpu.query.filters import build_filter
 from opentsdb_tpu.tsd.http_api import HttpRequest, HttpRpcRouter
 
@@ -807,7 +807,7 @@ def test_a_summary_from_the_layout_equals_one_from_the_rows(
         gids, n = QueryEngine._group_ids(selected, gb)
         got = summary_of(selected, gids, n, gb, k=3)
         want = summary_of(tags.select(rows), gids, n, gb, k=3)
-        small = len(rows) * engine_mod.SMALL_SELECTION < 3000
+        small = len(rows) * plan_mod.SMALL_SELECTION < 3000
         assert got[0] == ("small" if small else "index")
         assert want[0] == "matrix"
         assert small or selected._vids is None
