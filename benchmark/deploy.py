@@ -29,6 +29,21 @@ The contract:
   ``Unsupported`` for a sub-query the judge cannot answer, from the
   sub-query and the deployment's parameters alone: the harness calls it
   on every template of the traffic file before the server starts.
+- **A request is judged for the window it asked.** The harness reads
+  ``start`` and ``end`` (milliseconds) from the request
+  (:func:`window_of`). Where they are the deployment's span
+  (``1000 * data.t0`` to ``1000 * data.end``) it calls ``answer(sub)``
+  and ``supports(sub, data)`` as above and lays the rows on ``data.t0``
+  and the span's buckets. Any other window it hands over:
+  ``answer(sub, window=(start_ms, end_ms))`` and ``supports(sub, data,
+  (start_ms, end_ms))``, and lays the rows on
+  ``reference.window_buckets(start_ms, end_ms, secs)``. A judge that
+  answers the span alone takes the third argument all the same and
+  raises ``Unsupported`` for it (``reference.span_only``), so that
+  traffic with a window fails before the server starts. Where series
+  are not in step, the generator's ``Data`` has
+  ``point_offset_s(idx)``: the seconds after ``t0 + k * cadence_s`` at
+  which each series' ``k``-th point lies (``gen.py``: None, in step).
   ``rows_to_grid``, ``compare``, ``Cells``, ``Verdict`` and
   ``Unsupported`` are taken from the named module where it defines
   them and from ``reference.py`` otherwise.
@@ -166,6 +181,15 @@ def judge_of(config: dict):
         **{n: getattr(mod, n, getattr(reference, n)) for n in _SHARED})
 
 
+def window_of(doc: dict, data):
+    """``(start_ms, end_ms)`` of a request's body where it asks a
+    window of its own, None where it asks the deployment's span."""
+    if "start" not in doc or "end" not in doc:
+        return None
+    window = int(doc["start"]), int(doc["end"])
+    return None if window == reference.span_of(data) else window
+
+
 def refuse_unjudged(judge, data, requests, where: str) -> None:
     """:class:`Failed` where the judge cannot answer one of
     ``requests`` (one of each template is enough), naming the template:
@@ -176,9 +200,18 @@ def refuse_unjudged(judge, data, requests, where: str) -> None:
         if not subs:
             raise Failed(f"{where}: the template {req.template!r} is "
                          f"no /api/query body: nothing judges it")
+        window = window_of(req.doc, data)
+        if window is not None and window[0] > data.end * 1000:
+            # the read-back of what the window's writers sent: a span
+            # after the data's, judged whole as a deployment of its own
+            # (``run.written_data``)
+            window = None
         for sub in subs:
             try:
-                judge.Reference.supports(sub, data)
+                if window is None:
+                    judge.Reference.supports(sub, data)
+                else:
+                    judge.Reference.supports(sub, data, window)
             except judge.Unsupported as e:
                 raise Failed(
                     f"{where}: the reference "

@@ -109,6 +109,13 @@ class Data:
         return {"host": self.series, "dc": self.dcs,
                 "rack": self.racks, "fleet": self.fleets}[tagk]
 
+    def point_offset_s(self, idx: np.ndarray):
+        """Seconds after ``t0 + k * cadence_s`` at which the ``k``-th
+        point of each series of ``idx`` lies, in ``[0, cadence_s)``;
+        None where every series is in step (here). A generator whose
+        series are not overrides it (``deploy.py``)."""
+        return None
+
 
 def chunk_values(data: Data, seed: int, chunk: int):
     """Series ``[chunk * chunk_series, ...)``: global index, values in
@@ -151,7 +158,13 @@ def chunk_lines(data: Data, seed: int, chunk: int):
     buf[:] = np.frombuffer(line, dtype=np.uint8)
     ts = data.t0 + data.cadence_s * np.arange(data.points,
                                               dtype=np.int64)
-    buf[:, :, o:o + 10] = _digits(ts, 10)[None]
+    off = data.point_offset_s(idx)
+    if off is None:
+        buf[:, :, o:o + 10] = _digits(ts, 10)[None]
+    else:
+        buf[:, :, o:o + 10] = _digits(
+            (ts[None, :] + off[:, None]).reshape(-1), 10) \
+            .reshape(n, data.points, 10)
     d = _digits(cents.reshape(-1), 6).reshape(n, data.points, 6)
     buf[:, :, o + 11:o + 15] = d[:, :, :4]
     buf[:, :, o + 16:o + 18] = d[:, :, 4:]

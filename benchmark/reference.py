@@ -15,6 +15,43 @@ judged and must not be sent. :meth:`Reference.supports` says so from
 the sub-query and the deployment's parameters alone, and ``run.py``
 asks it of every template before the server starts.
 
+A request may ask a window of its own instead of the deployment's
+whole span (``traffic.py``'s ``window``: the last hour up to a "now"
+that moves). ``answer(sub, window=(start_ms, end_ms))`` answers it, by
+OpenTSDB 2.4's rules as this repository's conformance oracle states
+them (``tests/oracle.py`` holds the program to the same; nothing of it
+is imported here):
+
+- a point counts when ``start_ms <= its timestamp <= end_ms``, to the
+  millisecond, and in no other case (``TsdbQuery``'s range);
+- buckets are aligned down to the interval (``Downsampler``:
+  timestamps modulo the interval), so the first bucket starts at
+  ``start_ms - start_ms % interval`` and the last holds ``end_ms``:
+  both are partial where the window's ends are off an edge, and fold
+  only the points the window holds of them;
+- downsample, then rate (``RateSpan`` over the downsampler's values):
+  a series' first bucket in the window has no rate, and the rate of
+  its second reads the partial first;
+- a group's bucket is emitted where a member has a real value there;
+  a member counts between its first and its last value in the WINDOW,
+  on the straight line across a gap (``AggregationIterator``);
+- the first bucket is emitted under its aligned timestamp although
+  that lies before ``start_ms``, as the oracle and the program's
+  ``fixed_bucket_edges`` have it. The source tree the issue names for
+  this one question was not in the builder's sandbox (PERF.md section
+  7 has the doubt); no cell depends on it, a rate having no value in
+  that bucket either way.
+
+The interior buckets of a window are the span's own. For a ``sum``
+they are folded once a pair of first and last bucket, over the series
+with a value in every interior bucket; those series' two edge buckets
+depend only on how many of the bucket's points the window cuts, which
+is one of two neighbouring counts by the series' offset within the
+cadence (``data.point_offset_s``), so they come from prefix sums over
+the offsets; the few series with a gap inside are folded anew for each
+window. Anything else (another aggregator, an exclusion, a window of
+under three buckets) folds every selected series anew.
+
 A configuration file may name another judge (``deploy.py``); one that
 builds on this file subclasses :class:`Reference`.
 """
@@ -113,6 +150,43 @@ def lerp_fill(grid: np.ndarray) -> np.ndarray:
     return np.where(inner, v0 + (v1 - v0) * w, grid)
 
 
+def fold(v: np.ndarray, fn: str) -> np.ndarray:
+    """A downsample function over the last axis, NaN where a bucket
+    holds no point."""
+    cnt = (~np.isnan(v)).sum(axis=-1)
+    with np.errstate(invalid="ignore", divide="ignore"):
+        if fn == "avg":
+            return np.nansum(v, axis=-1) / cnt
+        if fn == "sum":
+            return np.where(cnt > 0, np.nansum(v, axis=-1), np.nan)
+        if fn == "max":
+            return np.where(cnt > 0, np.max(np.where(
+                np.isnan(v), -np.inf, v), axis=-1), np.nan)
+        return np.where(cnt > 0, np.min(np.where(
+            np.isnan(v), np.inf, v), axis=-1), np.nan)
+
+
+def window_buckets(start_ms: int, end_ms: int, secs: int):
+    """(first bucket's timestamp in seconds, number of buckets) of a
+    window: aligned down to the interval, the last one holds
+    ``end_ms``."""
+    first = start_ms - start_ms % (secs * 1000)
+    return first // 1000, (end_ms - first) // (secs * 1000) + 1
+
+
+def span_of(d) -> tuple[int, int]:
+    """The window of a request that asks the deployment's whole span."""
+    return d.t0 * 1000, d.end * 1000
+
+
+def span_only(d, window) -> None:
+    """For a judge that answers the deployment's span and no other
+    window."""
+    if window is not None and tuple(window) != span_of(d):
+        raise Unsupported(f"the window {tuple(window)} is not the "
+                          f"deployment's span {span_of(d)}")
+
+
 class Cells:
     """What one answer should be: per group and bucket the value, the
     relative scale and the absolute allowance of its comparison, and
@@ -159,6 +233,8 @@ class Reference:
         self.counter_tie = float(limits["counter_tie"])
         self._grids: dict = {}
         self._bases: dict = {}
+        self._selections: dict = {}     # the last few, by their filters
+        self._edges: dict = {}
 
     # -- per-series grids ----------------------------------------------
 
@@ -176,20 +252,8 @@ class Reference:
         if k == 1:
             grid = self.values
         else:
-            v = self.values.reshape(d.series, d.points // k, k)
-            cnt = (~np.isnan(v)).sum(axis=2)
-            with np.errstate(invalid="ignore", divide="ignore"):
-                if fn == "avg":
-                    grid = np.nansum(v, axis=2) / cnt
-                elif fn == "sum":
-                    grid = np.where(cnt > 0, np.nansum(v, axis=2),
-                                    np.nan)
-                elif fn == "max":
-                    grid = np.where(cnt > 0, np.max(np.where(
-                        np.isnan(v), -np.inf, v), axis=2), np.nan)
-                else:
-                    grid = np.where(cnt > 0, np.min(np.where(
-                        np.isnan(v), np.inf, v), axis=2), np.nan)
+            grid = fold(self.values.reshape(d.series, d.points // k, k),
+                        fn)
         ties = None
         if rate:
             grid, ties = ref_rate(grid, secs, counter_max,
@@ -234,11 +298,16 @@ class Reference:
     # -- one sub-query ---------------------------------------------------
 
     @classmethod
-    def supports(cls, sub: dict, d):
+    def supports(cls, sub: dict, d, window=None):
         """The parsing half of :meth:`answer`: raises
         :class:`Unsupported` for a sub-query this judge does not
-        answer over the deployment ``d``, from the two alone (no
-        values), and returns what it parsed."""
+        answer over the deployment ``d`` in the ``window`` asked
+        (``(start_ms, end_ms)``; None is the span), from those alone
+        (no values), and returns what it parsed."""
+        if window is not None and not \
+                d.t0 * 1000 <= window[0] <= window[1] < (d.end + 1) * 1000:
+            raise Unsupported(f"the window {tuple(window)} leaves the "
+                              f"data's span {span_of(d)}")
         if sub.get("metric") != d.metric:
             raise Unsupported(f"metric {sub.get('metric')!r}")
         agg = sub.get("aggregator")
@@ -275,9 +344,14 @@ class Reference:
         return (agg, secs, fn, rate, counter_max, include, exclude,
                 group_tag)
 
-    def answer(self, sub: dict):
-        """(group-by tag or '', group names, bucket seconds, Cells)."""
+    def answer(self, sub: dict, window=None):
+        """(group-by tag or '', group names, bucket seconds, Cells).
+        ``window`` is ``(start_ms, end_ms)`` of the request; None, or
+        the deployment's span, is the whole of the data. The Cells of
+        another window lie on :func:`window_buckets` of it."""
         d = self.data
+        if window is not None and tuple(window) != span_of(d):
+            return self._answer_window(sub, tuple(window))
         agg, secs, fn, rate, counter_max, include, exclude, group_tag \
             = self.supports(sub, d)
         base_key = json.dumps([agg, secs, fn, rate, counter_max,
@@ -325,25 +399,8 @@ class Reference:
     def _base(self, agg, secs, fn, rate, counter_max, include,
               group_tag):
         d = self.data
-        rows = None
-        for tagk, vals in include:
-            ids = np.array(sorted({d.tag_index(tagk, v) for v in vals}
-                                  - {-1}), dtype=np.int64)
-            if tagk == "host" and rows is None:
-                rows = ids
-            else:
-                pool = np.arange(d.series) if rows is None else rows
-                rows = pool[np.isin(d.tag_ids(tagk, pool), ids)]
-        if rows is None:
-            rows = np.arange(d.series)
+        rows, gids, names = self._selection(include, [], group_tag)
         grid, ties = self.series_grid(secs, fn, rate, counter_max)
-        if group_tag:
-            raw = d.tag_ids(group_tag, rows)
-            present, gids = np.unique(raw, return_inverse=True)
-            names = [d.tag_name(group_tag, int(i)) for i in present]
-        else:
-            gids = np.zeros(len(rows), dtype=np.int64)
-            names = [""]
         g = len(names)
         whole = len(rows) == d.series
         cells = self._reduce(
@@ -353,6 +410,250 @@ class Reference:
         order = np.argsort(gids, kind="stable")
         bounds = np.searchsorted(gids[order], np.arange(g + 1))
         return rows, gids, order, bounds, names, cells
+
+    # -- a window of the request's own ----------------------------------
+
+    def _offsets_ms(self, rows: np.ndarray) -> np.ndarray:
+        offsets = getattr(self.data, "point_offset_s", None)
+        off = offsets(rows) if offsets else None
+        return np.zeros(len(rows), dtype=np.int64) if off is None \
+            else off.astype(np.int64) * 1000
+
+    def _selection(self, include, exclude, group_tag):
+        """(rows, group of each row, group names) of a sub-query's
+        filters; the names are those of the groups before the
+        exclusion, as :meth:`answer` has them."""
+        key = json.dumps([include, exclude, group_tag], sort_keys=True)
+        hit = self._selections.get(key)
+        if hit is not None:
+            return hit
+        d = self.data
+        rows = None
+        for tagk, vals in include:
+            ids = np.array(sorted({d.tag_index(tagk, v) for v in vals}
+                                  - {-1}), dtype=np.int64)
+            if tagk == "host" and rows is None:
+                rows = ids          # a host is its series: no pass over all
+                continue
+            pool = np.arange(d.series) if rows is None else rows
+            rows = pool[np.isin(d.tag_ids(tagk, pool), ids)]
+        if rows is None:
+            rows = np.arange(d.series)
+        if group_tag:
+            present, gids = np.unique(d.tag_ids(group_tag, rows),
+                                      return_inverse=True)
+            names = [d.tag_name(group_tag, int(i)) for i in present]
+        else:
+            gids = np.zeros(len(rows), dtype=np.int64)
+            names = [""]
+        for tagk, vals in exclude:
+            ids = np.array([d.tag_index(tagk, v) for v in vals])
+            keep = ~np.isin(d.tag_ids(tagk, rows), ids[ids >= 0])
+            rows, gids = rows[keep], gids[keep]
+        for old in list(self._selections)[:-3]:
+            del self._selections[old]
+        self._selections[key] = rows, gids, names
+        return rows, gids, names
+
+    def _window_grid(self, rows, secs, fn, rate, counter_max,
+                     start_ms: int, end_ms: int):
+        """([rows, the window's buckets] grid, ties or None) folded
+        anew from the points: those outside ``[start_ms, end_ms]``
+        masked, each bucket folded over what is left, then the rate."""
+        d = self.data
+        k = secs // d.cadence_s
+        first_s, nb = window_buckets(start_ms, end_ms, secs)
+        j0 = (first_s - d.t0) // secs
+        cols = np.arange(j0 * k, (j0 + nb) * k)
+        v = self.values[rows[:, None], cols[None, :]]
+        ts = (d.t0 + d.cadence_s * cols)[None, :] * 1000 \
+            + self._offsets_ms(rows)[:, None]
+        v = np.where((ts >= start_ms) & (ts <= end_ms), v, np.nan)
+        grid = fold(v.reshape(len(rows), nb, k), fn)
+        if not rate:
+            return grid, None
+        return ref_rate(grid, secs, counter_max, self.counter_tie)
+
+    def _answer_window(self, sub: dict, window: tuple[int, int]):
+        d = self.data
+        agg, secs, fn, rate, counter_max, include, exclude, group_tag \
+            = self.supports(sub, d, window)
+        rows, gids, names = self._selection(include, exclude, group_tag)
+        start_ms, end_ms = window
+        first_s, nb = window_buckets(start_ms, end_ms, secs)
+        if agg != "sum" or exclude or nb < 3:
+            grid, ties = self._window_grid(rows, secs, fn, rate,
+                                           counter_max, start_ms, end_ms)
+            return group_tag, names, secs, self._reduce(
+                grid, ties, gids, len(names), agg, secs, rate,
+                counter_max)
+        j0 = (first_s - d.t0) // secs
+        key = json.dumps([secs, fn, rate, counter_max, include,
+                          group_tag, j0, nb], sort_keys=True)
+        edges = self._edges.get(key)
+        if edges is None:
+            edges = self._edges[key] = _WindowEdges(
+                self, rows, gids, len(names), secs, fn, rate,
+                counter_max, j0, nb)
+        return group_tag, names, secs, edges.cells(start_ms, end_ms)
+
+
+class _WindowEdges:
+    """The windows of one sub-query that share their first and last
+    bucket (``j0`` and ``nb`` buckets on, of the span's), summed by
+    group: what is the same for all of them once, and a window's two
+    edges from tables (the module's docstring has the reasoning).
+
+    A series is REGULAR here when it has a value in every interior
+    bucket. Its grid in any such window is: the partial first bucket,
+    the span's interior buckets, the partial last bucket; nothing to
+    interpolate; under a rate, bucket 1 against the partial first,
+    the interior against each other, the last against the last
+    interior. The partial first bucket loses the points before
+    ``start_ms``: with ``o = start_ms -`` the bucket's edge, ``q, r =
+    divmod(o, cadence)``, a series whose offset is at least ``r`` loses
+    ``q`` points and any other ``q + 1``; the last bucket keeps ``qe +
+    1`` points of a series whose offset is at most ``re`` and ``qe`` of
+    any other. So an edge's sums are two slices of two tables, each the
+    per-(offset, group) sums for one count, cumulated over the offsets.
+    The other series (a gap inside: about one in twenty of the gappy
+    tenth) are few and are folded anew."""
+
+    PARTS = 4       # sum, sum of magnitudes, members, near-tie members
+
+    def __init__(self, ref, rows, gids, g, secs, fn, rate, counter_max,
+                 j0, nb):
+        self.ref, self.g, self.secs, self.fn = ref, g, secs, fn
+        self.rate, self.counter_max = rate, counter_max
+        self.j0, self.nb = j0, nb
+        d = ref.data
+        self.k = secs // d.cadence_s
+        self.cad_ms = d.cadence_s * 1000
+        self.first_ms = (d.t0 + j0 * secs) * 1000
+        self.last_ms = self.first_ms + (nb - 1) * secs * 1000
+        span = ref.series_grid(secs, fn, False, None)[0]
+        inner = span[rows, j0 + 1:j0 + nb - 1]
+        regular = ~np.isnan(inner).any(axis=1)
+        self.rows, self.gids = rows[regular], gids[regular]
+        self.other_rows, self.other_gids = rows[~regular], gids[~regular]
+        inner = inner[regular]
+        self.before_last = inner[:, -1]
+        self.after_first = inner[:, 0]
+        self.offsets_ms, self.rank = np.unique(
+            ref._offsets_ms(self.rows), return_inverse=True)
+        # the interior: columns 1 .. nb - 2 of the window, and under a
+        # rate from column 2 on
+        self.mid = np.zeros((self.PARTS, g, nb))
+        if rate:
+            r, ties = ref_rate(inner, secs, counter_max, ref.counter_tie)
+            for j in range(1, inner.shape[1]):
+                self.mid[:, :, j + 1] = self._parts(
+                    r[:, j], ties[:, j], self.gids, g)
+        else:
+            for j in range(inner.shape[1]):
+                self.mid[:, :, j + 1] = self._parts(
+                    inner[:, j], None, self.gids, g)
+        self._first: dict = {}
+        self._last: dict = {}
+
+    def _parts(self, col, ties, keys, n):
+        """[PARTS, n] sums of one column by ``keys``."""
+        ok = ~np.isnan(col)
+        k, v = keys[ok], col[ok]
+        out = np.zeros((self.PARTS, n))
+        out[0] = np.bincount(k, weights=v, minlength=n)
+        out[1] = np.bincount(k, weights=np.abs(v), minlength=n)
+        out[2] = np.bincount(k, minlength=n)
+        if ties is not None and self.counter_max is not None:
+            out[3] = np.bincount(keys, weights=ties, minlength=n)
+        return out
+
+    def _table(self, edge):
+        """[offsets + 1, PARTS, groups]: the edge bucket's column (under
+        a rate its pair of columns) summed by (offset, group) and
+        cumulated over the offsets."""
+        if self.rate:           # a pair: the bucket before, this one
+            r, ties = ref_rate(np.stack(edge, axis=1), self.secs,
+                               self.counter_max, self.ref.counter_tie)
+            col, ties = r[:, 1], ties[:, 1]
+        else:
+            col, ties = edge, None
+        u = len(self.offsets_ms)
+        flat = self._parts(col, ties, self.rank * self.g + self.gids,
+                           u * self.g)
+        table = flat.reshape(self.PARTS, u, self.g).transpose(1, 0, 2)
+        return np.concatenate([np.zeros((1, self.PARTS, self.g)),
+                               np.cumsum(table, axis=0)])
+
+    def _nothing(self):
+        return np.zeros((len(self.offsets_ms) + 1, self.PARTS, self.g))
+
+    def _first_table(self, cut: int):
+        """The first bucket with its first ``cut`` points lost."""
+        hit = self._first.get(cut)
+        if hit is None:
+            if cut >= self.k:
+                hit = self._nothing()
+            else:
+                lo = self.j0 * self.k
+                part = fold(self.ref.values[
+                    self.rows, lo + cut:lo + self.k], self.fn)
+                hit = self._table([part, self.after_first]
+                                  if self.rate else part)
+            self._first[cut] = hit
+        return hit
+
+    def _last_table(self, kept: int):
+        """The last bucket with its first ``kept`` points alone."""
+        hit = self._last.get(kept)
+        if hit is None:
+            if kept <= 0:
+                hit = self._nothing()
+            else:
+                lo = (self.j0 + self.nb - 1) * self.k
+                part = fold(self.ref.values[self.rows, lo:lo + kept],
+                            self.fn)
+                hit = self._table([self.before_last, part]
+                                  if self.rate else part)
+            self._last[kept] = hit
+        return hit
+
+    def cells(self, start_ms: int, end_ms: int) -> Cells:
+        ref, nb = self.ref, self.nb
+        parts = self.mid.copy()
+        # the first bucket: offsets under r lose q + 1 points, the
+        # others q
+        q, r = divmod(start_ms - self.first_ms, self.cad_ms)
+        c = int(np.searchsorted(self.offsets_ms, r, "left"))
+        few, more = self._first_table(q), self._first_table(q + 1)
+        parts[:, :, 1 if self.rate else 0] = few[-1] - few[c] + more[c]
+        # the last bucket: offsets up to r keep q + 1 points, the
+        # others q
+        q, r = divmod(end_ms - self.last_ms, self.cad_ms)
+        c = int(np.searchsorted(self.offsets_ms, r, "right"))
+        few, more = self._last_table(q), self._last_table(q + 1)
+        parts[:, :, nb - 1] = few[-1] - few[c] + more[c]
+        out = Cells(self.g, nb)
+        term_atol = 2 * ref.value_atol / self.secs if self.rate \
+            else ref.value_atol
+        out.want[:] = parts[0]
+        out.scale[:] = parts[1]
+        out.atol[:] = term_atol * parts[2]
+        if self.counter_max is not None:
+            out.atol += parts[3] * (self.counter_max / self.secs)
+        out.emitted[:] = parts[2] > 0
+        if len(self.other_rows):
+            grid, ties = ref._window_grid(
+                self.other_rows, self.secs, self.fn, self.rate,
+                self.counter_max, start_ms, end_ms)
+            rest = ref._reduce(grid, ties, self.other_gids, self.g,
+                               "sum", self.secs, self.rate,
+                               self.counter_max)
+            out.want += rest.want
+            out.scale += rest.scale
+            out.atol += rest.atol
+            out.emitted |= rest.emitted
+        return out
 
 
 # ---------------------------------------------------------------------

@@ -113,11 +113,12 @@ class Reference:
     # -- parsing ---------------------------------------------------------
 
     @classmethod
-    def supports(cls, sub: dict, d):
+    def supports(cls, sub: dict, d, window=None):
         """Raises :class:`Unsupported` for a sub-query this judge does
         not answer over the deployment ``d``; returns what it parsed:
         (bucket seconds, percentiles, include, exclude, group-by tag).
         """
+        reference.span_only(d, window)      # no window but the span
         if sub.get("metric") != d.metric:
             raise Unsupported(f"metric {sub.get('metric')!r}")
         if sub.get("aggregator") != "sum":

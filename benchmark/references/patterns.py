@@ -159,10 +159,11 @@ class Reference(reference.Reference):
         self._names: dict = {}
 
     @classmethod
-    def supports(cls, sub: dict, d):
+    def supports(cls, sub: dict, d, window=None):
         """``reference.py``'s, with each of the five name filters
         among ``include`` as ``(tagk, {"type", "filter"})`` beside its
         ``(tagk, [names])``."""
+        reference.span_only(d, window)      # no window but the span
         plain, patterns = [], []
         for f in sub.get("filters") or []:
             p = _pattern_of(f, d)

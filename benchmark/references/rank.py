@@ -64,6 +64,11 @@ def compare(got: np.ndarray, stray: int, cells) -> reference.Verdict:
 class Reference(reference.Reference):
     aggregators = reference.Reference.aggregators + tuple(PERCENTILES)
 
+    @classmethod
+    def supports(cls, sub: dict, d, window=None):
+        reference.span_only(d, window)      # no window but the span
+        return super().supports(sub, d)
+
     def _reduce(self, grid, ties, gids, g, agg, secs, rate, counter_max):
         p = PERCENTILES.get(agg)
         if p is None:

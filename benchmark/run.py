@@ -150,12 +150,28 @@ def metrics_of(bench: dict, kind: str, cell: dict) -> list[dict]:
 # the comparison that decides ``correct``
 # ---------------------------------------------------------------------
 
+def answered(ref, data, doc: dict, sub: dict):
+    """What the judge ``ref`` says of one sub-query of the request
+    ``doc``, for the window THAT request asked (``deploy.window_of``:
+    its own ``start`` and ``end``, or the deployment's span), and where
+    its rows lie: (group-by tag, names, bucket seconds, Cells, first
+    bucket's timestamp in seconds, number of buckets)."""
+    window = deploy.window_of(doc, data)
+    if window is None:
+        tagk, names, secs, cells = ref.answer(sub)
+        return (tagk, names, secs, cells, data.t0,
+                data.points * data.cadence_s // secs)
+    tagk, names, secs, cells = ref.answer(sub, window=window)
+    return (tagk, names, secs, cells,
+            *reference.window_buckets(*window, secs))
+
+
 def check_answers(ref, data, results, limits: dict,
                   judge=reference) -> dict:
     """Every answer of the window against the reference ``ref``, a
-    ``Reference`` of ``judge`` (``deploy.judge_of``). Returns the
-    numbers compared, each with its limit, and the count that failed."""
-    n_buckets_of = {}
+    ``Reference`` of ``judge`` (``deploy.judge_of``), each for the
+    window its request asked. Returns the numbers compared, each with
+    its limit, and the count that failed."""
     worst = {"http_failures": 0, "shape_errors": 0,
              "sum_rel_err": 0.0, "rank_abs_err": 0.0}
     failed, notes = 0, []
@@ -175,13 +191,12 @@ def check_answers(ref, data, results, limits: dict,
             else:
                 at = 0
                 for sub in res.request.doc["queries"]:
-                    tagk, names, secs, cells = ref.answer(sub)
-                    nb = n_buckets_of.setdefault(
-                        secs, data.points * data.cadence_s // secs)
+                    tagk, names, secs, cells, first_s, nb = answered(
+                        ref, data, res.request.doc, sub)
                     mine = rows[at:at + len(names)]
                     at += len(names)
                     got, stray = judge.rows_to_grid(
-                        mine, tagk, names, data.t0, nb, secs,
+                        mine, tagk, names, first_s, nb, secs,
                         data.metric)
                     v = judge.compare(got, stray, cells)
                     worst["shape_errors"] += v.shape_errors
@@ -327,10 +342,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     ref = judge.Reference(data, values, config["limits"])
     verdict = check_answers(ref, data, ctx.results, config["limits"],
                             judge)
-    first = ctx.results[0].request.doc["queries"][0]
-    _tagk, names, secs, _cells = ref.answer(first)
-    ctx.first_shape = (ref.selected(first),
-                       data.points * data.cadence_s // secs, len(names))
+    first = ctx.results[0].request.doc
+    _tagk, names, _secs, _cells, _first_s, nb = answered(
+        ref, data, first, first["queries"][0])
+    ctx.first_shape = (ref.selected(first["queries"][0]), nb,
+                       len(names))
     attempted = len(ctx.results)
     if traffic.writes:
         w = check_writes(ctx, config["limits"])

@@ -30,6 +30,12 @@ def bench():
     return load("BENCHMARK.json")
 
 
+def listed_with(bench: dict, cell: str) -> set:
+    """The per-layer metrics whose list of cells holds ``cell``."""
+    return {m["name"] for m in bench["per_layer"]
+            if cell in m.get("workloads", ())}
+
+
 def tiny_config(name: str) -> dict:
     cfg = load(f"benchmark/configs/{name}.json")
     cfg["data"].update(TINY)

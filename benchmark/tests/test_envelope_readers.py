@@ -49,7 +49,13 @@ def ctx_of(before, after):
 def test_reader_gives_a_number_and_none_without_its_source(
         bench, traced, name):
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == ["fleet-1m.wide-groupby", CELL]
+    # PR 36's two cells, then the cells gate (b) appended (PR 47): rank
+    # wherever the wide cell is, the wildcard cell wherever the panels
+    # are, the histograms and the moving window with the wide cell
+    assert entry["workloads"] == [
+        "fleet-1m.wide-groupby", CELL, "fleet-1m.rank-p95",
+        "fleet-1m.wildcard-lookup", "hist-200k.percentiles",
+        "fleet-1m.refresh"]
     assert entry["moves"] == "query_p50_ms" and entry["better"] == "lower"
     got = traced["metrics"][name]
     assert got["unit"] == entry["unit"]

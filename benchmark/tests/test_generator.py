@@ -109,8 +109,11 @@ def _mix(name="wide-groupby", **top):
 
 def test_the_closed_list_is_a_key_of_the_traffic_file():
     data = gen.Data(tiny_config("fleet-1m")["data"])
-    # the default stays 2,000, warm-up included
-    t = traffic.Traffic(_mix(), data, 1, 51)
+    # the default stays 2,000, warm-up included; the wide cell ships
+    # 4,000 since PR 47
+    assert _mix()["closed_list"] == 4000
+    plain = {k: v for k, v in _mix().items() if k != "closed_list"}
+    t = traffic.Traffic(plain, data, 1, 51)
     assert len(t.warmup) == 3 and len(t.timed) == 2000 - 3
     t = traffic.Traffic(_mix(closed_list=300), data, 1, 51)
     assert len(t.warmup) == 3 and len(t.timed) == 300 - 3
@@ -128,13 +131,18 @@ def test_the_closed_list_is_a_key_of_the_traffic_file():
 
 def test_a_draw_repeats_only_where_its_file_says_so():
     data = gen.Data(tiny_config("fleet-1m")["data"])
-    # 2,000 racks and a list of 5,000: refused, a repeated request
-    # would be answered from the result cache
+    # 2,000 racks, one a request, and a list of 5,000: refused, a
+    # repeated request would be answered from the result cache (the
+    # file draws two a request since PR 47: 1,999,000 pairs)
+    spec = _mix(closed_list=5000)
+    assert spec["requests"][0]["draw"]["rack"]["pick"] == 2
+    traffic.Traffic(spec, data, 1, 51)
+    spec["requests"][0]["draw"]["rack"]["pick"] = 1
     with pytest.raises(ValueError, match="a repeated request"):
-        traffic.Traffic(_mix(closed_list=5000), data, 1, 51)
+        traffic.Traffic(spec, data, 1, 51)
     spec = _mix(closed_list=5000)
     spec["requests"][0]["draw"]["rack"].update(range=[0, 20],
-                                               repeat=True)
+                                               repeat=True, pick=1)
     seen = set()
     for seed in (1, 2):
         t = traffic.Traffic(spec, data, seed, 51)
@@ -148,6 +156,6 @@ def test_a_draw_repeats_only_where_its_file_says_so():
     # said of a range that is wide enough, it still draws with
     # replacement: some rack comes twice in 1,500 of 2,000
     spec = _mix(closed_list=1500)
-    spec["requests"][0]["draw"]["rack"]["repeat"] = True
+    spec["requests"][0]["draw"]["rack"].update(repeat=True, pick=1)
     t = traffic.Traffic(spec, data, 1, 51)
     assert len(set(r.body for r in t.timed)) < len(t.timed)

@@ -11,7 +11,7 @@ import types
 
 import numpy as np
 import pytest
-from conftest import load
+from conftest import listed_with, load
 
 import deploy
 import run
@@ -28,6 +28,19 @@ TRACE_ONLY = {"hist.merge_ms_per_query", "hist_merge_roofline"}
 EVERYWHERE = {"loadgen.late_ms", "loadgen.queries_per_s",
               "device.idle_share", "window.compiles",
               "startup.listen_s", "startup.compile_s"}
+# the wide cell's stage metrics that a histogram request cannot report
+NOT_A_HISTOGRAMS = {"scan.ms", "grid_build.ms", "grid_tail_roofline",
+                    "startup.import_resolve_s"}
+GENERIC = {
+    "plan.ms", "execute.ms", "execute.self_ms", "upload.ms",
+    "program.wait_ms", "download.ms", "assemble.ms", "serialize.ms",
+    "tail.query_p90_ms", "devicecache.hit_share", "device.resident_mb",
+    "program.busy_ms_per_query", "placement.on_device_share",
+    "device.unoccupied_share", "idle.unnamed_share",
+    "idle.no_request_share", "program.compiles", "gc.pause_share",
+    "startup.backend_s", "receive.ms", "admission.ms", "http.self_ms",
+    "respond.ms", "worker.cpu_ms_per_query", "gc.gen0_pause_ms",
+    "trace.finish_ms_per_query", "path.fallbacks"}
 # every tag rule and the gappy tenth; a rack of two hosts, 80 hosts a
 # datacentre
 SMALL = {"series": 8000, "chunk_series": 2000}
@@ -130,7 +143,13 @@ def test_new_metrics_list_the_cell_alone(bench):
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     reported = {m["name"] for m in run.metrics_of(bench, "per_layer",
                                                   cell)}
-    assert reported == NEW | EVERYWHERE
+    # gate (b), PR 47: the generic stage metrics the wide cell reads,
+    # but for the four a histogram request has nothing to show in (it
+    # scans no store, builds no grid, runs no grid tail, and its loader
+    # resolves no import text)
+    assert reported == NEW | EVERYWHERE | GENERIC
+    assert listed_with(bench, "fleet-1m.wide-groupby") - GENERIC \
+        == NOT_A_HISTOGRAMS
     assert {m["name"] for m in run.metrics_of(bench, "end_to_end",
                                               cell)} \
         == {"query_p50_ms", "setup_s"}
@@ -160,7 +179,9 @@ def test_cell_runs_to_its_last_line_and_then_wants_a_tpu(
     assert doc["attempted"] >= 5
     got = {k: m["value"] for k, m in doc["metrics"].items()}
     if trace:
-        assert set(got) == EVERYWHERE | NEW - TRACE_ONLY
+        assert set(got) == (EVERYWHERE | NEW | GENERIC) - TRACE_ONLY
+        assert got["path.fallbacks"] == 0
+        assert got["devicecache.hit_share"] == 1.0
         assert got["hist.on_device_share"] == 100.0
         # a label a resident row (8,192 of them) and a few vectors
         assert 0.03 < got["hist.upload_mb_per_query"] < 0.04

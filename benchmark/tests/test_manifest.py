@@ -129,14 +129,19 @@ def test_files_under_paths_have_plain_names():
             assert ok.match(rel) and len(rel) <= 200, rel
 
 
-@pytest.mark.parametrize("traffic", ["wide-groupby", "groupby-quiet",
-                                     "small-panels", "groupby-ingest"])
-def test_traffic_files(traffic):
+LISTS = {"wide-groupby": 4000, "groupby-quiet": 8000,
+         "small-panels": 16000, "groupby-ingest": 8000, "rank-p95": 4000,
+         "wildcard-lookup": 16000, "percentiles": 4000, "refresh": 2400}
+
+
+@pytest.mark.parametrize("traffic", sorted(LISTS))
+def test_traffic_files(traffic, bench):
     spec = load(f"benchmark/traffic/{traffic}.json")
-    # a closed list that a window of 51 s cannot use up above 3.2 ms a
-    # request where the cell aims under 25.5 ms (the panels, S1)
-    assert spec.get("closed_list", 2000) \
-        == (16000 if traffic == "small-panels" else 2000)
+    assert set(LISTS) == {w["traffic"] for w in bench["workloads"]}
+    # a closed list that a window of 51 s cannot use up at three times
+    # the rate of PR 47's day (test_refresh_cell.py states each cell's
+    # floor; the panels and the wildcard cell: 3.2 ms a request)
+    assert spec["closed_list"] == LISTS[traffic]
     assert spec["loop"] in ("closed", "open")
     assert spec["warmup_per_template"] >= 3
     assert spec["timeout_s"] == 30

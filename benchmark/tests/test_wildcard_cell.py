@@ -10,7 +10,7 @@ import types
 
 import numpy as np
 import pytest
-from conftest import load
+from conftest import listed_with, load
 
 import control
 import deploy
@@ -63,7 +63,8 @@ def test_the_store_is_fleet_1ms_key_for_key(bench):
             for w in bench["workloads"] if w["config"] == CONFIG] \
         == [(CELL, "wildcard-lookup", 1)]
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert "device bypassed" in cell["why"] and "PR 40" in cell["why"]
+    assert "device bypassed" in cell["why"] and "PR 41" in cell["why"]
+    assert "name table" in cell["why"]
 
 
 def test_the_traffic_is_one_pattern_a_request_and_the_panels_probe():
@@ -99,19 +100,21 @@ def test_new_metrics_list_the_cell_alone(bench):
     mine = {m["name"]: m for m in bench["per_layer"]
             if m.get("workloads") == [CELL]}
     assert set(mine) == NEW
+    panels = listed_with(bench, "fleet-1m.small-panels")
     assert {m["moves"] for m in mine.values()} == {"query_p50_ms"}
     assert {m["layer"] for m in mine.values()} == {"plan + placement"}
     assert mine["filter.resolve_ms"]["source"] == "program_span"
     assert mine["filter.names_read_per_query"]["source"] \
         == "program_counter"
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    # gate (b), PR 47: beside its own two the cell is listed wherever
+    # the panels are (the generic stages, PR 36's eight), nowhere else
+    assert len(panels) > 20 and "plan.ms" in panels
+    assert listed_with(bench, CELL) == NEW | panels
     assert {m["name"] for m in run.metrics_of(bench, "per_layer", cell)} \
-        == NEW | EVERYWHERE
+        == NEW | EVERYWHERE | panels
     assert {m["name"] for m in run.metrics_of(bench, "end_to_end", cell)} \
         == {"query_p50_ms", "setup_s"}
-    # no list the benchmark had was touched
-    assert not any(CELL in m["workloads"] for m in bench["per_layer"]
-                   if "workloads" in m and m["name"] not in NEW)
 
 
 # -- the judge ------------------------------------------------------------
@@ -197,9 +200,13 @@ def test_cell_runs_to_its_last_line_and_then_wants_a_tpu(
     assert doc["attempted"] >= 5
     got = {k: m["value"] for k, m in doc["metrics"].items()}
     if trace:
-        # what only a device trace gives is left out on the CPU
-        assert set(got) == EVERYWHERE | NEW
-        assert got["filter.names_read_per_query"] == SMALL["series"]
+        # every reader finds something to read, on the CPU too
+        assert set(got) == EVERYWHERE | NEW | listed_with(
+            bench, "fleet-1m.small-panels")
+        # the name table is built by the warm-up: a timed request
+        # reads no name (PR 41; PR 40's walk read every host's)
+        assert got["filter.names_read_per_query"] == 0
+        assert got["path.fallbacks"] == 0
         assert 0 < got["filter.resolve_ms"] < got["loadgen.late_ms"] \
             + 1000 / got["loadgen.queries_per_s"]
         assert got["window.compiles"] == 0

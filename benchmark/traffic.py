@@ -14,12 +14,33 @@ the deployment's span and metric, and ``$<name>`` for a draw. A draw
 names a ``tag``, a ``range`` of that tag's values (``[lo, hi)`` or
 ``"all"``) and how many to ``pick``: one value at a time from a
 seeded permutation of the range, or several distinct ones joined with
-``|``. A draw of one value that says ``"repeat": true`` is drawn with
+``|``, no set of them drawn twice in a list. A draw of one value that
+says ``"repeat": true`` is drawn with
 replacement instead: a few panels asked again and again, which the
 result cache may answer; one that does not say so is refused where the
 list needs more values than the range has. ``trace_probe`` is one more
 template, of which a traced run sends three drawn requests: two to
 warm it and one at the end of the traced stretch (see ``run.py``).
+
+A template may carry a ``window``: ``length_s``, ``step_ms``,
+``jitter_ms`` and ``"order": "ascending"``. Its requests then ask a
+window of their own instead of the deployment's span, the window of a
+dashboard whose "now" moves: request ``i`` of the template, the
+warm-up's first, gets
+
+    end_ms   = first_end_ms + i * step_ms + jitter_i
+    start_ms = end_ms - 1000 * length_s
+
+where ``first_end_ms`` is ``1000 * (data.t0 + length_s)`` and
+``jitter_i`` a seeded draw in ``[1, jitter_ms)`` (0 where ``jitter_ms``
+is under 2), and these fill ``$start_ms`` and ``$end_ms``. A "now" does
+not jump back: the timed list is shuffled as ever, and the requests of
+a template with a window then take the places the shuffle gave their
+template in ascending order, so a file may mix such a template with
+others and each keeps its relative order. A list whose last window
+ends after the data does (``data.end``) is refused. A template without
+a window draws, fills and shuffles as it always did and takes the same
+numbers from the seed in the same order.
 
 A closed loop sends as many requests as the server answers, so its
 list has to outlast any window: ``closed_list`` is its length, warm-up
@@ -37,6 +58,7 @@ cadence from the end of its history. The seed sets the values.
 from __future__ import annotations
 
 import json
+import math
 
 import numpy as np
 
@@ -81,10 +103,21 @@ def _draws(spec: dict, data, n: int, rng) -> list[str]:
                 f"answered from the result cache")
         ids = lo + rng.permutation(hi - lo)[:n, None]
     else:
+        if math.comb(hi - lo, pick) < n:
+            raise ValueError(
+                f"traffic needs {n} distinct sets of {pick} {tag} "
+                f"values and the file's range has "
+                f"{math.comb(hi - lo, pick)}: a repeated request would "
+                f"be answered from the result cache")
         ids = rng.integers(lo, hi, size=(n, pick))
         while True:
+            # distinct within a row, and no row's set drawn before
             srt = np.sort(ids, axis=1)
             dup = (srt[:, 1:] == srt[:, :-1]).any(axis=1)
+            _sets, first = np.unique(srt, axis=0, return_index=True)
+            again = np.ones(n, dtype=bool)
+            again[first] = False
+            dup |= again
             if not dup.any():
                 break
             ids[dup] = rng.integers(lo, hi, size=(int(dup.sum()), pick))
@@ -92,11 +125,36 @@ def _draws(spec: dict, data, n: int, rng) -> list[str]:
             for row in ids]
 
 
+def _windows(w: dict, data, n: int, rng) -> dict:
+    """``start_ms`` and ``end_ms`` of the ``n`` requests of a template
+    with a window, in the template's order."""
+    if w.get("order") != "ascending":
+        raise ValueError(f"window order {w.get('order')!r}: a \"now\" "
+                         f"moves forward (\"ascending\")")
+    length_ms, step_ms = 1000 * int(w["length_s"]), int(w["step_ms"])
+    jitter_ms = int(w.get("jitter_ms", 0))
+    if length_ms <= 0 or step_ms < max(jitter_ms, 1):
+        raise ValueError("a window has a length, and steps no shorter "
+                         "than its jitter: it never moves back")
+    jitter = rng.integers(1, jitter_ms, size=n) if jitter_ms > 1 \
+        else np.zeros(n, dtype=np.int64)
+    end = 1000 * data.t0 + length_ms + step_ms * np.arange(n) + jitter
+    if n and int(end[-1]) // 1000 > data.end:
+        raise ValueError(
+            f"the list's last window ends at {int(end[-1])} ms and the "
+            f"data at {data.end} s: {n} requests of {step_ms} ms after "
+            f"{w['length_s']} s do not fit {data.points} points")
+    return {"start_ms": (end - length_ms).tolist(),
+            "end_ms": end.tolist()}
+
+
 def _template_requests(tpl: dict, data, n: int, rng) -> list[Request]:
     env_base = {"start_ms": data.t0 * 1000, "end_ms": data.end * 1000,
                 "metric": data.metric}
     drawn = {name: _draws(spec, data, n, rng)
              for name, spec in sorted((tpl.get("draw") or {}).items())}
+    if tpl.get("window"):
+        drawn.update(_windows(tpl["window"], data, n, rng))
     out = []
     for i in range(n):
         env = dict(env_base, **{k: v[i] for k, v in drawn.items()})
@@ -137,6 +195,15 @@ class Traffic:
             timed += reqs[n_warm:]
         order = rng.permutation(len(timed))
         self.timed = [timed[i] for i in order]
+        for tpl in templates:
+            if tpl.get("window"):
+                # a "now" does not jump back: the places the shuffle
+                # gave the template, taken in the template's order
+                places = [p for p, r in enumerate(self.timed)
+                          if r.template == tpl["name"]]
+                mine = [r for r in timed if r.template == tpl["name"]]
+                for p, r in zip(places, mine):
+                    self.timed[p] = r
         if self.loop == "open":
             # a Poisson process conditioned on its count: the same
             # number of arrivals for every seed
