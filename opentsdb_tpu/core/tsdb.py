@@ -851,40 +851,110 @@ class TSDB:
                             is_groupby: bool, interval: str | None,
                             rollup_agg: str | None,
                             groupby_agg: str | None = None) -> None:
-        """Write a rollup / pre-aggregated point (ref: TSDB.java:1320-1418).
-
-        Pre-aggregates (``is_groupby``) are tagged with the agg-tag
-        (``tsd.rollups.agg_tag_key``) exactly like the reference.
+        """Write a rollup / pre-aggregated point (ref: TSDB.java:1320-1418):
+        :meth:`add_aggregate_batch` of a run of one, its error raised.
         """
-        if self.rollup_store is None:
-            raise RuntimeError("rollups are not enabled "
-                               "(tsd.rollups.enable=false)")
-        tags = dict(tags)
-        if is_groupby:
-            agg = (groupby_agg or rollup_agg or "").upper()
-            if not agg:
-                raise ValueError("missing group-by aggregator")
-            tags[self.agg_tag_key] = agg
-        tags_mod.check_metric_and_tags(metric, tags)
-        metric_id, tag_ids = self._resolve_write_uids(metric, tags)
-        ts_ms = codec.to_ms(timestamp)
-        if interval is None:
-            # pure pre-agg point: store in the pre-agg ("groupby") table
-            kind = "preagg"
-            store_obj = self.rollup_store.preagg_store()
-        else:
-            if rollup_agg is None:
-                raise ValueError("missing rollup aggregator")
-            kind = f"tier:{interval}:{rollup_agg.lower()}"
-            store_obj = self.rollup_store.tier(interval,
-                                               rollup_agg.lower())
-        sid = store_obj.get_or_create_series(metric_id, tag_ids)
-        store_obj.append(sid, ts_ms, float(value))
-        if self.wal is not None:
-            self.wal.ensure_series(kind, sid, metric, tags)
-            self.wal.log_point(kind, sid, ts_ms, float(value), False)
-            self.wal.sync()
-        self.datapoints_added += 1
+        def raise_it(_ref, e: Exception) -> None:
+            raise e
+
+        self.add_aggregate_batch(
+            [(interval, rollup_agg, metric, tags, (timestamp,), (value,),
+              groupby_agg, is_groupby)], on_error=raise_it)
+
+    def add_aggregate_batch(self, runs, on_error=None
+                            ) -> tuple[int, list[str]]:
+        """Columnar write of rollup cells: the one entry of
+        ``/api/rollup``, telnet ``rollup``, :meth:`add_aggregate_point`
+        and a bulk loader. ``runs`` yields
+        :class:`~opentsdb_tpu.rollup.store.AggregateRun` (or its first
+        six fields as a tuple): one series' cells of one (tier,
+        aggregator). A run is validated and resolved once (the
+        agg-tag of a pre-aggregate, ``tsd.rollups.agg_tag_key``, as
+        the reference's), lands by one ``append_many`` and one framed
+        WAL record, and the whole call syncs the WAL once: an answer
+        given after it returns is given after the fsync of every cell
+        it landed. A run that fails fails each of its cells with the
+        same error (``on_error(ref, exc)``); a run whose values are no
+        numbers is landed a cell at a time so that the others land.
+        Returns (cells written, error strings)."""
+        from opentsdb_tpu.obs import trace as trace_mod
+        if trace_mod.current() is not None:
+            return self._add_aggregate_batch(runs, on_error)
+        ctx = self.tracer.start_background("ingest.rollup", sample=True)
+        try:
+            with trace_mod.use(ctx):
+                written, errors = self._add_aggregate_batch(runs,
+                                                            on_error)
+                if ctx is not None:
+                    ctx.tag(points=written)
+                return written, errors
+        finally:
+            self.tracer.finish(ctx)
+
+    def _add_aggregate_batch(self, runs, on_error
+                             ) -> tuple[int, list[str]]:
+        from opentsdb_tpu.rollup.store import AggregateRun
+        errors: list[str] = []
+        written = 0
+
+        def land(run: AggregateRun, ts, values) -> int:
+            if self.rollup_store is None:
+                raise RuntimeError("rollups are not enabled "
+                                   "(tsd.rollups.enable=false)")
+            tags = dict(run.tags)
+            if run.is_groupby:
+                agg = (run.groupby_agg or run.aggregator or "").upper()
+                if not agg:
+                    raise ValueError("missing group-by aggregator")
+                tags[self.agg_tag_key] = agg
+            tags_mod.check_metric_and_tags(run.metric, tags)
+            ts = np.asarray(ts, dtype=np.int64)
+            vals = np.asarray(values, dtype=np.float64)
+            if ts.shape != vals.shape or ts.ndim != 1:
+                raise ValueError(
+                    "timestamps/values must be equal-length 1-D")
+            metric_id, tag_ids = self._resolve_write_uids(run.metric,
+                                                          tags)
+            # codec.to_ms, a column at a time
+            ts_ms = np.where(ts >= (1 << 32), ts, ts * 1000)
+            kind, sid = self.rollup_store.append_run(
+                run.interval, run.aggregator, metric_id, tag_ids,
+                ts_ms, vals)
+            if self.wal is not None:
+                self.wal.ensure_series(kind, sid, run.metric, tags)
+                self.wal.log_points(kind, sid, ts_ms, vals,
+                                    np.zeros(len(ts_ms), dtype=bool))
+            self.datapoints_added += len(ts_ms)
+            return len(ts_ms)
+
+        with self._wal_scope():
+            for run in runs:
+                run = AggregateRun(*run)
+                n = len(run.timestamps)
+                refs = run.refs if run.refs is not None else range(n)
+                try:
+                    written += land(run, run.timestamps, run.values)
+                    continue
+                except Exception as e:  # noqa: BLE001
+                    whole = e
+                for j in range(n):
+                    # a cell at a time, so that what can land does and
+                    # each error is its own cell's (a cell appended
+                    # twice is one cell: the store keeps the last); a
+                    # run of one has its error already
+                    try:
+                        if n == 1:
+                            raise whole
+                        written += land(run, run.timestamps[j:j + 1],
+                                        run.values[j:j + 1])
+                    except Exception as e:  # noqa: BLE001
+                        errors.append(
+                            f"{run.metric} @{run.timestamps[j]}: {e}")
+                        if on_error is not None:
+                            on_error(refs[j], e)
+            if written and self.wal is not None:
+                self.wal.sync()
+        return written, errors
 
     def add_histogram_batch(self, points, on_error=None
                             ) -> tuple[int, list[str]]:
@@ -1436,6 +1506,9 @@ class TSDB:
         collector.record("datapoints.added", self.datapoints_added)
         self.histogram_stats.collect_stats(collector,
                                            self._device_grid_cache)
+        if self.rollup_store is not None:
+            self.rollup_store.stats.collect_stats(
+                collector, self._device_grid_cache)
         dev = self.device_info()
         dev_tags = {
             "platform": dev["platform"] or "none",

@@ -103,6 +103,7 @@ KNOWN_SPANS: frozenset[str] = frozenset({
     "telemetry.pump",        # obs/telemetry.py self-stats ingest
     "control.loop",          # control/plane.py one control tick
     "ingest.import",         # core/tsdb.py import_buffer outside a request
+    "ingest.rollup",         # core/tsdb.py add_aggregate_batch, likewise
     # ingest stages
     "ingest.decode",         # body parse + validate + series grouping
     "ingest.resolve",        # import_buffer: UIDs + series per distinct key
@@ -788,6 +789,10 @@ class Tracer:
         # "hit" (planned from the cached index), "built" (built it
         # first), "bypass" (a selection that is not a whole metric)
         self.plans = {"hit": 0, "built": 0, "bypass": 0}
+        # plan stages, by where tier selection sent them: "raw", a
+        # rollup "tier", or raw as the "fallback" of a tier that
+        # holds nothing of the metric (the span's tag ``source``)
+        self.rollups = {"raw": 0, "tier": 0, "fallback": 0}
         # filters evaluated, by how each became a series mask: "ids"
         # (the UIDs of the exact names it holds), "table" (its
         # predicate over the plan index's table of the key's names,
@@ -1071,7 +1076,8 @@ class Tracer:
         looked one up (tag ``grid``) in ``grids``, every
         ``query.plan`` that reached its filters (tag ``index``) in
         ``plans`` and its filters (tags ``resolve_<way>``) in
-        ``filters``, every ``query.filter_resolve``'s ``names_read``
+        ``filters``, every ``query.plan`` by its tag ``source`` in
+        ``rollups``, every ``query.filter_resolve``'s ``names_read``
         in ``filter_names_read`` and its tag ``table`` in
         ``filter_tables``, every ``query.assemble`` by its tag
         ``tags`` in ``assembles``. Returns the histogram observations
@@ -1086,6 +1092,7 @@ class Tracer:
         builds = []
         grids = []
         plans = []
+        rollups = []
         filters = []
         names_read = 0
         tables = []
@@ -1121,12 +1128,14 @@ class Tracer:
                                   else "host")
                 elif s.tags.get("grid") in self.grids:
                     grids.append(s.tags["grid"])
-            elif s.name == "query.plan" and s.tags.get("index") \
-                    in self.plans:
-                plans.append(s.tags["index"])
-                filters += [(way, s.tags["resolve_" + way])
-                            for way in self.filters
-                            if "resolve_" + way in s.tags]
+            elif s.name == "query.plan":
+                if s.tags.get("source") in self.rollups:
+                    rollups.append(s.tags["source"])
+                if s.tags.get("index") in self.plans:
+                    plans.append(s.tags["index"])
+                    filters += [(way, s.tags["resolve_" + way])
+                                for way in self.filters
+                                if "resolve_" + way in s.tags]
             elif s.name == "query.filter_resolve":
                 names_read += s.tags.get("names_read", 0)
                 if s.tags.get("table") in self.filter_tables:
@@ -1148,6 +1157,8 @@ class Tracer:
                 self.grids[source] += 1
             for state in plans:
                 self.plans[state] += 1
+            for source in rollups:
+                self.rollups[source] += 1
             for way, n in filters:
                 self.filters[way] += n
             self.filter_names_read += names_read
@@ -1247,6 +1258,7 @@ class Tracer:
             builds = sorted(self.grid_builds.items())
             grids = sorted(self.grids.items())
             plans = sorted(self.plans.items())
+            rollups = sorted(self.rollups.items())
             filters = sorted(self.filters.items())
             names_read = self.filter_names_read
             tables = sorted(self.filter_tables.items())
@@ -1264,6 +1276,8 @@ class Tracer:
             collector.record("query.grid", n, source=source)
         for state, n in plans:
             collector.record("query.plan", n, index=state)
+        for source, n in rollups:
+            collector.record("query.rollup", n, source=source)
         for way, n in filters:
             collector.record("query.filter", n, resolve=way)
         collector.record("query.filter.names_read", names_read)

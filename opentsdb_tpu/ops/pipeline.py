@@ -332,6 +332,16 @@ def put_grid(grid, has_data, device=None):
                                device=device))
 
 
+def put_pair(grid_sum, grid_cnt, device=None):
+    """Upload a rollup average's SUM and COUNT grids (padded [S, B],
+    NaN where a bucket holds no cell) once, in the compute dtype: the
+    :func:`put_grid` of :func:`run_pipeline_avg_div`'s operands."""
+    dtype = pipeline_dtype()
+    with trace_span("query.upload"):
+        return (jax.device_put(as_operand(grid_sum, dtype), device=device),
+                jax.device_put(as_operand(grid_cnt, dtype), device=device))
+
+
 def _pad_2d(arr, s_pad: int, b_pad: int, fill):
     """Pad a [S, B] array to [s_pad, b_pad]. DEVICE arrays pad on
     device (an eager jnp.pad — never a host round trip: the engine's
@@ -431,7 +441,8 @@ def run_pipeline_avg_div(grid_sum, grid_cnt, bucket_ts, group_ids,
     SUM-tier grid by a bucketized COUNT-tier grid in-trace (no host
     round-trip for the [S,B] grids), then runs the shared
     rate/interpolate/aggregate chain."""
-    grid, valid = avg_divide_grid(grid_sum, grid_cnt, xp=jnp)
+    with jax.named_scope("tail.avg_divide"):
+        grid, valid = avg_divide_grid(grid_sum, grid_cnt, xp=jnp)
     return _finish_pipeline(grid, valid, bucket_ts, group_ids,
                             rate_params, fill_value, spec)
 

@@ -160,10 +160,13 @@ class NoSuchMetricError(BadRequestError):
 
 #: first item of the HBM cache's key for a metric's resident grid
 RESIDENT_GRID_KEY = "metricgrid"
-#: the ``grid`` tag of the ``query.grid_build stage=cache_lookup`` span
-#: of the kinds that have one: (a hit's, a build's)
-_LOOKUP_GRID = {RESIDENT_GRID_KEY: ("resident_hit", "resident_built"),
-                "grid": ("selection", "selection")}
+#: first item of the key of a rollup average's tier pair
+TIER_PAIR_KEY = "avgdiv"
+#: the ``grid`` tag of the ``query.grid_build stage=cache_lookup`` span,
+#: (a hit's, a build's): of a metric's resident entry, of a request's
+#: own rows
+_LOOKUP_RESIDENT = ("resident_hit", "resident_built")
+_LOOKUP_SELECTION = ("selection", "selection")
 
 
 def _store_id(store) -> int:
@@ -580,7 +583,7 @@ class QueryEngine:
             return tuple(parts)
         try:
             (store, _metric, _sids, avg_count_store,
-             _ds) = self._select_store(sub)
+             _ds, _source) = self._select_store(sub)
         except Exception:  # noqa: BLE001 - compute re-raises for real
             return ("all", t.serve_version(), ann)
         parts = ["sel", ann, _store_id(store), store.points_written,
@@ -641,7 +644,9 @@ class QueryEngine:
         # the enclosing query.execute span still carries the error)
         _h_plan = trace_begin("query.plan", sub=sub.index)
         (store, metric_name, sids, avg_count_store,
-         ds_fn_override) = self._select_store(sub)
+         ds_fn_override, source) = self._select_store(sub)
+        if _h_plan is not None:
+            _h_plan.tag(**source)
         budget = self.tsdb.config.get_int(
             "tsd.query.max_device_cells", 0) or DEFAULT_CELL_BUDGET
         if avg_count_store is not None:
@@ -715,10 +720,20 @@ class QueryEngine:
             _h_plan.tag(series=len(sids), groups=num_groups)
         trace_end(_h_plan)
 
+        # a selection planned from the plan index knows its rows of the
+        # metric (filters, explicit_tags and the replica mask
+        # composed): what the metric's resident grid, or its resident
+        # tier pair, is labelled by
+        metric_rows = None
+        if tag_mat.origin is not None:
+            rows = tag_mat.origin[1]
+            metric_rows = (metric_sids,
+                           slice(None) if rows is None else rows)
+
         if avg_count_store is not None:
             out = self._avg_rollup_pipeline(
                 store, avg_count_store, sids, tsq, sub, metric_name,
-                group_ids, num_groups, emit_raw, stats)
+                group_ids, num_groups, emit_raw, stats, metric_rows)
             if out is None:
                 return []
             result, emit, bucket_ts = out
@@ -730,15 +745,7 @@ class QueryEngine:
         # downsample functions the storage engine reduces the window to
         # the [S, B] grid in one native pass, so the device never sees
         # per-point data (SURVEY §7: HBM/transfer bandwidth is the
-        # bottleneck; here the "scan" IS the downsample). A selection
-        # planned from the plan index knows its rows of the metric
-        # (filters, explicit_tags and the replica mask composed): what
-        # the metric's resident grid is labelled by
-        metric_rows = None
-        if tag_mat.origin is not None:
-            rows = tag_mat.origin[1]
-            metric_rows = (metric_sids,
-                           slice(None) if rows is None else rows)
+        # bottleneck; here the "scan" IS the downsample)
         out = self._grid_pipeline(store, sids, tsq, sub, metric_name,
                                   group_ids, num_groups, emit_raw,
                                   budget, stats, ds_fn_override,
@@ -1136,7 +1143,11 @@ class QueryEngine:
         """Pick raw store or a rollup tier (ref: TsdbQuery rollup
         best-match :143-150 with ROLLUP_USAGE fallback :750).
         Returns (store, metric_name, sids, avg_count_store,
-        ds_fn_override).
+        ds_fn_override, source); ``source`` is the ``query.plan``
+        span's tags for the choice: ``source`` = ``raw`` | ``tier`` |
+        ``fallback`` (a tier was the match, held nothing of the
+        metric, and ``rollupUsage`` sent the request on to raw) and,
+        beside the last two, ``tier`` = its interval.
 
         ``avg_count_store`` is the COUNT-tier store when an ``avg``
         downsample is being answered from rollups: the reference
@@ -1153,7 +1164,7 @@ class QueryEngine:
         """
         uids = self.tsdb.uids
         if sub.tsuids:
-            return self._tsuid_store(sub)
+            return (*self._tsuid_store(sub), {"source": "raw"})
         try:
             metric_id = uids.metrics.get_id(sub.metric)
         except LookupError:
@@ -1205,13 +1216,17 @@ class QueryEngine:
                     rs.tier(tier.interval, "count"), metric_id,
                     tier.interval, "count")
         sids = store.series_ids_for_metric(metric_id)
+        source = {"source": "raw"} if store is self.tsdb.store \
+            else {"source": "tier", "tier": tier.interval}
         if store is not self.tsdb.store and len(sids) == 0 and \
                 usage in ("ROLLUP_FALLBACK", "ROLLUP_FALLBACK_RAW"):
             store = self.tsdb.store
             sids = store.series_ids_for_metric(metric_id)
             avg_count_store = None
             ds_fn_override = None
-        return store, sub.metric, sids, avg_count_store, ds_fn_override
+            source["source"] = "fallback"
+        return (store, sub.metric, sids, avg_count_store,
+                ds_fn_override, source)
 
     def _maybe_stitch(self, tier_store, metric_id: int, interval: str,
                       agg: str):
@@ -1320,9 +1335,10 @@ class QueryEngine:
             fill_padded_grid(stat, *reduced, grid, has_data)
         return grid, has_data, num_points
 
-    def _resident_operands(self, cache, kind: str, key_of, stores, build,
-                           stats, metric_name: str, n_rows: int,
-                           delete=None, points_of=None):
+    def _resident_operands(self, cache, kind: str, lookup: tuple,
+                           key_of, stores, build, stats,
+                           metric_name: str, n_rows: int, delete=None,
+                           points_of=None):
         """What a grid-shaped kind of the HBM cache does around its
         build, once: the operands through :func:`device_cache.resident`
         under ``(kind, *key_of())``, versioned by ``stores`` (``cache``
@@ -1338,18 +1354,18 @@ class QueryEngine:
         the limits' check and ``delete``: a build of what only this
         request reads calls it between its scan and its upload, so
         that a refused request puts nothing up and keeps nothing;
-        else, and on a hit, it follows here. A kind in
-        :data:`_LOOKUP_GRID` has a ``cache_lookup`` span: the key, the
-        look-up and the wait for another's build of it, no more."""
-        tags = _LOOKUP_GRID.get(kind) if cache is not None else None
-        lookup = trace_begin("query.grid_build", stage="cache_lookup") \
-            if tags else None
+        else, and on a hit, it follows here. The ``cache_lookup``
+        span, tagged ``grid`` = ``lookup[0]`` on a hit and
+        ``lookup[1]`` on a build, is the key, the look-up and the wait
+        for another's build of it, no more."""
+        span = trace_begin("query.grid_build", stage="cache_lookup") \
+            if cache is not None else None
         pending = True
 
         def end_lookup(built: bool):
-            if lookup is not None:
-                lookup.tag(grid=tags[built])
-                lookup.finish()
+            if span is not None:
+                span.tag(grid=lookup[built])
+                span.finish()
 
         def checked(num_points: int):
             nonlocal pending
@@ -1379,18 +1395,23 @@ class QueryEngine:
 
     @staticmethod
     def _resident_grid_fits(cache, n: int, num_selected: int, b: int,
-                            budget: int) -> bool:
+                            budget: int | None, grids: int = 1) -> bool:
         """Whether ``num_selected`` of a metric's ``n`` series over
-        ``b`` buckets run over the metric's resident grid: by what the
-        request shows, no key (:data:`RESIDENT_GRID_MIN_SHARE`, the
-        metric's grid within the cell ``budget`` and the cache)."""
+        ``b`` buckets run over the metric's resident entry: by what
+        the request shows, no key (:data:`RESIDENT_GRID_MIN_SHARE`,
+        the metric's grid within the cell ``budget`` and the cache).
+        The entry is a grid and its mask, or, for a rollup average,
+        ``grids`` = 2 grids with NaN holes; ``budget`` None where the
+        caller lays its own rows out whole whatever their number."""
         from opentsdb_tpu.ops import shapes
         from opentsdb_tpu.ops.pipeline import pipeline_dtype
         cells = shapes.shape_bucket(n) * shapes.shape_bucket(b)
+        cell_bytes = np.dtype(pipeline_dtype()).itemsize
+        entry = cells * (cell_bytes + 1 if grids == 1
+                         else grids * cell_bytes)
         return num_selected >= RESIDENT_GRID_MIN_SHARE * n \
-            and n * b <= budget \
-            and cells * (np.dtype(pipeline_dtype()).itemsize + 1) \
-            <= cache.max_bytes
+            and (budget is None or n * b <= budget) \
+            and entry <= cache.max_bytes
 
     def _resident_grid(self, cache, store, metric_sids: np.ndarray,
                        rows, num_selected: int, tsq: TSQuery,
@@ -1430,8 +1451,9 @@ class QueryEngine:
                     if meta["counts"].any() else None), meta
 
         return self._resident_operands(
-            cache, RESIDENT_GRID_KEY, key_of, (store,), build, stats,
-            metric_name, num_selected, points_of=points_of)
+            cache, RESIDENT_GRID_KEY, _LOOKUP_RESIDENT, key_of,
+            (store,), build, stats, metric_name, num_selected,
+            points_of=points_of)
 
     def _grid_pipeline(self, store, sids: np.ndarray, tsq: TSQuery,
                        sub: TSSubQuery, metric_name: str,
@@ -1531,8 +1553,8 @@ class QueryEngine:
                 return (grid, has_data), meta
 
             operands = self._resident_operands(
-                cache, "grid", key_of, (store,), build, stats,
-                metric_name, len(sids),
+                cache, "grid", _LOOKUP_SELECTION, key_of, (store,),
+                build, stats, metric_name, len(sids),
                 delete=(lambda: store.delete_range(
                     sids, tsq.start_ms, tsq.end_ms))
                 if tsq.delete and hasattr(store, "delete_range")
@@ -1609,41 +1631,75 @@ class QueryEngine:
                            (time.monotonic() - t2) * 1e3)
         return result, emit, bucket_ts
 
+    def _tier_pair(self, sum_store, cnt_store, sids: np.ndarray,
+                   csids: np.ndarray, tsq: TSQuery,
+                   bucket_ts: np.ndarray, interval_ms: int, scanned):
+        """A rollup average's operands, ``(SUM grid, COUNT grid, cells
+        read)``: the cells of ``sids`` in the SUM tier and of their
+        COUNT-tier series ``csids`` (-1: the tier has none), each
+        tier's bucket sums from :meth:`_reduce_to_grid`'s one pass,
+        padded and in the compute dtype, NaN where a bucket holds no
+        cell (the presence masks say the same and are dropped).
+        ``scanned(scan, cells, of_counts)`` closes each tier's pass."""
+        def reduce(store, of):
+            grid, _, num_points = self._reduce_to_grid(
+                store, of, tsq, bucket_ts, interval_ms, "sum",
+                lambda scan, n: scanned(scan, n, store is cnt_store))
+            return grid, num_points
+
+        gs, n_s = reduce(sum_store, sids)
+        present = np.flatnonzero(csids >= 0)
+        if len(present) == len(sids):
+            gc, n_c = reduce(cnt_store, csids)
+        else:
+            # a SUM series the COUNT tier lacks: its row holds nothing
+            gc, n_c = np.full_like(gs, np.nan), 0
+            if len(present):
+                rows, n_c = reduce(cnt_store, csids[present])
+                gc[present] = rows[:len(present)]
+        return gs, gc, n_s + n_c
+
     def _avg_rollup_pipeline(self, sum_store, cnt_store,
                              sids: np.ndarray, tsq: TSQuery,
                              sub: TSSubQuery, metric_name: str,
                              group_ids: np.ndarray, num_groups: int,
-                             emit_raw: bool, stats):
+                             emit_raw: bool, stats,
+                             metric_rows: tuple | None = None):
         """Answer an ``avg`` downsample from rollup tiers: bucketized
         SUM cells divided by bucketized COUNT cells — the true weighted
         average, not a mean of per-tier-point averages (ref: RollupSpan
         reading agg-prefixed sum+count qualifiers from one row).
-        Returns (result, emit, bucket_ts) or None for no data."""
-        # count series aligned to sum series by (metric, tags)
-        # identity — computed lazily: a device-cache hit never needs it
-        csids = present = None
+        Returns (result, emit, bucket_ts) or None for no data.
 
-        def align():
-            nonlocal csids, present
-            if csids is None:
-                csids = _match_series_by_tags(
-                    sum_store, cnt_store, sids,
-                    sum_store.series(int(sids[0])).metric_id)
-                present = np.nonzero(csids >= 0)[0]
-            return csids, present
+        ``metric_rows`` as :meth:`_grid_pipeline` takes it: a
+        device-placed tail over at least half of its metric reads the
+        METRIC's tier pair, resident once a window, and sends its
+        selection up as labels (the raw path's rule,
+        :meth:`_resident_grid_fits`); anything else keeps a pair of
+        its own rows."""
+        metric_id = sum_store.series(int(sids[0])).metric_id
+
+        def align(of: np.ndarray) -> np.ndarray:
+            # count series aligned to sum series by (metric, tags)
+            # identity: once a build, never on a hit
+            return _match_series_by_tags(sum_store, cnt_store, of,
+                                         metric_id)
 
         def delete():
-            csids, present = align()
+            csids = align(sids)
             sum_store.delete_range(sids, tsq.start_ms, tsq.end_ms)
-            cnt_store.delete_range(csids[present], tsq.start_ms,
+            cnt_store.delete_range(csids[csids >= 0], tsq.start_ms,
                                    tsq.end_ms)
 
         ds_spec = sub.ds_spec
         mesh = self.tsdb.query_mesh
         host_dev = cache = None
+        tail_rows, tail_gids = len(sids), group_ids
+        rollup_stats = self.tsdb.rollup_store.stats
         if self._fixed_interval(ds_spec):
-            # native pre-reduction: both tiers collapse to [S, B] sums
-            # in one storage pass each — no per-point upload
+            # both tiers collapse to padded [S, B] sums in one fused
+            # storage pass each — no per-point upload
+            from opentsdb_tpu.ops.pipeline import put_pair
             bucket_ts = ds_mod.fixed_bucket_edges(
                 tsq.start_ms, tsq.end_ms, ds_spec.interval_ms)
             s, b = len(sids), len(bucket_ts)
@@ -1658,68 +1714,81 @@ class QueryEngine:
                 # not evict HBM-resident grids)
                 if host_dev is None:
                     cache = self.tsdb.device_grid_cache
+            whole = cache is not None and not emit_raw \
+                and not tsq.delete and metric_rows is not None \
+                and self._resident_grid_fits(
+                    cache, len(metric_rows[0]), s, b, None, grids=2)
+            pair_sids = metric_rows[0] if whole else sids
 
-            def key_of():
-                return (_store_id(sum_store), _store_id(cnt_store),
-                        device_cache.array_digest(
-                            np.ascontiguousarray(sids)), *window)
-
-            def build(_checked):
-                scan = self._scan_begin()
-                csids, present = align()
-                sum_s, cnt_s, _, _ = sum_store.bucket_reduce(sids,
-                                                             *window)
-                if len(present) == s:
-                    sum_c, cnt_c, _, _ = cnt_store.bucket_reduce(
-                        csids, *window)
-                else:
-                    sum_c, cnt_c = np.zeros((2, s, b))
-                    if len(present):
-                        sum_c[present], cnt_c[present], _, _ = \
-                            cnt_store.bucket_reduce(csids[present],
-                                                    *window)
-                num_points = int(cnt_s.sum() + cnt_c.sum())
-                self._record_scan(stats, scan, num_points, len(sids))
-                meta = {"num_points": num_points}
-                with trace_span("query.grid_build", cells=s * b,
-                                bytes=sum_s.nbytes + sum_c.nbytes):
-                    # write NaN holes in place (np.where would copy
-                    # 4x ~100MB at 1M series)
-                    sum_s[cnt_s == 0] = np.nan
-                    sum_c[cnt_c == 0] = np.nan
-                    gs, gc = sum_s, sum_c
-                    if mesh is None:
-                        # pre-pad to the shape buckets (host, once;
-                        # the cache then holds padded device grids —
-                        # no per-query device pads on the warm path)
-                        from opentsdb_tpu.ops import shapes
-                        sp = shapes.shape_bucket(s)
-                        bp = shapes.shape_bucket(b)
-                        gs = shapes.pad_2d_host(gs, sp, bp, np.nan)
-                        gc = shapes.pad_2d_host(gc, sp, bp, np.nan)
+            def build(checked):
+                csids = align(pair_sids)
+                meta = {}
+                if whole:
+                    # each row's cells of the window, a tier: the
+                    # limits' check and the stat points are the
+                    # selection's, as on a hit
+                    cells = np.zeros((2, len(pair_sids)), np.int64)
+                    cells[0] = sum_store.count_range(
+                        pair_sids, tsq.start_ms, tsq.end_ms)
+                    have = csids >= 0
+                    cells[1, have] = cnt_store.count_range(
+                        csids[have], tsq.start_ms, tsq.end_ms)
+                    meta["counts"] = cells.sum(axis=0)
+                    mine = cells[:, metric_rows[1]].sum(axis=1)
+                gs, gc, num_points = self._tier_pair(
+                    sum_store, cnt_store, pair_sids, csids, tsq,
+                    bucket_ts, ds_spec.interval_ms,
+                    lambda scan, n, of_counts: self._record_scan(
+                        stats, scan,
+                        int(mine[int(of_counts)]) if whole else n, s))
+                if not whole:
+                    # a pair of this request's own rows: refused
+                    # before it goes up (the metric's is of use to the
+                    # selections the limits let through: kept first)
+                    meta["num_points"] = num_points
+                    checked(num_points)
                 if not num_points:
                     return None, meta
-                if cache is not None:
-                    from opentsdb_tpu.ops.pipeline import pipeline_dtype
-                    import jax
-                    import jax.numpy as jnp
-                    dt = pipeline_dtype()
-                    with trace_span("query.upload"):
-                        gs = jax.device_put(jnp.asarray(gs, dtype=dt))
-                        gc = jax.device_put(jnp.asarray(gc, dtype=dt))
+                if mesh is None:
+                    rollup_stats.add(upload_bytes=gs.nbytes + gc.nbytes)
+                    gs, gc = put_pair(gs, gc, device=host_dev)
                 return (gs, gc), meta
 
-            operands = self._resident_operands(
-                cache, "avgdiv", key_of, (sum_store, cnt_store), build,
-                stats, metric_name, len(sids),
-                delete=delete if tsq.delete else None)
+            if whole:
+                rows = metric_rows[1]
+                operands = self._resident_operands(
+                    cache, TIER_PAIR_KEY, _LOOKUP_RESIDENT,
+                    lambda: (_store_id(sum_store), _store_id(cnt_store),
+                             metric_id, len(pair_sids), *window),
+                    (sum_store, cnt_store), build, stats, metric_name,
+                    s, points_of=lambda meta: int(
+                        meta["counts"][rows].sum()))
+                if operands is not None:
+                    # all that this request puts up: a label a
+                    # resident row, the rows its filter dropped on the
+                    # dummy group (_grid_pipeline's labels)
+                    with trace_span("query.upload", stage="labels"):
+                        tail_rows = len(pair_sids)
+                        tail_gids = np.full(tail_rows, num_groups,
+                                            np.int32)
+                        tail_gids[rows] = group_ids
+            else:
+                operands = self._resident_operands(
+                    cache, TIER_PAIR_KEY, _LOOKUP_SELECTION,
+                    lambda: (_store_id(sum_store), _store_id(cnt_store),
+                             device_cache.array_digest(
+                                 np.ascontiguousarray(sids)), *window),
+                    (sum_store, cnt_store), build, stats, metric_name,
+                    s, delete=delete if tsq.delete else None)
             if operands is None:
                 return None
             (gs, gc), _ = operands
+            rollup_stats.add(upload_bytes=tail_gids.nbytes)
             t2 = time.monotonic()
         else:
             scan = self._scan_begin()
-            csids, present = align()
+            csids = align(sids)
+            present = np.flatnonzero(csids >= 0)
             batch_s = sum_store.materialize(sids, tsq.start_ms,
                                             tsq.end_ms)
             batch_c = cnt_store.materialize(csids[present],
@@ -1748,7 +1817,7 @@ class QueryEngine:
                     present[batch_c.series_idx].astype(np.int32),
                     bidx_c, s, b, "sum")
         spec = PipelineSpec(
-            num_series=s, num_buckets=b, num_groups=num_groups,
+            num_series=tail_rows, num_buckets=b, num_groups=num_groups,
             ds_function="avg", agg_name=sub.agg.name,
             fill_policy=sub.ds_spec.fill_policy,
             fill_value=sub.ds_spec.fill_value, rate=sub.rate,
@@ -1773,13 +1842,13 @@ class QueryEngine:
         else:
             def host_retry():
                 return execute_avg_divide(
-                    gs, gc, bucket_ts, group_ids,
+                    gs, gc, bucket_ts, tail_gids,
                     replace(spec, host=True), sub.rate_options,
                     device=self._host_cpu())
 
             result, emit = self._run_device(
                 lambda: execute_avg_divide(
-                    gs, gc, bucket_ts, group_ids, spec,
+                    gs, gc, bucket_ts, tail_gids, spec,
                     sub.rate_options, device=host_dev),
                 host_retry, on_device=host_dev is None)
         if stats:

@@ -12,16 +12,64 @@ jobs"); the TPU build ships one as a jitted segmented reduction.
 from __future__ import annotations
 
 import threading
-from typing import Sequence
+from typing import Any, NamedTuple, Sequence
 
 from opentsdb_tpu.core.store import PointBatch, TimeSeriesStore
 from opentsdb_tpu.rollup.config import RollupConfig
+
+
+class AggregateRun(NamedTuple):
+    """One series' run of rollup cells as
+    :meth:`TSDB.add_aggregate_batch` takes it (a plain tuple of the
+    first six will do). ``interval`` None is a pure pre-aggregate;
+    ``refs[i]`` is handed back to ``on_error`` for a failing cell
+    (absent: its index in the run)."""
+    interval: str | None
+    aggregator: str | None
+    metric: str
+    tags: dict[str, str]
+    timestamps: Sequence[int]
+    values: Sequence[Any]
+    groupby_agg: str | None = None
+    is_groupby: bool = False
+    refs: Sequence[Any] | None = None
+
+
+class RollupStats:
+    """Counters of the rollup write and query paths, exported at
+    ``/api/stats`` by :meth:`TSDB.collect_stats`."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.batch_points = 0    # cells landed a run at a time
+        self.slow_points = 0     # cells landed a point at a time
+        self.upload_bytes = 0    # bytes an avg request put on the device
+
+    def add(self, **grown: int) -> None:
+        """Grow counters by name; writers and query workers call side
+        by side, and ``+=`` alone would lose an update."""
+        with self._lock:
+            for name, n in grown.items():
+                setattr(self, name, getattr(self, name) + n)
+
+    def collect_stats(self, collector, cache) -> None:
+        collector.record("rollup.batch_points", self.batch_points)
+        collector.record("rollup.slow_points", self.slow_points)
+        collector.record("query.rollup.upload_bytes", self.upload_bytes)
+        collector.record(
+            "query.rollup.resident_bytes",
+            cache.bytes_of(RESIDENT_KEY) if cache is not None else 0)
+
+
+#: first element of a tier pair's key in the HBM cache
+RESIDENT_KEY = "avgdiv"
 
 
 class RollupStore:
     def __init__(self, config: RollupConfig, store_factory=None,
                  fault_injector=None):
         self.config = config
+        self.stats = RollupStats()
         # tier stores come from the same backend factory as the raw
         # store (native C++ by default) — the rollup job's bulk grid
         # writes were 15x slower through the portable Python store
@@ -81,18 +129,25 @@ class RollupStore:
                           getattr(store, "mutation_epoch", 0)))
         return tuple(parts)
 
-    def add_point(self, interval: str, agg: str, metric_id: int,
-                  tag_ids: Sequence[tuple[int, int]], ts_ms: int,
-                  value: float) -> None:
-        store = self.tier(interval, agg)
+    def append_run(self, interval: str | None, agg: str | None,
+                   metric_id: int, tag_ids: Sequence[tuple[int, int]],
+                   ts_ms, values) -> tuple[str, int]:
+        """The one write entry: one series' run of cells into the
+        (interval, agg) tier, or into the pre-aggregate store where
+        ``interval`` is None, by one ``append_many``. Returns (the
+        WAL's name of the store, the series id)."""
+        if interval is None:
+            kind, store = "preagg", self._preagg
+        else:
+            if agg is None:
+                raise ValueError("missing rollup aggregator")
+            store = self.tier(interval, agg)
+            kind = f"tier:{interval}:{agg.lower()}"
         sid = store.get_or_create_series(metric_id, tag_ids)
-        store.append(sid, ts_ms, value)
-
-    def add_preagg_point(self, metric_id: int,
-                         tag_ids: Sequence[tuple[int, int]], ts_ms: int,
-                         value: float) -> None:
-        sid = self._preagg.get_or_create_series(metric_id, tag_ids)
-        self._preagg.append(sid, ts_ms, value)
+        store.append_many(sid, ts_ms, values, False)
+        self.stats.add(**{"batch_points" if len(ts_ms) > 1
+                          else "slow_points": len(ts_ms)})
+        return kind, sid
 
     def preagg_store(self) -> TimeSeriesStore:
         return self._preagg

@@ -664,8 +664,11 @@ class HttpRpcRouter:
         if request.method != "POST":
             raise HttpError(405, "Method not allowed")
         points = request.serializer.parse_put(request.body)
-        success = 0
         errors: list[dict] = []
+        # a (tier, aggregator, series) a run: one UID resolution, one
+        # append and one WAL record each, one fsync for the body
+        from opentsdb_tpu.rollup.store import AggregateRun
+        runs: dict[tuple, AggregateRun] = {}
         for dp in points:
             try:
                 value = dp["value"]
@@ -674,19 +677,29 @@ class HttpRpcRouter:
                     # whitespace forms float() would silently accept
                     # (allow_special keeps the NaN/Infinity spellings
                     # float() always took on this endpoint)
-                    value = float(tags_parse_put_value(
-                        value, allow_special=True))
-                self.tsdb.add_aggregate_point(
-                    dp["metric"], int(dp["timestamp"]), value,
-                    dp.get("tags") or {},
-                    bool(dp.get("groupByAggregator")
-                         or dp.get("isGroupBy")),
-                    dp.get("interval"),
-                    dp.get("aggregator"),
-                    dp.get("groupByAggregator"))
-                success += 1
+                    value = tags_parse_put_value(value,
+                                                 allow_special=True)
+                value = float(value)
+                ts = int(dp["timestamp"])
+                tags = dp.get("tags") or {}
+                gb_agg = dp.get("groupByAggregator")
+                key = (dp.get("interval"), dp.get("aggregator"),
+                       dp["metric"], gb_agg,
+                       bool(gb_agg or dp.get("isGroupBy")))
+                run = runs.get((*key, tuple(sorted(tags.items()))))
+                if run is None:
+                    run = runs[(*key, tuple(sorted(tags.items())))] = \
+                        AggregateRun(*key[:3], tags, [], [], *key[3:],
+                                     refs=[])
             except Exception as e:  # noqa: BLE001
                 errors.append({"datapoint": dp, "error": str(e)})
+                continue
+            run.timestamps.append(ts)
+            run.values.append(value)
+            run.refs.append(dp)
+        success, _ = self.tsdb.add_aggregate_batch(
+            runs.values(), on_error=lambda dp, e: errors.append(
+                {"datapoint": dp, "error": str(e)}))
         if errors and not request.flag("details") \
                 and not request.flag("summary"):
             raise HttpError(400, "One or more data points had errors",

@@ -187,6 +187,50 @@ class TestAvgRollupCache:
         assert seen[0][1] == sum_store.instance_id
         assert seen[0][2] == cnt_store.instance_id
 
+    @pytest.mark.parametrize("hosts, whole", [
+        ("h0|h1|h2|h3", True), ("h0|h1|h2", True), ("h0|h1", False),
+        ("h4", False)])
+    def test_the_pair_is_the_metrics_from_half_of_it_on(self, hosts,
+                                                        whole):
+        """PR 48: a device-placed tail over at least half of its
+        metric reads the METRIC's tier pair, keyed by scalars (the
+        stores, the metric, its series count, the window); under a
+        half a pair of its own rows, keyed by their digest. Either
+        way the same cells come back."""
+        t = _tsdb(**{"tsd.rollups.enable": "true"})
+        _seed_rollup(t)
+
+        def ask():
+            return t.execute_query(TSQuery.from_json({
+                "start": BASE * 1000, "end": (BASE + 1800) * 1000,
+                "queries": [{"metric": "m", "aggregator": "sum",
+                             "downsample": "5m-avg", "filters": [{
+                                 "type": "literal_or", "tagk": "host",
+                                 "filter": hosts,
+                                 "groupBy": False}]}]}).validate())
+
+        got = ask()
+        (key,) = t.device_grid_cache._entries
+        assert key[0] == "avgdiv"
+        if whole:
+            metric_id = t.uids.metrics.get_id("m")
+            assert key[3:5] == (metric_id, 6)
+            assert not any(isinstance(k, bytes) for k in key)
+        else:
+            assert isinstance(key[3], bytes)
+        assert ask()[0].dps == got[0].dps
+        assert t.device_grid_cache.hits == 1
+        bare = _tsdb(**{"tsd.rollups.enable": "true",
+                        "tsd.query.device_cache_mb": "0"})
+        _seed_rollup(bare)
+        _same_answers(bare.execute_query(TSQuery.from_json({
+            "start": BASE * 1000, "end": (BASE + 1800) * 1000,
+            "queries": [{"metric": "m", "aggregator": "sum",
+                         "downsample": "5m-avg", "filters": [{
+                             "type": "literal_or", "tagk": "host",
+                             "filter": hosts, "groupBy": False}]}]}
+        ).validate()), got, False, 1e-6)
+
 
 class TestTierHasData:
     def test_emptied_tier_stops_winning_selection(self):
@@ -839,7 +883,9 @@ KINDS = {
     "metricgrid": ((1, 7, 40, 0, 1800, 0, 60, 30, "avg"), _one_store),
     "grid": ((1, b"digest", 0, 1800, 0, 60, 30, "avg", None),
              _one_store),
-    "avgdiv": ((1, 2, b"digest", 0, 1800, 0, 60, 30), _two_stores),
+    # a metric's tier pair (PR 48); a selection's own pair keeps a
+    # digest where the metric's id and series count stand
+    "avgdiv": ((1, 2, 7, 40, 0, 1800, 0, 60, 30), _two_stores),
     "prep": ((1, b"digest", 0, 1800, "union", None, None, "lin"),
              _one_store),
     "hist": ((7, 0, 1800), _arena),

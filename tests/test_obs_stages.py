@@ -476,15 +476,15 @@ def test_the_plan_span_says_what_the_plan_index_did(backend):
             "type": "wildcard", "tagk": "dc", "filter": "*",
             "groupBy": True}]}
         assert plan_tags(grid) == {
-            "sub": 0, "index": "built", "series": 16, "groups": 4,
-            "names_read": 0, "resolve_presence": 1}
+            "sub": 0, "source": "raw", "index": "built", "series": 16,
+            "groups": 4, "names_read": 0, "resolve_presence": 1}
         assert plan_tags(grid)["index"] == "hit"
         rec = tsdb.store.series(int(tsdb.store.series_ids_for_metric(
             tsdb.uids.metrics.get_id("sys.stage"))[3]))
         tsuid = tsdb.uids.tsuid(rec.metric_id, rec.tags).hex()
         assert plan_tags({"tsuids": [tsuid]}) == {
-            "sub": 0, "index": "bypass", "series": 1, "groups": 1,
-            "names_read": 0}
+            "sub": 0, "source": "raw", "index": "bypass", "series": 1,
+            "groups": 1, "names_read": 0}
         plans = {r["tags"]["index"]: r["value"] for r in json.loads(
             router.handle(req("GET", "/api/stats")).body)
             if r["metric"] == "tsd.query.plan"}
