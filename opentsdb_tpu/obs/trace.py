@@ -776,6 +776,10 @@ class Tracer:
         # its order statistics: "select" (counting, the device's
         # lowering) or "sort" (one sort of the grid)
         self.ranks = {"select": 0, "sort": 0}
+        # programs by the form their nearest-present carry takes
+        # along the buckets: "unrolled" (every step written out) or
+        # "loop" (ops.interp.carry_form of the padded bucket count)
+        self.carries = {"unrolled": 0, "loop": 0}
         # grids built, by who wrote the padded grid: "fused" (the
         # store's own pass) or "host" (fill_padded_grid)
         self.grid_builds = {"fused": 0, "host": 0}
@@ -1071,7 +1075,8 @@ class Tracer:
         span has children; the part of it with no program in flight
         on the device adds to ``idle_stage_ms`` of its stage; every
         ``query.program`` counts in ``tails`` (and by its tag ``rank``
-        in ``ranks``), every ``query.grid_build``
+        in ``ranks``, by ``carry`` in ``carries``), every
+        ``query.grid_build``
         that built a grid (tag ``fused``) in ``grid_builds`` and that
         looked one up (tag ``grid``) in ``grids``, every
         ``query.plan`` that reached its filters (tag ``index``) in
@@ -1089,6 +1094,7 @@ class Tracer:
         observed = 0
         tails = []
         ranks = []
+        carries = []
         builds = []
         grids = []
         plans = []
@@ -1122,6 +1128,8 @@ class Tracer:
                               str(s.tags.get("class", "?"))))
                 if s.tags.get("rank") in self.ranks:
                     ranks.append(s.tags["rank"])
+                if s.tags.get("carry") in self.carries:
+                    carries.append(s.tags["carry"])
             elif s.name == "query.grid_build":
                 if "fused" in s.tags:
                     builds.append("fused" if s.tags["fused"]
@@ -1151,6 +1159,8 @@ class Tracer:
                 self.tails[key] = self.tails.get(key, 0) + 1
             for method in ranks:
                 self.ranks[method] += 1
+            for form in carries:
+                self.carries[form] += 1
             for mode in builds:
                 self.grid_builds[mode] += 1
             for source in grids:
@@ -1255,6 +1265,7 @@ class Tracer:
             idle = sorted(self.idle_stage_ms.items())
             tails = sorted(self.tails.items())
             ranks = sorted(self.ranks.items())
+            carries = sorted(self.carries.items())
             builds = sorted(self.grid_builds.items())
             grids = sorted(self.grids.items())
             plans = sorted(self.plans.items())
@@ -1270,6 +1281,8 @@ class Tracer:
                              placement=placement, **{"class": cls})
         for method, n in ranks:
             collector.record("query.rank", n, method=method)
+        for form, n in carries:
+            collector.record("query.carry", n, form=form)
         for mode, n in builds:
             collector.record("query.grid_build", n, mode=mode)
         for source, n in grids:

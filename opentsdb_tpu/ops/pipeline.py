@@ -25,6 +25,7 @@ from opentsdb_tpu.obs.trace import RUNTIME, trace_span
 from opentsdb_tpu.ops import aggregators as aggs_mod
 from opentsdb_tpu.ops import downsample as ds_mod
 from opentsdb_tpu.ops import groupby as gb_mod
+from opentsdb_tpu.ops import interp as interp_mod
 from opentsdb_tpu.ops.rate import RateOptions, _rate_kernel
 
 
@@ -285,7 +286,12 @@ def run_staged(path: str, program, operands,
     ``rank`` (``select`` | ``sort``: which lowering a ``class=rank``
     program's group stage takes, from the predicate the jitted code
     applies to the same padded shape; the mesh step has an estimator
-    of its own and carries none), the
+    of its own and carries none), ``carry`` (``unrolled`` | ``loop``:
+    which form a nearest-present carry along the program's padded
+    buckets takes, :func:`opentsdb_tpu.ops.interp.carry_form`, the
+    predicate the jitted fill and rate apply; every
+    :class:`PipelineSpec` program carries it but the mesh step, which
+    sweeps a time shard's buckets and not the spec's), the
     padded ``shape`` SxBxG and ``compiled`` when
     JAX compiled (or loaded from its cache) inside it; a
     device-placed program occupies :data:`RUNTIME`'s clock for that
@@ -302,6 +308,8 @@ def run_staged(path: str, program, operands,
         tags["rank"] = gb_mod.rank_lowering(
             spec.num_series, spec.num_groups, pipeline_dtype(),
             spec.host)
+    if isinstance(spec, PipelineSpec) and path != "mesh":
+        tags["carry"] = interp_mod.carry_form(spec.num_buckets)
     with trace_span(
             "query.program", path=path,
             placement="device" if on_device else "host",

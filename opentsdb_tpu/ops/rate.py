@@ -3,9 +3,10 @@
 
 First difference dv/dt (per second) between a series' successive
 *present* points, vectorized over the ``[series, bucket]`` grid: each
-present cell looks up the previous present cell of its own series via a
-cumulative-max index scan, so holes (NaN) are skipped exactly like the
-reference's iterator skips to the prior datapoint.
+present cell reads the previous present cell of its own series from
+one forward sweep along the buckets (``interp.carry_prev``), so holes
+(NaN) are skipped exactly like the reference's iterator skips to the
+prior datapoint.
 
 Counter semantics (RateOptions):
 - ``counter``: negative delta means rollover; corrected rate =
@@ -25,7 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from opentsdb_tpu.ops.interp import carry_prev, shift_prev
+from opentsdb_tpu.ops.interp import carry_prev
 
 
 @dataclass(frozen=True)
@@ -69,15 +70,12 @@ class RateOptions:
 def _rate_kernel(grid, bucket_ts, counter: bool, counter_max,
                  reset_value, drop_resets: bool):
     mask = ~jnp.isnan(grid)
-    # previous present cell, *strictly* before each cell: an inclusive
-    # 'nearest present' associative scan shifted one column right (no
-    # gathers — see interp.carry_prev on the B>=14 select-chain cliff)
+    # previous present cell, *strictly* before each cell: the forward
+    # sweep's carry as it stood when the step reached the cell
     t_cur = bucket_ts[None, :]
-    ts_row = jnp.broadcast_to(t_cur, grid.shape)
     gz = jnp.where(mask, grid, 0.0)
-    pv, pt, pp = carry_prev((gz, ts_row), mask)
-    v_prev, t_prev, has_prev = shift_prev(
-        (pv, pt, pp), (0.0, 0, False))
+    v_prev, t_prev, has_prev = carry_prev((gz, t_cur), mask,
+                                          exclusive=True)
     # difference timestamps BEFORE any float cast: bucket_ts arrives as
     # small relative offsets (device_bucket_ts) so integer diffs are
     # exact even on TPU where int64/float64 are unavailable

@@ -165,23 +165,21 @@ def _block_boundaries(grid, bucket_ts):
 def _fill_with_boundaries(grid, bucket_ts, mode: str,
                           prev_v, prev_t, prev_p,
                           next_v, next_t, next_p):
-    """fill_gaps with per-series cross-block boundary carries
-    (associative nearest-present scans — no gathers; see
-    interp.carry_prev on the select-chain cliff)."""
+    """fill_gaps with per-series cross-block boundary carries (the
+    block's own carries are interp's sweeps along the buckets)."""
     from opentsdb_tpu.ops.interp import carry_next, carry_prev
     mask = ~jnp.isnan(grid)
     if mode == Interpolation.ZIM.value:
         return jnp.where(mask, grid, 0.0)
     ts = bucket_ts.astype(grid.dtype)
-    ts_row = jnp.broadcast_to(ts[None, :], grid.shape)
     gz = jnp.where(mask, grid, 0.0)
-    v0_l, t0_l, has_lp = carry_prev((gz, ts_row), mask)
+    v0_l, t0_l, has_lp = carry_prev((gz, ts), mask)
     v0 = jnp.where(has_lp, v0_l, prev_v[:, None])
     t0 = jnp.where(has_lp, t0_l, prev_t[:, None])
     has0 = has_lp | prev_p[:, None]
     if mode == Interpolation.PREV.value:
         return jnp.where(mask, grid, jnp.where(has0, v0, jnp.nan))
-    v1_l, t1_l, has_ln = carry_next((gz, ts_row), mask)
+    v1_l, t1_l, has_ln = carry_next((gz, ts), mask)
     v1 = jnp.where(has_ln, v1_l, next_v[:, None])
     t1 = jnp.where(has_ln, t1_l, next_t[:, None])
     has1 = has_ln | next_p[:, None]
@@ -200,16 +198,13 @@ def _fill_with_boundaries(grid, bucket_ts, mode: str,
 def _rate_with_boundary(grid, bucket_ts, counter: bool, counter_max,
                         reset_value, drop_resets: bool,
                         carry_v, carry_t, carry_p):
-    """Rate kernel with the previous block's last-present carry
-    (associative scans, no gathers)."""
-    from opentsdb_tpu.ops.interp import carry_prev, shift_prev
+    """Rate kernel with the previous block's last-present carry."""
+    from opentsdb_tpu.ops.interp import carry_prev
     mask = ~jnp.isnan(grid)
     ts = bucket_ts.astype(grid.dtype)
-    ts_row = jnp.broadcast_to(ts[None, :], grid.shape)
     gz = jnp.where(mask, grid, 0.0)
-    pv, pt, pp = carry_prev((gz, ts_row), mask)
-    v_loc, t_loc, has_local = shift_prev((pv, pt, pp),
-                                         (0.0, 0.0, False))
+    v_loc, t_loc, has_local = carry_prev((gz, ts), mask,
+                                         exclusive=True)
     v_prev = jnp.where(has_local, v_loc, carry_v[:, None])
     t_prev = jnp.where(has_local, t_loc, carry_t[:, None])
     has_prev = has_local | carry_p[:, None]

@@ -81,6 +81,33 @@ def test_blocked_very_sparse_cross_block_lerp():
     _compare(spec, block_buckets=3, seed=5, density=0.08)
 
 
+@pytest.mark.parametrize("agg, rate", [("sum", False), ("pfsum", False),
+                                       ("mimmax", False), ("sum", True)])
+def test_hole_straddles_a_block_edge(agg, rate):
+    """Series 0 has values at buckets 3 and 8 alone and blocks are 5
+    buckets: the hole 4-7 lies on both sides of the edge 4|5, so the
+    block's own sweep ends inside it and the boundary carry has to
+    finish it (lerp, prev, the extremes' range, the rate's previous
+    point); series 1 is complete."""
+    b = 10
+    values = np.asarray([30.0, 80.0] + [100.0 + 7 * j for j in range(b)])
+    sidx = np.asarray([0, 0] + [1] * b, np.int32)
+    bidx = np.asarray([3, 8] + list(range(b)), np.int32)
+    bts = np.arange(b, dtype=np.int64) * 60_000 + 1_356_998_400_000
+    gids = np.zeros(2, np.int32)
+    spec = PipelineSpec(num_series=2, num_buckets=b, num_groups=1,
+                        ds_function="sum", agg_name=agg, rate=rate)
+    ro = RateOptions() if rate else None
+    ref, ref_emit = execute(values, sidx, bidx, bts, gids, spec, ro)
+    got, got_emit = execute_blocked(values, sidx, bidx, bts, gids, spec,
+                                    ro, block_buckets=5)
+    np.testing.assert_array_equal(got, ref)
+    np.testing.assert_array_equal(got_emit, ref_emit)
+    if agg == "sum" and not rate:
+        # 30 + (80 - 30) * 2/5 at bucket 5, beside series 1's 135
+        assert got[0, 5] == 50.0 + 135.0
+
+
 def test_block_size_one():
     spec = PipelineSpec(num_series=4, num_buckets=10, num_groups=2,
                         ds_function="avg", agg_name="avg", rate=True)

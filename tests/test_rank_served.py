@@ -18,7 +18,10 @@ Since PR 44 a third server runs float32 with its tails forced to the
 device's branch (as ``tests/test_device_cache.py`` forces it), where
 the rank group stage selects by counting: the same answers, and the
 span's ``rank=select|sort`` and ``tsd.query.rank{method}`` say which
-lowering ran (a host-placed tail and float64 keep the sort).
+lowering ran (a host-placed tail and float64 keep the sort). Since PR
+49 every program's span says ``carry=unrolled|loop``, the form its
+nearest-present carry takes along its padded buckets, and
+``tsd.query.carry{form}`` counts it.
 CPU only.
 """
 
@@ -157,9 +160,9 @@ def served(request):
     jax.config.update("jax_enable_x64", was)
 
 
-def _query(agg: str) -> dict:
+def _query(agg: str, downsample: str = "5m-avg") -> dict:
     return {"start": T0 * 1000, "end": END * 1000, "queries": [{
-        "metric": METRIC, "aggregator": agg, "downsample": "5m-avg",
+        "metric": METRIC, "aggregator": agg, "downsample": downsample,
         "filters": [
             {"type": "wildcard", "tagk": "dc", "filter": "*",
              "groupBy": True},
@@ -206,9 +209,10 @@ def _counted(tsd, metric: str, tag: str) -> dict:
     return out
 
 
-def _program_of(tsd, agg: str) -> dict:
+def _program_of(tsd, agg: str, downsample: str = "5m-avg") -> dict:
     """The ``query.program`` span of one request for ``agg``."""
-    _rows, headers = tsd.ask("POST", "/api/query", _query(agg))
+    _rows, headers = tsd.ask("POST", "/api/query",
+                             _query(agg, downsample))
     doc, _ = tsd.ask("GET", "/api/trace/" + headers["X-TSD-Trace-Id"])
     (root,) = doc["tree"]
 
@@ -256,4 +260,26 @@ def test_a_rank_program_says_how_it_read_its_ranks(served, agg):
     assert program["tags"]["rank"] == tsd.rank_method
     other = "sort" if tsd.rank_method == "select" else "select"
     assert after[tsd.rank_method] == before[tsd.rank_method] + 1
+    assert after[other] == before[other]
+
+
+@pytest.mark.parametrize("downsample, buckets, form", [
+    ("5m-avg", 12, "unrolled"), ("5s-avg", 768, "loop")])
+def test_a_program_says_which_form_its_carry_takes(served, downsample,
+                                                   buckets, form):
+    """An hour in 12 buckets sweeps with every step written out; in
+    720 (padded to 768) it runs the steps as a loop:
+    ``ops.interp.carry_form`` of the padded bucket count, the
+    predicate the jitted fill applies. The counter moves by one a
+    program, the other form's not at all."""
+    from opentsdb_tpu.ops.interp import carry_form
+    tsd, _vals, _tol = served
+    before = _counted(tsd, "tsd.query.carry", "form")
+    assert set(before) == {"unrolled", "loop"}
+    program = _program_of(tsd, "sum", downsample)
+    assert program["tags"]["shape"].split("x")[1] == str(buckets)
+    assert program["tags"]["carry"] == form == carry_form(buckets)
+    after = _counted(tsd, "tsd.query.carry", "form")
+    other = "loop" if form == "unrolled" else "unrolled"
+    assert after[form] == before[form] + 1
     assert after[other] == before[other]

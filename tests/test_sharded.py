@@ -189,6 +189,34 @@ def test_lerp_across_time_blocks(mesh_shape):
     compare(mesh_shape, spec, 6, seed=11, points_per=4)
 
 
+@pytest.mark.parametrize("agg, rate", [("sum", False), ("pfsum", False),
+                                       ("mimmax", False), ("sum", True)])
+def test_hole_straddles_a_time_shard_edge(agg, rate):
+    """Series 0 has values at buckets 6 and 9 alone on four time
+    shards of 4 buckets: the hole 7-8 lies on both sides of the edge
+    7|8 (PR 49: the shard's own sweep ends inside it, the boundary
+    carry finishes it); the other series are complete."""
+    num_series, b, mesh_shape = 4, 16, (2, 4)
+    rows = [(0, 6, 30.0), (0, 9, 90.0)]
+    for s in range(1, num_series):
+        rows += [(s, bb, float(100 * s + 3 * bb)) for bb in range(b)]
+    arr = np.asarray(rows)
+    values = arr[:, 2].astype(np.float64)
+    sidx, bidx = arr[:, 0].astype(np.int32), arr[:, 1].astype(np.int32)
+    bts = np.arange(b, dtype=np.int64) * 60_000
+    gids = np.zeros(num_series, np.int32)
+    spec = PipelineSpec(num_series=num_series, num_buckets=b,
+                        num_groups=1, ds_function="sum", agg_name=agg,
+                        rate=rate)
+    ro = RateOptions() if rate else None
+    ref, ref_emit = execute(values, sidx, bidx, bts, gids, spec, ro)
+    batch = prepare_sharded_batch(values, sidx, bidx, bts, gids,
+                                  num_series, 1, *mesh_shape)
+    got, got_emit = run_sharded(make_mesh(*mesh_shape), spec, batch, ro)
+    np.testing.assert_allclose(got, ref, rtol=1e-9, equal_nan=True)
+    np.testing.assert_array_equal(got_emit, ref_emit)
+
+
 @pytest.mark.parametrize("mesh_shape", [(4, 2)])
 def test_counter_rate_sharded(mesh_shape):
     spec = PipelineSpec(num_series=8, num_buckets=16, num_groups=1,

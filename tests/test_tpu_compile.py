@@ -91,3 +91,80 @@ def test_rank_p95s_program_selects_and_builds_no_one_hot(one_chip):
             stored.append(line.strip()[:120])
     assert not stored, stored
     assert compiled.memory_analysis().temp_size_in_bytes < 640 << 20
+
+
+def _reversed_or_padded(text: str, s: int) -> list[str]:
+    """The module's ``reverse`` instructions, and its ``pad``
+    instructions whose result has the grid's ``s`` rows."""
+    found = []
+    for line in text.splitlines():
+        head, _, rest = line.partition(" = ")
+        if not rest:
+            continue
+        result, _, op = rest.partition(" ")
+        if op.startswith("reverse(") or (
+                op.startswith("pad(") and str(s) in result):
+            found.append(line.strip()[:120])
+    return found
+
+
+def test_wides_program_sweeps_and_reverses_nothing(one_chip):
+    """``fleet-1m.wide-groupby`` (PR 49): 1,048,576 x 12 float32
+    cells, 112 padded groups, ``sum`` of a counter's rate. The
+    nearest-present carries of the rate and of ``fill_gaps`` are a
+    sweep along the 12 buckets with every step written out: no
+    ``reverse``, no grid-sized ``pad`` (the associative scan's 12 and
+    76), no loop, and temporaries of a few grids (460 MB for the
+    fill alone before)."""
+    from opentsdb_tpu.ops.interp import carry_form
+    from opentsdb_tpu.ops.pipeline import PipelineSpec, run_pipeline_grid
+    s, b, g = 1 << 20, 12, 112
+    assert carry_form(b) == "unrolled"
+    spec = PipelineSpec(num_series=s, num_buckets=b, num_groups=g,
+                        ds_function="avg", agg_name="sum", rate=True,
+                        rate_counter=True)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = run_pipeline_grid.lower(
+        shape((s, b), jnp.float32), shape((s, b), jnp.bool_),
+        shape((b,), jnp.int32), shape((s,), jnp.int32),
+        (shape((), jnp.float32), shape((), jnp.float32)),
+        shape((), jnp.float32), spec=spec).compile()
+    text = compiled.as_text()
+    assert not _reversed_or_padded(text, s)
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_month_avgs_program_sweeps_in_two_loops(one_chip):
+    """``rollup-100k.month-avg`` (PR 49): two float32 grids of
+    114,688 x 768, 128 padded groups, the division and ``sum``. At
+    768 buckets the sweep runs as a loop each way (``carry=loop``):
+    no ``reverse`` and no grid-sized ``pad`` (12 and 220 before), and
+    under 2 GB of temporaries beside the pair of 705 MB (3.85 GB
+    before, which took 76-88 s to compile)."""
+    from opentsdb_tpu.ops.interp import carry_form
+    from opentsdb_tpu.ops.pipeline import (PipelineSpec,
+                                           run_pipeline_avg_div)
+    s, b, g = 114_688, 768, 128
+    assert carry_form(b) == "loop"
+    spec = PipelineSpec(num_series=s, num_buckets=b, num_groups=g,
+                        ds_function="avg", agg_name="sum")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    # a month of hours passes int32 milliseconds: float32 offsets
+    compiled = run_pipeline_avg_div.lower(
+        shape((s, b), jnp.float32), shape((s, b), jnp.float32),
+        shape((b,), jnp.float32), shape((s,), jnp.int32),
+        (shape((), jnp.float32), shape((), jnp.float32)),
+        shape((), jnp.float32), spec=spec).compile()
+    text = compiled.as_text()
+    assert not _reversed_or_padded(text, s)
+    assert text.count(" while(") == 2
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 2 * s * b * 4
+    assert memory.temp_size_in_bytes < 2 << 30
