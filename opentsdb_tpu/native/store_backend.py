@@ -191,6 +191,14 @@ def load_library():
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int]
         bucket_grid.restype = ctypes.c_int64
+        lib.tss_bucket_columns.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_int]
+        lib.tss_bucket_columns.restype = ctypes.c_int
         lib.tss_parse_import.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_void_p,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
@@ -637,6 +645,48 @@ class NativeTimeSeriesStore:
         if num_points < 0:
             raise IndexError("invalid series id in bucket_grid")
         return int(num_points)
+
+    def bucket_columns(self, series_ids, start_ms: int, end_ms: int,
+                       t0: int, interval_ms: int, nbuckets: int, fn: str,
+                       wanted, cols: np.ndarray,
+                       masks: np.ndarray) -> np.ndarray:
+        """:meth:`bucket_grid`'s cells a bucket at a time, for a caller
+        that keeps a metric's whole buckets and lacks a few: bucket
+        ``wanted[w]`` of the window (indexes, strictly rising) is
+        written into ``cols[w]`` and ``masks[w]`` (``[len(wanted),
+        s_pad]``, float32 or float64, and bool), the bits
+        :meth:`bucket_grid` writes into that column of its grid; a
+        bucket the window cuts holds the points inside ``[start_ms,
+        end_ms]`` alone. One walk, one lock take a series, whatever is
+        wanted; the same walk returns each series' point count of the
+        whole window (:meth:`count_range`'s, NaN points counted)."""
+        if self.fault_injector is not None:
+            self.fault_injector.check(self.fault_site)
+        sids = np.ascontiguousarray(series_ids, dtype=np.int64)
+        wanted = np.ascontiguousarray(wanted, dtype=np.int64)
+        if cols.dtype not in (np.float32, np.float64) \
+                or masks.dtype != np.bool_ \
+                or cols.ndim != 2 or cols.shape != masks.shape \
+                or not cols.flags.c_contiguous \
+                or not masks.flags.c_contiguous \
+                or not (cols.flags.writeable and masks.flags.writeable):
+            raise ValueError(
+                "bucket_columns writes C-contiguous float32/float64 "
+                "columns and bool masks of one [wanted, s_pad] shape")
+        if cols.shape[0] != len(wanted) or cols.shape[1] < len(sids):
+            raise ValueError(
+                f"{cols.shape} columns cannot hold {len(wanted)} "
+                f"buckets of {len(sids)} series")
+        counts = np.empty(len(sids), dtype=np.int64)
+        rc = self._lib.tss_bucket_columns(
+            self._h, _ptr(sids), len(sids), start_ms, end_ms, t0,
+            interval_ms, nbuckets, _GRID_FN_CODES[fn], _ptr(wanted),
+            len(wanted), cols.shape[1], int(cols.dtype == np.float64),
+            _ptr(cols), _ptr(masks), _ptr(counts), self.threads)
+        if rc != 0:
+            raise IndexError(
+                "invalid series id or bucket in bucket_columns")
+        return counts
 
     def shards_of(self, series_ids: Iterable[int]) -> np.ndarray:
         return np.asarray([self._records[s].shard for s in series_ids],

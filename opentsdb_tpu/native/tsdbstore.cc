@@ -270,11 +270,15 @@ void parallel_for(int cap, int64_t items, int64_t grain, Body&& body) {
 // and a helper starts claiming ~0.3 ms after its wake: two participants
 // lose to one up to ~0.4 ms of work. So a grain is >= 0.25 ms at the
 // item's cost there: a grid row of 360 points 1.2 us, a bucket_reduce
-// row 0.85-1.05 us, a series of count_range 0.04 us, a copied point
+// row 0.85-1.05 us (a bucket_columns row of two cut buckets about a
+// quarter of that: PERF.md section 6, PR 50), a series of count_range
+// 0.04 us, a copied point
 // ~1 ns; an appended cell 40 ns and a MB of import text 6.5 ms, both
 // with more room because their participants fight over fresh memory
 // (vectors that grow, a group table a chunk that the merge walks alone).
 constexpr int64_t kGridRows = 256;         // bucket_grid: rows of the grid
+constexpr int64_t kColumnRows = 1024;      // bucket_columns: rows
+constexpr int64_t kColumnsAhead = 8;  // series whose buffer it prefetches
 constexpr int64_t kReduceRows = 256;       // bucket_reduce: series
 constexpr int64_t kCountSeries = 8192;     // count_range: series
 constexpr int64_t kFillPoints = 1 << 18;   // fill_range: points copied
@@ -586,12 +590,37 @@ namespace {
 // skipped, matching the device bucketize's NaN guard, ref:
 // Aggregators.runDouble skipping NaN), so cnt may be 0. mn/mx are
 // +inf/-inf unless MINMAX. Holds the buffer's lock for the walk.
+// One bucket's arithmetic, what every pass below shares so that a cell
+// is the same bits whichever pass wrote it: the f64 sum and count of the
+// non-NaN values of [v, ve) in stored order, with min / max when MINMAX
+// (+inf / -inf otherwise). A fixed-bound loop the compiler can
+// vectorize; the NaN guard is a branchless blend.
+template <bool MINMAX>
+inline void reduce_points(const double* v, const double* ve, double* sum,
+                          double* cnt, double* mn, double* mx) {
+  const double inf = std::numeric_limits<double>::infinity();
+  double s = 0.0, c = 0.0, lo = inf, hi = -inf;
+  for (; v < ve; ++v) {
+    double x = *v;
+    bool ok = x == x;
+    s += ok ? x : 0.0;
+    c += ok ? 1.0 : 0.0;
+    if (MINMAX) {
+      lo = (ok && x < lo) ? x : lo;
+      hi = (ok && x > hi) ? x : hi;
+    }
+  }
+  *sum = s;
+  *cnt = c;
+  *mn = lo;
+  *mx = hi;
+}
+
 template <bool MINMAX, typename Emit>
 inline void reduce_series(SeriesBuffer* buf, int64_t start_ms,
                           int64_t end_ms, int64_t t0,
                           int64_t interval_ms, int64_t nbuckets,
                           Emit&& emit) {
-  const double inf = std::numeric_limits<double>::infinity();
   std::lock_guard<std::mutex> lock(buf->mu);
   buf->ensure_sorted_locked();
   int64_t lo =
@@ -601,9 +630,8 @@ inline void reduce_series(SeriesBuffer* buf, int64_t start_ms,
       std::upper_bound(buf->ts.begin(), buf->ts.end(), end_ms) -
       buf->ts.begin();
   // timestamps are sorted: resolve each bucket's point range with
-  // a binary search, then accumulate over a fixed-bound inner loop
-  // the compiler can vectorize (no per-point divide or
-  // data-dependent exit). The NaN guard is a branchless blend.
+  // a binary search, then accumulate over reduce_points' fixed-bound
+  // loop (no per-point divide or data-dependent exit)
   const int64_t* tsd = buf->ts.data();
   const double* vd = buf->vals.data();
   int64_t p = lo;
@@ -620,17 +648,8 @@ inline void reduce_series(SeriesBuffer* buf, int64_t start_ms,
     if (b >= nbuckets) break;
     int64_t bucket_end = t0 + (b + 1) * interval_ms;
     int64_t pe = std::lower_bound(tsd + p, tsd + hi, bucket_end) - tsd;
-    double sum = 0.0, cnt = 0.0, mn = inf, mx = -inf;
-    for (int64_t q = p; q < pe; ++q) {
-      double v = vd[q];
-      bool ok = v == v;
-      sum += ok ? v : 0.0;
-      cnt += ok ? 1.0 : 0.0;
-      if (MINMAX) {
-        mn = (ok && v < mn) ? v : mn;
-        mx = (ok && v > mx) ? v : mx;
-      }
-    }
+    double sum, cnt, mn, mx;
+    reduce_points<MINMAX>(vd + p, vd + pe, &sum, &cnt, &mn, &mx);
     emit(b, sum, cnt, mn, mx);
     p = pe;
   }
@@ -677,6 +696,15 @@ void bucket_reduce_rows(const std::vector<SeriesBuffer*>& bufs,
 // result to the output type once.
 enum GridFn { kSum = 0, kCount = 1, kAvg = 2, kMin = 3, kMax = 4 };
 
+inline double grid_stat(int fn, double sum, double cnt, double mn,
+                        double mx) {
+  return fn == kSum     ? sum
+         : fn == kCount ? cnt
+         : fn == kAvg   ? sum / cnt
+         : fn == kMin   ? mn
+                        : mx;
+}
+
 // Rows [0, nsids) of the [s_pad, b_pad] grid are series, each written
 // once, left to right: NaN / 0 up to the next bucket with data, the
 // statistic / 1 there, NaN / 0 to the end of the padded row. Rows
@@ -704,12 +732,7 @@ int64_t bucket_grid_rows(const std::vector<SeriesBuffer*>& bufs,
               if (cnt == 0.0) return;  // only NaNs stored: a hole
               std::fill(grow + done, grow + b, nan);
               std::memset(mrow + done, 0, b - done);
-              double v = fn == kSum     ? sum
-                         : fn == kCount ? cnt
-                         : fn == kAvg   ? sum / cnt
-                         : fn == kMin   ? mn
-                                        : mx;
-              grow[b] = static_cast<T>(v);
+              grow[b] = static_cast<T>(grid_stat(fn, sum, cnt, mn, mx));
               mrow[b] = 1;
               done = b + 1;
               points += (int64_t)cnt;
@@ -721,6 +744,103 @@ int64_t bucket_grid_rows(const std::vector<SeriesBuffer*>& bufs,
     num_points.fetch_add(points, std::memory_order_relaxed);
   });
   return num_points.load();
+}
+
+// std::lower_bound over the sorted [b, e), begun where a series of even
+// cadence would hold `key` and widened from there by doubling: such a
+// series costs its first and last timestamp and the line of the guess,
+// where halving from the middle touches four or five lines of a
+// hundred points; any other series still ends in a halving search, of
+// the stretch the doubling fenced in.
+inline const int64_t* guessed_lower_bound(const int64_t* b, const int64_t* e,
+                                          int64_t key) {
+  if (b == e || key <= *b) return b;
+  const int64_t n = e - b;
+  const int64_t first = *b, last = e[-1];
+  if (key > last) return e;
+  // first < key <= last, so n >= 2 and last > first
+  const int64_t at = (int64_t)((double)(key - first) / (double)(last - first) *
+                               (double)(n - 1));
+  const int64_t* p = b + std::min(std::max<int64_t>(at, 0), n - 1);
+  int64_t step = 1;
+  if (*p < key) {  // the answer lies after p
+    const int64_t* lo = p + 1;
+    while (lo + step < e && lo[step - 1] < key) {
+      lo += step;
+      step <<= 1;
+    }
+    return std::lower_bound(lo, std::min(lo + step, e), key);
+  }
+  const int64_t* hi = p;  // *hi >= key: the answer lies at or before p
+  while (hi - step > b && hi[-step] >= key) {
+    hi -= step;
+    step <<= 1;
+  }
+  return std::lower_bound(std::max(hi - step, b), hi, key);
+}
+
+// tss_bucket_columns' walk. Participants claim chunks of rows; a chunk
+// first writes NaN / 0 down its stretch of every column (contiguous: a
+// column is [s_pad]), then each of its series takes its lock ONCE:
+// the window's two searches give the row's point count, and each wanted
+// bucket's range inside them is one search more (none where the bucket
+// before it was wanted too: its end is this one's beginning). The pass
+// is bound by the misses it takes a series (the buffer, then lines of
+// its timestamps and values), not by the points it reads: hence the
+// guessed searches, and the buffers of the series ahead fetched early.
+template <typename T, bool MINMAX>
+void bucket_columns_rows(const std::vector<SeriesBuffer*>& bufs,
+                         int64_t start_ms, int64_t end_ms, int64_t t0,
+                         int64_t interval_ms, int fn,
+                         const int64_t* wanted, int64_t nwanted,
+                         int64_t s_pad, T* cols, uint8_t* masks,
+                         int64_t* counts, int threads) {
+  const int64_t nsids = (int64_t)bufs.size();
+  const T nan = std::numeric_limits<T>::quiet_NaN();
+  parallel_for(threads, s_pad, kColumnRows, [&](int64_t r0, int64_t r1) {
+    for (int64_t w = 0; w < nwanted; ++w) {
+      std::fill(cols + w * s_pad + r0, cols + w * s_pad + r1, nan);
+      std::memset(masks + w * s_pad + r0, 0, r1 - r0);
+    }
+    const int64_t rows = std::min(r1, nsids);
+    for (int64_t i = r0; i < rows; ++i) {
+      if (i + kColumnsAhead < rows) {  // the buffer's two lines, early
+        const char* ahead = reinterpret_cast<const char*>(bufs[i + kColumnsAhead]);
+        __builtin_prefetch(ahead);
+        __builtin_prefetch(ahead + 64);
+      }
+      SeriesBuffer* buf = bufs[i];
+      std::lock_guard<std::mutex> lock(buf->mu);
+      buf->ensure_sorted_locked();
+      const int64_t* tsd = buf->ts.data();
+      const int64_t* tse = tsd + buf->ts.size();
+      const double* vd = buf->vals.data();
+      const int64_t* lo = guessed_lower_bound(tsd, tse, start_ms);
+      // upper_bound(end) of whole milliseconds
+      const int64_t* hi = end_ms == std::numeric_limits<int64_t>::max()
+                              ? tse
+                              : guessed_lower_bound(tsd, tse, end_ms + 1);
+      counts[i] = hi - lo;  // count_range's: NaN points counted
+      if (hi < lo) hi = lo;
+      const int64_t* pe = lo;
+      int64_t prev = -2;
+      for (int64_t w = 0; w < nwanted; ++w) {
+        const int64_t b = wanted[w];
+        const int64_t* p =
+            b == prev + 1 ? pe
+                          : guessed_lower_bound(pe, hi, t0 + b * interval_ms);
+        pe = guessed_lower_bound(p, hi, t0 + (b + 1) * interval_ms);
+        prev = b;
+        if (p == pe) continue;
+        double sum, cnt, mn, mx;
+        reduce_points<MINMAX>(vd + (p - tsd), vd + (pe - tsd), &sum, &cnt,
+                              &mn, &mx);
+        if (cnt == 0.0) continue;  // only NaNs stored: a hole
+        cols[w * s_pad + i] = static_cast<T>(grid_stat(fn, sum, cnt, mn, mx));
+        masks[w * s_pad + i] = 1;
+      }
+    }
+  });
 }
 
 }  // namespace
@@ -786,6 +906,48 @@ int64_t tss_bucket_grid(void* h, const int64_t* sids, int64_t nsids,
     return minmax ? TSS_GRID(double, true) : TSS_GRID(double, false);
   return minmax ? TSS_GRID(float, true) : TSS_GRID(float, false);
 #undef TSS_GRID
+}
+
+// tss_bucket_grid's buckets ONE AT A TIME, for a caller that keeps a
+// metric's whole buckets and asks only for those it lacks: the same
+// statistic of each of the `nwanted` buckets `wanted` (indexes into the
+// window's nbuckets, strictly rising), written column-major into
+// [nwanted, s_pad] values (f32, f64 when out_f64) and masks, NaN / 0
+// in the holes and in rows [nsids, s_pad). A bucket the window cuts
+// holds the points inside [start_ms, end_ms] alone. counts_out[i] is
+// series i's point count of the whole window (tss_count_range's: the
+// two searches the walk makes anyway). One lock take a series. Returns
+// 0, -1 on a bad sid or bad dimensions.
+int tss_bucket_columns(void* h, const int64_t* sids, int64_t nsids,
+                       int64_t start_ms, int64_t end_ms, int64_t t0,
+                       int64_t interval_ms, int64_t nbuckets, int fn,
+                       const int64_t* wanted, int64_t nwanted,
+                       int64_t s_pad, int out_f64, void* cols_out,
+                       uint8_t* masks_out, int64_t* counts_out,
+                       int threads) {
+  Store* s = static_cast<Store*>(h);
+  std::vector<SeriesBuffer*> bufs;
+  if (!s->snapshot(sids, nsids, &bufs)) return -1;
+  if (interval_ms <= 0 || nbuckets <= 0 || s_pad < nsids || nwanted < 0 ||
+      fn < kSum || fn > kMax)
+    return -1;
+  for (int64_t w = 0; w < nwanted; ++w)
+    if (wanted[w] < 0 || wanted[w] >= nbuckets ||
+        (w > 0 && wanted[w] <= wanted[w - 1]))
+      return -1;
+  const bool minmax = fn == kMin || fn == kMax;
+#define TSS_COLUMNS(T, MM)                                             \
+  bucket_columns_rows<T, MM>(bufs, start_ms, end_ms, t0, interval_ms,  \
+                             fn, wanted, nwanted, s_pad,               \
+                             static_cast<T*>(cols_out), masks_out,     \
+                             counts_out, threads)
+  if (out_f64) {
+    if (minmax) TSS_COLUMNS(double, true); else TSS_COLUMNS(double, false);
+  } else {
+    if (minmax) TSS_COLUMNS(float, true); else TSS_COLUMNS(float, false);
+  }
+#undef TSS_COLUMNS
+  return 0;
 }
 
 }  // extern "C"

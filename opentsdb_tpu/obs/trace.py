@@ -769,7 +769,7 @@ class Tracer:
         self.observations = 0
         # programs dispatched, by (path, placement, class)
         # tsdlint: allow[unbounded-growth] keyed by run_staged's
-        # tags: the six paths its callers name x two placements x
+        # tags: the seven paths its callers name x two placements x
         # two classes of group stage (rank | linear)
         self.tails: dict[tuple[str, str, str], int] = {}
         # the rank class's programs, by how their group stage reads
@@ -785,10 +785,12 @@ class Tracer:
         self.grid_builds = {"fused": 0, "host": 0}
         # HBM cache look-ups of grid sub-queries, by what answered:
         # "resident_hit" (the metric's resident grid was there),
-        # "resident_built" (this request built it) or "selection"
-        # (a grid of the request's own rows, keyed by their digest)
+        # "resident_built" (this request built it whole),
+        # "resident_columns" (this request put it together from the
+        # metric's per-bucket columns) or "selection" (a grid of the
+        # request's own rows, keyed by their digest)
         self.grids = {"resident_hit": 0, "resident_built": 0,
-                      "selection": 0}
+                      "resident_columns": 0, "selection": 0}
         # plan stages, by what the engine's plan index did for them:
         # "hit" (planned from the cached index), "built" (built it
         # first), "bypass" (a selection that is not a whole metric)

@@ -239,6 +239,30 @@ def run_pipeline_grid(grid, has_data, bucket_ts, group_ids, rate_params,
                             rate_params, fill_value, spec)
 
 
+@partial(jax.jit, static_argnames=("spec",))
+def run_pipeline_columns(cols, masks, bucket_ts, group_ids, rate_params,
+                         fill_value, spec: PipelineSpec):
+    """:func:`run_pipeline_grid` over a metric's buckets held a column
+    each (``cols`` and ``masks``: as many ``[S]`` vectors as the
+    window has buckets, some resident in HBM, some this request's
+    own): the ``[S, B]`` grid and mask are put together here, under
+    the scope ``tail.assemble_columns``, in the module that runs the
+    tail, so a request over columns still executes ONE module. Returns
+    ``(result, emit, grid, has_data)``: the assembled operands come
+    back for the caller to keep (they are on the device already), so
+    the same window asked again runs :func:`run_pipeline_grid` over
+    them."""
+    with jax.named_scope("tail.assemble_columns"):
+        pad = spec.num_buckets - len(cols)
+        grid = jnp.stack(
+            cols + (jnp.full_like(cols[0], jnp.nan),) * pad, axis=1)
+        has_data = jnp.stack(
+            masks + (jnp.zeros_like(masks[0]),) * pad, axis=1)
+    return (*_finish_pipeline(grid, has_data, bucket_ts, group_ids,
+                              rate_params, fill_value, spec),
+            grid, has_data)
+
+
 def pipeline_dtype():
     """The compute dtype every host entry uses (f64 only under x64)."""
     return jnp.float64 if jax.config.read("jax_enable_x64") \
@@ -271,7 +295,8 @@ def host_cpu_device():
 
 
 def run_staged(path: str, program, operands,
-               spec: PipelineSpec | None = None, download=np.asarray):
+               spec: PipelineSpec | None = None, download=np.asarray,
+               stays: int = 0):
     """Upload, run, download: the one way a host entry reaches a
     compiled program, so that every path names the same three stages.
 
@@ -296,7 +321,8 @@ def run_staged(path: str, program, operands,
     JAX compiled (or loaded from its cache) inside it; a
     device-placed program occupies :data:`RUNTIME`'s clock for that
     stretch. ``query.download`` is ``download`` (``np.asarray``) of
-    each output. ``spec`` defaults to the :class:`PipelineSpec` among
+    each output but the last ``stays``, which are handed back as the
+    device arrays they are. ``spec`` defaults to the :class:`PipelineSpec` among
     the operands."""
     with trace_span("query.upload"):
         args = operands()
@@ -326,6 +352,9 @@ def run_staged(path: str, program, operands,
         if span is not None and RUNTIME.compiles != compiles:
             span.tag(compiled=True)
     with trace_span("query.download"):
+        if stays:
+            return (*jax.tree_util.tree_map(download, out[:-stays]),
+                    *out[-stays:])
         return jax.tree_util.tree_map(download, out)
 
 
@@ -381,6 +410,19 @@ def _bucket_dims_and_aux(bucket_ts, group_ids, spec: PipelineSpec,
         spec, num_series=s_pad, num_buckets=b_pad, num_groups=g_pad)
 
 
+def _tail_operands(bts, gids, ro: RateOptions, pspec: PipelineSpec,
+                   dtype) -> tuple:
+    """What every grid-shaped program takes after its grids: the
+    padded bucket timestamps and group ids, the rate's parameters, the
+    fill value and the padded spec, as numpy (they ride along with the
+    committed operands: no eager default-device round trips)."""
+    return (as_operand(device_bucket_ts(bts)),
+            as_operand(gids, np.int32),
+            (as_operand(ro.counter_max, dtype),
+             as_operand(ro.reset_value, dtype)),
+            as_operand(pspec.fill_value, dtype), pspec)
+
+
 def bucket_grid_shapes(grid, has_data, bucket_ts, group_ids,
                        spec: PipelineSpec):
     """Pad (S, B, G) up to geometric shape buckets (ops.shapes) so
@@ -414,19 +456,56 @@ def execute_grid(grid: np.ndarray, has_data: np.ndarray,
             grid if isinstance(grid, jax.Array) else np.asarray(grid),
             has_data if isinstance(has_data, jax.Array)
             else np.asarray(has_data), bucket_ts, group_ids, spec)
-        # the grid is the committed operand deciding placement;
-        # everything else rides along as numpy (no eager
-        # default-device round trips)
+        # the grid is the committed operand deciding placement
         return (put(as_operand(gp, dtype)), put(as_operand(hp, bool)),
-                as_operand(device_bucket_ts(bts)),
-                as_operand(gids, np.int32),
-                (as_operand(ro.counter_max, dtype),
-                 as_operand(ro.reset_value, dtype)),
-                as_operand(spec.fill_value, dtype), pspec)
+                *_tail_operands(bts, gids, ro, pspec, dtype))
 
     result, emit = run_staged("grid", run_pipeline_grid, operands)
     rows = s if spec.emit_raw else g
     return result[:rows, :b], emit[:rows, :b]
+
+
+def put_columns(cols: np.ndarray, masks: np.ndarray, device=None):
+    """Upload the ``[wanted, S]`` columns and masks a storage pass
+    wrote, a pair of ``[S]`` device arrays a bucket, in the compute
+    dtype: what :func:`execute_columns` runs over and the HBM cache
+    keeps a bucket at a time."""
+    dtype = pipeline_dtype()
+    with trace_span("query.upload"):
+        # one call for all of them: a transfer a column, one dispatch
+        up = jax.device_put(
+            [as_operand(c, dtype) for c in cols]
+            + [as_operand(m, bool) for m in masks], device=device)
+    return list(zip(up[:len(cols)], up[len(cols):]))
+
+
+def execute_columns(columns, bucket_ts: np.ndarray,
+                    group_ids: np.ndarray, spec: PipelineSpec,
+                    rate_options: RateOptions | None = None,
+                    dtype=None, device=None):
+    """:func:`execute_grid` over ``columns``, a ``(values, mask)`` pair
+    of padded ``[S]`` vectors a bucket (:func:`put_columns`) ->
+    ``(result, emit, grid, has_data)``: the last two are the assembled
+    padded operands, left on the device
+    (:func:`run_pipeline_columns`)."""
+    if dtype is None:
+        dtype = pipeline_dtype()
+    ro = rate_options or RateOptions()
+    s, b, g = spec.num_series, spec.num_buckets, spec.num_groups
+
+    def operands():
+        _, _, bts, gids, pspec = _bucket_dims_and_aux(
+            bucket_ts, group_ids, spec, s, b)
+        grids = (tuple(as_operand(c, dtype) for c, _ in columns),
+                 tuple(as_operand(m, bool) for _, m in columns))
+        if device is not None:  # elsewhere than where they lie
+            grids = jax.device_put(grids, device)
+        return (*grids, *_tail_operands(bts, gids, ro, pspec, dtype))
+
+    result, emit, grid, has_data = run_staged(
+        "columns", run_pipeline_columns, operands, stays=2)
+    rows = s if spec.emit_raw else g
+    return result[:rows, :b], emit[:rows, :b], grid, has_data
 
 
 def avg_divide_grid(grid_sum, grid_cnt, xp=jnp):
@@ -477,11 +556,7 @@ def execute_avg_divide(grid_sum, grid_cnt, bucket_ts: np.ndarray,
         gcnt = _pad_2d(grid_cnt, s_pad, b_pad, np.nan)
         return (put(as_operand(gsum, dtype)),
                 put(as_operand(gcnt, dtype)),
-                as_operand(device_bucket_ts(bts_p)),
-                as_operand(gids_p, np.int32),
-                (as_operand(ro.counter_max, dtype),
-                 as_operand(ro.reset_value, dtype)),
-                as_operand(spec.fill_value, dtype), pspec)
+                *_tail_operands(bts_p, gids_p, ro, pspec, dtype))
 
     result, emit = run_staged("avg_div", run_pipeline_avg_div, operands)
     rows = s if spec.emit_raw else g
@@ -600,6 +675,21 @@ class PreparedBatch:
     @property
     def nbytes(self) -> int:
         return sum(getattr(a, "nbytes", 0) for a in self.arrays)
+
+
+@dataclass(frozen=True)
+class GridColumns:
+    """A window's operands as :func:`execute_columns` takes them: a
+    ``(values, mask)`` pair of padded ``[S]`` device vectors a bucket.
+    What the HBM cache holds under a window's key from the request
+    that put the window together out of the metric's columns until its
+    program has assembled the grid (most of the pairs are the cache's
+    own per-bucket entries, counted here again)."""
+    columns: tuple
+
+    @property
+    def arrays(self) -> tuple:
+        return tuple(x for pair in self.columns for x in pair)
 
 
 def _pad_rows(arr2d: np.ndarray, s_pad: int, fill) -> np.ndarray:

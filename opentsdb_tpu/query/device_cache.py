@@ -60,9 +60,45 @@ mesh, not ``aggregator=none``, not ``delete``, the grid within the
 cell budget and this cache's bytes, and at least half of the metric's
 rows selected (``engine.RESIDENT_GRID_MIN_SHARE``: the tail costs the
 METRIC's rows, the scan, digest and upload it replaces the
-SELECTION's; the measured crossover is in PERF.md section 5). The
-window is in the key, so ``end=now`` traffic builds anew when it moves
-(time-blocked columns are the next step).
+SELECTION's; the measured crossover is in PERF.md section 5).
+
+The kind has two levels, so that a window asked again and a window
+that moves both find what they can reuse. The first look-up is the
+window's key above, and a hit is the whole story: one look-up, the
+resident grid, the grid program. Only its miss goes to the second
+level, ``metriccol`` (``QueryEngine._metric_columns``): ONE bucket of
+the metric, the statistic of each padded row and its presence mask as
+two ``[series]`` vectors, under (store, metric, plan-index version,
+interval, downsample function, the bucket's start in ms) and NO
+window. Buckets are aligned to the epoch, so a bucket that lies whole
+inside a window holds the same cells in every window that holds it
+whole: ``end=now`` traffic finds all of its buckets but the two its
+window cuts. Those are computed from the points inside ``[start,
+end]`` by the request itself and never kept, by the same storage pass
+(``bucket_columns``, one walk a request) that builds whatever whole
+bucket was not there and counts every row's points of the window. The
+columns of one (metric, interval, function) are looked up and built
+under ONE flight (:meth:`DeviceGridCache.resident_columns`), not a
+flight a column: requests whose windows share some columns cannot each
+hold a few and wait for the other's; with every column there, nobody
+waits. The version is read once, before any column is looked up or
+the store is scanned, and stamps every column built; each column
+counts as a hit or a miss of this cache like any entry. The window's
+own entry is first the columns themselves (``ops.pipeline
+.GridColumns``: the resident ones and the two cut ones, so the second
+sub-query of a request waits for the first's build and hits, as
+ever); the program that runs over them assembles the padded grid in
+the same module as the tail and hands it back, and that grid takes
+the columns' place under the window's key (:meth:`DeviceGridCache
+.replace`: no bytes move). A store without the pass, or a window of
+more than ``engine.RESIDENT_COLUMNS_MAX_BUCKETS`` buckets (a column
+is an operand and an entry each), builds the window whole in one
+row-major pass, as before the second level. **What a write drops:**
+everything of the store, both levels: the version rule knows no
+finer grain, so the request behind a write builds every column again
+in one pass (an append at the head that leaves older columns resident
+needs the store's word on the oldest timestamp written since a
+version: PERF.md section 7).
 
 ``grid`` (``QueryEngine._grid_pipeline``): the grid of one request's
 own rows, keyed by a digest of its series ids: every other
@@ -176,21 +212,65 @@ class DeviceGridCache:
         hit = self._hit(key, version_of())
         if hit is not None:
             return (*hit, HIT)
+        with self._flight(key), _build_turn(key):
+            version = version_of()
+            hit = self.get(key, version)
+            if hit is not None:
+                return (*hit, HIT)
+            arrays, meta = build()
+            kept = arrays is not None \
+                and self.put(key, version, arrays, meta)
+            return arrays, meta, BUILT if kept else NOT_KEPT
+
+    def resident_columns(self, flight_key, keys, version, build):
+        """``(columns, rest)``: the arrays under each of ``keys`` (one
+        (metric, interval, function)'s per-bucket columns), every one
+        of ``version``, and what ``build`` made beside them.
+
+        ``build(missing) -> (built, rest)`` makes the arrays of the
+        keys at the indexes ``missing`` (what is not there, or is of
+        another version) in ONE pass, with whatever else the caller
+        wants of that pass (``rest``: a moving window's cut buckets
+        and counts); each is kept under its key. All of it happens
+        under the ONE flight ``flight_key``, not a flight a column:
+        two requests whose windows share some columns cannot each
+        hold a few and wait for the other's. With every key there,
+        nobody waits: ``build(())`` runs outside the flight. Each key
+        counts as a hit or a miss, like any entry's."""
+        def arrays_of(hit):
+            return None if hit is None else hit[0]
+
+        found = [arrays_of(self._hit(key, version)) for key in keys]
+        if None in found:
+            with self._flight(flight_key):
+                missing = []
+                for i, key in enumerate(keys):
+                    if found[i] is None:
+                        found[i] = arrays_of(self.get(key, version))
+                        if found[i] is None:
+                            missing.append(i)
+                if missing:
+                    built, rest = build(tuple(missing))
+                    for i, arrays in zip(missing, built):
+                        self.put(keys[i], version, arrays, {})
+                        found[i] = arrays
+                    return found, rest
+        # every column was there, or another request built the missing
+        # ones while this one waited: nothing to build but the rest
+        return found, build(())[1]
+
+    @contextlib.contextmanager
+    def _flight(self, key):
+        """Hold ``key``'s flight: one holder at a time, the others
+        wait; the flight is gone when nobody is inside or waiting."""
         with self._lock:
             flight = self._flights.get(key)
             if flight is None:
                 flight = self._flights[key] = [threading.Lock(), 0]
             flight[1] += 1
         try:
-            with flight[0], _build_turn(key):
-                version = version_of()
-                hit = self.get(key, version)
-                if hit is not None:
-                    return (*hit, HIT)
-                arrays, meta = build()
-                kept = arrays is not None \
-                    and self.put(key, version, arrays, meta)
-                return arrays, meta, BUILT if kept else NOT_KEPT
+            with flight[0]:
+                yield
         finally:
             with self._lock:
                 flight[1] -= 1
@@ -239,14 +319,32 @@ class DeviceGridCache:
         if nbytes > self.max_bytes:
             return False
         with self._lock:
-            old = self._entries.pop(key, None)
-            if old is not None:
-                self._bytes -= old[3]
-            self._entries[key] = (version, arrays, meta, nbytes)
-            self._bytes += nbytes
-            while self._bytes > self.max_bytes and self._entries:
-                _, (_, _, _, nb) = self._entries.popitem(last=False)
-                self._bytes -= nb
+            self._put_locked(key, version, arrays, meta, nbytes)
+        return True
+
+    def _put_locked(self, key, version, arrays, meta, nbytes) -> None:
+        old = self._entries.pop(key, None)
+        if old is not None:
+            self._bytes -= old[3]
+        self._entries[key] = (version, arrays, meta, nbytes)
+        self._bytes += nbytes
+        while self._bytes > self.max_bytes and self._entries:
+            _, (_, _, _, nb) = self._entries.popitem(last=False)
+            self._bytes -= nb
+
+    def replace(self, key, old: tuple, arrays: tuple, meta: dict) -> bool:
+        """Put ``arrays`` and ``meta`` where ``key`` holds ``old`` (the
+        very tuple), under the version ``old`` was kept with: a form of
+        the same operands that is cheaper to read. False, and nothing
+        done, where the entry is gone or holds something else (a write
+        dropped it, another request rebuilt it)."""
+        nbytes = sum(self._entry_nbytes(a) for a in arrays)
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None or entry[1] is not old \
+                    or nbytes > self.max_bytes:
+                return False
+            self._put_locked(key, entry[0], arrays, meta, nbytes)
         return True
 
     def bytes_of(self, kind) -> int:

@@ -160,12 +160,16 @@ class NoSuchMetricError(BadRequestError):
 
 #: first item of the HBM cache's key for a metric's resident grid
 RESIDENT_GRID_KEY = "metricgrid"
+#: and for ONE whole bucket of a metric, the column such a grid is
+#: assembled from when its window is not resident
+RESIDENT_COLUMN_KEY = "metriccol"
 #: first item of the key of a rollup average's tier pair
 TIER_PAIR_KEY = "avgdiv"
 #: the ``grid`` tag of the ``query.grid_build stage=cache_lookup`` span,
-#: (a hit's, a build's): of a metric's resident entry, of a request's
-#: own rows
+#: (a hit's, a build's): of a metric's resident entry built whole, of
+#: one assembled from the metric's columns, of a request's own rows
 _LOOKUP_RESIDENT = ("resident_hit", "resident_built")
+_LOOKUP_COLUMNS = ("resident_hit", "resident_columns")
 _LOOKUP_SELECTION = ("selection", "selection")
 
 
@@ -203,6 +207,10 @@ HOST_TAIL_DEFAULT_CELLS_LINEAR = 1 << 16
 # over a grid of its own rows: the measured costs behind the half are
 # with the ``metricgrid`` kind in query/device_cache.py
 RESIDENT_GRID_MIN_SHARE = 0.5
+# the most buckets of a window whose resident grid is assembled from
+# per-bucket columns: a column is an operand of the program and an
+# entry of the cache, and past this a window is built whole
+RESIDENT_COLUMNS_MAX_BUCKETS = 128
 
 
 def _rank_class_agg(agg_name: str) -> bool:
@@ -1423,37 +1431,121 @@ class QueryEngine:
         for ``rows``, the request's ``num_selected`` rows of
         ``metric_sids``. The entry keeps each row's point count of the
         window, so the limits' check and the scan's stat points are
-        the selection's, as on the path it replaces."""
-        from opentsdb_tpu.ops.pipeline import put_grid
+        the selection's, as on the path it replaces.
+
+        A window that is not resident is built from the metric's
+        per-bucket columns (:meth:`_metric_columns`) where the store
+        has the pass for them, else whole: two builds that share the
+        contract and no logic. The first gives the entry as
+        :class:`~opentsdb_tpu.ops.pipeline.GridColumns`, ``meta``
+        naming its key under ``columns_of``: the program that runs
+        over it assembles the grid, which then takes its place
+        (:meth:`_grid_pipeline`)."""
+        from opentsdb_tpu.ops.pipeline import GridColumns, put_grid
+        metric_id = store.series(int(metric_sids[0])).metric_id
 
         def key_of():
-            return (_store_id(store),
-                    store.series(int(metric_sids[0])).metric_id,
+            return (_store_id(store), metric_id,
                     len(metric_sids), tsq.start_ms, tsq.end_ms,
                     int(bucket_ts[0]), interval_ms, len(bucket_ts), fn)
 
         def points_of(meta) -> int:
             return int(meta["counts"][rows].sum())
 
-        def build(_checked):
-            meta = {"counts": store.count_range(
-                metric_sids, tsq.start_ms, tsq.end_ms)}
+        def scanned(meta):
             # the pass reads the metric; the request answers for its
             # own rows of it, as a hit will
+            return lambda scan, _: self._record_scan(
+                stats, scan, points_of(meta), num_selected)
+
+        def build_whole(_checked):
+            meta = {"counts": store.count_range(
+                metric_sids, tsq.start_ms, tsq.end_ms)}
             grid, has_data, _ = self._reduce_to_grid(
                 store, metric_sids, tsq, bucket_ts, interval_ms,
-                GRID_STATS[fn],
-                lambda scan, _: self._record_scan(
-                    stats, scan, points_of(meta), num_selected))
+                GRID_STATS[fn], scanned(meta))
             # a window without a point: nothing to keep. Else kept
             # before THIS selection is checked: others read the entry
             return (put_grid(grid, has_data)
                     if meta["counts"].any() else None), meta
 
+        def build_columns(_checked):
+            meta = {"columns_of": (RESIDENT_GRID_KEY, *key_of())}
+            columns = self._metric_columns(
+                cache, store, metric_sids, metric_id, tsq, bucket_ts,
+                interval_ms, fn, meta, scanned(meta))
+            return ((GridColumns(columns),)
+                    if meta["counts"].any() else None), meta
+
+        by_column = hasattr(store, "bucket_columns") \
+            and len(bucket_ts) <= RESIDENT_COLUMNS_MAX_BUCKETS
         return self._resident_operands(
-            cache, RESIDENT_GRID_KEY, _LOOKUP_RESIDENT, key_of,
-            (store,), build, stats, metric_name, num_selected,
-            points_of=points_of)
+            cache, RESIDENT_GRID_KEY,
+            _LOOKUP_COLUMNS if by_column else _LOOKUP_RESIDENT, key_of,
+            (store,), build_columns if by_column else build_whole,
+            stats, metric_name, num_selected, points_of=points_of)
+
+    def _metric_columns(self, cache, store, metric_sids: np.ndarray,
+                        metric_id: int, tsq: TSQuery,
+                        bucket_ts: np.ndarray, interval_ms: int,
+                        fn: str, meta: dict, scanned) -> tuple:
+        """The window's buckets of the whole metric, a ``(values,
+        mask)`` pair of padded ``[series]`` device vectors each, and
+        ``meta["counts"]``, each row's point count of the window.
+
+        A bucket that lies whole inside the window does not depend on
+        the window: its column stays in HBM (``metriccol`` in
+        :mod:`~opentsdb_tpu.query.device_cache`; buckets are aligned
+        to the epoch, so the next window's are the same cells), under
+        the version read here, before anything is looked up or
+        scanned. The buckets the window cuts (its first, its last)
+        are this request's own and are not kept. ONE storage pass
+        (``store.bucket_columns``) writes what is wanted, the cut
+        buckets and the whole ones that were not there, and counts
+        every row's points of the window on its way;
+        ``scanned(scan, num_points)`` closes it. The
+        ``query.grid_build stage=columns`` span is the look-ups and
+        the wait for another request's build, tagged with the columns
+        that ``hit``, were ``built`` and were ``cut``."""
+        from opentsdb_tpu.ops import shapes
+        from opentsdb_tpu.ops.pipeline import pipeline_dtype, put_columns
+        version = device_cache.store_version(store)
+        b = len(bucket_ts)
+        s_pad = shapes.shape_bucket(len(metric_sids))
+        dtype = np.dtype(pipeline_dtype())
+        starts = [int(t) for t in bucket_ts]
+        whole = [k for k, t in enumerate(starts) if t >= tsq.start_ms
+                 and t + interval_ms - 1 <= tsq.end_ms]
+        cut = sorted(set(range(b)).difference(whole))
+        group = (RESIDENT_COLUMN_KEY, _store_id(store), metric_id,
+                 len(metric_sids), interval_ms, fn)
+        span = trace_begin("query.grid_build", stage="columns")
+
+        def build(missing):
+            wanted = sorted(cut + [whole[i] for i in missing])
+            if span is not None:
+                span.tag(hit=len(whole) - len(missing),
+                         built=len(missing), cut=len(cut))
+                span.finish()
+            cells = len(wanted) * s_pad
+            with trace_span("query.grid_build", stage="alloc",
+                            fused=True, cells=cells,
+                            bytes=cells * (dtype.itemsize + 1)):
+                cols = np.empty((len(wanted), s_pad), dtype)
+                masks = np.empty((len(wanted), s_pad), np.bool_)
+            scan = self._scan_begin()
+            meta["counts"] = store.bucket_columns(
+                metric_sids, tsq.start_ms, tsq.end_ms, starts[0],
+                interval_ms, b, GRID_STATS[fn], wanted, cols, masks)
+            scanned(scan, None)
+            up = dict(zip(wanted, put_columns(cols, masks)))
+            return [up[whole[i]] for i in missing], \
+                {k: up[k] for k in cut}
+
+        kept, own = cache.resident_columns(
+            group, [(*group, starts[k]) for k in whole], version, build)
+        columns = {**dict(zip(whole, kept)), **own}
+        return tuple(columns[k] for k in range(b))
 
     def _grid_pipeline(self, store, sids: np.ndarray, tsq: TSQuery,
                        sub: TSSubQuery, metric_name: str,
@@ -1609,6 +1701,28 @@ class QueryEngine:
             rows = len(sids) if emit_raw else num_groups
             result = result[:rows, :len(bucket_ts)]
             emit = emit[:rows, :len(bucket_ts)]
+        elif "columns_of" in meta:
+            # the metric's buckets a column each (_resident_grid): the
+            # program puts the grid together, and the grid takes the
+            # columns' place under the window's key, where the same
+            # window asked again finds it as any resident grid
+            from opentsdb_tpu.ops.pipeline import execute_columns
+            (operand,) = arrays
+
+            def over_columns(spec, device):
+                result, emit, grid, has_data = execute_columns(
+                    operand.columns, bucket_ts, tail_gids, spec,
+                    sub.rate_options, device=device)
+                if device is None:
+                    cache.replace(meta["columns_of"], arrays,
+                                  (grid, has_data),
+                                  {"counts": meta["counts"]})
+                return result, emit
+
+            result, emit = self._run_device(
+                lambda: over_columns(spec, None),
+                lambda: over_columns(replace(spec, host=True),
+                                     self._host_cpu()))
         else:
             from opentsdb_tpu.ops.pipeline import execute_grid
             grid, has_data = arrays

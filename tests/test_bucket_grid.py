@@ -193,6 +193,211 @@ def test_a_wide_grid_is_claimed_in_chunks_by_every_worker():
         assert same_bits(grid, want) and same_bits(has_data, want_mask)
 
 
+# ---------------------------------------------------------------------
+# the column pass (PR 50): bucket_grid's cells a bucket at a time
+# ---------------------------------------------------------------------
+
+WANTED = {"one": lambda b: [b // 2], "two": lambda b: [0, b - 1],
+          "all": lambda b: list(range(b))}
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64],
+                         ids=["f32", "f64"])
+@pytest.mark.parametrize("wanted", sorted(WANTED))
+@pytest.mark.parametrize("fn", sorted(GRID_STATS))
+@pytest.mark.parametrize("name", SCENARIOS)
+def test_the_column_pass_writes_the_wanted_columns_of_that_grid(
+        name, fn, wanted, dtype):
+    """``bucket_columns`` against the twin's ``bucket_reduce`` (through
+    the composition ``bucket_grid`` is held to above), for every
+    downsample function the grid serves: column ``w`` of the pass is
+    column ``wanted[w]`` of the grid, bit for bit, rows beyond the
+    series NaN / False (``s_pad`` > series in every scenario but
+    ``no_pad``), and the counts are ``count_range``'s."""
+    series, (start, end, t0, interval, b, s_pad, _) = scenario(name)
+    stat = GRID_STATS[fn]
+    twin = TimeSeriesStore(num_shards=4)
+    native = store_backend.NativeTimeSeriesStore(num_shards=4)
+    sids = load(twin, series)
+    load(native, series)
+    reduced = twin.bucket_reduce(sids, start, end, t0, interval, b,
+                                 want_minmax=stat in ("min", "max"))
+    want, want_mask = replaced_composition(stat, *reduced, s_pad, b,
+                                           dtype)
+    which = WANTED[wanted](b)
+    cols = np.full((len(which), s_pad), 12345.0, dtype)
+    masks = np.ones((len(which), s_pad), np.bool_)
+    counts = native.bucket_columns(sids, start, end, t0, interval, b,
+                                   stat, which, cols, masks)
+    assert same_bits(cols, np.ascontiguousarray(want[:, which].T))
+    assert same_bits(masks, np.ascontiguousarray(want_mask[:, which].T))
+    assert counts.dtype == np.int64
+    np.testing.assert_array_equal(
+        counts, twin.count_range(sids, start, end))
+    np.testing.assert_array_equal(
+        counts, native.count_range(sids, start, end))
+
+
+def test_columns_of_many_rows_are_claimed_in_chunks_by_every_worker():
+    """More rows than one claim (1,024), more workers than one, pad
+    rows beyond the last chunk boundary, a cut bucket at each end."""
+    rng = np.random.default_rng(50)
+    s, b, s_pad = 2500, 12, 3072
+    ts = BASE_MS + np.arange(72) * STEP
+    native = store_backend.NativeTimeSeriesStore(
+        num_shards=4, materialize_threads=8)
+    series = []
+    for _ in range(s):
+        keep = rng.random(72) >= 0.1
+        series.append((ts[keep], _values(rng, int(keep.sum()))))
+    sids = load(native, series)
+    window = (sids, BASE_MS + 25_000, BASE_MS + 72 * STEP - 15_001,
+              BASE_MS, INTERVAL, b)
+    grid = np.empty((s_pad, b), np.float32)
+    has_data = np.empty((s_pad, b), np.bool_)
+    native.bucket_grid(*window, "avg", grid, has_data)
+    for which in ([0, b - 1], [0, 4, 5, b - 1], list(range(b))):
+        cols = np.full((len(which), s_pad), 12345.0, np.float32)
+        masks = np.ones((len(which), s_pad), np.bool_)
+        counts = native.bucket_columns(*window, "avg", which, cols,
+                                       masks)
+        assert same_bits(cols, np.ascontiguousarray(grid[:, which].T))
+        assert same_bits(masks,
+                         np.ascontiguousarray(has_data[:, which].T))
+        np.testing.assert_array_equal(
+            counts, native.count_range(*window[:3]))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_column_pass_finds_its_ranges_in_series_of_any_cadence(seed):
+    """The pass begins each search where a series of even cadence
+    would hold the key and widens from there: series whose points
+    bunch, thin out a thousandfold, lie all on one side of the window
+    or number one or two must give the ranges a halving search gives
+    (``bucket_grid``'s and ``count_range``'s)."""
+    rng = np.random.default_rng(500 + seed)
+    native = store_backend.NativeTimeSeriesStore(num_shards=4)
+    span = 3_600_000
+    series = []
+    for i in range(64):
+        n = int(rng.choice([1, 2, 3, 40, 400]))
+        kind = i % 4
+        if kind == 0:       # gaps over three decades
+            gaps = np.exp(rng.uniform(0, 7, n)).astype(np.int64) + 1
+            ts = BASE_MS + np.cumsum(gaps) * 50
+        elif kind == 1:     # nearly all in one minute, a few far off
+            ts = BASE_MS + np.sort(np.concatenate([
+                rng.integers(1_700_000, 1_760_000, max(n - 2, 1)),
+                rng.integers(0, span, 2)]))
+        elif kind == 2:     # even, at the series' own phase
+            ts = BASE_MS + int(rng.integers(0, 60_000)) \
+                + np.arange(n) * (span // n)
+        else:               # all before or all after most windows
+            ts = BASE_MS + (0 if i % 8 == 3 else span - 100_000) \
+                + np.sort(rng.integers(0, 100_000, n))
+        ts = np.unique(ts)
+        series.append((ts, _values(rng, len(ts))))
+    sids = load(native, series)
+    for _ in range(12):
+        interval = int(rng.choice([60_000, 300_000]))
+        start = BASE_MS - 100_000 + int(rng.integers(0, span))
+        end = start + int(rng.integers(0, span // 2))
+        t0 = start // interval * interval
+        b = (end - t0) // interval + 1
+        grid = np.empty((64, b), np.float64)
+        has_data = np.empty((64, b), np.bool_)
+        native.bucket_grid(sids, start, end, t0, interval, b, "avg",
+                           grid, has_data)
+        which = sorted(set(rng.integers(0, b, 3).tolist()) | {0, b - 1})
+        cols = np.empty((len(which), 64), np.float64)
+        masks = np.empty((len(which), 64), np.bool_)
+        counts = native.bucket_columns(sids, start, end, t0, interval, b,
+                                       "avg", which, cols, masks)
+        assert same_bits(cols, np.ascontiguousarray(grid[:, which].T))
+        assert same_bits(masks,
+                         np.ascontiguousarray(has_data[:, which].T))
+        np.testing.assert_array_equal(
+            counts, native.count_range(sids, start, end))
+
+
+class TestColumnRefusals:
+
+    @pytest.fixture
+    def native(self):
+        store = store_backend.NativeTimeSeriesStore(num_shards=4)
+        load(store, scenario("dense")[0])
+        return store
+
+    WINDOW = (BASE_MS, BASE_MS + 719_999, BASE_MS, INTERVAL, 12)
+
+    @staticmethod
+    def buffers(n=2, s_pad=8):
+        return (np.empty((n, s_pad), np.float32),
+                np.empty((n, s_pad), np.bool_))
+
+    def test_an_invalid_series_id_raises_as_bucket_grid_does(
+            self, native):
+        with pytest.raises(IndexError):
+            native.bucket_columns(np.array([0, 99]), *self.WINDOW, "sum",
+                                  [0, 11], *self.buffers())
+
+    @pytest.mark.parametrize("wanted", [[12], [-1, 3], [3, 3], [5, 2]],
+                             ids=["past_the_window", "negative",
+                                  "twice", "falling"])
+    def test_a_bucket_the_window_lacks_or_out_of_order_is_refused(
+            self, native, wanted):
+        with pytest.raises(IndexError):
+            native.bucket_columns(np.arange(5), *self.WINDOW, "sum",
+                                  wanted, *self.buffers(len(wanted)))
+
+    @pytest.mark.parametrize("dims", [
+        dict(interval_ms=0), dict(nbuckets=0), dict(s_pad=4),
+        dict(fn=5), dict(nwanted=-1), dict(sid=99), dict(bucket=12)],
+        ids=lambda d: next(iter(d)))
+    def test_the_entry_itself_returns_minus_one(self, native, dims):
+        """Straight at the library, past the wrapper's own checks."""
+        lib = store_backend.load_library()
+        sids = np.array([0, dims.get("sid", 1)], dtype=np.int64)
+        wanted = np.array([0, dims.get("bucket", 11)], dtype=np.int64)
+        cols, masks = self.buffers()
+        counts = np.empty(2, np.int64)
+        ptr = store_backend._ptr
+
+        def call(**over):
+            a = {"interval_ms": INTERVAL, "nbuckets": 12, "fn": 0,
+                 "nwanted": 2, "s_pad": 8, **over}
+            return lib.tss_bucket_columns(
+                native._h, ptr(sids), 2, BASE_MS, BASE_MS + 719_999,
+                BASE_MS, a["interval_ms"], a["nbuckets"], a["fn"],
+                ptr(wanted), a["nwanted"], a["s_pad"], 0, ptr(cols),
+                ptr(masks), ptr(counts), 2)
+
+        over = {k: v for k, v in dims.items()
+                if k not in ("sid", "bucket")}
+        if "s_pad" in over:
+            over["s_pad"] = 1     # fewer rows than series
+        assert call(**over) == -1
+        if not over:
+            return
+        sids[1], wanted[1] = 1, 11
+        assert call() == 0
+
+    @pytest.mark.parametrize("cols, masks", [
+        (np.empty((2, 8), np.float16), np.empty((2, 8), np.bool_)),
+        (np.empty((2, 8), np.float32), np.empty((2, 8), np.uint8)),
+        (np.empty((2, 8), np.float32), np.empty((2, 16), np.bool_)),
+        (np.empty((8, 2), np.float32).T, np.empty((2, 8), np.bool_)),
+        (np.empty((2, 4), np.float32), np.empty((2, 4), np.bool_)),
+        (np.empty((3, 8), np.float32), np.empty((3, 8), np.bool_)),
+    ], ids=["f16", "mask_u8", "shapes_differ", "not_contiguous",
+            "too_few_rows", "not_a_column_a_bucket"])
+    def test_buffers_the_pass_cannot_write_are_refused(
+            self, native, cols, masks):
+        with pytest.raises(ValueError):
+            native.bucket_columns(np.arange(5), *self.WINDOW, "sum",
+                                  [0, 11], cols, masks)
+
+
 class TestRefusals:
 
     @pytest.fixture
@@ -336,6 +541,9 @@ def answer(backend, query, flags):
         (build,) = [c["tags"] for c in execute["children"]
                     if c["name"] == "query.grid_build"
                     and "fused" in c["tags"]]
+        (program,) = [c["tags"] for c in execute["children"]
+                      if c["name"] == "query.program"]
+        build["path"] = program["path"]
         modes = {r["tags"]["mode"]: r["value"] for r in json.loads(
             router.handle(HttpRequest(
                 method="GET", path="/api/stats", params={}, headers={},
@@ -363,7 +571,20 @@ def test_a_grid_query_answers_the_same_bytes_through_either_path(
             "memory", QUERIES[name], flags)
     assert fused_modes == {"fused": 1, "host": 0}
     assert host_modes == {"fused": 0, "host": 1}
-    assert fused_build == {**host_build, "stage": "alloc", "fused": True}
+    if fused_build["path"] == "columns":
+        # the metric's resident grid, put together from its per-bucket
+        # columns (PR 50; tests/test_moving_window.py): the pass wrote
+        # the window's buckets a column each and no pad column
+        # (900 s: 4 buckets of 5m padded to 8, 16 of 1m to 16)
+        buckets, padded = (4, 8) \
+            if QUERIES[name]["downsample"].startswith("5m") else (16, 16)
+        host_build = {**host_build,
+                      "cells": host_build["cells"] // padded * buckets,
+                      "bytes": host_build["bytes"] // padded * buckets}
+    else:
+        assert fused_build["path"] == host_build["path"] == "grid"
+    assert fused_build == {**host_build, "stage": "alloc", "fused": True,
+                           "path": fused_build["path"]}
     # a cell of the compute dtype and a byte of mask
     assert host_build["bytes"] == host_build["cells"] * (9 if x64 else 5)
     assert fused == host

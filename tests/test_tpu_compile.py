@@ -168,3 +168,49 @@ def test_month_avgs_program_sweeps_in_two_loops(one_chip):
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes >= 2 * s * b * 4
     assert memory.temp_size_in_bytes < 2 << 30
+
+
+def test_refreshs_program_assembles_its_columns_in_the_one_module(
+        one_chip):
+    """``fleet-1m.refresh`` (PR 50): thirteen ``[1,048,576]`` float32
+    columns and their masks (eleven resident, two the request's own),
+    112 padded groups, ``sum`` of a counter's rate. The module that
+    runs the tail puts the ``[1,048,576 x 14]`` grid together and
+    hands it back beside the result, laid out as the grid program
+    takes it (series on the lanes: the columns are its rows as they
+    lie in memory, so no transpose), with the wide program's sweep:
+    no ``reverse``, no grid-sized ``pad``, no loop."""
+    from opentsdb_tpu.ops.pipeline import (PipelineSpec,
+                                           run_pipeline_columns,
+                                           run_pipeline_grid)
+    s, b, b_pad, g = 1 << 20, 13, 14, 112
+    spec = PipelineSpec(num_series=s, num_buckets=b_pad, num_groups=g,
+                        ds_function="avg", agg_name="sum", rate=True,
+                        rate_counter=True)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rest = (shape((b_pad,), jnp.int32), shape((s,), jnp.int32),
+            (shape((), jnp.float32), shape((), jnp.float32)),
+            shape((), jnp.float32))
+    compiled = run_pipeline_columns.lower(
+        tuple(shape((s,), jnp.float32) for _ in range(b)),
+        tuple(shape((s,), jnp.bool_) for _ in range(b)),
+        *rest, spec=spec).compile()
+    text = compiled.as_text()
+    assert not _reversed_or_padded(text, s)
+    assert " while(" not in text and " transpose(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 256 << 20
+    # the grid and its mask come back: 14 buckets on 16 sublanes
+    assert s * b_pad * 5 <= memory.output_size_in_bytes \
+        < s * 16 * 5 + (1 << 20)
+    results = text.split("entry_computation_layout=")[1].split("->")[1]
+    grid = run_pipeline_grid.lower(
+        shape((s, b_pad), jnp.float32), shape((s, b_pad), jnp.bool_),
+        *rest, spec=spec).compile().as_text()
+    operands = grid.split("entry_computation_layout={(")[1].split("->")[0]
+    for array in ("f32[1048576,14]{0,1:T(8,128)}",
+                  "pred[1048576,14]{0,1:T(8,128)(4,1)}"):
+        assert array in results and array in operands
