@@ -21,6 +21,7 @@ The ``StorageBackend`` protocol preserves the reference's swap point
 
 from __future__ import annotations
 
+import bisect
 import threading
 from typing import Iterable, NamedTuple, Protocol, Sequence
 
@@ -404,6 +405,65 @@ import itertools as _itertools
 STORE_INSTANCE_IDS = _itertools.count()
 
 
+#: what ``oldest_written_since`` answers where the store no longer
+#: knows ("everything"): older than any timestamp, so that a reader's
+#: ``oldest > hi_ms`` needs no case of its own
+ALL = float("-inf")
+
+
+class WrittenLog:
+    """Where in time a store's recent appends landed: what
+    ``oldest_written_since`` answers from.
+
+    One entry an append call, ``(points_written after it, the oldest
+    timestamp it wrote)``, pushed in the critical section that bumps
+    the counter (:attr:`lock`, held by the caller of :meth:`note`).
+    What a reader asks is a suffix minimum, so an entry no newer call
+    undercuts is all that is kept: a push first pops the entries whose
+    timestamp is not older than its own (every suffix that held them
+    holds the new one too), and the log ascends in both columns.
+    Appends that share a timestamp, a fleet reporting one minute, are
+    one entry however many they are. At most :data:`MAX_ENTRIES`:
+    the oldest entry beyond that is folded into
+    :attr:`floor_version`, and a question about a version under the
+    floor is answered :data:`ALL`."""
+
+    MAX_ENTRIES = 4096
+
+    __slots__ = ("lock", "_versions", "_oldest", "floor_version")
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self._versions: list[int] = []
+        self._oldest: list[int] = []
+        self.floor_version = 0
+
+    def __len__(self) -> int:
+        return len(self._versions)
+
+    def note(self, version: int, oldest_ts: int) -> None:
+        """An append brought ``points_written`` to ``version``, its
+        oldest point at ``oldest_ts``. The caller holds :attr:`lock`
+        across the counter's bump and this."""
+        while self._oldest and self._oldest[-1] >= oldest_ts:
+            self._oldest.pop()
+            self._versions.pop()
+        self._versions.append(version)
+        self._oldest.append(oldest_ts)
+        if len(self._versions) > self.MAX_ENTRIES:
+            self.floor_version = self._versions.pop(0)
+            self._oldest.pop(0)
+
+    def oldest_since(self, version: int):
+        with self.lock:
+            if version < self.floor_version:
+                return ALL
+            # ascending in both columns: the first entry past
+            # ``version`` is the oldest of all that follow it
+            i = bisect.bisect_right(self._versions, version)
+            return self._oldest[i] if i < len(self._oldest) else None
+
+
 class TimeSeriesStore:
     """In-memory storage engine: all series of all metrics.
 
@@ -435,6 +495,9 @@ class TimeSeriesStore:
         # tsdlint: allow[unbounded-growth] see _series
         self._metric_index: dict[int, MetricIndex] = {}
         self.points_written = 0
+        # where in time the recent appends landed
+        # (oldest_written_since), pushed where the counter is bumped
+        self._written = WrittenLog()
         # bumped on destructive ops (delete_range); together with
         # points_written it versions the store for read-side caches
         self.mutation_epoch = 0
@@ -522,13 +585,14 @@ class TimeSeriesStore:
     def append(self, series_id: int, ts_ms: int, value: float,
                is_int: bool = False) -> None:
         self._series[series_id].buffer.append(ts_ms, value, is_int)
-        self.points_written += 1
+        self._note_written(1, ts_ms)
 
     def append_many(self, series_id: int, ts_ms: np.ndarray,
                     values: np.ndarray,
                     is_int: np.ndarray | bool = False) -> None:
         self._series[series_id].buffer.append_many(ts_ms, values, is_int)
-        self.points_written += len(ts_ms)
+        if len(ts_ms):
+            self._note_written(len(ts_ms), int(np.min(ts_ms)))
 
     def append_grid(self, series_ids, bucket_ts: np.ndarray,
                     grid: np.ndarray, mask: np.ndarray) -> int:
@@ -539,15 +603,41 @@ class TimeSeriesStore:
         if len(sids) and ((sids < 0) | (sids >= len(self._series))).any():
             raise IndexError("invalid series id in append_grid")
         written = 0
+        oldest = None
         for i, sid in enumerate(sids):
             m = mask[i]
             if not m.any():
                 continue
-            self._series[sid].buffer.append_many(bucket_ts[m],
-                                                 grid[i][m])
-            written += int(m.sum())
-        self.points_written += written
+            ts = bucket_ts[m]
+            self._series[sid].buffer.append_many(ts, grid[i][m])
+            written += len(ts)
+            first = int(ts.min())
+            oldest = first if oldest is None else min(oldest, first)
+        if written:
+            self._note_written(written, oldest)
         return written
+
+    def _note_written(self, n: int, oldest_ts: int) -> None:
+        """``n`` points are in their buffers, the oldest of them at
+        ``oldest_ts``: count them and log where they landed, in ONE
+        critical section, after the points became readable. A reader
+        that read ``points_written`` before this call's bump gets the
+        call from :meth:`oldest_written_since` whether or not its scan
+        saw the points; one that read it after has seen them."""
+        log = self._written
+        with log.lock:
+            self.points_written += n
+            log.note(self.points_written, int(oldest_ts))
+
+    def oldest_written_since(self, points_written: int):
+        """The smallest timestamp (ms) any append has written since
+        ``points_written`` read that value; None if nothing was
+        written; :data:`ALL` where the log no longer reaches back that
+        far. What lets a reader keep what it built from a span of time
+        no write has touched (:mod:`opentsdb_tpu.query.device_cache`,
+        "The version rule"). Deletes and repairs are not appends:
+        they bump ``mutation_epoch``, which no log refines."""
+        return self._written.oldest_since(points_written)
 
     def delete_range(self, series_ids: Sequence[int], start_ms: int,
                      end_ms: int) -> int:
@@ -796,6 +886,10 @@ class TimeSeriesStore:
     def collect_stats(self, collector) -> None:
         collector.record("storage.series.count", self.num_series())
         collector.record("storage.points.written", self.points_written)
+        collector.record("storage.written_log.entries",
+                         len(self._written))
+        collector.record("storage.written_log.floor_version",
+                         self._written.floor_version)
         collector.record("storage.shards", self.num_shards)
         mi = self.memory_info()
         collector.record("storage.resident_bytes",

@@ -20,7 +20,11 @@ from typing import Iterable, Sequence
 import numpy as np
 
 from opentsdb_tpu.core import const
-from opentsdb_tpu.core.store import MetricIndex, PaddedBatch, PointBatch
+from opentsdb_tpu.core.store import (ALL, MetricIndex, PaddedBatch,
+                                     PointBatch)
+
+_INT64_MAX = np.iinfo(np.int64).max
+_INT64_MIN = np.iinfo(np.int64).min
 
 _SRC = os.path.join(os.path.dirname(__file__), "tsdbstore.cc")
 _LIB_DIR = os.path.dirname(__file__)
@@ -137,6 +141,11 @@ def load_library():
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
         lib.tss_points_written.argtypes = [ctypes.c_void_p]
         lib.tss_points_written.restype = ctypes.c_int64
+        lib.tss_oldest_written_since.argtypes = [ctypes.c_void_p,
+                                                 ctypes.c_int64]
+        lib.tss_oldest_written_since.restype = ctypes.c_int64
+        lib.tss_written_log_stats.argtypes = [ctypes.c_void_p,
+                                              ctypes.c_void_p]
         lib.tss_repair_series.argtypes = [ctypes.c_void_p,
                                           ctypes.c_int64, ctypes.c_int64,
                                           ctypes.c_int64, ctypes.c_int]
@@ -442,6 +451,19 @@ class NativeTimeSeriesStore:
     def points_written(self) -> int:
         return int(self._lib.tss_points_written(self._h))
 
+    def oldest_written_since(self, points_written: int):
+        """The smallest timestamp (ms) any append has written since
+        ``points_written`` read that value, None if nothing was
+        written, :data:`~opentsdb_tpu.core.store.ALL` where the log no
+        longer reaches back that far: ``TimeSeriesStore``'s answer,
+        from the log ``tsdbstore.cc`` keeps where it bumps the counter
+        (``Store::note_written``, every append path's one way to it)."""
+        ts = int(self._lib.tss_oldest_written_since(self._h,
+                                                    points_written))
+        if ts == _INT64_MAX:
+            return None
+        return ALL if ts == _INT64_MIN else ts
+
     def series(self, series_id: int) -> _NativeSeriesRecord:
         return self._records[series_id]
 
@@ -728,6 +750,11 @@ class NativeTimeSeriesStore:
     def collect_stats(self, collector) -> None:
         collector.record("storage.series.count", self.num_series())
         collector.record("storage.points.written", self.points_written)
+        log = np.zeros(2, dtype=np.int64)
+        self._lib.tss_written_log_stats(self._h, _ptr(log))
+        collector.record("storage.written_log.entries", int(log[0]))
+        collector.record("storage.written_log.floor_version",
+                         int(log[1]))
         collector.record("storage.shards", self.num_shards)
         collector.record("storage.backend", 1, backend="native")
         mi = self.memory_info()

@@ -22,6 +22,7 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <deque>
 #include <limits>
 #include <mutex>
 #include <shared_mutex>
@@ -109,6 +110,37 @@ struct Store {
   std::vector<SeriesBuffer*> series;
   std::shared_mutex dir_mu;
   std::atomic<int64_t> points_written{0};
+
+  // Where in time the recent appends landed (tss_oldest_written_since;
+  // the Python twin is core/store.py WrittenLog, rule for rule): one
+  // entry an append call, {points_written after it, the oldest
+  // timestamp it wrote}, pushed in the critical section that bumps the
+  // counter. A reader asks for a suffix minimum, so a push first pops
+  // the entries whose timestamp is not older than its own, and the log
+  // ascends in both fields; beyond kWrittenLogMax the oldest entry is
+  // folded into written_floor, below which the answer is "everything".
+  struct Written { int64_t version, oldest; };
+  static constexpr size_t kWrittenLogMax = 4096;
+  std::mutex written_mu;
+  std::deque<Written> written_log;
+  int64_t written_floor = 0;
+
+  // n points are in their buffers, the oldest at oldest_ts: count them
+  // and log where they landed, under one lock, AFTER they became
+  // readable. points_written moves nowhere else.
+  void note_written(int64_t n, int64_t oldest_ts) {
+    if (n <= 0) return;
+    std::lock_guard<std::mutex> lock(written_mu);
+    while (!written_log.empty() && written_log.back().oldest >= oldest_ts)
+      written_log.pop_back();
+    const int64_t v = points_written.load(std::memory_order_relaxed) + n;
+    written_log.push_back({v, oldest_ts});
+    if (written_log.size() > kWrittenLogMax) {
+      written_floor = written_log.front().version;
+      written_log.pop_front();
+    }
+    points_written.store(v);
+  }
 
   // nullptr on a bad sid.
   SeriesBuffer* lookup(int64_t sid) {
@@ -345,7 +377,7 @@ int tss_append(void* h, int64_t sid, int64_t ts_ms, double value,
   SeriesBuffer* buf = s->lookup(sid);
   if (!buf) return -1;
   buf->append(ts_ms, value, (uint8_t)is_int);
-  s->points_written.fetch_add(1, std::memory_order_relaxed);
+  s->note_written(1, ts_ms);
   return 0;
 }
 
@@ -355,12 +387,37 @@ int tss_append_many(void* h, int64_t sid, int64_t n, const int64_t* ts,
   SeriesBuffer* buf = s->lookup(sid);
   if (!buf) return -1;
   buf->append_many(n, ts, vals, is_int);
-  s->points_written.fetch_add(n, std::memory_order_relaxed);
+  if (n > 0) s->note_written(n, *std::min_element(ts, ts + n));
   return 0;
 }
 
 int64_t tss_points_written(void* h) {
   return static_cast<Store*>(h)->points_written.load();
+}
+
+// The smallest timestamp any append has written since points_written
+// read `version`: INT64_MAX if nothing was written, INT64_MIN where
+// the log no longer reaches back that far ("everything").
+int64_t tss_oldest_written_since(void* h, int64_t version) {
+  Store* s = static_cast<Store*>(h);
+  std::lock_guard<std::mutex> lock(s->written_mu);
+  if (version < s->written_floor)
+    return std::numeric_limits<int64_t>::min();
+  // ascending in both fields: the first entry past `version` is the
+  // oldest of all that follow it
+  auto it = std::upper_bound(
+      s->written_log.begin(), s->written_log.end(), version,
+      [](int64_t v, const Store::Written& w) { return v < w.version; });
+  return it == s->written_log.end()
+             ? std::numeric_limits<int64_t>::max() : it->oldest;
+}
+
+// out[0..1]: the entries of the write log and its floor version.
+void tss_written_log_stats(void* h, int64_t* out) {
+  Store* s = static_cast<Store*>(h);
+  std::lock_guard<std::mutex> lock(s->written_mu);
+  out[0] = (int64_t)s->written_log.size();
+  out[1] = s->written_floor;
 }
 
 // fsck in-place repair (ref: Fsck.java:99-119 repairing bad values /
@@ -425,9 +482,12 @@ int64_t tss_append_grid(void* h, const int64_t* sids, int64_t nsids,
   std::vector<SeriesBuffer*> bufs;
   if (!s->snapshot(sids, nsids, &bufs)) return -1;
   std::atomic<int64_t> total{0};
+  // the oldest timestamp any row wrote (bucket_ts need not ascend)
+  std::atomic<int64_t> oldest{std::numeric_limits<int64_t>::max()};
   parallel_for(threads, nsids, items_per(kAppendCells, nbuckets),
                [&](int64_t r0, int64_t r1) {
     int64_t local = 0;
+    int64_t first = std::numeric_limits<int64_t>::max();
     for (int64_t i = r0; i < r1; ++i) {
       SeriesBuffer* buf = bufs[i];
       const double* row = grid + i * nbuckets;
@@ -441,12 +501,15 @@ int64_t tss_append_grid(void* h, const int64_t* sids, int64_t nsids,
         buf->ts.push_back(bucket_ts[b]);
         buf->vals.push_back(row[b]);
         buf->is_int.push_back(0);
+        first = std::min(first, bucket_ts[b]);
         ++local;
       }
     }
     total.fetch_add(local);
+    int64_t seen = oldest.load();
+    while (first < seen && !oldest.compare_exchange_weak(seen, first)) {}
   });
-  s->points_written.fetch_add(total.load());
+  s->note_written(total.load(), oldest.load());
   return total.load();
 }
 
@@ -1310,6 +1373,7 @@ int64_t tss_append_lines(void* h, const int64_t* sids, int64_t n,
                          const uint8_t* ints) {
   Store* s = static_cast<Store*>(h);
   int64_t written = 0;
+  int64_t oldest = std::numeric_limits<int64_t>::max();
   SeriesBuffer* buf = nullptr;
   int64_t cur = -2;  // current locked-in sid (runs are the common case)
   for (int64_t i = 0; i < n; ++i) {
@@ -1319,7 +1383,7 @@ int64_t tss_append_lines(void* h, const int64_t* sids, int64_t n,
       SeriesBuffer* nb = s->lookup(sid);
       if (buf) buf->mu.unlock();
       if (!nb) {
-        s->points_written.fetch_add(written);
+        s->note_written(written, oldest);
         return -1;
       }
       nb->mu.lock();
@@ -1331,10 +1395,11 @@ int64_t tss_append_lines(void* h, const int64_t* sids, int64_t n,
     buf->ts.push_back(ts_ms[i]);
     buf->vals.push_back(vals[i]);
     buf->is_int.push_back(ints ? ints[i] : 0);
+    oldest = std::min(oldest, ts_ms[i]);
     ++written;
   }
   if (buf) buf->mu.unlock();
-  s->points_written.fetch_add(written);
+  s->note_written(written, oldest);
   return written;
 }
 

@@ -17,7 +17,26 @@ so the order is the order of two calls in one function and no caller
 can get it wrong. For a store the version is :func:`store_version`,
 ``(points_written, mutation_epoch)`` of each store read (every write,
 delete and lifecycle sweep bumps one of them); the histogram arenas
-have ``TSDB._histogram_version``.
+have ``TSDB._histogram_version``. **Unless what was written since
+did not touch what the entry covers:** an entry may be kept with the
+span of time it covers of ONE store, the first its version reads
+(``covers=(lo_ms, hi_ms)``, both inclusive; absent: everything). A
+look-up that reads a newer version keeps such an entry iff nothing
+but that store's ``points_written`` moved (its epoch stands: nothing
+but appends happened) and the store's word, ``oldest_written_since(the
+entry's points_written)``, is None or greater than ``hi_ms``; the entry is
+then re-stamped with the version just read, so the store's bounded
+log of recent writes need only reach back to an entry's last use.
+The store pushes an append into that log in the critical section
+that bumps ``points_written``, after the points became readable: a
+reader that read the counter before the bump gets the write as
+"since", whether or not its scan saw the points; one that read it
+after has seen them. Only the OLDEST timestamp is known, so a
+backfill behind an entry (older than ``lo_ms``) drops it needlessly:
+conservative, and exact. A store without the method (the cold store,
+the stitched and rollup wrappers, the histogram arenas), a log that
+no longer reaches back (``ALL``), an epoch that moved, an entry
+without a span: any write drops it, as before.
 
 **The flight.** One build a key at a time: the first caller builds,
 callers of the same key wait for it and hit, callers of another key
@@ -94,16 +113,23 @@ the columns' place under the window's key (:meth:`DeviceGridCache
 more than ``engine.RESIDENT_COLUMNS_MAX_BUCKETS`` buckets (a column
 is an operand and an entry each), builds the window whole in one
 row-major pass, as before the second level. **What a write drops:**
-everything of the store, both levels: the version rule knows no
-finer grain, so the request behind a write builds every column again
-in one pass (an append at the head that leaves older columns resident
-needs the store's word on the oldest timestamp written since a
-version: PERF.md section 7).
+what it touched. Both levels pass their span (the window ``[start_ms,
+end_ms]``; the bucket ``[start, start + interval_ms - 1]``), so an
+append at the head of the store, after the window's end, leaves the
+window's grid and every column resident, and the request behind it
+is the hit it would have been; an append inside a bucket drops the
+window's grid, that bucket's column and (the oldest timestamp being
+all the store keeps) the columns after it, and the next request
+builds those in one pass over the older ones that stayed; a delete,
+a lifecycle sweep and a repair (``mutation_epoch``) drop everything
+of the store. The look-up spans say which (``stale`` = kept, dropped
+or none), and the cache counts them (``tsd.query.residency``).
 
 ``grid`` (``QueryEngine._grid_pipeline``): the grid of one request's
 own rows, keyed by a digest of its series ids: every other
 device-placed grid request. Under a mesh the same key (the mesh in it)
-holds the pre-sharded operands.
+holds the pre-sharded operands. This kind and the three below pass no
+span: any write to what they were built from drops them.
 
 ``avgdiv`` (``QueryEngine._avg_rollup_pipeline``): the sum and count
 grids a rollup average divides, versioned by both tiers' stores.
@@ -132,6 +158,8 @@ import threading
 from collections import OrderedDict
 from typing import Any
 
+from opentsdb_tpu.core.store import ALL
+
 
 #: what :func:`resident` says of the arrays it returns: they were
 #: there, this call built and kept them, or it built them and nothing
@@ -152,6 +180,41 @@ def store_version(*stores) -> tuple:
                            getattr(store, "mutation_epoch", 0)))
 
 
+class Lookup:
+    """One request's account of the entries it found under an OLDER
+    version: how many it kept and dropped (:attr:`stale`, the look-up
+    span's tag), and, given the ONE ``store`` whose span of time the
+    entries cover, that store's word on what was written since a
+    version (``oldest_written_since``, asked once a version however
+    many entries carry it; a store without the method, or no store,
+    answers ``ALL``: today's whole-store rule). The store is asked
+    inside a look-up, so after that look-up's version was read: an
+    answer remembered here is never older than the version an entry
+    is re-stamped with, and no write falls between the two (where
+    ``resident`` reads the version again under the flight, what is
+    remembered of the first look-up can only be an answer that
+    dropped the entry). One request, one of these."""
+
+    __slots__ = ("store", "kept", "dropped", "_since")
+
+    def __init__(self, store=None):
+        self.store = store
+        self.kept = self.dropped = 0
+        self._since: dict = {}
+
+    def written_since(self, points_written: int):
+        if points_written not in self._since:
+            ask = getattr(self.store, "oldest_written_since", None)
+            self._since[points_written] = ALL if ask is None \
+                else ask(points_written)
+        return self._since[points_written]
+
+    @property
+    def stale(self) -> str:
+        return "dropped" if self.dropped else \
+            "kept" if self.kept else "none"
+
+
 #: the kinds whose builds take turns (the module's docstring, "The
 #: turn"): one lock a process, since the chip is one a process
 SERIAL_BUILD_KINDS = frozenset({"hist"})
@@ -165,14 +228,15 @@ def _build_turn(key):
     return contextlib.nullcontext()
 
 
-def resident(cache: "DeviceGridCache | None", key, version_of, build):
+def resident(cache: "DeviceGridCache | None", key, version_of, build,
+             covers=None, lookup: Lookup | None = None):
     """:meth:`DeviceGridCache.resident` of ``cache``; with no cache,
     what ``build`` makes (in its turn, where ``key``'s kind takes
     turns), and nothing kept."""
     if cache is None:
         with _build_turn(key):
             return (*build(), NOT_KEPT)
-    return cache.resident(key, version_of, build)
+    return cache.resident(key, version_of, build, covers, lookup)
 
 
 class DeviceGridCache:
@@ -187,7 +251,8 @@ class DeviceGridCache:
         self.stat_prefix = stat_prefix
         self.max_bytes = max_bytes
         self._lock = threading.Lock()
-        # key -> (version, arrays: tuple, meta: dict, nbytes: int)
+        # key -> (version, arrays: tuple, meta: dict, nbytes: int,
+        # covers: (lo_ms, hi_ms) | None)
         self._entries: OrderedDict[Any, tuple] = OrderedDict()
         # key -> [its flight's lock, the calls inside resident() for
         # it]: an entry a key somebody is looking up or building now
@@ -195,8 +260,14 @@ class DeviceGridCache:
         self._bytes = 0
         self.hits = 0
         self.misses = 0
+        # look-ups that met a newer version than their entry's, by
+        # what became of the entry, and the bytes of the dropped ones
+        self.stale_kept = 0
+        self.stale_dropped = 0
+        self.stale_dropped_bytes = 0
 
-    def resident(self, key, version_of, build):
+    def resident(self, key, version_of, build, covers=None,
+                 lookup: Lookup | None = None):
         """``(arrays, meta, how)``: the entry under ``key`` if it is of
         the version ``version_of()`` reads now (:data:`HIT`), else
         what ``build()`` makes, ``(arrays: tuple | None, meta)``, kept
@@ -208,21 +279,29 @@ class DeviceGridCache:
         whoever else asks for ``key`` meanwhile waits, then reads the
         version for itself and hits. A ``build`` that raises keeps
         nothing and the waiters go on. An entry that is there costs a
-        hit what it always did, one lock and no flight."""
-        hit = self._hit(key, version_of())
+        hit what it always did, one lock and no flight.
+
+        ``covers``: the span of time ``(lo_ms, hi_ms)``, both
+        inclusive, that what ``build`` makes covers of the ONE store
+        ``version_of`` reads (``lookup.store``), kept with the entry;
+        absent, everything. ``lookup`` takes the tally of what an
+        older version's entry came to, and gives the store's word
+        ("The version rule")."""
+        hit = self._hit(key, version_of(), lookup)
         if hit is not None:
             return (*hit, HIT)
         with self._flight(key), _build_turn(key):
             version = version_of()
-            hit = self.get(key, version)
+            hit = self.get(key, version, lookup)
             if hit is not None:
                 return (*hit, HIT)
             arrays, meta = build()
             kept = arrays is not None \
-                and self.put(key, version, arrays, meta)
+                and self.put(key, version, arrays, meta, covers)
             return arrays, meta, BUILT if kept else NOT_KEPT
 
-    def resident_columns(self, flight_key, keys, version, build):
+    def resident_columns(self, flight_key, keys, version, build,
+                         covers=None, lookup: Lookup | None = None):
         """``(columns, rest)``: the arrays under each of ``keys`` (one
         (metric, interval, function)'s per-bucket columns), every one
         of ``version``, and what ``build`` made beside them.
@@ -236,23 +315,28 @@ class DeviceGridCache:
         two requests whose windows share some columns cannot each
         hold a few and wait for the other's. With every key there,
         nobody waits: ``build(())`` runs outside the flight. Each key
-        counts as a hit or a miss, like any entry's."""
+        counts as a hit or a miss, like any entry's. ``covers`` has
+        the span of time each key's column covers, ``lookup`` as in
+        :meth:`resident`."""
         def arrays_of(hit):
             return None if hit is None else hit[0]
 
-        found = [arrays_of(self._hit(key, version)) for key in keys]
+        found = [arrays_of(self._hit(key, version, lookup))
+                 for key in keys]
         if None in found:
             with self._flight(flight_key):
                 missing = []
                 for i, key in enumerate(keys):
                     if found[i] is None:
-                        found[i] = arrays_of(self.get(key, version))
+                        found[i] = arrays_of(
+                            self.get(key, version, lookup))
                         if found[i] is None:
                             missing.append(i)
                 if missing:
                     built, rest = build(tuple(missing))
                     for i, arrays in zip(missing, built):
-                        self.put(keys[i], version, arrays, {})
+                        self.put(keys[i], version, arrays, {},
+                                 covers[i] if covers else None)
                         found[i] = arrays
                     return found, rest
         # every column was there, or another request built the missing
@@ -277,26 +361,58 @@ class DeviceGridCache:
                 if not flight[1]:
                     del self._flights[key]
 
-    def _hit(self, key, version):
+    def _good_locked(self, key, entry, version,
+                     lookup: Lookup | None) -> bool:
+        """Whether ``entry`` holds what a build at ``version`` (read
+        just now) would: it is of that version, or ("The version
+        rule") it covers a span of time of ``lookup.store``, the
+        first store of the version, nothing but appends to that store
+        happened since the entry's version (every other item of the
+        version stands, its epoch among them), and the oldest of them
+        lies after the span's end. Such an entry is re-stamped with
+        ``version`` and counted as kept."""
+        if entry[0] == version:
+            return True
+        covers = entry[4]
+        if covers is None or lookup is None \
+                or entry[0][1:] != version[1:]:
+            return False
+        oldest = lookup.written_since(entry[0][0])
+        if oldest is not None and not oldest > covers[1]:
+            return False
+        self._entries[key] = (version, *entry[1:])
+        self.stale_kept += 1
+        lookup.kept += 1
+        return True
+
+    def _hit(self, key, version, lookup: Lookup | None = None):
         """(arrays, meta) of a matching entry, counted as a hit; else
         None, and nothing counted or dropped (:meth:`get` does that,
         once the caller holds the key's flight)."""
         with self._lock:
             entry = self._entries.get(key)
-            if entry is None or entry[0] != version:
+            if entry is None or not self._good_locked(
+                    key, entry, version, lookup):
                 return None
             self._entries.move_to_end(key)
             self.hits += 1
             return entry[1], entry[2]
 
-    def get(self, key, version):
-        """(arrays, meta) on hit with a matching version, else None."""
+    def get(self, key, version, lookup: Lookup | None = None):
+        """(arrays, meta) of an entry of ``version``, or of an older
+        one that no write since has touched ("The version rule");
+        else None, the stale entry dropped."""
         with self._lock:
             entry = self._entries.get(key)
-            if entry is None or entry[0] != version:
+            if entry is None or not self._good_locked(
+                    key, entry, version, lookup):
                 if entry is not None:  # stale: the store changed
                     self._bytes -= entry[3]
                     del self._entries[key]
+                    self.stale_dropped += 1
+                    self.stale_dropped_bytes += entry[3]
+                    if lookup is not None:
+                        lookup.dropped += 1
                 self.misses += 1
                 return None
             self._entries.move_to_end(key)
@@ -312,25 +428,27 @@ class DeviceGridCache:
             return sum(getattr(x, "nbytes", 0) for x in inner)
         return getattr(a, "nbytes", 0)
 
-    def put(self, key, version, arrays: tuple, meta: dict) -> bool:
+    def put(self, key, version, arrays: tuple, meta: dict,
+            covers=None) -> bool:
         """Keep ``arrays`` under ``key``; False where they are larger
         than the whole cache (nothing kept: don't thrash)."""
         nbytes = sum(self._entry_nbytes(a) for a in arrays)
         if nbytes > self.max_bytes:
             return False
         with self._lock:
-            self._put_locked(key, version, arrays, meta, nbytes)
+            self._put_locked(key, version, arrays, meta, nbytes, covers)
         return True
 
-    def _put_locked(self, key, version, arrays, meta, nbytes) -> None:
+    def _put_locked(self, key, version, arrays, meta, nbytes,
+                    covers) -> None:
         old = self._entries.pop(key, None)
         if old is not None:
             self._bytes -= old[3]
-        self._entries[key] = (version, arrays, meta, nbytes)
+        self._entries[key] = (version, arrays, meta, nbytes, covers)
         self._bytes += nbytes
         while self._bytes > self.max_bytes and self._entries:
-            _, (_, _, _, nb) = self._entries.popitem(last=False)
-            self._bytes -= nb
+            _, evicted = self._entries.popitem(last=False)
+            self._bytes -= evicted[3]
 
     def replace(self, key, old: tuple, arrays: tuple, meta: dict) -> bool:
         """Put ``arrays`` and ``meta`` where ``key`` holds ``old`` (the
@@ -344,7 +462,8 @@ class DeviceGridCache:
             if entry is None or entry[1] is not old \
                     or nbytes > self.max_bytes:
                 return False
-            self._put_locked(key, entry[0], arrays, meta, nbytes)
+            self._put_locked(key, entry[0], arrays, meta, nbytes,
+                             entry[4])
         return True
 
     def bytes_of(self, kind) -> int:
@@ -380,3 +499,10 @@ class DeviceGridCache:
                          len(self._entries))
         collector.record(f"{self.stat_prefix}.hits", self.hits)
         collector.record(f"{self.stat_prefix}.misses", self.misses)
+        pool = self.stat_prefix.rsplit(".", 1)[-1]
+        collector.record("query.residency", self.stale_kept,
+                         outcome="kept", cache=pool)
+        collector.record("query.residency", self.stale_dropped,
+                         outcome="dropped", cache=pool)
+        collector.record("query.residency.dropped_bytes",
+                         self.stale_dropped_bytes, cache=pool)

@@ -1346,7 +1346,7 @@ class QueryEngine:
     def _resident_operands(self, cache, kind: str, lookup: tuple,
                            key_of, stores, build, stats,
                            metric_name: str, n_rows: int, delete=None,
-                           points_of=None):
+                           points_of=None, covers=None):
         """What a grid-shaped kind of the HBM cache does around its
         build, once: the operands through :func:`device_cache.resident`
         under ``(kind, *key_of())``, versioned by ``stores`` (``cache``
@@ -1365,14 +1365,24 @@ class QueryEngine:
         else, and on a hit, it follows here. The ``cache_lookup``
         span, tagged ``grid`` = ``lookup[0]`` on a hit and
         ``lookup[1]`` on a build, is the key, the look-up and the wait
-        for another's build of it, no more."""
+        for another's build of it, no more; its tag ``stale`` says
+        what became of an entry found under an older version of the
+        store (``kept``, ``dropped``; ``none``: no such entry).
+
+        ``covers``: the span of time ``(lo_ms, hi_ms)`` the entry
+        covers of ``stores``' ONE store; a write that landed after it
+        leaves the entry resident (the version rule of
+        :mod:`~opentsdb_tpu.query.device_cache`). Absent: any write
+        to ``stores`` drops it."""
         span = trace_begin("query.grid_build", stage="cache_lookup") \
             if cache is not None else None
         pending = True
+        found = device_cache.Lookup(
+            stores[0] if covers is not None else None)
 
         def end_lookup(built: bool):
             if span is not None:
-                span.tag(grid=lookup[built])
+                span.tag(grid=lookup[built], stale=found.stale)
                 span.finish()
 
         def checked(num_points: int):
@@ -1390,7 +1400,7 @@ class QueryEngine:
         arrays, meta, how = device_cache.resident(
             cache, (kind, *key_of()) if cache is not None else None,
             lambda: device_cache.store_version(*stores),
-            build_after_lookup)
+            build_after_lookup, covers, found)
         if how == device_cache.HIT:
             end_lookup(False)  # before the points are counted
         num_points = points_of(meta) if points_of else meta["num_points"]
@@ -1483,7 +1493,8 @@ class QueryEngine:
             cache, RESIDENT_GRID_KEY,
             _LOOKUP_COLUMNS if by_column else _LOOKUP_RESIDENT, key_of,
             (store,), build_columns if by_column else build_whole,
-            stats, metric_name, num_selected, points_of=points_of)
+            stats, metric_name, num_selected, points_of=points_of,
+            covers=(tsq.start_ms, tsq.end_ms))
 
     def _metric_columns(self, cache, store, metric_sids: np.ndarray,
                         metric_id: int, tsq: TSQuery,
@@ -1506,7 +1517,10 @@ class QueryEngine:
         ``scanned(scan, num_points)`` closes it. The
         ``query.grid_build stage=columns`` span is the look-ups and
         the wait for another request's build, tagged with the columns
-        that ``hit``, were ``built`` and were ``cut``."""
+        that ``hit``, were ``built`` and were ``cut``, and ``stale``:
+        whether a column found under an older version was ``kept``
+        (nothing written since lies in its bucket or before it) or
+        ``dropped`` (any was), ``none`` where the store stood still."""
         from opentsdb_tpu.ops import shapes
         from opentsdb_tpu.ops.pipeline import pipeline_dtype, put_columns
         version = device_cache.store_version(store)
@@ -1520,12 +1534,14 @@ class QueryEngine:
         group = (RESIDENT_COLUMN_KEY, _store_id(store), metric_id,
                  len(metric_sids), interval_ms, fn)
         span = trace_begin("query.grid_build", stage="columns")
+        found = device_cache.Lookup(store)
 
         def build(missing):
             wanted = sorted(cut + [whole[i] for i in missing])
             if span is not None:
                 span.tag(hit=len(whole) - len(missing),
-                         built=len(missing), cut=len(cut))
+                         built=len(missing), cut=len(cut),
+                         stale=found.stale)
                 span.finish()
             cells = len(wanted) * s_pad
             with trace_span("query.grid_build", stage="alloc",
@@ -1543,7 +1559,9 @@ class QueryEngine:
                 {k: up[k] for k in cut}
 
         kept, own = cache.resident_columns(
-            group, [(*group, starts[k]) for k in whole], version, build)
+            group, [(*group, starts[k]) for k in whole], version, build,
+            [(starts[k], starts[k] + interval_ms - 1) for k in whole],
+            found)
         columns = {**dict(zip(whole, kept)), **own}
         return tuple(columns[k] for k in range(b))
 

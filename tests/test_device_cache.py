@@ -171,10 +171,10 @@ class TestAvgRollupCache:
         seen = []
         orig_get = cache.get
 
-        def spy(key, version):
+        def spy(key, version, *lookup):
             if key[0] == "avgdiv":
                 seen.append(key)
-            return orig_get(key, version)
+            return orig_get(key, version, *lookup)
 
         cache.get = spy
         try:
@@ -605,6 +605,13 @@ def _write_outside(t):
                                           "rack": "r1"})
 
 
+def _write_before(t):
+    # a backfill behind the window: only the OLDEST timestamp written
+    # since a version is known, so the entry goes, needlessly
+    t.add_point("m", BASE - 600, 1.0, {"host": "h01", "dc": "d1",
+                                       "rack": "r1"})
+
+
 def _delete(t):
     sids = t.store.series_ids_for_metric(t.uids.metrics.get_id("m"))
     assert t.store.delete_range(sids[:3], BASE * 1000,
@@ -622,13 +629,16 @@ def _new_series(t):
 
 
 class TestResidentGridValidity:
-    @pytest.mark.parametrize("change, moves", [
-        (_write_inside, True), (_write_outside, False),
-        (_delete, True), (_epoch_bump, False), (_new_series, True)],
-        ids=["write_inside", "write_outside", "delete", "epoch_bump",
-             "new_series"])
+    @pytest.mark.parametrize("change, moves, rebuilds", [
+        (_write_inside, True, True), (_write_outside, False, False),
+        (_write_before, False, True), (_delete, True, True),
+        (_epoch_bump, False, True), (_new_series, True, True)],
+        ids=["write_inside", "write_outside", "write_before", "delete",
+             "epoch_bump", "new_series"])
     def test_a_change_of_the_store_rebuilds(self, monkeypatch, change,
-                                            moves):
+                                            moves, rebuilds):
+        """All but an append AFTER the window's end (PR 51: the store
+        says where the writes since the entry's version landed)."""
         t = _tsdb()
         _seed_fleet(t)
         q = _rq("sum")
@@ -637,7 +647,9 @@ class TestResidentGridValidity:
         change(t)
         misses = cache.misses
         got = t.execute_query(q)
-        assert cache.misses == misses + _built(t)
+        # the write at BASE + 45 leaves no whole bucket before it
+        assert cache.misses == misses + rebuilds * _built(t)
+        assert cache.stale_kept == (not rebuilds)
         assert ([r.dps for r in got] != [r.dps for r in first]) == moves
         _same_answers(got, _scanned(t, monkeypatch, q), False, 1e-12)
         hits = cache.hits
@@ -1181,6 +1193,217 @@ SITES = {
 }
 
 
+# ---------------------------------------------------------------------
+# the finer version rule (PR 51): an entry that covers a span of time
+# of one store outlives the appends that landed after the span's end
+# ---------------------------------------------------------------------
+
+class _Logged(_Versioned):
+    """A store that says where its appends landed: the real log
+    (``core/store.py WrittenLog``) behind ``_Versioned``'s counters."""
+
+    def __init__(self):
+        super().__init__()
+        from opentsdb_tpu.core.store import WrittenLog
+        self.log = WrittenLog()
+
+    def append(self, ts_ms: int) -> None:
+        with self.log.lock:
+            self.points_written += 1
+            self.log.note(self.points_written, ts_ms)
+
+    def oldest_written_since(self, points_written: int):
+        return self.log.oldest_since(points_written)
+
+
+COVERS = (1_000, 1_999)         # what the entry covers, inclusive
+
+
+def _nothing(store):
+    pass
+
+
+def _epoch(store):
+    store.append(5_000)
+    store.mutation_epoch += 1
+
+
+def _forgotten(store):
+    store.append(5_000)
+    store.log.floor_version = store.points_written
+
+
+# what happened to the store since the entry's version -> kept?
+SINCE = {
+    "nothing": (_nothing, True),
+    "above_hi": (lambda s: s.append(2_000), True),
+    "far_above_hi": (lambda s: [s.append(9_000), s.append(8_000)], True),
+    "at_hi": (lambda s: s.append(1_999), False),
+    "inside": (lambda s: s.append(1_500), False),
+    "at_lo": (lambda s: s.append(1_000), False),
+    "below_lo": (lambda s: s.append(10), False),
+    "above_then_inside": (lambda s: [s.append(5_000), s.append(1_200)],
+                          False),
+    "inside_then_above": (lambda s: [s.append(1_200), s.append(5_000)],
+                          False),
+    "epoch_moved": (_epoch, False),
+    "the_log_forgot": (_forgotten, False),
+}
+
+
+class TestWhatAWriteDrops:
+    @pytest.mark.parametrize("since", sorted(SINCE))
+    @pytest.mark.parametrize("door", ["resident", "resident_columns"])
+    def test_the_rules_table(self, since, door):
+        change, kept = SINCE[since]
+        store = _Logged()
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        key = ("metriccol", 1, 7, 40, 60, "avg", COVERS[0])
+        builds = []
+
+        def ask():
+            found = dc_mod.Lookup(store)
+            if door == "resident":
+                def build():
+                    builds.append(1)
+                    return _arrays()
+                arrays = cache.resident(
+                    key, lambda: dc_mod.store_version(store), build,
+                    COVERS, found)[0]
+            else:
+                def build(missing):
+                    builds.extend(missing)
+                    return [_arrays()[0] for _ in missing], None
+                (arrays,), _ = cache.resident_columns(
+                    key[:-1], [key], dc_mod.store_version(store),
+                    build, [COVERS], found)
+            return arrays, found.stale
+
+        first, stale = ask()
+        assert stale == "none" and len(builds) == 1
+        change(store)
+        moved = dc_mod.store_version(store) != (0, 0)
+        again, stale = ask()
+        assert (again is first) == kept
+        assert len(builds) == 2 - kept
+        assert stale == ("none" if not moved
+                         else "kept" if kept else "dropped")
+        assert (cache.stale_kept, cache.stale_dropped) \
+            == (int(moved and kept), int(not kept))
+        assert cache.stale_dropped_bytes == (0 if kept else 64)
+        # whatever it was, it now carries the version just read: a
+        # third look-up asks the store nothing
+        asked = []
+        store.oldest_written_since = asked.append
+        assert ask() == (again, "none") and not asked
+        (entry,) = cache._entries.values()
+        assert entry[0] == dc_mod.store_version(store)
+        assert entry[4] == COVERS
+
+    @pytest.mark.parametrize("case", ["no_method", "no_covers",
+                                      "no_lookup", "two_stores"])
+    def test_without_the_stores_word_any_write_drops(self, case):
+        """A store without ``oldest_written_since`` (the cold store,
+        the rollup tiers' wrapper, the histogram arenas) answers
+        "everything"; so does an entry kept without a span and a
+        look-up that brings no store; and the span is of the version's
+        FIRST store: a write to any other drops the entry."""
+        store = _Versioned() if case == "no_method" else _Logged()
+        stores = (store, _Versioned()) if case == "two_stores" \
+            else (store,)
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        builds = []
+
+        def build():
+            builds.append(1)
+            return _arrays()
+
+        def ask():
+            found = None if case == "no_lookup" \
+                else dc_mod.Lookup(store)
+            cache.resident(("metricgrid", 1),
+                           lambda: dc_mod.store_version(*stores), build,
+                           None if case == "no_covers" else COVERS,
+                           found)
+            return found.stale if found else None
+
+        ask()
+        if case == "two_stores":
+            stores[1].points_written += 1
+        elif isinstance(store, _Logged):
+            store.append(5_000)         # far beyond the span
+        else:
+            store.points_written += 1
+        assert ask() == (None if case == "no_lookup" else "dropped")
+        assert len(builds) == 2
+        assert (cache.stale_kept, cache.stale_dropped) == (0, 1)
+
+    def test_a_look_up_asks_the_store_once_a_version(self):
+        """Eleven columns of one version: one question."""
+        store = _Logged()
+        asked = []
+        real = store.oldest_written_since
+        store.oldest_written_since = \
+            lambda v: asked.append(v) or real(v)
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        keys = [("metriccol", 1, 7, 40, 60, "avg", 60 * k)
+                for k in range(11)]
+        covers = [(60 * k, 60 * k + 59) for k in range(11)]
+
+        def build(missing):
+            return [_arrays()[0] for _ in missing], None
+
+        cache.resident_columns(
+            keys[0][:-1], keys, dc_mod.store_version(store), build,
+            covers, dc_mod.Lookup(store))
+        store.append(60 * 7 + 5)          # into the eighth bucket
+        found = dc_mod.Lookup(store)
+        built = []
+        cache.resident_columns(
+            keys[0][:-1], keys, dc_mod.store_version(store),
+            lambda missing: (built.extend(missing) or build(missing)),
+            covers, found)
+        assert asked == [0] and built == [7, 8, 9, 10]
+        assert (found.kept, found.dropped, found.stale) \
+            == (7, 4, "dropped")
+
+    def test_replace_keeps_the_span(self):
+        """The grid a program assembled takes its columns' place under
+        the window's key with the span the columns were kept with."""
+        store = _Logged()
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        key = ("metricgrid", 1)
+        old = cache.resident(key, lambda: dc_mod.store_version(store),
+                             _arrays, COVERS, dc_mod.Lookup(store))[0]
+        assert cache.replace(key, old, _arrays(128)[0], {})
+        store.append(2_000)
+        found = dc_mod.Lookup(store)
+        assert cache.resident(
+            key, lambda: dc_mod.store_version(store), _arrays, COVERS,
+            found)[2] == dc_mod.HIT and found.stale == "kept"
+
+    def test_the_counters_are_exported(self):
+        from opentsdb_tpu.stats.stats import StatsCollector
+        store = _Logged()
+        cache = dc_mod.DeviceGridCache(1 << 20)
+        for ts in (5_000, 1_500):
+            cache.resident(("metricgrid", 1),
+                           lambda: dc_mod.store_version(store), _arrays,
+                           COVERS, dc_mod.Lookup(store))
+            store.append(ts)
+        cache.resident(("metricgrid", 1),
+                       lambda: dc_mod.store_version(store), _arrays,
+                       COVERS, dc_mod.Lookup(store))
+        collector = StatsCollector("tsd")
+        cache.collect_stats(collector)
+        got = {(name, tags.get("outcome")): value
+               for name, value, tags in collector.records
+               if "residency" in name}
+        assert got == {("tsd.query.residency", "kept"): 1,
+                       ("tsd.query.residency", "dropped"): 1,
+                       ("tsd.query.residency.dropped_bytes", None): 64}
+
+
 class TestEveryKindThroughTheOneEntry:
     @pytest.mark.parametrize("kind", sorted(SITES))
     def test_built_once_hit_on_repeat_rebuilt_after_a_write(self,
@@ -1198,7 +1421,11 @@ class TestEveryKindThroughTheOneEntry:
         assert [r.dps for r in warm] == [r.dps for r in cold]
         write(t)
         moved = t.execute_query(query())
-        assert (cache.misses, cache.hits) == (2 * build, 1)
+        # the metric's columns: the bucket that ends before the write
+        # stays resident (PR 51); every other kind drops whole
+        kept = int(kind == "metricgrid" and build > 1)
+        assert (cache.misses, cache.hits) == (2 * build - kept,
+                                              1 + kept)
         assert [r.dps for r in moved] != [r.dps for r in cold]
         assert _kinds(t) == [kind] and cache._flights == {}
         # and the same answers with nothing resident at all

@@ -138,24 +138,29 @@ def _group_by(agg: str, ds: str, skip_rack: int | None) -> dict:
 
 
 def _check(rows: list, sub: dict, vals: np.ndarray, start: int,
-           end: int, interval_s: int, skip_rack: int | None) -> None:
+           end: int, interval_s: int, skip_rack, cadence: int = CADENCE,
+           **rate) -> None:
     """One sub-query's rows against the oracle over ``vals``
-    ([series, steps] from ``start`` at the cadence, NaN where absent)."""
+    ([series, steps] from ``start`` at the cadence, NaN where absent);
+    ``skip_rack`` a rack or several left out; ``rate``: the oracle's
+    ``rate_kwargs`` of a sub-query that asks a rate."""
     got = {r["tags"]["dc"]: {int(t): v for t, v in r["dps"].items()}
            for r in rows}
-    ts_ms = (start + np.arange(vals.shape[1]) * CADENCE) * 1000
+    ts_ms = (start + np.arange(vals.shape[1]) * cadence) * 1000
+    skipped = () if skip_rack is None else np.atleast_1d(skip_rack)
     want_groups = 0
     for dc in range(DCS):
         members = []
         for i in range(dc, SERIES, DCS):
-            if skip_rack is not None and i % RACKS == skip_rack:
+            if i % RACKS in skipped:
                 continue
             keep = ~np.isnan(vals[i])
             if keep.any():
                 members.append((ts_ms[keep], vals[i][keep]))
         want = run_oracle(members, sub["aggregator"],
                           interval_s * 1000, "avg", start * 1000,
-                          end * 1000) if members else {}
+                          end * 1000, rate=bool(rate),
+                          rate_kwargs=rate) if members else {}
         want = {t // 1000: v for t, v in want.items()
                 if not np.isnan(v)}
         if not want:
@@ -284,6 +289,145 @@ def test_answers_stay_exact_and_acknowledged_points_are_read_back(
     assert after["tsd.storage.series.count"] \
         == before["tsd.storage.series.count"]    # appends only
     reader.close()
+
+
+# -- the 1M-shaped cell at small size (fleet-1m-live.wide-ingest) -----
+
+FLEET_CADENCE = 60
+FLEET_POINTS = 60                       # the hour
+FLEET_END = T0 + FLEET_POINTS * FLEET_CADENCE - 1
+FLEET_HEAD = FLEET_END + 1
+FLEET_PER_BODY = 20
+
+
+def _fleet_request(racks: tuple) -> dict:
+    """The north-star request: two racks left out, so every request
+    is distinct."""
+    sub = _group_by("sum", "5m-avg", None)
+    sub["filters"].append({
+        "type": "not_literal_or", "tagk": "rack",
+        "filter": "|".join(f"r{r}" for r in racks), "groupBy": False})
+    sub.update(rate=True, rateOptions={"counter": True,
+                                       "counterMax": 10000})
+    return sub
+
+
+def _ask_fleet(conn, vals: np.ndarray, racks: tuple) -> None:
+    sub = _fleet_request(racks)
+    status, _h, raw = _exchange(conn, "POST", "/api/query", {
+        "start": T0 * 1000, "end": FLEET_END * 1000, "queries": [sub]})
+    assert status == 200, raw[:300]
+    _check(json.loads(raw), sub, vals, T0, FLEET_END, 300, racks,
+           FLEET_CADENCE, counter=True, counter_max=10000)
+
+
+@pytest.mark.parametrize("backend", ["native", "memory"])
+def test_head_writes_beside_the_north_star_request_keep_its_grid(
+        tmp_path, backend):
+    """``fleet-1m-live.wide-ingest`` at small size: the hour of a
+    counter a minute apart, ``sum:5m-avg:rate`` by dc with two racks
+    left out, asked back to back while four writers post bodies of one
+    new point a series at the head of the store, a second after the
+    window's end. Every answer is the oracle's, every acknowledged
+    point is read back, and behind the first request no write costs a
+    reader its window: the metric's grid is built once (PR 51: the
+    store says where the writes since the entry's version landed)."""
+    s = Served(_tsdb(tmp_path / "data", **{
+        "tsd.storage.backend": backend,
+        # the small grid's tail on the device's branch, as at 1M
+        "tsd.query.host_tail_max_cells_linear": "-1"}))
+    try:
+        rng = np.random.default_rng(51)
+        vals = rng.integers(1000, 999900,
+                            size=(SERIES, FLEET_POINTS)) / 100.0
+        vals[rng.random(vals.shape) < 0.02] = np.nan
+        lines = []
+        for i in range(SERIES):
+            tags = " ".join(f"{k}={v}" for k, v in _tags(i).items())
+            for j in np.flatnonzero(~np.isnan(vals[i])).tolist():
+                lines.append(f"{METRIC} {T0 + j * FLEET_CADENCE} "
+                             f"{vals[i, j]:.2f} {tags}\n")
+        written, errors = s.tsdb.import_buffer("".join(lines).encode(),
+                                               durable=False)
+        assert not errors and written == int((~np.isnan(vals)).sum())
+        reader = s.connect()
+        _ask_fleet(reader, vals, (0, 1))      # builds the grid, once
+        cache = s.tsdb.device_grid_cache
+        built = cache.misses
+        writers, blocks = 4, SERIES // FLEET_PER_BODY
+        cents = rng.integers(1000, 999900, size=(blocks,
+                                                 FLEET_PER_BODY))
+        acked: list[int] = []
+        failures: list[str] = []
+        answers = [0]
+        go = threading.Barrier(writers + 1)
+
+        def writer(k: int) -> None:
+            conn = s.connect()
+            try:
+                go.wait(30)
+                for block in range(k, blocks, writers):
+                    ids = range(block * FLEET_PER_BODY,
+                                (block + 1) * FLEET_PER_BODY)
+                    status, _h, raw = _exchange(conn, "POST", "/api/put", [
+                        {"metric": METRIC, "timestamp": FLEET_HEAD,
+                         "value": int(c) / 100.0, "tags": _tags(i)}
+                        for i, c in zip(ids, cents[block])])
+                    if status != 204:
+                        failures.append(f"{status}: {raw[:200]!r}")
+                        return
+                    acked.append(block)
+                    # a request between two bodies of this writer
+                    seen = answers[0]
+                    while answers[0] == seen and block + writers \
+                            < blocks and not failures:
+                        threading.Event().wait(0.002)
+            except Exception as e:  # noqa: BLE001 - reported below
+                failures.append(repr(e))
+            finally:
+                conn.close()
+
+        threads = [threading.Thread(target=writer, args=(k,))
+                   for k in range(writers)]
+        for th in threads:
+            th.start()
+        go.wait(30)
+        pair = 0
+        try:
+            while any(th.is_alive() for th in threads):
+                pair += 1
+                _ask_fleet(reader, vals, (pair % RACKS,
+                                          (pair * 7 + 3) % RACKS))
+                answers[0] += 1
+        except BaseException as e:
+            failures.append(repr(e))
+            raise
+        finally:
+            for th in threads:
+                th.join(60)
+        assert not failures, failures[:3]
+        assert sorted(acked) == list(range(blocks))
+        assert answers[0] >= blocks // writers - 1
+        _ask_fleet(reader, vals, (2, 9))
+        # no look-up missed after the first request's: every one that
+        # met a newer version kept the window's grid
+        assert cache.misses == built
+        assert cache.stale_dropped == 0
+        assert cache.stale_kept >= blocks // writers - 1
+        # every acknowledged point, read back
+        sub = _group_by("sum", f"{FLEET_CADENCE}s-avg", None)
+        status, _h, raw = _exchange(reader, "POST", "/api/query", {
+            "start": FLEET_HEAD * 1000,
+            "end": (FLEET_HEAD + FLEET_CADENCE - 1) * 1000,
+            "queries": [sub]})
+        assert status == 200, raw[:300]
+        _check(json.loads(raw), sub, (cents / 100.0).reshape(-1, 1),
+               FLEET_HEAD, FLEET_HEAD + FLEET_CADENCE - 1,
+               FLEET_CADENCE, None, FLEET_CADENCE)
+        reader.close()
+    finally:
+        s.stop()
+        s.tsdb.shutdown()
 
 
 def _reopened_points(copy_dir) -> dict:

@@ -310,7 +310,7 @@ def test_the_spans_and_counters_say_which_columns_hit(fleet):
     grids = [e for k, e in t.device_grid_cache._entries.items()
              if k[0] == engine_mod.RESIDENT_GRID_KEY]
     assert len(grids) == 2
-    for _, arrays, meta, _ in grids:
+    for _, arrays, meta, *_ in grids:
         assert len(arrays) == 2 and arrays[0].shape == (24, 12)
         assert set(meta) == {"counts"}
     cache = t.device_grid_cache
@@ -321,7 +321,10 @@ def test_the_spans_and_counters_say_which_columns_hit(fleet):
 def test_a_write_between_two_requests_drops_the_columns(fleet):
     """And the acknowledged point is read back: into a bucket that lay
     whole in both windows, whose column the second request would
-    otherwise have reused."""
+    otherwise have reused. Since PR 51 the columns of the buckets
+    that end BEFORE the write's timestamp stay (the store says what
+    the oldest timestamp written since their version is); the bucket
+    it landed in and every later one are built again."""
     t = fleet
     fresh = _tsdb()
     _seed(fresh)
@@ -335,7 +338,9 @@ def test_a_write_between_two_requests_drops_the_columns(fleet):
     misses = cache.misses
     after = _dps(t.execute_query(_q(end - 10 * MIN + 1000, end + 1000,
                                     "max", "1m-max", False)))
-    assert cache.misses == misses + 1 + 9     # every column again
+    # the window, minute 6 and the four after it; minutes 2-5 stay
+    assert cache.misses == misses + 1 + 5
+    assert (cache.stale_kept, cache.stale_dropped) == (4, 5)
     assert after == _dps(fresh.execute_query(_q(
         end - 10 * MIN + 1000, end + 1000, "max", "1m-max", False)))
     row = dict(next(dps for tags, dps in after if tags == {"dc": "d1"}))
